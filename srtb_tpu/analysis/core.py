@@ -240,7 +240,7 @@ class ModuleSource:
 
 def _jit_callee(call: ast.Call, mod: ModuleSource) -> bool:
     return mod.resolves_to(call.func, "jax.jit", "jax.api.jit",
-                           "jax._src.api.jit", "jax.pjit")
+                           "jax.pjit")
 
 
 def _donated_positions(call: ast.Call):

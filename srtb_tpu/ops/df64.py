@@ -26,25 +26,6 @@ _SPLITTER = np.float32(4097.0)  # 2^12 + 1 for f32 Dekker splitting
 # optimization_barrier makes the intermediate opaque to the simplifier.
 _ob = jax.lax.optimization_barrier
 
-# Older jax has no batching rule for optimization_barrier, which breaks
-# any vmap over df64 code (micro-batched segments, DM-grid trials under
-# shard_map).  The barrier is shape-identity per operand, so the rule is
-# trivial: bind and pass the batch dims through unchanged.
-try:
-    from jax._src.lax import lax as _lax_internal
-    from jax.interpreters import batching as _batching
-
-    _ob_p = getattr(_lax_internal, "optimization_barrier_p", None)
-    if _ob_p is not None and _ob_p not in _batching.primitive_batchers:
-        def _ob_batcher(args, dims):
-            outs = _ob_p.bind(*args)
-            if not isinstance(outs, (list, tuple)):
-                outs = [outs]
-            return outs, dims
-        _batching.primitive_batchers[_ob_p] = _ob_batcher
-except ImportError:  # pragma: no cover - newer jax: rule ships built in
-    pass
-
 
 def two_sum(a, b):
     """Error-free sum: a + b = s + e exactly."""

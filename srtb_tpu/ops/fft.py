@@ -514,7 +514,7 @@ def finish_rfft_subbyte(a: jnp.ndarray,
 # Threshold (packed C2C length, = n/2) above which the segment R2C
 # switches to the four-step path.  Tuned on a v5e: the monolithic XLA R2C
 # works and wins through n = 2^29; at n = 2^30 XLA's compile OOMs
-# (PERF_TPU.jsonl n2_29/n2_30 A/Bs), so only 2^30+ takes the four-step.
+# (not re-measured on this JAX), so only 2^30+ takes the four-step.
 LARGE_FFT_THRESHOLD = 1 << 28
 
 

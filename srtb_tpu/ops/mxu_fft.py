@@ -47,8 +47,7 @@ _RADIX = 128
 # 6-pass bf16 by default; SRTB_MXU_PRECISION=high selects 3-pass bf16
 # (pallas_fft runs 3-pass at even longer contractions with ~1e-6
 # relative error on chip) — the accuracy x throughput A/B at this
-# radix is probed on hardware by tools_tpu_r3_queue.sh before any
-# default flip.  Read at trace time.
+# radix needs a hardware probe before any default flip.  Read at trace time.
 def _precision():
     import os
     return (jax.lax.Precision.HIGH

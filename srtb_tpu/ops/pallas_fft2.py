@@ -4,8 +4,8 @@ two kernel passes plus one fusable transpose.
 The existing "pallas" strategy runs the four-step legs (ops/pallas_fft)
 inside XLA's decomposition: transpose, leg FFT, twiddle multiply,
 transpose, leg FFT, transpose — each arrow a full HBM pass, ~6 round
-trips for the C2C (measured 1481 vs monolithic's 1746 Msamples/s at
-2^27, PERF_TPU.jsonl).  This module fuses each leg's surrounding
+trips for the C2C (slower than monolithic at 2^27 on the July record;
+not re-measured on this JAX).  This module fuses each leg's surrounding
 layout work *into the leg's kernel* so the C2C is two passes total:
 
   pass 1 (grid over j2 column blocks of z viewed [n1, n2] row-major):
@@ -674,8 +674,7 @@ def unblock(y: jnp.ndarray, m: int) -> jnp.ndarray:
 # spectrum tail (Hermitian + RFI s1 + chirp) as pass 2's epilogue.
 # ==================================================================
 
-# Pending on-chip Mosaic validation (tools_tpu_r9_queue.sh "ffuse
-# probe" legs, then flip to True): the front kernels use the sub-byte
+# Pending on-chip Mosaic validation (then flip to True): the front kernels use the sub-byte
 # lane interleave ops/pallas_kernels.UNPACK_MOSAIC_OK documents as
 # unlowerable today, plus strided lane de-interleaves, an in-kernel
 # minor-lb flatten (_row_fft_block) and a lane flip/roll — every one

@@ -775,8 +775,7 @@ def unpack_subbyte_planes_window(data: jnp.ndarray, nbits: int,
     return out.reshape(count, m)
 
 
-# Pending on-chip Mosaic validation (run tools_tpu_r3_queue.sh section
-# "planes unpack probe", then flip to True): the spelling avoids every
+# Pending on-chip Mosaic validation (then flip to True): the spelling avoids every
 # construct the sample-order kernel died on, but Mosaic acceptance is
 # only provable by compiling on a real chip.  SRTB_PALLAS_PLANES_UNPACK=1
 # opts in before that.

@@ -27,7 +27,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from srtb_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from srtb_tpu.ops.fft import _fft_minor, _phase_exp, pack_even_odd

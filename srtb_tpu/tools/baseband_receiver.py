@@ -9,11 +9,9 @@ from srtb_tpu.config import Config
 from srtb_tpu.io.udp import UdpReceiverSource
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.termination import install_termination_handler
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     install_termination_handler()
     cfg = Config.from_args(argv)
     src = UdpReceiverSource(cfg)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from srtb_tpu.utils.logging import log
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 # module-level jit (srtb-lint recompile-hazard caught the old
@@ -46,7 +45,6 @@ def correlate(x1: np.ndarray, x2: np.ndarray) -> np.ndarray:
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     argv = sys.argv[1:] if argv is None else argv
     in_file_1 = argv[0] if len(argv) > 0 else "pol_1.bin"
     in_file_2 = argv[1] if len(argv) > 1 else "pol_2.bin"

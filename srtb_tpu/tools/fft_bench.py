@@ -19,7 +19,6 @@ import sys
 import time
 
 import numpy as np
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def bench_one(n: int, strategy: str, reps: int = 5) -> float | None:
@@ -46,7 +45,6 @@ def bench_one(n: int, strategy: str, reps: int = 5) -> float | None:
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     argv = sys.argv[1:] if argv is None else argv
     lo = int(argv[0]) if len(argv) > 0 else 20
     hi = int(argv[1]) if len(argv) > 1 else 27

@@ -19,11 +19,9 @@ from srtb_tpu.ops import dedisperse as dd
 from srtb_tpu.pipeline.runtime import Pipeline
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.termination import install_termination_handler
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     install_termination_handler()
     cfg = Config.from_args(argv)
     if cfg.distributed_num_processes > 1:

@@ -22,11 +22,9 @@ import sys
 from srtb_tpu.io.synth import make_dispersed_baseband
 from srtb_tpu.utils.expression import parse_expression
 from srtb_tpu.utils.logging import log
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--out", required=True)
     p.add_argument("--n", default="2 ** 22",

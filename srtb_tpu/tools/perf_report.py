@@ -4,9 +4,9 @@ Groups records by (metric unit, shape, plan) and prints each group's
 time-ordered trajectory — value, platform, git sha, host fingerprint,
 compile time and roofline fraction where recorded — as markdown
 tables (default) or one JSON document.  This is the queryable form of
-the history PERF.md narrates and BENCH_r0*.json only hints at; seed
-it with ``python -m srtb_tpu.tools.perf_ledger LEDGER --import
-BENCH_r0*.json``.
+the history PERF.md narrates; legacy per-round bench artifacts can be
+backfilled with ``python -m srtb_tpu.tools.perf_ledger LEDGER --import
+FILES``.
 
 Usage: python -m srtb_tpu.tools.perf_report LEDGER.jsonl
            [--format md|json] [--source bench,import,...]

@@ -16,7 +16,6 @@ from __future__ import annotations
 import json
 import sys
 
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def plot(trials_path: str, out_path: str | None = None) -> str:
@@ -53,7 +52,6 @@ def plot(trials_path: str, out_path: str | None = None) -> str:
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     argv = sys.argv[1:] if argv is None else argv
     if not argv:
         print(__doc__, file=sys.stderr)

@@ -11,7 +11,6 @@ import glob
 import sys
 
 import numpy as np
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def plot_one(path: str) -> str:
@@ -41,7 +40,6 @@ def plot_one(path: str) -> str:
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     argv = sys.argv[1:] if argv is None else argv
     paths = []
     for pattern in (argv or ["*.npy"]):

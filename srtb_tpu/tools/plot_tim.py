@@ -9,11 +9,9 @@ import glob
 import sys
 
 import numpy as np
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     argv = sys.argv[1:] if argv is None else argv
     paths = []
     for pattern in (argv or ["*.tim"]):

@@ -61,8 +61,6 @@ def main(argv=None) -> int:
                   f" {msg}", file=sys.stderr, flush=True)
     t_start = time.monotonic()
 
-    from srtb_tpu.utils.platform import apply_platform_env
-    apply_platform_env()
     import numpy as np
 
     ou = _import_oracle()

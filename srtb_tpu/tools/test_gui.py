@@ -24,7 +24,6 @@ import numpy as np
 
 from srtb_tpu.config import Config
 from srtb_tpu.utils.logging import log
-from srtb_tpu.utils.platform import apply_platform_env
 
 
 def synthetic_frame(n_freq: int, n_time: int, seed: int,
@@ -51,7 +50,6 @@ def synthetic_frame(n_freq: int, n_time: int, seed: int,
 
 
 def main(argv=None) -> int:
-    apply_platform_env()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--out", default="test_gui_out")
     p.add_argument("--frames", type=int, default=8)

@@ -102,15 +102,10 @@ class AotPlanCache:
             # single-device programs, and the default (all local
             # devices) makes the loaded executable demand one shard
             # per device on multi-device hosts (e.g. the forced
-            # 8-device CPU test platform).  Older jax releases do not
-            # take the kwarg — fall back to the default placement
-            # (single-device hosts are unaffected).
-            try:
-                compiled = deserialize_and_load(
-                    blob, in_tree, out_tree,
-                    execution_devices=[jax.devices()[0]])
-            except TypeError:
-                compiled = deserialize_and_load(blob, in_tree, out_tree)
+            # 8-device CPU test platform)
+            compiled = deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[jax.devices()[0]])
             log.info(f"[aot_cache] loaded {name} from {path}")
             self._count("aot_cache_hits")
             return compiled

@@ -1,7 +1,7 @@
 """Append-only perf ledger: every measurement becomes queryable history.
 
 The repo's perf trajectory lived in two places that don't compose:
-hand-written PERF.md rounds and driver-captured ``BENCH_r0*.json``
+hand-written PERF.md rounds and driver-captured per-round bench
 artifacts — neither queryable, neither keyed well enough to compare
 apples to apples across hosts and commits.  The ledger is one JSONL
 file of structured records keyed by the four things that make a perf
@@ -18,7 +18,7 @@ number comparable:
 Writers: ``bench.py`` (``SRTB_PERF_LEDGER=path``), steady-state
 pipeline runs (``Config.perf_ledger_path`` — one record per run at
 drain end), ``tools/perf_gate.py`` captures, and
-``tools/perf_ledger.py --import`` (the legacy BENCH_r0*.json
+``tools/perf_ledger.py --import`` (the legacy per-round artifact
 backfill).  Reader: ``tools/perf_report.py`` renders the trajectory.
 
 Records carry ``samples_s`` (per-rep seconds) when the producer has

@@ -115,20 +115,40 @@ def test_ledger_roundtrip_and_record_fields(tmp_path):
     assert len(PL.load(path)) == 1
 
 
+def _write_legacy_bench(tmp_path) -> str:
+    """Five legacy-format bench-round files (one JSON object per round:
+    n / cmd / rc / tail / parsed), two failed rounds and three measured
+    ones — synthetic values, the shape of the retired per-round
+    artifacts.  Returns the glob that matches them."""
+    cmd = "python bench.py"
+    for n, value in enumerate((None, None, 100.0, 150.0, 175.0), 1):
+        doc = {"n": n, "cmd": cmd, "rc": 0 if value else 1,
+               "tail": "" if value else "Traceback ..."}
+        if value:
+            doc["parsed"] = {
+                "metric": "coherent_dedispersion_pipeline_throughput",
+                "value": value, "unit": "Msamples/s/chip",
+                "vs_baseline": value / 128.0, "platform": "tpu",
+                "log2n": 27, "segment_time_s": 134.2 / value,
+                "compile_s": 20.0, "roofline_frac": 0.05,
+                "pass": True}
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
+    return str(tmp_path / "BENCH_r0*.json")
+
+
 def test_legacy_bench_import_idempotent(tmp_path):
-    """Satellite: the legacy BENCH_r0*.json artifacts (the REAL ones
-    checked into this repo) import into the ledger, failed rounds
-    included as value-0 outage records, and a re-import is a no-op."""
+    """Legacy per-round bench artifacts import into the ledger, failed
+    rounds included as value-0 outage records, and a re-import is a
+    no-op."""
     from srtb_tpu.tools import perf_ledger as CLI
     path = str(tmp_path / "led.jsonl")
-    pat = os.path.join(REPO, "BENCH_r0*.json")
-    assert glob.glob(pat), "legacy BENCH artifacts missing from repo"
+    pat = _write_legacy_bench(tmp_path)
     assert CLI.main([path, "--import", pat]) == 0
     recs = PL.load(path)
-    assert len(recs) == len(glob.glob(pat))
+    assert len(recs) == len(glob.glob(pat)) == 5
     measured = [r for r in recs if r["value"] > 0]
     failed = [r for r in recs if r["value"] == 0]
-    assert measured and failed  # the repo history holds both kinds
+    assert measured and failed  # the history holds both kinds
     assert all(r["source"] == "import" for r in recs)
     # provenance honesty: the importer's host/git must not be stamped
     assert all(r["host_fp"] == "" and r["git_sha"] == "" for r in recs)
@@ -142,7 +162,7 @@ def test_perf_report_renders_trajectory(tmp_path, capsys):
     from srtb_tpu.tools import perf_ledger as CLI
     from srtb_tpu.tools import perf_report as PR
     path = str(tmp_path / "led.jsonl")
-    CLI.main([path, "--import", os.path.join(REPO, "BENCH_r0*.json")])
+    CLI.main([path, "--import", _write_legacy_bench(tmp_path)])
     capsys.readouterr()
     assert PR.main([path, "--format", "json"]) == 0
     doc = json.loads(capsys.readouterr().out)
@@ -256,14 +276,27 @@ def _obs_cfg(tmp_path, n, **kw):
         baseband_reserve_sample=False, writer_thread_count=0, **kw)
 
 
-def test_device_accounting_v8_spans_and_gauges(tmp_path):
+@pytest.mark.parametrize("peak_known", [True, False])
+def test_device_accounting_v8_spans_and_gauges(tmp_path, monkeypatch,
+                                               peak_known):
     """Every drained segment of the async engine journals device_ms +
-    roofline_frac + achieved_msamps (v8) plus the cumulative
-    compile/cache books, and the live gauges + device_seconds
-    histogram land on /metrics — with per-stream labeled twins for a
-    named lane."""
+    achieved_msamps (v8) plus the cumulative compile/cache books, and
+    the live gauges + device_seconds histogram land on /metrics — with
+    per-stream labeled twins for a named lane.  roofline_frac rides
+    along only where the device's kind is in the HBM peak table
+    (utils/platform.HBM_PEAK_GBPS): on any other device — this CPU
+    included — there is no span field and no gauge, never another
+    chip's peak."""
+    import jax
+
     from srtb_tpu.pipeline.runtime import Pipeline
     from srtb_tpu.tools import telemetry_report as TR
+    from srtb_tpu.utils import platform
+    peak = 819.0
+    kind = jax.devices()[0].device_kind
+    assert platform.hbm_peak_gbps(kind) is None  # the CPU has no roof
+    if peak_known:
+        monkeypatch.setitem(platform.HBM_PEAK_GBPS, kind, peak)
     n = 1 << 13
     journal = str(tmp_path / "j.jsonl")
     cfg = _obs_cfg(tmp_path, n, segments=4, inflight_segments=2,
@@ -278,7 +311,8 @@ def test_device_accounting_v8_spans_and_gauges(tmp_path):
     for r in recs:
         assert r["v"] == 11
         assert r["device_ms"] > 0
-        assert r["roofline_frac"] > 0 and r["achieved_msamps"] > 0
+        assert r["achieved_msamps"] > 0
+        assert ("roofline_frac" in r) == peak_known
         assert r["aot_cache_hits"] == 0 and r["aot_cache_misses"] == 0
     # first dispatch = the run's one (lazy-jit) compile event, and the
     # named span carries the stream's OWN labeled books
@@ -289,22 +323,26 @@ def test_device_accounting_v8_spans_and_gauges(tmp_path):
     # device_ms is concurrent, never inside the host stage sum
     assert "device" not in recs[0]["stages_ms"]
     # live gauges + labeled twins
-    for g in ("roofline_frac", "achieved_msamps", "achieved_gbps"):
+    for g in ("achieved_msamps", "achieved_gbps"):
         assert metrics.get(g) > 0
         assert metrics.get(g, labels={"stream": "beam7"}) > 0
     prom = metrics.prometheus()
     assert "# TYPE srtb_device_seconds histogram" in prom
-    assert 'srtb_roofline_frac{stream="beam7"}' in prom
     assert 'srtb_plan_compiles{stream="beam7"}' in prom
-    # roofline sanity: the gauge equals the plan-floor model over the
-    # journaled device wall (lower-bound contract)
-    proc = pipe.processor
-    model_bytes = proc._segment_bytes + 8.0 * proc.n_spectrum \
-        * proc.hbm_passes
-    last = recs[-1]
-    expect = model_bytes / (last["device_ms"] / 1e3) / 1e9 \
-        / cfg.hbm_peak_gbps
-    assert abs(last["roofline_frac"] - expect) < 0.05 * expect + 1e-4
+    assert ('srtb_roofline_frac{stream="beam7"}' in prom) == peak_known
+    if peak_known:
+        assert metrics.get("roofline_frac") > 0
+        # roofline sanity: the gauge equals the plan-floor model over
+        # the journaled device wall (lower-bound contract)
+        proc = pipe.processor
+        model_bytes = proc._segment_bytes + 8.0 * proc.n_spectrum \
+            * proc.hbm_passes
+        last = recs[-1]
+        expect = model_bytes / (last["device_ms"] / 1e3) / 1e9 / peak
+        assert abs(last["roofline_frac"] - expect) \
+            < 0.05 * expect + 1e-4
+    else:
+        assert metrics.get("roofline_frac") == 0  # never set
     # report surfaces the device section
     rep = TR.report(journal)
     assert rep["device"]["records"] == 4

@@ -161,7 +161,7 @@ class TestFlags:
         """A program that genuinely lowers f64 ops must be flagged (the
         drift the dtype-drift lint rule guards at source level, proven
         at artifact level here)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             f = jax.jit(lambda x: x * 2.0 + 1.0)
             aval = jax.ShapeDtypeStruct((4096,), jnp.float64)
             prog = HA.audit_program(f, (aval,), (), 8 * 4096)

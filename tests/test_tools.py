@@ -407,88 +407,6 @@ def test_plot_dm_curve(tmp_path):
     assert data[:8] == b"\x89PNG\r\n\x1a\n"
 
 
-def test_queue_decisions(tmp_path):
-    """The hardware queue's decision tree, evaluated from rows: FLIP
-    when the data clears the documented bars, KEEP otherwise, and no
-    crash on error rows / missing variants."""
-    import json
-
-    from srtb_tpu.tools import queue_decisions as QD
-
-    rows = [
-        {"variant": "pallas2_mosaic_probe_24", "rc": 0,
-         "result": {"probe": "pallas2_mosaic", "ok": True}},
-        {"variant": "pallas2_mosaic_probe_29", "rc": 0,
-         "result": {"probe": "pallas2_mosaic", "ok": True}},
-        {"variant": "baseline", "result": {"value": 1746.0,
-                                           "segment_time_s": 0.0769}},
-        {"variant": "pallas2", "result": {"value": 2500.0,
-                                          "segment_time_s": 0.054}},
-        {"variant": "n2_30_pallas2", "result": {"value": 900.0,
-                                                "segment_time_s": 1.2}},
-        {"variant": "pallas_sk", "result": {"value": 1500.0}},
-        {"variant": "cache_warm", "result": {"compile_s": 4.0}},
-        {"variant": "mxu_precision_probe_highest",
-         "result": {"prec": "highest", "rel_err": 4e-7, "ms": 9.0}},
-        {"variant": "mxu_precision_probe_high",
-         "result": {"prec": "high", "rel_err": 1.1e-6, "ms": 4.4}},
-        {"variant": "planes_unpack_mosaic_probe", "rc": 1, "result": None},
-        {"variant": "note", "note": "irrelevant"},
-    ]
-    perf = tmp_path / "perf.jsonl"
-    perf.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    out = tmp_path / "DECISIONS.md"
-    rc = QD.main(["--perf", str(perf), "--out", str(out)])
-    assert rc == 0
-    decisions = {d["decision"]: d
-                 for d in QD.evaluate(QD.load_rows(str(perf)))}
-    assert decisions["pallas2 auto-default"]["verdict"] == "FLIP"
-    assert decisions["2^30 default plan"]["verdict"] == "FLIP"
-    assert "n2_30_pallas2" in decisions["2^30 default plan"]["evidence"]
-    # (the dense-vs-classic rows-helper decision retired in round 5:
-    # one legal Mosaic spelling remains, so no flip to evaluate)
-    assert "pallas rows helper default" not in decisions
-    assert decisions["PLANES_UNPACK_MOSAIC_OK"]["verdict"] == "KEEP False"
-    assert decisions["warm restart"]["verdict"] == "MET"
-    assert decisions["SRTB_MXU_PRECISION default"]["verdict"] \
-        == "FLIP to high"
-    text = out.read_text()
-    assert "pallas2 auto-default" in text and "| FLIP |" in text
-    # empty log -> explicit no-data row, rc still 0
-    empty = tmp_path / "empty.jsonl"
-    empty.write_text("")
-    assert QD.evaluate(QD.load_rows(str(empty)))[0]["verdict"] == "NO DATA"
-
-
-def test_queue_decisions_failed_and_aot_rows(tmp_path):
-    """Round-5 review hardening: AOT warm verdicts require the cache to
-    have actually engaged (aot_active); a failed (0.0) bench row is
-    present evidence, never a flip justification (the rows-helper A/B
-    that exercised that rule is retired — one Mosaic spelling remains)."""
-    import json
-
-    from srtb_tpu.tools import queue_decisions as QD
-
-    rows = [
-        # a failed bench row must not create spurious decisions
-        {"variant": "pallas_sk", "result": {"value": 0.0}},
-        # aot_warm fast but the cache never engaged -> INVALID
-        {"variant": "aot_warm", "result": {"compile_s": 1.0,
-                                           "aot_active": False}},
-        # aot_warm_30 engaged and fast -> MET
-        {"variant": "aot_warm_30", "result": {"compile_s": 6.0,
-                                              "aot_active": True}},
-    ]
-    perf = tmp_path / "perf.jsonl"
-    perf.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    decisions = {d["decision"]: d
-                 for d in QD.evaluate(QD.load_rows(str(perf)))}
-    assert "pallas rows helper default" not in decisions
-    assert decisions["AOT warm restart (2^27)"]["verdict"].startswith(
-        "INVALID")
-    assert decisions["AOT warm restart (2^30 staged)"]["verdict"] == "MET"
-
-
 def test_pallas2_pin_loud_at_dispatch(monkeypatch):
     """An SRTB_PALLAS2_N1 pin that cannot fit the actual segment size
     must fail loudly at the dispatch fallback (ops/fft and the staged

@@ -1,0 +1,157 @@
+"""Ask the chip's compiler without a chip: the served plans and the
+Pallas kernels AOT-compiled for a described (not attached) v5e.
+
+Interpret mode and XLA:CPU accept programs the TPU compiler refuses —
+a slice off the tiling, too much VMEM, a program that does not fit
+16 GB of HBM.  These cases cost no chip time and guard every later PR.
+Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import, never in a skipif/parametrize): only one process may load
+libtpu, and under xdist every worker imports every test file.  All the
+cases live in this one file so one worker owns the library; the
+compiles run in the test's own process with the persistent compilation
+cache off (an entry written for a described device cannot be read back
+without one).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from srtb_tpu.config import Config
+
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _j1644(log2n: int, **over) -> Config:
+    """The J1644-4559 served configuration (chip_smoke.py's)."""
+    kw = dict(
+        baseband_input_count=1 << log2n,
+        baseband_input_bits=2,
+        baseband_format_type="simple",
+        baseband_freq_low=1405.0 + 32.0,
+        baseband_bandwidth=-64.0,
+        baseband_sample_rate=128e6,
+        dm=-478.80 * min(1.0, 2.0 ** (log2n - 27)),
+        spectrum_channel_count=1 << 11,
+        signal_detect_signal_noise_threshold=8.0,
+        mitigate_rfi_spectral_kurtosis_threshold=1.05,
+        baseband_reserve_sample=True,
+    )
+    kw.update(over)
+    return Config(**kw)
+
+
+def _compile_all(proc, one_chip) -> dict:
+    """Compile every program of the plan for the described chip;
+    {name: compiled}."""
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    out = {}
+    for name, fn, avals, _donated in proc.lowerables():
+        out[name] = fn.lower(*jax.tree.map(on_chip, avals)).compile()
+    return out
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return m.temp_size_in_bytes + m.argument_size_in_bytes
+
+
+def test_j1644_default_plan_compiles_and_fits(one_chip):
+    """2^27 samples / 2^11 channels, default plan with the overlap-save
+    ring: ``fused``, ``ring`` and ``ring_cold`` all compile, each within
+    one v5e's 16 GB."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    proc = SegmentProcessor(_j1644(27), donate_input=True)
+    assert proc.ring and not proc.staged
+    compiled = _compile_all(proc, one_chip)
+    assert set(compiled) == {"fused", "ring", "ring_cold"}
+    for name, c in compiled.items():
+        assert _device_bytes(c) < V5E_HBM_BYTES, (name,
+                                                  c.memory_analysis())
+
+
+def test_j1644_pallas_plan_lowers_through_mosaic(one_chip, monkeypatch):
+    """The same configuration at 2^24 with the Pallas RFI+chirp,
+    row-FFT and SK-zap kernels: steered onto the non-interpret path in
+    the test (the program has no option for it), every program must
+    carry a Mosaic custom call and compile."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+    from srtb_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "on_accelerator", lambda: True)
+    proc = SegmentProcessor(
+        _j1644(24, use_pallas=True, use_pallas_sk=True),
+        donate_input=True)
+    assert proc._pallas_interpret is False
+    compiled = _compile_all(proc, one_chip)
+    assert set(compiled) == {"fused", "ring", "ring_cold"}
+    for name, c in compiled.items():
+        assert "tpu_custom_call" in c.as_text(), name
+        assert _device_bytes(c) < V5E_HBM_BYTES, name
+
+
+def test_pallas_row_fft_at_waterfall_shape(one_chip):
+    """The Pallas row-FFT at the J1644 2^27 waterfall shape: 2048
+    channels x 2^15 time samples."""
+    from srtb_tpu.ops import pallas_fft
+
+    plane = jax.ShapeDtypeStruct((2048, 1 << 15), jnp.float32,
+                                 sharding=one_chip)
+    c = jax.jit(lambda re, im: pallas_fft.fft_rows_ri(
+        re, im, inverse=True, interpret=False)).lower(plane,
+                                                      plane).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < V5E_HBM_BYTES
+
+
+def test_staged_plan_three_programs_compile(one_chip):
+    """The staged three-program plan (the 2^30 production segment's) at
+    a size that compiles in seconds: ``fft_len_cap`` lowered so the
+    four-step recursion the big shape takes is the one compiled."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    proc = SegmentProcessor(
+        _j1644(20, spectrum_channel_count=1 << 8, fft_len_cap=1 << 9,
+               baseband_reserve_sample=False),
+        staged=True, donate_input=True)
+    assert proc.staged
+    compiled = _compile_all(proc, one_chip)
+    assert set(compiled) == {"stage_a", "stage_b", "stage_c"}
+    for name, c in compiled.items():
+        assert _device_bytes(c) < V5E_HBM_BYTES, name
