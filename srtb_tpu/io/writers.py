@@ -18,6 +18,7 @@ helpers (src/plot_spectrum.py, plot_tim.py) work unmodified:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -31,6 +32,7 @@ from srtb_tpu.config import Config
 from srtb_tpu.pipeline.work import (NO_UDP_PACKET_COUNTER, SegmentResultWork)
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
+from srtb_tpu.utils.tracing import span
 
 # crash consistency: candidate files are written to <path>.srtb_tmp
 # and atomically renamed into place, so a reader (or a restarted run)
@@ -267,6 +269,14 @@ class WriteSignalSink:
         # nothing — nothing to protect, and the common all-negative
         # observation keeps its WAL one record per segment)
         self.last_push_wrote = False
+        # the candidate path's spans (utils/tracing.span): the
+        # pipeline binds its StageTimer and takes, after each push,
+        # the seconds this sink spent in ``d2h`` / ``write`` /
+        # ``publish`` for the segment's journal record; a quiet push
+        # opens none
+        self.stage_timer = None
+        self._spans: dict[str, float] = {}
+        self._span_tid = 0
         # check directory writability up front (ref: write_signal_pipe.hpp:62-75)
         check_path = cfg.baseband_output_file_prefix + ".check"
         with open(check_path, "wb"):
@@ -280,6 +290,20 @@ class WriteSignalSink:
 
     def set_manifest_key(self, key) -> None:
         self._manifest_key = key
+
+    def bind_stage_timer(self, timer) -> None:
+        self.stage_timer = timer
+
+    @contextlib.contextmanager
+    def _span(self, name: str):
+        with span(name, self.stage_timer, self._span_tid) as sp:
+            yield
+        self._spans[name] = self._spans.get(name, 0.0) + sp.seconds
+
+    def take_spans(self) -> dict:
+        """Seconds per candidate-path stage since the last call."""
+        spans, self._spans = self._spans, {}
+        return spans
 
     # ------------------------------------------------------------------
 
@@ -296,6 +320,10 @@ class WriteSignalSink:
     def push(self, work: SegmentResultWork, has_signal: bool) -> None:
         """Feed one processed segment; writes to disk when warranted."""
         self.last_push_wrote = False
+        # a wait that a drain outside any push left behind (the
+        # checkpoint's flush) is in the timer, not in a later record
+        self._spans = {}
+        self._span_tid = getattr(work.segment, "trace_id", 0)
         real_time = self.cfg.input_file_path == ""
         w = self._overlap_window_ns()
         ts = work.segment.timestamp
@@ -379,8 +407,19 @@ class WriteSignalSink:
                 and self.pool is None:
             self._tx_staged = []
         try:
-            self._write_artifacts(work, base)
-            self._publish_staged()
+            wf = None
+            if work.waterfall is not None:
+                # the waterfall may still be device-resident (lazy
+                # sink-side transfer): fetch via the explicit D2H
+                # spelling so the sanitizer's transfer tripwire stays
+                # quiet on this sanctioned sync
+                from srtb_tpu.utils.platform import to_host
+                with self._span("d2h"):
+                    wf = to_host(work.waterfall)
+            with self._span("write"):
+                self._write_artifacts(work, base, wf)
+            with self._span("publish"):
+                self._publish_staged()
         except BaseException:
             self._tx_abort()
             raise
@@ -390,21 +429,17 @@ class WriteSignalSink:
         self._inflight_npy = {}
         log.info(f"[write_signal] finished writing, file_counter = {counter}")
 
-    def _write_artifacts(self, work: SegmentResultWork,
-                         base: str) -> None:
+    def _write_artifacts(self, work: SegmentResultWork, base: str,
+                         wf: np.ndarray | None) -> None:
+        """``wf``: the segment's waterfall already on the host, or
+        None where the segment has none."""
         bin_path = base + ".bin"
         self._write_bytes(bin_path,
                           np.ascontiguousarray(work.segment.data),
                           fsync=self.fdatasync)
 
         npy_paths = []
-        if work.waterfall is not None:
-            # the waterfall may still be device-resident (lazy sink-side
-            # transfer): fetch via the explicit D2H spelling so the
-            # sanitizer's transfer tripwire stays quiet on this
-            # sanctioned sync
-            from srtb_tpu.utils.platform import to_host
-            wf = to_host(work.waterfall)
+        if wf is not None:
             if wf.ndim == 4:  # stacked (re, im) boundary representation
                 wf = (wf[0] + 1j * wf[1]).astype(np.complex64)
             if wf.ndim == 2:
@@ -541,7 +576,13 @@ class WriteSignalSink:
         purpose.
         """
         if self.pool is not None:
-            self.pool.drain()
+            # the wait for the pool's writers is the candidate's
+            # write time too: it goes to the segment whose push (or a
+            # later sink of the same push) drains.  Nothing queued,
+            # no span: quiet segments pay nothing
+            with self._span("write") if self._assigned_paths \
+                    else contextlib.nullcontext():
+                self.pool.drain()
             self._assigned_paths.clear()
             self.pool.raise_new_errors(
                 f"candidate prefix {self.cfg.baseband_output_file_prefix}")

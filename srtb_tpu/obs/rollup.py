@@ -42,6 +42,7 @@ import zlib
 
 from srtb_tpu.obs.digest import QuantileDigest
 from srtb_tpu.obs.store import RollupStore
+from srtb_tpu.utils.telemetry import segment_wall
 
 CURSOR_NAME = "cursor.json"
 TMP_SUFFIX = ".srtb_tmp"
@@ -346,10 +347,10 @@ class Aggregator:
         if bw is not None:
             row["batch_waits_ms"] = round(
                 row["batch_waits_ms"] + float(bw), 3)
-        stage_sum = 0.0
-        for name, ms in (rec.get("stages_ms") or {}).items():
+        stages = rec.get("stages_ms") or {}
+        for name, ms in stages.items():
             self._digest(("stage", str(name))).add(float(ms))
-            stage_sum += float(ms)
+        stage_sum = float(segment_wall(stages))
         if stage_sum > 0.0:
             self._digest(("stage", "segment")).add(stage_sum)
         if plan and stage_sum > 0.0:

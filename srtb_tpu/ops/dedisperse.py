@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from srtb_tpu.ops import df64 as ds
+from srtb_tpu.ops import scopes as S
 
 # dispersion constant, MHz^2 pc^-1 cm^3 s (ref: coherent_dedispersion.hpp:67)
 D = 4.148808e3
@@ -82,6 +83,7 @@ def chirp_factor_host(n: int, f_min: float, df: float, f_c: float,
     return (np.cos(delta_phi) + 1j * np.sin(delta_phi)).astype(np.complex64)
 
 
+@S.scoped(S.CHIRP)
 def chirp_factor_df64(n: int, f_min: float, df: float, f_c: float, dm,
                       dtype=jnp.complex64, i0: int = 0,
                       dm_lo=None, exact: bool = False) -> jnp.ndarray:
@@ -112,6 +114,7 @@ def chirp_factor_host_ri(n: int, f_min: float, df: float, f_c: float,
     return np.stack([c.real, c.imag]).astype(np.float32)
 
 
+@S.scoped(S.CHIRP)
 def chirp_factor_df64_ri(n: int, f_min: float, df: float, f_c: float,
                          dm, i0: int = 0, dm_lo=None,
                          anchor_consts=None,
@@ -385,6 +388,7 @@ def spectrum_frequencies(cfg, n: int):
     return f_min, f_c, df
 
 
+@S.scoped(S.CHIRP)
 def dedisperse(spectrum: jnp.ndarray, chirp: jnp.ndarray) -> jnp.ndarray:
     """Apply the chirp: one complex multiply per channel
     (ref: coherent_dedispersion.hpp:223-248)."""

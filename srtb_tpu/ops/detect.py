@@ -27,11 +27,14 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from srtb_tpu.ops import scopes as S
+
 
 def _norm(c):
     return jnp.real(c) ** 2 + jnp.imag(c) ** 2
 
 
+@S.scoped(S.DETECT)
 def tree_sum_freq(power: jnp.ndarray) -> jnp.ndarray:
     """Sum ``power [..., K, T]`` over the frequency axis (-2) with an
     explicit pairwise (binary-tree) reduction: K -> K/2 -> ... -> 1.
@@ -127,6 +130,7 @@ def time_series_error_gates(k_ch: int, t_len: int, ts_raw_max: float,
     return ts_sum_gate, ts_prop_gate
 
 
+@S.scoped(S.DETECT)
 def tree_mean(ts: jnp.ndarray) -> jnp.ndarray:
     """Mean over the last axis via the pairwise tree (shape [..., 1]) —
     the single home of the mean-subtract spelling whose rounding the
@@ -146,6 +150,7 @@ def boxcar_lengths(max_boxcar_length: int, time_series_count: int) -> tuple:
     return tuple(lengths)
 
 
+@S.scoped(S.DETECT)
 def count_signal(x: jnp.ndarray, snr_threshold: float):
     """Count samples with x > threshold*sqrt(mean(x^2)), assuming mean(x)=0
     (ref: signal_detect.hpp:32-72).  Returns (count, peak_snr)."""
@@ -166,6 +171,7 @@ def trimmed_length(time_samples: int, time_reserved_count: int) -> int:
     return time_samples - time_reserved_count
 
 
+@S.scoped(S.DETECT)
 def detect(waterfall: jnp.ndarray, time_reserved_count: int,
            snr_threshold: float, max_boxcar_length: int) -> DetectResult:
     """Full detection chain on a frequency-major dynamic spectrum."""
@@ -182,6 +188,7 @@ def detect(waterfall: jnp.ndarray, time_reserved_count: int,
                                    max_boxcar_length)
 
 
+@S.scoped(S.DETECT)
 def detect_from_time_series(ts: jnp.ndarray, zero_count: jnp.ndarray,
                             snr_threshold: float,
                             max_boxcar_length: int) -> DetectResult:

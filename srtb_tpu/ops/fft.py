@@ -27,7 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from srtb_tpu.ops import scopes as S
 
+
+@S.scoped(S.FFT_R2C)
 def rfft_drop_nyquist(x: jnp.ndarray) -> jnp.ndarray:
     """R2C FFT of the whole segment, highest bin dropped: n real samples ->
     n/2 complex channels (ref: fft_pipe.hpp:44-78)."""
@@ -43,6 +46,7 @@ def c2c_backward(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     return jnp.fft.ifft(x, axis=axis, norm="forward")
 
 
+@S.scoped(S.WATERFALL)
 def waterfall_c2c(spectrum: jnp.ndarray, channel_count: int,
                   dewindow: jnp.ndarray | None = None,
                   len_cap: int | None = None) -> jnp.ndarray:
@@ -69,6 +73,7 @@ def waterfall_c2c(spectrum: jnp.ndarray, channel_count: int,
     return wf
 
 
+@S.scoped(S.WATERFALL)
 def ifft_refft_waterfall(spectrum: jnp.ndarray, channel_count: int,
                          nsamps_reserved_complex: int = 0,
                          window: jnp.ndarray | None = None,
@@ -290,6 +295,7 @@ def four_step_fft(x: jnp.ndarray, inverse: bool = False,
                             inverse, rows_impl, len_cap)
 
 
+@S.scoped(S.FFT_R2C)
 def rfft_via_c2c(x: jnp.ndarray, use_four_step: bool = False,
                  drop_nyquist: bool = False,
                  len_cap: int | None = None,
@@ -315,6 +321,7 @@ def rfft_via_c2c(x: jnp.ndarray, use_four_step: bool = False,
                                premul=premul)
 
 
+@S.scoped(S.FFT_R2C)
 def pack_even_odd(x: jnp.ndarray) -> jnp.ndarray:
     """Pack 2m reals into m complex (even -> re, odd -> im) for the
     half-size C2C trick.  NOT x.reshape(m, 2): a materialized [m, 2] f32
@@ -336,6 +343,7 @@ def pack_even_odd(x: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.complex(re, im)
 
 
+@S.scoped(S.FFT_R2C)
 def hermitian_rfft_post(zf: jnp.ndarray,
                         drop_nyquist: bool = False,
                         epilogue=None,
@@ -400,6 +408,7 @@ def subbyte_window_planes(window: np.ndarray, nbits: int) -> np.ndarray:
         np.asarray(window).reshape(-1, count).T)
 
 
+@S.scoped(S.FFT_R2C)
 def rfft_subbyte(data: jnp.ndarray, nbits: int, strategy: str = "four_step",
                  window_planes: jnp.ndarray | None = None,
                  drop_nyquist: bool = True,
@@ -478,6 +487,7 @@ def _pallas2_or_fallback(z: jnp.ndarray, strategy: str,
                       len_cap=len_cap)
 
 
+@S.scoped(S.FFT_R2C)
 def subbyte_planes_to_packed(planes: jnp.ndarray) -> jnp.ndarray:
     """Blocked field planes [..., count, M] -> packed complex plane pairs
     z[..., p, M] (p = count/2): z[p*b + k'] = x[2t] + i*x[2t+1] of the
@@ -485,6 +495,7 @@ def subbyte_planes_to_packed(planes: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.complex(planes[..., 0::2, :], planes[..., 1::2, :])
 
 
+@S.scoped(S.FFT_R2C)
 def finish_rfft_subbyte(a: jnp.ndarray,
                         drop_nyquist: bool = True,
                         epilogue=None, premul=None) -> jnp.ndarray:
@@ -527,6 +538,7 @@ def resolve_strategy(n: int, strategy: str) -> str:
     return strategy
 
 
+@S.scoped(S.FFT_R2C)
 def segment_rfft(x: jnp.ndarray, strategy: str = "auto",
                  len_cap: int | None = None,
                  epilogue=None, premul=None) -> jnp.ndarray:

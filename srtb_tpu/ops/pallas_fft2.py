@@ -80,6 +80,7 @@ import numpy as np
 
 from srtb_tpu.ops import fft as F
 from srtb_tpu.ops import pallas_fft as PF
+from srtb_tpu.ops import scopes as S
 
 
 def _factor(m: int, strict: bool = True):
@@ -592,6 +593,7 @@ def _fft2_2d(re2, im2, n1, n2, inverse, natural, interpret):
     return yr, yi
 
 
+@S.scoped(S.FFT_R2C)
 def pass1_ri(re: jnp.ndarray, im: jnp.ndarray, inverse: bool = False,
              interpret: bool = False):
     """Batched pass 1: [..., m] f32 pair -> [..., n1, n2] intermediate
@@ -608,6 +610,7 @@ def pass1_ri(re: jnp.ndarray, im: jnp.ndarray, inverse: bool = False,
     return br, bi
 
 
+@S.scoped(S.FFT_R2C)
 def pass2_ri(br: jnp.ndarray, bi: jnp.ndarray, inverse: bool = False,
              interpret: bool = False):
     """Batched pass 2: [..., n1, n2] intermediate pair -> [..., m]
@@ -779,6 +782,7 @@ def _pass1_front_kernel(byte_ref, *rest, n1, bb, la, lb, m, sign, kind,
             axis=0, keepdims=True)
 
 
+@S.scoped(S.FFT_R2C)
 def pass1_front(raw: jnp.ndarray, *, m: int, streams: int, variant: str,
                 nbits: int, window_eo=None, inverse: bool = False,
                 interpret: bool = False):
@@ -878,6 +882,7 @@ def pass1_front(raw: jnp.ndarray, *, m: int, streams: int, variant: str,
     return br, bi, aux
 
 
+@S.scoped(S.FFT_R2C)
 def front_mean_power(aux: jnp.ndarray, n2: int, m: int) -> jnp.ndarray:
     """Per-stream RFI-s1 mean |X_k|^2 from the pass-1 accumulators
     ``aux [S, 3, 128]`` — rfi.mean_power_packed with the reduction
@@ -977,6 +982,7 @@ def _pass2_spec_kernel(*refs, n1, n2, rb, la, lb, m, kind, norm,
     out_im_ref[:] = xi
 
 
+@S.scoped(S.FFT_R2C)
 def pass2_spectrum(br: jnp.ndarray, bi: jnp.ndarray, *, thr, norm: float,
                    mask_blocked=None, premul_blocked=None, chirp=None,
                    interpret: bool = False):

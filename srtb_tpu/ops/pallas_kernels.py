@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from srtb_tpu.ops import dedisperse as dd
+from srtb_tpu.ops import scopes as S
 
 # lane-friendly tile: rows x 128 lanes; f32 min tile is (8, 128)
 _LANES = 128
@@ -337,6 +338,7 @@ def _rfi_dedisperse_kernel(re_ref, im_ref, thr_ref, mask_ref, out_re_ref,
     out_im_ref[:] = re * s + im * c
 
 
+@S.scoped(S.CHIRP)
 def rfi_s1_dedisperse_df64(spec_ri: jnp.ndarray, threshold: float,
                            norm: float, f_min: float, df: float,
                            f_c: float, dm: float,
@@ -394,6 +396,7 @@ def rfi_s1_dedisperse_df64(spec_ri: jnp.ndarray, threshold: float,
     return jnp.stack([out_re.reshape(n), out_im.reshape(n)])
 
 
+@S.scoped(S.CHIRP)
 def dedisperse_df64(spec_ri: jnp.ndarray, f_min: float, df: float,
                     f_c: float, dm: float,
                     interpret: bool = False, i0: int = 0,
@@ -505,6 +508,7 @@ def sk_tiling_ok(nfreq: int, ntime: int) -> bool:
     return _sk_tiles(nfreq, ntime) is not None
 
 
+@S.scoped(S.DETECT)
 def sk_zap_timeseries(wf_ri: jnp.ndarray, sk_threshold: float,
                       interpret: bool = False):
     """Fused spectral-kurtosis zap + detection front half in two HBM
@@ -563,6 +567,7 @@ def sk_zap_timeseries(wf_ri: jnp.ndarray, sk_threshold: float,
     return out_ri, zero_count, ts
 
 
+@S.scoped(S.DETECT)
 def sk_zap_decision(s2_sum, s4_sum, m: int, sk_threshold: float):
     """Per-row zap verdict from the power moments (thresholds shared with
     rfi.mitigate_rfi_spectral_kurtosis)."""
@@ -572,6 +577,7 @@ def sk_zap_decision(s2_sum, s4_sum, m: int, sk_threshold: float):
     return (sk > thr_high_) | (sk < thr_low_)
 
 
+@S.scoped(S.DETECT)
 def sk_apply_timeseries(wf_ri: jnp.ndarray, zap: jnp.ndarray,
                         interpret: bool = False):
     """Pass 2 of the fused SK chain, standalone: zap the verdict rows and
@@ -645,6 +651,7 @@ def _unpack_subbyte_kernel(byte_ref, win_ref, out_ref, *, nbits,
     out_ref[:] = out
 
 
+@S.scoped(S.UNPACK)
 def unpack_subbyte_window(data: jnp.ndarray, nbits: int,
                           window: jnp.ndarray | None = None,
                           interpret: bool = False) -> jnp.ndarray:
@@ -692,6 +699,7 @@ def unpack_subbyte_window(data: jnp.ndarray, nbits: int,
     return out.reshape(per_byte * m)
 
 
+@S.scoped(S.UNPACK)
 def unpack_2bit_window(data: jnp.ndarray,
                        window: jnp.ndarray | None = None,
                        interpret: bool = False) -> jnp.ndarray:
@@ -716,6 +724,7 @@ def _unpack_planes_kernel(byte_ref, win_ref, out_ref, *, nbits,
         out_ref[j] = f
 
 
+@S.scoped(S.UNPACK)
 def unpack_subbyte_planes_window(data: jnp.ndarray, nbits: int,
                                  window_planes: jnp.ndarray | None = None,
                                  interpret: bool = False) -> jnp.ndarray:

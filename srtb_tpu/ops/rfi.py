@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from srtb_tpu.ops import scopes as S
 from srtb_tpu.utils.logging import log
 
 
@@ -27,6 +28,7 @@ def _norm(c: jnp.ndarray) -> jnp.ndarray:
     return jnp.real(c) ** 2 + jnp.imag(c) ** 2
 
 
+@S.scoped(S.RFI_S1)
 def mitigate_rfi_average_and_normalize(
         spectrum: jnp.ndarray, threshold: float,
         normalization_coefficient) -> jnp.ndarray:
@@ -44,6 +46,7 @@ def mitigate_rfi_average_and_normalize(
                                       normalization_coefficient)
 
 
+@S.scoped(S.RFI_S1)
 def mitigate_rfi_s1_given_mean(spectrum: jnp.ndarray, mean_power,
                                threshold: float,
                                normalization_coefficient) -> jnp.ndarray:
@@ -58,6 +61,7 @@ def mitigate_rfi_s1_given_mean(spectrum: jnp.ndarray, mean_power,
                      spectrum * normalization_coefficient)
 
 
+@S.scoped(S.RFI_S1)
 def mean_power_packed(zf: jnp.ndarray) -> jnp.ndarray:
     """Mean ``|X_k|^2`` over the m dropped-Nyquist rfft bins, computed
     from the packed half-size C2C output ``zf [..., m]`` WITHOUT forming
@@ -148,6 +152,7 @@ def rfi_ranges_to_mask(ranges, n_channels: int, baseband_freq_low: float,
     return mask if any_zap else None
 
 
+@S.scoped(S.RFI_S1)
 def mitigate_rfi_manual(spectrum: jnp.ndarray,
                         zap_mask: jnp.ndarray | None) -> jnp.ndarray:
     """Apply a precomputed zap mask (ref: rfi_mitigation.hpp:97-158)."""
@@ -173,6 +178,7 @@ def sk_decision_thresholds(m: int, sk_threshold: float):
             np.float32(thr_high * scale + 1.0))
 
 
+@S.scoped(S.DETECT)
 def mitigate_rfi_spectral_kurtosis(waterfall: jnp.ndarray,
                                    sk_threshold: float) -> jnp.ndarray:
     """Zap frequency rows of the dynamic spectrum whose spectral kurtosis

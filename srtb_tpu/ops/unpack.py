@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from srtb_tpu.ops import scopes as S
+
 SUPPORTED_BITS = (1, 2, 4, 8, -8, 16, -16, 32, 64)
 
 
@@ -41,6 +43,7 @@ def _unpack_subbyte(data: jnp.ndarray, nbits: int) -> jnp.ndarray:
     return fields.reshape(-1).astype(jnp.float32)
 
 
+@S.scoped(S.UNPACK)
 def unpack_subbyte_planes(data: jnp.ndarray, nbits: int) -> jnp.ndarray:
     """Unpack 1/2/4-bit fields to **blocked field planes** ``[count, M]``
     (count = 8/nbits fields per byte, M = byte count): plane k holds field
@@ -63,6 +66,7 @@ def unpack_subbyte_planes(data: jnp.ndarray, nbits: int) -> jnp.ndarray:
     return fields.astype(jnp.float32)
 
 
+@S.scoped(S.UNPACK)
 def unpack(data: jnp.ndarray, nbits: int,
            window: jnp.ndarray | None = None) -> jnp.ndarray:
     """Unpack a uint8 byte stream into float32 samples.
@@ -125,6 +129,7 @@ def samples_per_byte(nbits: int) -> float:
 # de-interleave variants (multi-stream packet formats)
 # ----------------------------------------------------------------
 
+@S.scoped(S.UNPACK)
 def unpack_interleaved_2pol(data: jnp.ndarray, nbits: int,
                             window: jnp.ndarray | None = None):
     """"1212" byte-interleaved 2 polarizations -> 2 streams
@@ -139,6 +144,7 @@ def unpack_interleaved_2pol(data: jnp.ndarray, nbits: int,
     return out1, out2
 
 
+@S.scoped(S.UNPACK)
 def unpack_naocpsr_snap1(data: jnp.ndarray, nbits: int = -8,
                          window: jnp.ndarray | None = None):
     """"1122" pair-interleaved 2 polarizations -> 2 streams
@@ -149,6 +155,7 @@ def unpack_naocpsr_snap1(data: jnp.ndarray, nbits: int = -8,
     return out1, out2
 
 
+@S.scoped(S.UNPACK)
 def unpack_gznupsr_a1(data: jnp.ndarray,
                       window: jnp.ndarray | None = None):
     """4-way word-interleaved (4 samples per stream per 16-byte word group),
@@ -164,6 +171,7 @@ def unpack_gznupsr_a1(data: jnp.ndarray,
     return tuple(outs)
 
 
+@S.scoped(S.UNPACK)
 def unpack_gznupsr_a1_v2_1(data: jnp.ndarray,
                            window: jnp.ndarray | None = None):
     """2-way word-interleaved variant, int8 without the XOR trick

@@ -37,10 +37,12 @@ from srtb_tpu.ops import dedisperse as dd
 from srtb_tpu.ops import detect as det
 from srtb_tpu.ops import fft as F
 from srtb_tpu.ops import rfi
+from srtb_tpu.ops import scopes as S
 from srtb_tpu.ops import unpack as U
 from srtb_tpu.ops import window as W
 from srtb_tpu.parallel import dist_fft as DF
 from srtb_tpu.parallel import dm_grid
+from srtb_tpu.utils.metrics import metrics
 
 
 class DistSegmentResult(NamedTuple):
@@ -227,18 +229,22 @@ class DistSegmentProcessor:
         for s in range(n_streams):  # S is tiny (1-4); loop, don't vmap
             # lane-dense even/odd pack — a [m, 2] reshape pads its minor
             # dim 2 -> 128 lanes on real TPU (64x HBM, ops/fft.py)
-            z = F.pack_even_odd(xs[s])
-            zf = DF._dist_fft_block(z, axis_name="seq", n1=n1, n2=n2,
-                                    n_dev=n_seq, inverse=False,
-                                    rows_impl=rows_impl, len_cap=len_cap)
-            spec = DF._dist_rfft_post_block(zf, axis_name="seq", m=m,
-                                            n_dev=n_seq)   # [m/n_seq]
+            with jax.named_scope(S.FFT_R2C):
+                z = F.pack_even_odd(xs[s])
+                zf = DF._dist_fft_block(z, axis_name="seq", n1=n1, n2=n2,
+                                        n_dev=n_seq, inverse=False,
+                                        rows_impl=rows_impl,
+                                        len_cap=len_cap)
+                spec = DF._dist_rfft_post_block(zf, axis_name="seq", m=m,
+                                                n_dev=n_seq)  # [m/n_seq]
             # RFI stage 1: global mean power via psum, zap + normalize
-            power = jnp.real(spec) ** 2 + jnp.imag(spec) ** 2
-            mean_power = jax.lax.psum(jnp.sum(power), "seq") / n_spectrum
-            zap = power > avg_threshold * mean_power
-            spec = jnp.where(zap, 0.0 + 0.0j, spec * norm_coeff)
-            spec = jnp.where(mask_block, 0.0 + 0.0j, spec)
+            with jax.named_scope(S.RFI_S1):
+                power = jnp.real(spec) ** 2 + jnp.imag(spec) ** 2
+                mean_power = jax.lax.psum(jnp.sum(power),
+                                          "seq") / n_spectrum
+                zap = power > avg_threshold * mean_power
+                spec = jnp.where(zap, 0.0 + 0.0j, spec * norm_coeff)
+                spec = jnp.where(mask_block, 0.0 + 0.0j, spec)
             specs.append(spec)
         spec_all = jnp.stack(specs)                    # [S, m/n_seq]
 
@@ -248,25 +254,8 @@ class DistSegmentProcessor:
         t = wlen - time_reserved_count \
             if wlen > time_reserved_count else wlen
 
-        def one_trial(chirp_in):
-            if chirp_on_device:
-                # generate this trial's chirp block in-place with df64
-                # (chirp_in is the (dm_hi, dm_lo) pair; no HBM bank)
-                n_local = n_spectrum // n_seq
-                seq_idx = jax.lax.axis_index("seq")
-                chirp_ri = dd.chirp_factor_df64_ri(
-                    n_local, f_min, df, f_c, chirp_in[0],
-                    i0=seq_idx * n_local, dm_lo=chirp_in[1],
-                    anchor_consts=chirp_anchor_consts)
-            else:
-                chirp_ri = chirp_in
-            s = spec_all * jax.lax.complex(chirp_ri[0], chirp_ri[1])
-            # local channels are complete contiguous sub-bands
-            wf = s.reshape(n_streams, ch_local, wlen)
-            wf = jnp.fft.ifft(wf, axis=-1, norm="forward")
-            if watfft_dewindow is not None:
-                wf = wf / watfft_dewindow
-            wf = rfi.mitigate_rfi_spectral_kurtosis(wf, sk_threshold)
+        @S.scoped(S.DETECT)
+        def detect_trial(wf):
             # global zapped-channel count per stream
             zero_count = jax.lax.psum(
                 jnp.sum((jnp.abs(wf[:, :, 0]) == 0).astype(jnp.int32),
@@ -296,6 +285,29 @@ class DistSegmentProcessor:
             return (zero_count, jnp.stack(counts, axis=-1),
                     jnp.stack(peaks, axis=-1), ts)
 
+        def one_trial(chirp_in):
+            if chirp_on_device:
+                # generate this trial's chirp block in-place with df64
+                # (chirp_in is the (dm_hi, dm_lo) pair; no HBM bank)
+                n_local = n_spectrum // n_seq
+                seq_idx = jax.lax.axis_index("seq")
+                chirp_ri = dd.chirp_factor_df64_ri(
+                    n_local, f_min, df, f_c, chirp_in[0],
+                    i0=seq_idx * n_local, dm_lo=chirp_in[1],
+                    anchor_consts=chirp_anchor_consts)
+            else:
+                chirp_ri = chirp_in
+            with jax.named_scope(S.CHIRP):
+                s = spec_all * jax.lax.complex(chirp_ri[0], chirp_ri[1])
+            # local channels are complete contiguous sub-bands
+            with jax.named_scope(S.WATERFALL):
+                wf = s.reshape(n_streams, ch_local, wlen)
+                wf = jnp.fft.ifft(wf, axis=-1, norm="forward")
+                if watfft_dewindow is not None:
+                    wf = wf / watfft_dewindow
+            return detect_trial(
+                rfi.mitigate_rfi_spectral_kurtosis(wf, sk_threshold))
+
         zc, counts, peaks, ts = jax.vmap(one_trial)(chirp_block)
 
         # replicate the small per-trial summaries across the dm axis
@@ -317,9 +329,23 @@ class DistSegmentProcessor:
 
     # ------------------------------------------------------------------
 
+    def stage_input(self, raw) -> jax.Array:
+        """Host bytes -> the mesh, sharded over "seq" (so every dm-row
+        of chips gets its copy), counted in ``h2d_bytes`` like the
+        single-chip path's upload."""
+        staged = _put_sharded(np.asarray(raw, dtype=np.uint8),
+                              NamedSharding(self.mesh, P("seq")))
+        metrics.add("h2d_bytes", sum(s.data.nbytes
+                                     for s in staged.addressable_shards))
+        return staged
+
     def process(self, raw) -> DistSegmentResult:
-        raw = _put_sharded(np.asarray(raw, dtype=np.uint8),
-                           NamedSharding(self.mesh, P("seq")))
+        """One segment through the sharded step.  ``raw``: host bytes,
+        or a segment ``stage_input`` already uploaded (a loop that
+        times the upload and the step's call apart,
+        DMSearchPipeline.run)."""
+        if not isinstance(raw, jax.Array):
+            raw = self.stage_input(raw)
         args = [raw, self.chirp_bank, self.rfi_mask]
         if self.window is not None:
             args.append(self.window)

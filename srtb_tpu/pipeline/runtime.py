@@ -61,7 +61,7 @@ journal field ``active_plan`` (schema v4).
 from __future__ import annotations
 
 import collections
-import contextlib
+import itertools
 import os
 import threading
 import time
@@ -82,7 +82,28 @@ from srtb_tpu.utils import events, slo, telemetry
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
 from srtb_tpu.utils.platform import hbm_peak_gbps
-from srtb_tpu.utils.tracing import StageTimer, trace_annotation
+from srtb_tpu.utils.tracing import StageTimer, span as stage_span
+
+
+def _metrics_stage_timer() -> StageTimer:
+    """A StageTimer whose every completed timing also lands in a bounded
+    histogram, so /metrics carries live p50/p95/p99 per stage."""
+    return StageTimer(
+        on_stage=lambda name, dt: metrics.histogram(
+            "stage_seconds", labels={"stage": name}).observe(dt))
+
+
+def _stamp_trace_id(seg) -> int:
+    """The segment's causal trace id, stamped at its birth (a source
+    that pre-stamped its own keeps it)."""
+    tid = getattr(seg, "trace_id", 0)
+    if not tid:
+        tid = events.next_trace_id()
+        try:
+            seg.trace_id = tid
+        except AttributeError:  # read-only stub segments
+            pass
+    return tid
 
 
 @dataclass
@@ -401,9 +422,11 @@ class Pipeline:
             recover_orphan_temps(cfg.baseband_output_file_prefix)
         # every completed host-stage timing also lands in a bounded
         # histogram, so /metrics carries live p50/p95/p99 per stage
-        self.stage_timer = StageTimer(
-            on_stage=lambda name, dt: metrics.histogram(
-                "stage_seconds", labels={"stage": name}).observe(dt))
+        self.stage_timer = _metrics_stage_timer()
+        for s in self.sinks:
+            bind = getattr(s, "bind_stage_timer", None)
+            if bind is not None:
+                bind(self.stage_timer)
         # ---- performance observatory (always-on) ----
         # pre-register the compile/cache families so /metrics exposes
         # them from the first scrape (a counter that was never bumped
@@ -418,16 +441,7 @@ class Pipeline:
         # (Config.profile_capture_segments; None = off, zero-cost)
         from srtb_tpu.utils.tracing import ProfileCapture
         self.profile_capture = ProfileCapture.from_config(cfg)
-        self.journal = None
-        jpath = getattr(cfg, "telemetry_journal_path", "")
-        if jpath:
-            from srtb_tpu.utils.telemetry import SpanJournal
-            self.journal = SpanJournal(
-                jpath, max_bytes=getattr(
-                    cfg, "telemetry_journal_max_bytes", 64 << 20),
-                compress=bool(getattr(cfg,
-                                      "telemetry_journal_compress",
-                                      True)))
+        self.journal = telemetry.SpanJournal.from_config(cfg)
         # ---- science observatory (srtb_tpu/quality/) ----
         # data-quality monitor (gauges + drift detector + journal
         # payload for the plans' quality epilogue) and the pulse-
@@ -445,15 +459,6 @@ class Pipeline:
         # checkpoint's resume-continuous drain count at run start, so
         # "every N-th segment" means the same segments across resumes
         self._canary_base = 0
-
-    @contextlib.contextmanager
-    def _stage(self, name: str):
-        """One named host stage: StageTimer accumulation + per-segment
-        ``last`` capture + an xprof TraceAnnotation so device traces and
-        the span journal correlate by stage name."""
-        with trace_annotation(f"srtb:{name}"), \
-                self.stage_timer.stage(name):
-            yield
 
     def _op(self, site: str, index: int, fn):
         """One guarded pipeline operation: the fault-injection hook
@@ -485,23 +490,17 @@ class Pipeline:
         read runs under the "ingest" fault site: transient receiver
         errors (interrupted syscalls, connection churn) retry with
         backoff instead of killing the run."""
-        t0 = time.perf_counter()
-        with trace_annotation("srtb:ingest"):
+        with stage_span("ingest", self.stage_timer) as sp:
             seg = self._op("ingest", index, lambda: next(it, None))
+            if seg is None:
+                sp.cancel()
         if seg is not None:
-            dt = time.perf_counter() - t0
-            self.stage_timer.record("ingest", dt)
+            dt = sp.seconds
             if self.events is not None:
                 # stamp the causal trace id at the segment's birth (a
                 # source that pre-stamped its own keeps it) and bind
                 # the ambient context so retry/fault events attribute
-                tid = getattr(seg, "trace_id", 0)
-                if not tid:
-                    tid = events.next_trace_id()
-                    try:
-                        seg.trace_id = tid
-                    except AttributeError:  # read-only stub segments
-                        pass
+                tid = _stamp_trace_id(seg)
                 events.set_current(tid, self.stream)
                 self.events.emit("stage.ingest", trace=tid,
                                  stream=self.stream, seg=index, dur=dt)
@@ -587,7 +586,8 @@ class Pipeline:
             # clock (the span's summed stages — what the journal's
             # synthetic 'segment' stage reports); overlap-hidden time
             # is concurrent and deliberately excluded
-            self.slo.note_segment(self.stream, sum(span.values()))
+            self.slo.note_segment(self.stream,
+                                  telemetry.segment_wall(span))
         det_count = 0
         counts = getattr(det_res, "signal_counts", None)
         if counts is not None:
@@ -812,7 +812,8 @@ class Pipeline:
                 and seg.seq == prev[1] + 1
                 and getattr(seg, "data_stream_id", 0) == prev[0])
 
-    def _dispatch_ring(self, seg, index: int, requeue: bool) -> tuple:
+    def _dispatch_ring(self, seg, index: int, requeue: bool,
+                       span: dict) -> tuple:
         """Ring-mode device dispatch of one segment.  Warm when a
         carry is live: upload stride bytes only and run the two-input
         assemble plan.  Cold (no carry / requeue): full upload through
@@ -825,6 +826,7 @@ class Pipeline:
         and the requeued segment's own carry is already history."""
         proc = self.processor
         stage_in = proc.stage_input
+        tid = getattr(seg, "trace_id", 0)
         # canary-injected copy when attached (the delta is zero over
         # the head/tail reserved spans, so the warm stride slice and
         # the adopted carry stay consistent with a cold dispatch)
@@ -842,9 +844,9 @@ class Pipeline:
             else self._ring_carry
         if carry is not None:
             self._ring_carry = None  # consumed below (donated)
-            staged = self._op("h2d", index,
-                              lambda: stage_in(data,
-                                               stride_only=True))
+            staged = self._h2d(span, index, tid,
+                               lambda: stage_in(data,
+                                                stride_only=True))
             attempt = [0]
 
             def run_it():
@@ -854,14 +856,13 @@ class Pipeline:
                 # the failed warm attempt consumed the carry: go cold
                 return proc.run_device_cold(stage_in(data))
 
-            out, next_carry = self._op("dispatch", index, run_it)
+            out, next_carry = self._enqueue(span, index, tid, run_it)
         else:
             if self.events is not None:
-                self.events.emit("ring.cold",
-                                 trace=getattr(seg, "trace_id", 0),
+                self.events.emit("ring.cold", trace=tid,
                                  stream=self.stream, seg=index,
                                  info="requeue" if requeue else "")
-            staged = self._op("h2d", index, lambda: stage_in(data))
+            staged = self._h2d(span, index, tid, lambda: stage_in(data))
             first = [True]
 
             def run_it():
@@ -870,7 +871,7 @@ class Pipeline:
                     return proc.run_device_cold(staged)
                 return proc.run_device_cold(stage_in(data))
 
-            out, next_carry = self._op("dispatch", index, run_it)
+            out, next_carry = self._enqueue(span, index, tid, run_it)
         if not requeue or ring_down:
             # adopt the carry for the next dispatch; a requeued
             # segment's carry is stale (the ring has moved past it)
@@ -967,6 +968,25 @@ class Pipeline:
                             if self.quality is not None else [])})
         return False
 
+    def _h2d(self, span: dict, index: int, tid: int, stage):
+        """The "h2d" child span of "dispatch": ``stage`` hands one
+        segment's bytes to ``jax.device_put`` (SegmentProcessor.
+        stage_input, which also counts ``h2d_bytes``), under the "h2d"
+        fault site."""
+        with stage_span("h2d", self.stage_timer, tid) as sp:
+            staged = self._op("h2d", index, stage)
+        span["h2d"] = sp.seconds
+        return staged
+
+    def _enqueue(self, span: dict, index: int, tid: int, run_it):
+        """The "enqueue" child span of "dispatch": the jit call that
+        hands the segment's program to the device, under the "dispatch"
+        fault site (a retry re-stages inside it)."""
+        with stage_span("enqueue", self.stage_timer, tid) as sp:
+            out = self._op("dispatch", index, run_it)
+        span["enqueue"] = sp.seconds
+        return out
+
     def _dispatch_segment(self, seg, ingest_s: float,
                           offset_after: int, index: int = 0,
                           requeue: bool = False) -> tuple:
@@ -984,13 +1004,15 @@ class Pipeline:
             events.set_current(tid, self.stream)
         self._canary_prepare(seg, index)
         data = self._device_bytes(seg)
-        with self._stage("dispatch"):
+        span = {"ingest": ingest_s}
+        with stage_span("dispatch", self.stage_timer, tid) as sp:
             stage_in = getattr(self.processor, "stage_input", None)
             if self._ring_live:
-                wf, det_res = self._dispatch_ring(seg, index, requeue)
+                wf, det_res = self._dispatch_ring(seg, index, requeue,
+                                                  span)
             elif stage_in is not None:
-                staged = self._op("h2d", index,
-                                  lambda: stage_in(data))
+                staged = self._h2d(span, index, tid,
+                                   lambda: stage_in(data))
                 first = [True]
 
                 def run_it():
@@ -1004,13 +1026,12 @@ class Pipeline:
                     return self.processor.run_device(
                         stage_in(data))
 
-                wf, det_res = self._op("dispatch", index, run_it)
+                wf, det_res = self._enqueue(span, index, tid, run_it)
             else:  # duck-typed stub processors (tests)
                 wf, det_res = self._op(
                     "dispatch", index,
                     lambda: self.processor.process(data))
-        span = {"ingest": ingest_s,
-                "dispatch": self.stage_timer.last["dispatch"]}
+        span["dispatch"] = sp.seconds
         if self.events is not None:
             self.events.emit("stage.dispatch", trace=tid,
                              stream=self.stream, seg=index,
@@ -1030,10 +1051,12 @@ class Pipeline:
         the first undrained segment, not past the whole batch.  The
         whole batch dispatch runs under the first segment's "dispatch"
         fault site (one jit call = one failure domain)."""
-        t0 = time.perf_counter()
-        for i, s in enumerate(segs):
-            self._canary_prepare(s, first_index + i)
-        with trace_annotation("srtb:dispatch"):
+        # one span for the batch (no timer: its cost is amortized over
+        # the segments' "dispatch" samples below)
+        with stage_span("dispatch",
+                        trace_id=getattr(segs[0], "trace_id", 0)) as sp:
+            for i, s in enumerate(segs):
+                self._canary_prepare(s, first_index + i)
             if self._ring_live:
                 wf_b, det_b = self._dispatch_batch_ring(segs, first_index)
             else:
@@ -1048,7 +1071,7 @@ class Pipeline:
                 wf_b, det_b = self._op(
                     "dispatch", first_index,
                     lambda: self.processor.process_batch(stacked))
-        per_seg = (time.perf_counter() - t0) / len(segs)
+        per_seg = sp.seconds / len(segs)
         items = []
         for i, seg in enumerate(segs):
             self.stage_timer.record("dispatch", per_seg)
@@ -1187,7 +1210,8 @@ class Pipeline:
         # the same manifest key its first life used
         mkey = (None if self.manifest is None
                 else (getattr(seg, "data_stream_id", 0), drained[0]))
-        with self._stage("sink"):
+        with stage_span("sink", self.stage_timer,
+                        getattr(seg, "trace_id", 0)) as sp:
             # ``sinks_done`` rides with the item: a retry (or a
             # supervisor replay) re-enters _push_sinks but skips the
             # sinks that already succeeded — exactly-once per sink,
@@ -1197,7 +1221,8 @@ class Pipeline:
                                               positive, degrade_level,
                                               done=sinks_done,
                                               seg_key=mkey))
-        span["sink"] = self.stage_timer.last["sink"]
+        span["sink"] = sp.seconds
+        self._take_sink_spans(span)
         if self.events is not None:
             self.events.emit("stage.sink",
                              trace=getattr(seg, "trace_id", 0),
@@ -2137,6 +2162,18 @@ class Pipeline:
             if done is not None:
                 done.add(i)
 
+    def _take_sink_spans(self, span: dict) -> None:
+        """Add what the sinks timed inside this segment's "sink" stage
+        (a candidate writer's ``d2h`` / ``write`` / ``publish``) to its
+        ``stages_ms``.  Only a sink that wrote has any: a quiet
+        segment's record carries none."""
+        for sink in self.sinks:
+            take = getattr(sink, "take_spans", None)
+            if take is None:
+                continue
+            for name, seconds in take().items():
+                span[name] = span.get(name, 0.0) + seconds
+
     def _on_segment_deadline(self) -> None:  # pragma: no cover - aborts
         _abort_on_deadline(self.cfg.segment_deadline_s)
 
@@ -2165,7 +2202,8 @@ class Pipeline:
         if self.events is not None:
             events.set_current(getattr(seg, "trace_id", 0),
                                self.stream)
-        with self._stage("fetch"):
+        with stage_span("fetch", self.stage_timer,
+                        getattr(seg, "trace_id", 0)) as sp:
             # explicit D2H (device_get) — this is the engine's one
             # sanctioned blocking fetch; implicit np.asarray here
             # would trip the sanitizer's transfer guard.  Under the
@@ -2175,7 +2213,7 @@ class Pipeline:
                 "fetch", index,
                 lambda: self._sync_with_deadline(
                     lambda: jax.device_get(det_res)))
-        span["fetch"] = self.stage_timer.last["fetch"]
+        span["fetch"] = sp.seconds
         if self.events is not None:
             self.events.emit("stage.fetch",
                              trace=getattr(seg, "trace_id", 0),
@@ -2264,30 +2302,52 @@ class DMSearchPipeline:
         self.trials_path = cfg.baseband_output_file_prefix + \
             "dm_trials.jsonl"
         self.stats = PipelineStats()
+        # the same spans, timer and journal as Pipeline: the loop's
+        # five host stages (ingest, h2d, enqueue, fetch, record) are
+        # flat siblings — nothing here overlaps
+        self.stage_timer = _metrics_stage_timer()
+        self.journal = telemetry.SpanJournal.from_config(cfg)
 
     def run(self, max_segments: int | None = None) -> PipelineStats:
         import json
 
         cfg = self.cfg
+        timer = self.stage_timer
+        proc = self.processor
         start = time.perf_counter()
         # multi-controller runs: summaries are replicated, so only the
         # first process records them (all write identical content)
         write_records = jax.process_index() == 0
+        it = iter(self.source)
         with open(self.trials_path if write_records else os.devnull,
                   "a") as trials_file:
-            for i, seg in enumerate(self.source):
-                if max_segments is not None and i >= max_segments:
+            for i in itertools.count():
+                with stage_span("ingest", timer) as sp:
+                    seg = next(it, None)
+                    if seg is None:
+                        sp.cancel()
+                if seg is None or (max_segments is not None
+                                   and i >= max_segments):
                     break
-                res = self.processor.process(seg.data)
+                stages = {"ingest": sp.seconds}
+                tid = _stamp_trace_id(seg)
+                with stage_span("h2d", timer, tid) as sp:
+                    staged = proc.stage_input(seg.data)
+                stages["h2d"] = sp.seconds
+                with stage_span("enqueue", timer, tid) as sp:
+                    res = proc.process(staged)
+                stages["enqueue"] = sp.seconds
                 n_dm = len(self.dm_list)
                 # reduce over (stream, boxcar) axes -> per-dm quantities;
                 # every device transfer runs under the fail-fast deadline
                 # (a wedged device blocks transfers, not just compute)
-                peaks, counts, zero = sync_with_deadline(
-                    cfg.segment_deadline_s,
-                    lambda: (jax.device_get(res.snr_peaks),
-                             jax.device_get(res.signal_counts),
-                             jax.device_get(res.zero_count)))
+                with stage_span("fetch", timer, tid) as sp:
+                    peaks, counts, zero = sync_with_deadline(
+                        cfg.segment_deadline_s,
+                        lambda: (jax.device_get(res.snr_peaks),
+                                 jax.device_get(res.signal_counts),
+                                 jax.device_get(res.zero_count)))
+                stages["fetch"] = sp.seconds
                 peaks = peaks.reshape(n_dm, -1)
                 counts = counts.reshape(n_dm, -1)
                 zero = zero.reshape(n_dm, -1).max(axis=-1)
@@ -2308,9 +2368,12 @@ class DMSearchPipeline:
                     "signal_counts": counts.sum(axis=-1).tolist(),
                     "zero_counts": zero.tolist(),
                 }
-                trials_file.write(json.dumps(record) + "\n")
-                trials_file.flush()
-                if bool((ok & fired).any()):
+                with stage_span("record", timer, tid) as sp:
+                    trials_file.write(json.dumps(record) + "\n")
+                    trials_file.flush()
+                stages["record"] = sp.seconds
+                positive = bool((ok & fired).any())
+                if positive:
                     self.stats.signals += 1
                     log.info(f"[dm_search] segment {i}: best dm "
                              f"{record['best_dm']} "
@@ -2322,8 +2385,19 @@ class DMSearchPipeline:
                 metrics.window("segments").add(1)
                 metrics.window("samples").add(cfg.baseband_input_count)
                 telemetry.mark_segment()  # /healthz liveness
+                if self.journal is not None:
+                    self.journal.write(telemetry.segment_span(
+                        i, stages, 0, int(counts.sum()), positive,
+                        cfg.baseband_input_count,
+                        timestamp_ns=getattr(seg, "timestamp", 0),
+                        trace_id=tid))
         self.stats.elapsed_s = time.perf_counter() - start
         return self.stats
+
+    def close(self) -> None:
+        if self.journal is not None:
+            self.journal.close()
+            self.journal = None
 
 
 class ThreadedPipeline(Pipeline):
@@ -2386,7 +2460,8 @@ class ThreadedPipeline(Pipeline):
                                    self.stream)
             self._canary_prepare(seg, index)
             data = self._device_bytes(seg)
-            with self._stage("dispatch"):
+            with stage_span("dispatch", self.stage_timer,
+                            getattr(seg, "trace_id", 0)) as sp:
                 while True:
                     try:
                         wf, det_res = self._op(
@@ -2411,8 +2486,7 @@ class ThreadedPipeline(Pipeline):
                                 "device fault survived every demotion "
                                 f"rung: {e}") from e
                         self._swap_processor(newp)
-            span = {"ingest": ingest_dt,
-                    "dispatch": self.stage_timer.last["dispatch"]}
+            span = {"ingest": ingest_dt, "dispatch": sp.seconds}
             if self.events is not None:
                 self.events.emit("stage.dispatch",
                                  trace=getattr(seg, "trace_id", 0),
@@ -2465,12 +2539,14 @@ class ThreadedPipeline(Pipeline):
             mkey = (None if self.manifest is None
                     else (getattr(seg, "data_stream_id", 0),
                           drained[0]))
-            with self._stage("sink"):
+            with stage_span("sink", self.stage_timer,
+                            getattr(seg, "trace_id", 0)) as sp:
                 self._op("sink_write", seg_index,
                          lambda: self._push_sinks(seg, wf, det_res,
                                                   positive, done=done,
                                                   seg_key=mkey))
-            span["sink"] = self.stage_timer.last["sink"]
+            span["sink"] = sp.seconds
+            self._take_sink_spans(span)
             if self.events is not None:
                 self.events.emit("stage.sink",
                                  trace=getattr(seg, "trace_id", 0),

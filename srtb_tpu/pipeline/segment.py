@@ -32,6 +32,7 @@ from srtb_tpu.ops import dedisperse as dd
 from srtb_tpu.ops import detect as det
 from srtb_tpu.ops import fft as F
 from srtb_tpu.ops import rfi
+from srtb_tpu.ops import scopes as S
 from srtb_tpu.ops import unpack as U
 from srtb_tpu.ops import window as W
 from srtb_tpu.utils.logging import log
@@ -626,7 +627,8 @@ class SegmentProcessor:
                 c_ri = dd.chirp_factor_df64_ri(
                     spec.shape[-1], self.f_min, self.df, self.f_c,
                     cfg.dm, exact=getattr(cfg, "chirp_exact", False))
-                spec = spec * jax.lax.complex(c_ri[0], c_ri[1])
+                with jax.named_scope(S.CHIRP):
+                    spec = spec * jax.lax.complex(c_ri[0], c_ri[1])
             return spec
         return epilogue
 
@@ -746,6 +748,7 @@ class SegmentProcessor:
                         else "pallas")
         return impl
 
+    @S.scoped(S.FFT_R2C)
     def _staged_pack(self, raw: jnp.ndarray) -> jnp.ndarray:
         """unpack + pack for the staged plan: blocked field-plane pairs
         [S, p, M] (sub-byte, lane-dense by construction) or even/odd
@@ -839,6 +842,7 @@ class SegmentProcessor:
             f_min=float(self.f_min), df=float(self.df),
             f_c=float(self.f_c), dm=float(self.cfg.dm))
 
+    @S.scoped(S.FFT_R2C)
     def _stage_a_front(self, raw: jnp.ndarray):
         """Front-fused stage (a): the raw uint8 segment goes straight
         into the pass-1 megakernel (in-kernel unpack + window +
@@ -855,6 +859,7 @@ class SegmentProcessor:
             interpret=self._pallas_interpret)
         return self._boundary_canon(jnp.stack([br, bi])), aux
 
+    @S.scoped(S.FFT_R2C)
     def _stage_b_front(self, a_ri, aux):
         """Front-fused stage (b): pass 2 emits the dedispersed
         spectrum directly — row FFT + in-kernel Hermitian post +
@@ -887,6 +892,7 @@ class SegmentProcessor:
             jnp.stack([o[1] for o in outs])])  # [2, S, n1, n2] blocked
         return self._boundary_canon(spec_ri)
 
+    @S.scoped(S.FFT_R2C)
     def _stage_a_nat(self, raw: jnp.ndarray):
         """unpack + even/odd pack + segment-FFT first half."""
         impl = self._staged_impl()
@@ -902,6 +908,7 @@ class SegmentProcessor:
                                len_cap=self._len_cap)  # [..., n2, n1]
         return jnp.stack([jnp.real(a), jnp.imag(a)])
 
+    @S.scoped(S.FFT_R2C)
     def _stage_b_nat(self, a_ri: jnp.ndarray):
         """segment-FFT second half + Hermitian post -> spectrum [S, n/2].
         With the fused tail the RFI-s1 + df64-chirp epilogue folds into
@@ -942,6 +949,7 @@ class SegmentProcessor:
         chirped, qtap = self._apply_s1_chirp(spec, chirp_ri)
         return self._waterfall_detect(chirped, qspec=qtap)
 
+    @S.scoped(S.CHIRP)
     def _apply_s1_chirp(self, spec: jnp.ndarray, chirp_ri):
         """RFI stage 1 + manual mask + chirp multiply as standalone
         spectrum sweeps (the passes the fused tail folds into the FFT's
@@ -998,6 +1006,7 @@ class SegmentProcessor:
         chirp = jax.lax.complex(chirp_ri[0], chirp_ri[1])
         return dd.dedisperse(spec, chirp), qtap
 
+    @S.scoped(S.WATERFALL)
     def _waterfall_detect(self, spec: jnp.ndarray, qspec=None):
         """Waterfall backward C2C + RFI stage 2 + detection from an
         already-dedispersed spectrum.  With the fully-fused skzap plan

@@ -45,6 +45,7 @@ import math
 
 import numpy as np
 
+from srtb_tpu.ops import scopes as S
 from srtb_tpu.utils.metrics import metrics
 
 # scalar slots ahead of the two coarse maps (see module docstring)
@@ -85,6 +86,7 @@ def _coarse_split(n_spec: int, coarse_bins: int) -> tuple[int, int]:
     return b, n_spec // b
 
 
+@S.scoped(S.QUALITY)
 def quality_stats_device(spec, wf, coarse_bins: int,
                          dead_threshold: float, hot_threshold: float,
                          subsample: int = 1):

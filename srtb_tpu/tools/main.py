@@ -84,7 +84,10 @@ def main(argv=None) -> int:
         # multi-chip DM-trial search mode
         from srtb_tpu.pipeline.runtime import DMSearchPipeline
         search = DMSearchPipeline(cfg, source=source)
-        stats = search.run()
+        try:
+            stats = search.run()
+        finally:
+            search.close()
         log.info(f"[main] dm search done: {stats.segments} segments, "
                  f"{stats.signals} with signal; trials in "
                  f"{search.trials_path}")

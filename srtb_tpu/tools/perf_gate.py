@@ -105,6 +105,7 @@ def capture(segments: int = 20, warmup: int = 4, log2n: int = 13,
     from srtb_tpu.io.synth import make_dispersed_baseband
     from srtb_tpu.pipeline.runtime import Pipeline
     from srtb_tpu.tools import telemetry_report as TR
+    from srtb_tpu.utils.telemetry import segment_wall
     from srtb_tpu.utils.metrics import metrics
 
     n = 1 << log2n
@@ -124,7 +125,7 @@ def capture(segments: int = 20, warmup: int = 4, log2n: int = 13,
         raise RuntimeError(
             f"mini-bench expected {total} segments, drained "
             f"{stats.segments} with {len(recs)} journal spans")
-    samples = [sum((r.get("stages_ms") or {}).values()) / 1e3
+    samples = [segment_wall(r.get("stages_ms") or {}) / 1e3
                for r in recs[warmup:]]
     return {
         "samples_s": samples,

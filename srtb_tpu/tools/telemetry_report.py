@@ -1,8 +1,9 @@
 """Summarize a segment-span telemetry journal (utils/telemetry.py).
 
-``trace_summary`` attributes *device* time from an xprof trace; this
-tool is its host-side complement: it reads the JSONL span journal the
-pipeline writes (one record per segment) and reports
+The benchmark's trace reducers (``benchmark/trace.py``,
+``benchmark/reducers/scopes.py``) attribute *device* time from a profiler
+trace; this tool is their host-side complement: it reads the JSONL span
+journal the pipeline writes (one record per segment) and reports
 
 - a per-stage wall-clock table with exact p50/p95/p99 (computed from
   the raw per-segment samples, unlike the bounded-bucket /metrics
@@ -135,6 +136,8 @@ def stage_stats(records: list[dict]) -> dict:
     'segment' sum.  Fields are read tolerantly: a mixed v1/v2 journal
     (rotation can leave a v1 tail after an upgrade) must summarize, not
     KeyError."""
+    from srtb_tpu.utils.telemetry import segment_wall
+
     samples: dict[str, list[float]] = {}
     for rec in records:
         stages = rec.get("stages_ms") or {}
@@ -142,7 +145,7 @@ def stage_stats(records: list[dict]) -> dict:
             samples.setdefault(name, []).append(float(ms))
         if stages:
             samples.setdefault("segment", []).append(
-                float(sum(stages.values())))
+                float(segment_wall(stages)))
         hidden = rec.get("overlap_hidden_ms")
         if hidden is not None:
             samples.setdefault("overlap", []).append(float(hidden))

@@ -45,6 +45,16 @@ def enable_compile_cache(path: str = "") -> str | None:
     # what matters, not disk
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # key the cache on the programs' metadata too.  JAX leaves it out by
+    # default, and says what that costs: "executables loaded from the
+    # cache may have stale metadata, which may show up in, e.g.,
+    # profiles".  The stage scopes (ops/scopes.py) ARE metadata: a cache
+    # written before they existed served all seven programs of the
+    # served plan to the build that has them (7 hits, 0 misses, my chip
+    # run, PR 27) and its trace named no stage.  The price is one
+    # compile after a source edit that moves a traced line.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         log.debug(f"[compile_cache] JAX_COMPILATION_CACHE_DIR={env_dir}")

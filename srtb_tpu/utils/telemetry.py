@@ -89,9 +89,32 @@ from srtb_tpu.utils.metrics import metrics
 # labels at the migration boundary, which is how the migration soak
 # proves victims resumed on the survivor.  OMITTED outside a fleet
 # (no pool, no label): a solo run's journal reads exactly as v10.
+# Still v11 (additions every reader already tolerates): ``stages_ms``
+# is an open dictionary and gained CHILD stages, timed inside a stage
+# that is journaled beside them — ``h2d`` and ``enqueue`` inside
+# ``dispatch`` (the jax.device_put of the segment's bytes; the jit
+# call), and on segments that dump ``d2h`` / ``write`` / ``publish``
+# inside ``sink`` (the lazy waterfall fetch; the writers, with the wait
+# for the writer pool where a sink drained it; manifest barrier +
+# renames).  Whoever sums a record's stages uses ``segment_wall``,
+# which leaves a child out where its parent is there.  The DM-search
+# loop journals the same record with five flat stages (``ingest``,
+# ``h2d``, ``enqueue``, ``fetch``, ``record``).
 # Readers must tolerate mixed v1-v11 journals: rotation can leave an
 # older-schema tail in the previous generation after an upgrade.
 SPAN_SCHEMA_VERSION = 11
+
+# child stage -> the stage it is timed inside (see the note above)
+CHILD_STAGES = {"h2d": "dispatch", "enqueue": "dispatch",
+                "d2h": "sink", "write": "sink", "publish": "sink"}
+
+
+def segment_wall(stages: dict) -> float:
+    """Sum of one record's stages (seconds or milliseconds, as given)
+    without double counting: a child stage is left out where the stage
+    it ran inside is in the record too."""
+    return sum(v for k, v in stages.items()
+               if CHILD_STAGES.get(k) not in stages)
 
 # gauge names shared between the pipeline (writer) and health() (reader)
 LAST_SEGMENT_MONOTONIC = "last_segment_monotonic"
@@ -109,6 +132,19 @@ class SpanJournal:
     dispatch path since rotation happens at most once per max_bytes of
     spans); ``compress=False`` keeps the legacy plaintext ``<path>.1``.
     Readers (tools/telemetry_report.load) handle both transparently."""
+
+    @classmethod
+    def from_config(cls, cfg) -> "SpanJournal | None":
+        """The journal ``Config.telemetry_journal_path`` asks for, or
+        None (off) where it is empty."""
+        path = getattr(cfg, "telemetry_journal_path", "")
+        if not path:
+            return None
+        return cls(path,
+                   max_bytes=getattr(cfg, "telemetry_journal_max_bytes",
+                                     64 << 20),
+                   compress=bool(getattr(cfg, "telemetry_journal_compress",
+                                         True)))
 
     def __init__(self, path: str, max_bytes: int = 64 << 20,
                  compress: bool = True):

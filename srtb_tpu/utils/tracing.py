@@ -15,21 +15,55 @@ import time
 from srtb_tpu.utils.logging import log
 
 
-@contextlib.contextmanager
-def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available (shows host-side
-    stage extents on the xprof timeline, correlating the span journal
-    with device traces by stage name); a no-op on backends without it.
-    Importing jax lazily keeps pure-host tools (telemetry_report) free
-    of the jax import cost."""
-    try:
-        import jax
+class span:
+    """The one way to open a host span: a ``jax.profiler.TraceAnnotation``
+    named ``srtb:<name>`` (so the span lands on the profiler's host
+    plane, on the device trace's clock) plus one ``StageTimer`` record
+    when ``timer`` is given.  ``trace_id`` (the segment's causal id,
+    utils/events.py) rides along as an argument of the annotation, so
+    the spans of one segment share an identifier in the trace as they do
+    in the journal; 0 = not known yet (the ingest read stamps it after).
 
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler-less backend
-        cm = contextlib.nullcontext()
-    with cm:
-        yield
+    ``seconds`` holds the duration once the block is left: callers put
+    it into the segment's ``stages_ms`` instead of reading the timer's
+    ``last`` back.  ``cancel()`` inside the block keeps the sample out
+    of the timer (the terminal failed source read is no ingest).
+
+    Outside a profiler session this costs two ``perf_counter`` reads and
+    an inactive annotation (tests/test_stage_tracing.py holds it under
+    20 us).  jax is imported on first use only, so pure-host tools that
+    import this module stay free of the jax import cost."""
+
+    __slots__ = ("name", "timer", "trace_id", "seconds", "_ann", "_t0")
+    _annotation = None  # jax.profiler.TraceAnnotation, bound lazily
+
+    def __init__(self, name: str, timer: "StageTimer | None" = None,
+                 trace_id: int = 0):
+        self.name = name
+        self.timer = timer
+        self.trace_id = trace_id
+        self.seconds = 0.0
+
+    def cancel(self) -> None:
+        self.timer = None
+
+    def __enter__(self) -> "span":
+        ann = span._annotation
+        if ann is None:
+            import jax
+
+            ann = span._annotation = jax.profiler.TraceAnnotation
+        self._ann = ann(f"srtb:{self.name}", trace_id=self.trace_id) \
+            if self.trace_id else ann(f"srtb:{self.name}")
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if self.timer is not None:
+            self.timer.record(self.name, self.seconds)
 
 
 @contextlib.contextmanager

@@ -83,9 +83,12 @@ def test_compile_cache_dir_from_environment(on_fake_tpu, monkeypatch,
     assert CC.enable_compile_cache() == str(tmp_path)
     assert CC.enable_compile_cache("/elsewhere") == str(tmp_path)
     assert "jax_compilation_cache_dir" not in on_fake_tpu.calls
+    # the stage scopes are metadata: a cache keyed without it serves a
+    # build that has them the executables of one that had none
     assert on_fake_tpu.calls == {
         "jax_persistent_cache_min_entry_size_bytes": -1,
-        "jax_persistent_cache_min_compile_time_secs": 0.0}
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_compilation_cache_include_metadata_in_key": True}
 
 
 def test_compile_cache_dir_default_is_in_checkout(on_fake_tpu,

@@ -1,0 +1,379 @@
+"""The program names its own time (ISSUE 27): stable ``srtb.<stage>``
+scopes on the device programs, and host spans opened through one helper
+(``utils/tracing.span``) in ``Pipeline``, the candidate writer and the DM
+search loop, journaled under the same names.
+
+(a) the scopes are in the lowered HLO and change nothing but metadata;
+(b) the served path journals ``h2d`` / ``enqueue`` inside ``dispatch``,
+    ``d2h`` / ``write`` / ``publish`` on segments that dump;
+(c) the DM search loop records its five stages once a segment, and one
+    ``segment_span`` a segment where it has a journal path; the grid's
+    processor takes a segment the loop already uploaded;
+(d) a span outside a profiler session costs microseconds.
+"""
+
+import contextlib
+import json
+import re
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from srtb_tpu.config import Config
+from srtb_tpu.io.synth import make_dispersed_baseband
+from srtb_tpu.ops import scopes as S
+from srtb_tpu.pipeline.runtime import DMSearchPipeline, Pipeline
+from srtb_tpu.pipeline.segment import SegmentProcessor
+from srtb_tpu.tools import telemetry_report as TR
+from srtb_tpu.utils import telemetry
+from srtb_tpu.utils.metrics import metrics
+from srtb_tpu.utils.tracing import StageTimer, span
+
+N = 1 << 14
+SIX = {S.UNPACK, S.FFT_R2C, S.RFI_S1, S.CHIRP, S.WATERFALL, S.DETECT}
+
+
+def _cfg(**extra):
+    kw = dict(
+        baseband_input_count=N, baseband_input_bits=8,
+        baseband_freq_low=1405.0, baseband_bandwidth=64.0,
+        baseband_sample_rate=128e6, dm=0.05,
+        spectrum_channel_count=64,
+        mitigate_rfi_average_method_threshold=100.0,
+        mitigate_rfi_spectral_kurtosis_threshold=2.0,
+        signal_detect_max_boxcar_length=64,
+        baseband_reserve_sample=True, writer_thread_count=0)
+    kw.update(extra)
+    return Config(**kw)
+
+
+# ------------------------------------------------- (a) scopes in the HLO
+
+def _hlo(fn, avals) -> tuple:
+    """(the lowered module as text, the same with its locations: the
+    name stack of every operation)."""
+    lowered = fn.lower(*avals)
+    return lowered.as_text(), lowered.as_text(debug_info=True)
+
+
+def _scopes_in(text: str) -> set:
+    return set(re.findall(r"srtb\.[a-z0-9_]+", text))
+
+
+def _served_programs(names, **cfg_extra):
+    """{program name: (lowered text, with locations)} of a FRESH
+    processor (the tracing caches key on the bound methods, so a second
+    build traces again)."""
+    staged = cfg_extra.pop("staged", False)
+    proc = SegmentProcessor(_cfg(**cfg_extra), staged=staged)
+    found = {name: _hlo(fn, avals)
+             for name, fn, avals, _d in proc.lowerables() if name in names}
+    assert set(found) == set(names), (proc.plan_name, sorted(found))
+    return found
+
+
+def _grid_program():
+    from srtb_tpu.parallel import mesh as M
+    from srtb_tpu.parallel.segment_dist import DistSegmentProcessor
+
+    cfg = _cfg(baseband_reserve_sample=False, dm=30.0,
+               use_emulated_fp64=True)
+    proc = DistSegmentProcessor(cfg, M.make_mesh(n_dm=4, n_seq=1),
+                                [0.0, 10.0, 20.0, 30.0, 40.0, 50.0,
+                                 60.0, 70.0])
+    raw = jax.ShapeDtypeStruct((cfg.segment_bytes(1),), np.uint8)
+    args = [raw, proc.chirp_bank, proc.rfi_mask]
+    return {"grid_step": _hlo(proc._step, args)}
+
+
+FAMILIES = {
+    # the quiet cell's plan: monolithic R2C, fused, overlap-save ring
+    "ring": (lambda: _served_programs({"ring"}), SIX),
+    "ring_cold": (lambda: _served_programs({"ring_cold"}), SIX),
+    "staged": (lambda: _served_programs(
+        {"stage_a", "stage_b", "stage_c"}, staged=True,
+        baseband_reserve_sample=False), SIX),
+    "quality": (lambda: _served_programs({"ring"}, quality_stats=True),
+                SIX | {S.QUALITY}),
+    "grid_step": (_grid_program, SIX),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_scopes_are_metadata_only(family, monkeypatch):
+    build, expected = FAMILIES[family]
+    with_scopes = build()
+    seen = set().union(*(_scopes_in(loc) for _t, loc in
+                         with_scopes.values()))
+    assert seen >= expected, f"{family}: missing {expected - seen}"
+    assert seen <= SIX | {S.QUALITY}, f"{family}: unknown {seen}"
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = build()
+    for name, (text, _loc) in with_scopes.items():
+        bare, bare_loc = without[name]
+        assert not _scopes_in(bare_loc), name
+        assert text == bare, \
+            f"{family}/{name}: the scopes changed more than metadata"
+
+
+def test_staged_stages_carry_their_own_scopes():
+    """Each staged program names what runs in it: the R2C's two halves in
+    (a) and (b), the waterfall and the detection in (c)."""
+    progs = _served_programs({"stage_a", "stage_b", "stage_c"},
+                             staged=True, baseband_reserve_sample=False)
+    locs = {name: _scopes_in(loc) for name, (_t, loc) in progs.items()}
+    assert {S.UNPACK, S.FFT_R2C} <= locs["stage_a"]
+    assert S.FFT_R2C in locs["stage_b"]
+    assert {S.WATERFALL, S.DETECT} <= locs["stage_c"]
+    assert S.FFT_R2C not in locs["stage_c"]
+
+
+# ------------------------------------------ (b) the served path's journal
+
+def _baseband(tmp_path, segments, dm, pulse_at=None, amp=25.0):
+    data = make_dispersed_baseband(
+        N * segments, 1405.0, 64.0, dm,
+        pulse_positions=pulse_at if pulse_at is not None else [],
+        pulse_amp=amp, nbits=8)
+    path = str(tmp_path / "bb.bin")
+    data.tofile(path)
+    return path
+
+
+@pytest.fixture()
+def served_run(tmp_path):
+    """A few ring segments, a pulse in the first: its record dumps."""
+    metrics.reset()
+    journal = str(tmp_path / "journal.jsonl")
+    cfg = _cfg(input_file_path=_baseband(tmp_path, 3, 0.05,
+                                         pulse_at=N // 2),
+               baseband_output_file_prefix=str(tmp_path / "out_"),
+               telemetry_journal_path=journal,
+               signal_detect_signal_noise_threshold=8.0,
+               inflight_segments=2)
+    with Pipeline(cfg) as pipe:
+        stats = pipe.run()
+        timer = pipe.stage_timer.summary()
+    recs = TR.load(journal)
+    assert len(recs) == stats.segments >= 3
+    yield recs, timer
+    metrics.reset()
+
+
+def test_dispatch_children_are_journaled(served_run):
+    recs, timer = served_run
+    for rec in recs:
+        ms = rec["stages_ms"]
+        assert {"ingest", "dispatch", "h2d", "enqueue", "fetch",
+                "sink"} <= set(ms)
+        assert ms["h2d"] + ms["enqueue"] <= ms["dispatch"] + 1e-3
+        assert ms["h2d"] >= 0 and ms["enqueue"] > 0
+    for stage in ("h2d", "enqueue", "dispatch"):
+        assert timer[stage]["count"] == len(recs)
+
+
+def test_candidate_stages_only_where_a_segment_dumps(served_run):
+    recs, _timer = served_run
+    dumped = [r for r in recs if r["dump"]]
+    quiet = [r for r in recs if not r["dump"]]
+    assert dumped and quiet
+    for rec in dumped:
+        ms = rec["stages_ms"]
+        assert {"d2h", "write", "publish"} <= set(ms)
+        assert ms["d2h"] + ms["write"] + ms["publish"] <= ms["sink"] + 1e-3
+        assert ms["write"] > 0
+    for rec in quiet:
+        assert not {"d2h", "write", "publish"} & set(rec["stages_ms"])
+
+
+def test_segment_wall_leaves_children_out(served_run):
+    recs, _timer = served_run
+    rec = next(r for r in recs if r["dump"])
+    ms = rec["stages_ms"]
+    top = ms["ingest"] + ms["dispatch"] + ms["fetch"] + ms["sink"]
+    assert telemetry.segment_wall(ms) == pytest.approx(top)
+    # flat siblings (the DM search loop has no "dispatch") all count
+    flat = {"ingest": 1.0, "h2d": 2.0, "enqueue": 3.0, "fetch": 4.0,
+            "record": 5.0}
+    assert telemetry.segment_wall(flat) == 15.0
+    report = TR.stage_stats(recs)
+    assert report["segment"]["max_ms"] <= max(
+        telemetry.segment_wall(r["stages_ms"]) for r in recs) + 1e-6
+
+
+def test_writer_pool_wait_is_the_candidates_write_time(tmp_path):
+    """With the asynchronous writer pool the files land after push()
+    returns; a later sink that drains (the checkpoint does, so does the
+    benchmark's last sink) puts the wait on the same segment's record."""
+    metrics.reset()
+    journal = str(tmp_path / "journal.jsonl")
+    cfg = _cfg(input_file_path=_baseband(tmp_path, 3, 0.05,
+                                         pulse_at=N // 2),
+               baseband_output_file_prefix=str(tmp_path / "out_"),
+               telemetry_journal_path=journal, writer_thread_count=2,
+               signal_detect_signal_noise_threshold=8.0)
+
+    class Drainer:
+        def __init__(self, pipe):
+            self.pipe = pipe
+
+        def push(self, work, positive):
+            if positive:
+                self.pipe.sinks[0].drain()
+
+    with Pipeline(cfg) as pipe:
+        pipe.sinks.append(Drainer(pipe))
+        pipe.run()
+        assert pipe.stage_timer.summary()["write"]["count"] >= 2
+    recs = TR.load(journal)
+    dumped = [r for r in recs if r["dump"]]
+    assert dumped and all("write" in r["stages_ms"] for r in dumped)
+    assert all("write" not in r["stages_ms"]
+               for r in recs if not r["dump"])
+    metrics.reset()
+
+
+# ------------------------------------------------ (c) the DM search loop
+
+def test_dm_search_loop_spans_and_journal(tmp_path):
+    metrics.reset()
+    journal = str(tmp_path / "grid.jsonl")
+    segments = 3
+    cfg = _cfg(
+        baseband_reserve_sample=False, dm=30.0, use_emulated_fp64=True,
+        dm_list=[0.0, 10.0, 20.0, 30.0, 40.0, 50.0, 60.0, 70.0],
+        n_devices=4,
+        input_file_path=_baseband(tmp_path, segments, 30.0,
+                                  pulse_at=N // 2),
+        baseband_output_file_prefix=str(tmp_path / "dm_"),
+        signal_detect_signal_noise_threshold=7.0,
+        telemetry_journal_path=journal)
+    search = DMSearchPipeline(cfg)
+    assert dict(search.mesh.shape) == {"dm": 4, "seq": 1}
+    try:
+        stats = search.run()
+    finally:
+        search.close()
+    assert stats.segments == segments
+    timer = search.stage_timer.summary()
+    five = ("ingest", "h2d", "enqueue", "fetch", "record")
+    assert set(timer) == set(five)
+    for stage in five:
+        assert timer[stage]["count"] == segments, stage
+    recs = TR.load(journal)
+    assert [r["segment"] for r in recs] == list(range(segments))
+    ids = [r["trace_id"] for r in recs]
+    assert len(set(ids)) == segments and all(ids)
+    for rec in recs:
+        assert rec["type"] == "segment_span"
+        assert rec["v"] == telemetry.SPAN_SCHEMA_VERSION
+        assert set(rec["stages_ms"]) == set(five)
+        assert rec["samples"] == N
+    upload = [b["h2d_bytes"] - a["h2d_bytes"]
+              for a, b in zip(recs, recs[1:])]
+    # the segment is replicated over the four dm-rows of the mesh
+    assert upload == [4 * N] * (segments - 1)
+    with open(search.trials_path) as f:
+        trials = [json.loads(ln) for ln in f]
+    assert len(trials) == segments and trials[0]["best_dm"] == 30.0
+    metrics.reset()
+
+
+def test_dm_search_loop_without_a_journal(tmp_path):
+    cfg = _cfg(
+        baseband_reserve_sample=False, dm=30.0,
+        dm_list=[0.0, 30.0], n_devices=2,
+        input_file_path=_baseband(tmp_path, 2, 30.0),
+        baseband_output_file_prefix=str(tmp_path / "dm_"))
+    search = DMSearchPipeline(cfg)
+    assert search.journal is None
+    assert search.run(max_segments=1).segments == 1
+    assert search.stage_timer.summary()["record"]["count"] == 1
+    search.close()
+
+
+def test_grid_processor_takes_a_staged_segment():
+    """``stage_input`` then ``process(staged)`` is ``process(host
+    bytes)``: the loop opens its ``h2d`` and ``enqueue`` spans around
+    the two, the processor knows nothing of spans; one upload is
+    counted either way."""
+    from srtb_tpu.parallel import mesh as M
+    from srtb_tpu.parallel.segment_dist import DistSegmentProcessor
+
+    cfg = _cfg(baseband_reserve_sample=False, dm=30.0)
+    proc = DistSegmentProcessor(cfg, M.make_mesh(n_dm=2, n_seq=1),
+                                [0.0, 30.0])
+    raw = make_dispersed_baseband(N, 1405.0, 64.0, 30.0,
+                                  pulse_positions=[N // 2], nbits=8)
+    metrics.reset()
+    whole = proc.process(raw)
+    assert metrics.get("h2d_bytes") == 2 * N
+    staged = proc.stage_input(raw)
+    assert isinstance(staged, jax.Array)
+    assert metrics.get("h2d_bytes") == 4 * N
+    apart = proc.process(staged)
+    assert metrics.get("h2d_bytes") == 4 * N
+    np.testing.assert_array_equal(np.asarray(whole.snr_peaks),
+                                  np.asarray(apart.snr_peaks))
+    metrics.reset()
+
+
+# ------------------------------------------------------ (d) the helper
+
+def test_span_records_and_cancels():
+    timer = StageTimer()
+    with span("h2d", timer, trace_id=7) as sp:
+        time.sleep(0.002)
+    assert sp.seconds >= 0.002
+    assert timer.last["h2d"] == sp.seconds and timer.counts["h2d"] == 1
+    with span("ingest", timer) as sp:
+        sp.cancel()
+    assert "ingest" not in timer.counts and sp.seconds >= 0.0
+    with span("write"):            # no timer: annotation only
+        pass
+    with pytest.raises(ValueError):
+        with span("fetch", timer):
+            raise ValueError("a failing stage is still timed")
+    assert timer.counts["fetch"] == 1
+
+
+def test_span_lands_on_the_profilers_host_plane(tmp_path):
+    """Annotation and journal share the stage's name; the trace_id rides
+    as an argument, not in the name the benchmark matches on."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("h2d", trace_id=41):
+            time.sleep(0.001)
+        with span("record"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    paths = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert paths
+    names = {}
+    for plane in ProfileData.from_file(str(paths[-1])).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("srtb:"):
+                    names[ev.name] = dict(ev.stats)
+    assert set(names) == {"srtb:h2d", "srtb:record"}
+    assert int(names["srtb:h2d"]["trace_id"]) == 41
+
+
+def test_span_is_cheap_outside_a_profiler_session():
+    timer = StageTimer()
+    with span("warm", timer, trace_id=1):
+        pass
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for i in range(1000):
+            with span("h2d", timer, trace_id=i + 1):
+                pass
+        best = min(best, (time.perf_counter() - t0) / 1000)
+    assert best < 20e-6, f"{best * 1e6:.1f} us a span"

@@ -332,64 +332,6 @@ def test_e2e_live_overload_degrades_gracefully(tmp_path):
         assert rec["packets_total"] > rec["packets_lost"]
 
 
-def test_trace_summary_wire_parser():
-    """The hand-rolled xplane wire parser against a hand-built message:
-    XSpace{planes=[XPlane{name, event_metadata{1: "fusion.1"},
-    lines=[XLine{events=[XEvent{metadata_id=1, duration_ps=...}]}]}]}."""
-    from srtb_tpu.tools import trace_summary as TS
-
-    def varint(x):
-        out = b""
-        while True:
-            b7 = x & 0x7F
-            x >>= 7
-            out += bytes([b7 | (0x80 if x else 0)])
-            if not x:
-                return out
-
-    def ld(field, payload):
-        return varint((field << 3) | 2) + varint(len(payload)) + payload
-
-    def vi(field, value):
-        return varint(field << 3) + varint(value)
-
-    meta = vi(1, 1) + ld(2, b"fusion.1")          # XEventMetadata
-    entry = vi(1, 1) + ld(2, meta)                # map entry key/value
-    smeta = vi(1, 9) + ld(2, b"hlo_category")     # XStatMetadata
-    sentry = vi(1, 9) + ld(2, smeta)
-    stat = vi(1, 9) + ld(5, b"convolution")       # XStat.str_value
-    ev1 = vi(1, 1) + vi(3, 5_000_000) + ld(4, stat)   # XEvent 5 us
-    ev2 = vi(1, 1) + vi(3, 7_000_000) + ld(4, stat)   # XEvent 7 us
-    line = ld(4, ev1) + ld(4, ev2)                # XLine.events
-    plane = (ld(2, b"/device:TPU:0") + ld(3, line) + ld(4, entry)
-             + ld(5, sentry))
-    space = ld(1, plane)
-
-    import pathlib
-    import tempfile
-    with tempfile.TemporaryDirectory() as td:
-        p = pathlib.Path(td) / "t.xplane.pb"
-        p.write_bytes(space)
-        planes = TS.parse_xspace(str(p))
-        assert planes == [("/device:TPU:0",
-                           {("fusion.1", "convolution"): 12_000_000})]
-        s = TS.summarize(str(p))
-        assert s[0]["plane"] == "/device:TPU:0"
-        assert s[0]["total_ms"] == 0.012
-        assert s[0]["top_ops"][0]["cat"] == "convolution"
-    assert TS.bucket("fusion.fft.3") == "fft"
-    assert TS.bucket("rfi_s1_dedisperse_df64") == "rfi+chirp"
-    assert TS.bucket("loop_transpose_fusion") == "transpose/copy"
-    # opaque fusion name + semantic category -> category decides
-    assert TS.bucket("fusion.42", "fft") == "fft"
-    assert TS.bucket("fusion.42", "elementwise") == "hlo:elementwise"
-    # round-3 advisor: a semantic category OUTRANKS a broad name match
-    # (this fused op carries "slice" in its name but is categorially a
-    # convert); an opaque category still falls through to the name
-    assert TS.bucket("fusion.slice.7", "convert") == "unpack+pack"
-    assert TS.bucket("pass1_kernel.slice", "loop fusion") == "pallas_fft"
-
-
 def test_plot_dm_curve(tmp_path):
     """The DM-search acceptance plot renders from a trials record."""
     import json
