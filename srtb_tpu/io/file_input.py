@@ -114,6 +114,8 @@ class BasebandFileReader:
         metrics.set("segment_pool_cached_bytes",
                     pool_stats["cached_bytes"])
         metrics.set("segment_pool_in_use", pool_stats["in_use"])
+        metrics.set("segment_pool_acquires", pool_stats["acquires"])
+        metrics.set("segment_pool_new_blocks", pool_stats["new_blocks"])
         self.logical_offset += self.segment_bytes
         if len(chunk) < self.segment_bytes - reserved:
             # final partial segment: emit zero-padded, then stop

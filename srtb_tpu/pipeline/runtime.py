@@ -2274,6 +2274,15 @@ class DMSearchPipeline:
     best trial per segment is logged.  This is the capability the
     reference leaves as a TODO ("DM search list for unknown source",
     ref: config.hpp:129-132), made practical by chip-parallel trials.
+
+    Who owns a segment's host buffer: the loop, from the pull to the
+    end of that segment's fetch.  ``stage_input`` returns with the
+    uploads pending and they read the buffer; once the step's results
+    are back every chip has consumed its input, and the loop returns
+    the buffer to the source's pool (file input with a pooled reader,
+    the condition ``Pipeline`` uses) so the next pull fills a warm
+    block.  Every way out of the step gives the buffer back: the
+    segment ``max_segments`` drops, and a step that raises.
     """
 
     def __init__(self, cfg: Config, source=None, mesh=None):
@@ -2321,33 +2330,47 @@ class DMSearchPipeline:
         it = iter(self.source)
         with open(self.trials_path if write_records else os.devnull,
                   "a") as trials_file:
+            pool = None
             for i in itertools.count():
                 with stage_span("ingest", timer) as sp:
                     seg = next(it, None)
                     if seg is None:
                         sp.cancel()
-                if seg is None or (max_segments is not None
-                                   and i >= max_segments):
+                if seg is None:
                     break
-                stages = {"ingest": sp.seconds}
-                tid = _stamp_trace_id(seg)
-                with stage_span("h2d", timer, tid) as sp:
-                    staged = proc.stage_input(seg.data)
-                stages["h2d"] = sp.seconds
-                with stage_span("enqueue", timer, tid) as sp:
-                    res = proc.process(staged)
-                stages["enqueue"] = sp.seconds
+                # looked up at the pull: a source may swap its reader
+                # between passes and drop it when it ends
+                pool = getattr(self.source, "pool", None) \
+                    if cfg.input_file_path else None
+                try:
+                    if max_segments is not None and i >= max_segments:
+                        break
+                    stages = {"ingest": sp.seconds}
+                    tid = _stamp_trace_id(seg)
+                    with stage_span("h2d", timer, tid) as sp:
+                        staged = proc.stage_input(seg.data)
+                    stages["h2d"] = sp.seconds
+                    with stage_span("enqueue", timer, tid) as sp:
+                        res = proc.process(staged)
+                    stages["enqueue"] = sp.seconds
+                    # reduce over (stream, boxcar) axes -> per-dm
+                    # quantities; every device transfer runs under the
+                    # fail-fast deadline (a wedged device blocks
+                    # transfers, not just compute)
+                    with stage_span("fetch", timer, tid) as sp:
+                        peaks, counts, zero = sync_with_deadline(
+                            cfg.segment_deadline_s,
+                            lambda: (jax.device_get(res.snr_peaks),
+                                     jax.device_get(res.signal_counts),
+                                     jax.device_get(res.zero_count)))
+                    stages["fetch"] = sp.seconds
+                finally:
+                    # not before the fetch: until the results are back
+                    # a pending upload may still read the buffer, and
+                    # the next pull would zero and refill it
+                    if pool is not None:
+                        pool.release(seg.data)
                 n_dm = len(self.dm_list)
-                # reduce over (stream, boxcar) axes -> per-dm quantities;
-                # every device transfer runs under the fail-fast deadline
-                # (a wedged device blocks transfers, not just compute)
-                with stage_span("fetch", timer, tid) as sp:
-                    peaks, counts, zero = sync_with_deadline(
-                        cfg.segment_deadline_s,
-                        lambda: (jax.device_get(res.snr_peaks),
-                                 jax.device_get(res.signal_counts),
-                                 jax.device_get(res.zero_count)))
-                stages["fetch"] = sp.seconds
                 peaks = peaks.reshape(n_dm, -1)
                 counts = counts.reshape(n_dm, -1)
                 zero = zero.reshape(n_dm, -1).max(axis=-1)
@@ -2392,6 +2415,11 @@ class DMSearchPipeline:
                         timestamp_ns=getattr(seg, "timestamp", 0),
                         trace_id=tid))
         self.stats.elapsed_s = time.perf_counter() - start
+        if pool is not None:
+            ps = pool.stats()
+            log.info(f"[dm_search] {self.stats.segments} segments; "
+                     f"reader pool {pool.name}: {ps['acquires']} "
+                     f"acquires, {ps['new_blocks']} new blocks")
         return self.stats
 
     def close(self) -> None:

@@ -433,6 +433,10 @@ class Metrics:
         "last_segment_unix": "Wall-clock stamp of the last drained "
                              "segment",
         "segment_pool_in_use": "Reader buffer-pool buffers in use",
+        "segment_pool_acquires": "Reader buffer-pool acquires "
+                                 "(cumulative)",
+        "segment_pool_new_blocks": "Reader buffer-pool acquires that "
+                                   "allocated a new block (cumulative)",
         "file_bytes_read": "Bytes read from baseband input files",
     }
 

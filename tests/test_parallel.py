@@ -14,6 +14,7 @@ from srtb_tpu.parallel import dm_grid, mesh as M
 from srtb_tpu.parallel.segment_dist import DistSegmentProcessor
 from srtb_tpu.pipeline.segment import SegmentProcessor
 from srtb_tpu.io.synth import make_dispersed_baseband
+from srtb_tpu.utils.bufferpool import BufferPool
 
 
 def _cfg(tmpdir="", n=1 << 14, dm=30.0):
@@ -159,6 +160,196 @@ def test_dm_search_pipeline(tmp_path):
         rec = json.loads(f.readline())
     assert rec["best_dm"] == 30.0
     assert rec["best_snr"] > 7.0
+
+
+# ---------------------------------------- the loop and its reader buffers
+
+GRID_SEGMENTS = 6
+
+
+class _SpyPool(BufferPool):
+    """A ``BufferPool`` that writes what happens to it into ``events``
+    (shared with the spy around the fetch): ("acquire" | "release",
+    address of the block)."""
+
+    def __init__(self, events):
+        super().__init__("spy")
+        self.events = events
+
+    def acquire(self, nbytes, zero=True):
+        buf = super().acquire(nbytes, zero)
+        self.events.append(("acquire", buf.ctypes.data))
+        return buf
+
+    def release(self, buf):
+        self.events.append(("release", buf.ctypes.data))
+        super().release(buf)
+
+
+class _PoolLessSource:
+    """What a UDP receiver looks like to the loop: segments in buffers
+    of its own, no ``pool`` attribute."""
+
+    def __init__(self, cfg):
+        from srtb_tpu.pipeline.work import SegmentWork
+        raw = np.fromfile(cfg.input_file_path, dtype=np.uint8)
+        self._segs = iter(
+            SegmentWork(data=chunk.copy(), timestamp=k)
+            for k, chunk in enumerate(
+                raw.reshape(-1, cfg.baseband_input_count)))
+
+    def __iter__(self):
+        return self._segs
+
+
+@pytest.fixture(scope="module")
+def grid(tmp_path_factory):
+    """One compiled ``DMSearchPipeline`` over a file of
+    ``GRID_SEGMENTS`` segments (a pulse at DM 30 in each); every test
+    gives it a source and an output file of its own through
+    ``_grid_run``."""
+    from srtb_tpu.pipeline.runtime import DMSearchPipeline
+    tmp = tmp_path_factory.mktemp("grid")
+    cfg = _cfg()
+    n = cfg.baseband_input_count
+    raw = make_dispersed_baseband(
+        n * GRID_SEGMENTS, cfg.baseband_freq_low, cfg.baseband_bandwidth,
+        30.0, pulse_positions=[n * k + n // 2
+                               for k in range(GRID_SEGMENTS)],
+        pulse_amp=25.0)
+    path = str(tmp / "in.bin")
+    raw.tofile(path)
+    cfg = cfg.replace(
+        dm_list=[0.0, 30.0, 60.0, 90.0], n_devices=4,
+        input_file_path=path,
+        baseband_output_file_prefix=str(tmp / "dm_"),
+        signal_detect_signal_noise_threshold=7.0)
+    search = DMSearchPipeline(cfg)
+    yield search
+    search.close()
+
+
+def _grid_run(search, source, out, **kw):
+    """``search.run(**kw)`` on ``source``, records into ``out``; returns
+    the records written (also when the run raises: re-raised after)."""
+    import json
+
+    from srtb_tpu.pipeline.runtime import PipelineStats
+    search.source = source
+    search.trials_path = str(out)
+    search.stats = PipelineStats()
+    try:
+        search.run(**kw)
+    finally:
+        source_close = getattr(source, "close", None)
+        if source_close is not None:
+            source_close()
+    with open(out) as f:
+        return [json.loads(ln) for ln in f]
+
+
+def _pooled_reader(search, pool):
+    from srtb_tpu.io.file_input import BasebandFileReader
+    return BasebandFileReader(search.cfg, buffer_pool=pool)
+
+
+def test_dm_search_returns_reader_buffers(grid, tmp_path):
+    """A file of 6 segments through a pool of its own: the loop hands
+    every buffer back, so the reader allocates once and fills the same
+    warm block again; the records are those of a source with no pool
+    (which runs as it always did), timestamps apart."""
+    pool = BufferPool("t")
+    got = _grid_run(grid, _pooled_reader(grid, pool), tmp_path / "a")
+    stats = pool.stats()
+    assert grid.stats.segments == GRID_SEGMENTS
+    # one per pull and one for the reader's look past the file's end
+    assert stats["acquires"] == GRID_SEGMENTS + 1
+    assert stats["new_blocks"] <= 2
+    assert stats["in_use"] == 0 and stats["cached_blocks"] >= 1
+    want = _grid_run(grid, _PoolLessSource(grid.cfg), tmp_path / "b")
+    assert grid.stats.segments == GRID_SEGMENTS
+    assert len(got) == len(want) == GRID_SEGMENTS
+    assert any(r["best_dm"] == 30.0 and r["best_snr"] > 7.0 for r in got)
+    for a, b in zip(got, want):
+        a.pop("timestamp"), b.pop("timestamp")
+        assert a == b
+
+
+def test_dm_search_releases_after_its_own_fetch(grid, tmp_path,
+                                                monkeypatch):
+    """Order: the uploads ``stage_input`` starts may still be reading
+    the buffer until the step's results are back, so each segment's
+    release follows the return of its own fetch and nothing of the pool
+    is touched between the pull and that return."""
+    from srtb_tpu.pipeline import runtime
+    events = []
+    real = runtime.sync_with_deadline
+
+    def spy_sync(deadline_s, fn):
+        events.append(("fetch", None))
+        out = real(deadline_s, fn)
+        events.append(("fetched", None))
+        return out
+
+    monkeypatch.setattr(runtime, "sync_with_deadline", spy_sync)
+    _grid_run(grid, _pooled_reader(grid, _SpyPool(events)),
+              tmp_path / "a")
+    # the reader's last acquire finds the file read and gives it back
+    tail = events[-2:]
+    assert [e for e, _ in tail] == ["acquire", "release"]
+    steps = events[:-2]
+    assert len(steps) == 4 * GRID_SEGMENTS
+    for k in range(GRID_SEGMENTS):
+        (e0, a0), (e1, _), (e2, _), (e3, a3) = steps[4 * k:4 * k + 4]
+        assert (e0, e1, e2, e3) == ("acquire", "fetch", "fetched",
+                                    "release"), (k, steps)
+        assert a0 == a3
+
+
+@pytest.mark.parametrize("way_out", ["max_segments", "stage_input",
+                                     "process", "fetch"])
+def test_dm_search_every_way_out_gives_the_buffer_back(
+        grid, tmp_path, monkeypatch, way_out):
+    """The segment pulled and dropped by ``max_segments``, and a segment
+    in hand when the upload, the step or the fetch raises: the buffer
+    is back in the pool and the exception is the caller's."""
+    from srtb_tpu.pipeline import runtime
+
+    class Boom(RuntimeError):
+        pass
+
+    def fail_on_second(real):
+        calls = []
+
+        def wrapper(*a, **kw):
+            calls.append(1)
+            if len(calls) == 2:
+                raise Boom(way_out)
+            return real(*a, **kw)
+        return wrapper
+
+    pool = BufferPool("t")
+    reader = _pooled_reader(grid, pool)
+    if way_out == "max_segments":
+        recs = _grid_run(grid, reader, tmp_path / "a", max_segments=2)
+        assert len(recs) == 2 and grid.stats.segments == 2
+        # the third segment was pulled, dropped and handed back
+        assert pool.stats()["acquires"] == 3
+    else:
+        if way_out == "fetch":
+            monkeypatch.setattr(runtime, "sync_with_deadline",
+                                fail_on_second(
+                                    runtime.sync_with_deadline))
+        else:
+            monkeypatch.setattr(grid.processor, way_out,
+                                fail_on_second(
+                                    getattr(grid.processor, way_out)))
+        with pytest.raises(Boom, match=way_out):
+            _grid_run(grid, reader, tmp_path / "a")
+        assert grid.stats.segments == 1
+        assert pool.stats()["acquires"] == 2
+    stats = pool.stats()
+    assert stats["in_use"] == 0 and stats["new_blocks"] == 1
 
 
 def test_dist_segment_two_streams():

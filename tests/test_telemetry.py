@@ -588,10 +588,17 @@ def test_file_reader_ingest_gauges(tmp_path):
                  input_file_path=str(path),
                  baseband_reserve_sample=False)
     reader = BasebandFileReader(cfg, buffer_pool=BufferPool("t"))
-    next(reader)
+    seg = next(reader)
     snap = metrics.snapshot()
     assert snap["file_bytes_read"] == 1 << 10
     assert snap["file_bytes_read_per_sec_10s"] > 0
     assert snap["segment_pool_in_use"] == 1
+    assert snap["segment_pool_acquires"] == 1
+    assert snap["segment_pool_new_blocks"] == 1
+    reader.pool.release(seg.data)
+    next(reader)
+    snap = metrics.snapshot()
+    assert snap["segment_pool_acquires"] == 2
+    assert snap["segment_pool_new_blocks"] == 1
     reader.close()
     metrics.reset()

@@ -25,6 +25,31 @@ def test_buffer_pool_reuse():
     assert pool.free_all() == 0
 
 
+def test_buffer_pool_counts_acquires_and_new_blocks():
+    """The two counters that say whether reuse engages: a released
+    block serves the next acquire of its size and allocates nothing; a
+    second buffer out at once, or a size no cached block fits, does."""
+    pool = BufferPool("count")
+    assert pool.stats()["acquires"] == pool.stats()["new_blocks"] == 0
+    for _ in range(6):
+        pool.release(pool.acquire(1024))
+    assert pool.stats()["acquires"] == 6
+    assert pool.stats()["new_blocks"] == 1
+    a = pool.acquire(1024)
+    b = pool.acquire(1024)          # the one cached block is out
+    c = pool.acquire(64)            # too small for a 1024 block
+    stats = pool.stats()
+    assert (stats["acquires"], stats["new_blocks"]) == (9, 3)
+    assert stats["in_use"] == 3
+    for buf in (a, b, c):
+        pool.release(buf)
+    pool.free_all()                 # the counters are cumulative
+    pool.release(pool.acquire(1024))
+    stats = pool.stats()
+    assert (stats["acquires"], stats["new_blocks"]) == (10, 4)
+    assert stats["in_use"] == 0
+
+
 def test_buffer_pool_leak_detection():
     pool = BufferPool("leak")
     a = pool.acquire(64)
