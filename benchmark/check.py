@@ -24,17 +24,27 @@ import numpy as np
 
 
 class Checks:
-    """Prints each number beside its limit and keeps the verdict."""
+    """Prints each number beside its limit, keeps the verdict and what
+    the result line carries: per limit the widest reading, and the text
+    of every requirement that failed."""
+
+    MAX_TEXT, MAX_FAILURES = 200, 16
 
     def __init__(self, limits: dict):
         self.limits = limits
         self.ok = True
         self.failed_segments: set = set()
+        self.widest: dict = {}       # limit key -> widest value read
+        self.failures: list = []     # texts of failed requirements
 
     def number(self, name: str, value: float, limit_key: str) -> None:
         limit = float(self.limits[limit_key])
+        value = float(value)
         good = bool(np.isfinite(value)) and value <= limit
         self.ok = self.ok and good
+        old = self.widest.get(limit_key)
+        if old is None or not value <= old:     # a NaN is the widest
+            self.widest[limit_key] = value
         print(f"[check] {name} = {value!r} (limit {limit!r}) "
               f"{'ok' if good else 'FAIL'}", flush=True)
 
@@ -43,7 +53,32 @@ class Checks:
             self.ok = False
             if segment is not None:
                 self.failed_segments.add(segment)
+            self.failures.append(what[:self.MAX_TEXT])
             print(f"[check] FAIL: {what}", flush=True)
+
+    def _failed_texts(self) -> list:
+        more = len(self.failures) - self.MAX_FAILURES
+        return self.failures[:self.MAX_FAILURES] + (
+            [f"... and {more} more"] if more > 0 else [])
+
+    def summary(self) -> dict:
+        """``{"<limit key>": [widest value, limit], ..., "failed":
+        [texts]}``: the result line's ``checks``.  A reading that is not
+        finite is written as text (JSON has no such number)."""
+        out = {key: [value if np.isfinite(value) else repr(value),
+                     float(self.limits[key])]
+               for key, value in self.widest.items()}
+        out["failed"] = self._failed_texts()
+        return out
+
+    def lines(self) -> list:
+        """The same as ``[check]`` lines, for the end of stderr."""
+        rows = []
+        for key, value in self.widest.items():
+            limit = float(self.limits[key])
+            rows.append(f"[check] widest {key} = {value!r} (limit "
+                        f"{limit!r}) {'ok' if value <= limit else 'FAIL'}")
+        return rows + [f"[check] FAIL: {t}" for t in self._failed_texts()]
 
 
 def noise_scale(series: np.ndarray) -> float:

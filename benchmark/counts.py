@@ -18,10 +18,11 @@ def segment_bytes_per_chip(p: dict, chips: int = 1) -> float:
     chips the trials divide among them and every chip transforms the
     whole segment (the DM grid's ("dm", "seq") mesh with seq = 1)."""
     n = p["n"]
+    streams = p["streams"]              # every stream runs the chain
     trials = max(1, len(p["dm_list"]))
     per_chip = -(-trials // chips)
-    packed = n * p["bits"] / 8          # uint8 in
+    packed = n * abs(p["bits"]) / 8     # uint8 in
     r2c = 4 * n + 8 * (n // 2)          # f32 in, complex64 out
     c2c = 2 * 8 * (n // 2)              # complex64 in and out, per trial
     detect = 8 * (n // 2)               # one read of the waterfall
-    return packed + r2c + per_chip * (c2c + detect)
+    return streams * (packed + r2c + per_chip * (c2c + detect))

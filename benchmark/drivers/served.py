@@ -19,6 +19,7 @@ import numpy as np
 
 from benchmark import check
 from benchmark.harness import remove_file, say
+from benchmark.reference import chain
 
 
 class Stamp:
@@ -178,20 +179,26 @@ def judge(run, ref: dict) -> None:
             ck.require(not s.fired, f"{tag} holds no pulse but fired "
                        f"({s.detections} detections)", key)
     compared = 0
+    streams = run.params["streams"]
     for s in done:
         if s.series is None:
             continue
-        name = f"s{s.file_seg}.t0"
-        want = ref[f"{name}.series"]
-        where = f"{s.phase}.{s.index}.file_seg{s.file_seg}"
-        ck.number(f"series_gap.{where}", check.series_gap(s.series, want),
-                  "series_gap")
-        if s.pulsed:
+        series = np.asarray(s.series).reshape(streams, -1)
+        peaks = np.asarray(s.snr_peaks).reshape(streams, -1)
+        for st in range(streams):
+            name = f"{chain.stream_tag(s.file_seg, st)}.t0"
+            where = f"{s.phase}.{s.index}.file_seg{s.file_seg}" \
+                + (f".p{st}" if st else "")
+            ck.number(f"series_gap.{where}",
+                      check.series_gap(series[st], ref[f"{name}.series"]),
+                      "series_gap")
+            if not s.pulsed:
+                continue
             ck.number(f"snr_gap.{where}",
-                      check.relative_gap(s.snr_peaks,
+                      check.relative_gap(peaks[st],
                                          ref[f"{name}.snr_peaks"]),
                       "snr_gap")
-            got_bin = int(np.argmax(np.asarray(s.series).reshape(-1)))
+            got_bin = int(np.argmax(series[st]))
             ck.number(f"bin_gap.{where}",
                       abs(got_bin - int(ref[f"{name}.peak_bins"][0])),
                       "bin_gap")

@@ -4,7 +4,8 @@ file (``Layout``) and the bytes themselves, made on the device from
 
 Semantics are ``srtb_tpu/io/synth.py``'s (unit Gaussian noise plus
 impulses dispersed by the inverse of the dedispersion chirp, a digitizer
-that keeps ~3 sigma in range, MSB-first sub-byte packing) at a speed a
+that keeps ~3 sigma in range, MSB-first sub-byte packing, 8-bit samples
+unsigned or two's complement) at a speed a
 benchmark can pay every run: the noise, the quantizer and the packing run
 on the device in one jitted call per block; only one short dispersed-pulse
 template is computed on the host, once.  ``selftest/test_gen.py`` holds
@@ -13,6 +14,14 @@ the bytes against ``io/synth`` on the same floats.
 The digitizer's gain is fixed (unit noise), not re-derived from each
 block's sample deviation as ``io/synth.quantize`` does: a block with a
 pulse in it must not be scaled differently from its neighbours.
+
+A file may hold several streams (``reference/chain.FORMATS``): each is
+independent noise from the seed with the same pulse template added,
+and the streams are written interleaved as the format says.  Everything
+counted in samples (``n``, ``stride``, ``total``, ``pulse_at``) is per
+stream, as the program's ``baseband_input_count`` is (its reader takes
+``count * |bits| / 8 * streams`` bytes a segment and ``unpack_streams``
+returns ``[streams, count]``); only ``bytes_of`` counts the streams.
 """
 
 from __future__ import annotations
@@ -51,6 +60,8 @@ class Layout:
         p = params
         self.n = p["n"]
         self.bits = p["bits"]
+        self.streams = p["streams"]
+        self.group_bytes = p["group_bytes"]
         self.channels = min(p["channels"], self.n // 2)
         self.reserved = chain.nsamps_reserved(p)
         self.stride = self.n - self.reserved
@@ -88,7 +99,9 @@ class Layout:
                     // col * col + jitter
 
     def bytes_of(self, samples: int) -> int:
-        return samples * self.bits // 8
+        """Bytes that ``samples`` samples of every stream take in the
+        file."""
+        return samples * abs(self.bits) // 8 * self.streams
 
     @property
     def stride_bytes(self) -> int:
@@ -146,62 +159,82 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, seed >> 32)
 
 
-def make_block_fn(bits: int, cols: int, sigma: float = 1.0):
+STREAM_SALT = 0x5354524D     # "STRM": keeps stream keys off block keys
+
+
+def make_block_fn(bits: int, cols: int, sigma: float = 1.0,
+                  streams: int = 1, group_bytes: int = 1):
     """The jitted generator of one block: ``cols`` bytes = ``cols *
-    8/bits`` samples.  Sample ``per_byte * b + j`` of the block is
-    ``noise[j, b] + planes[j, col0 + b]``: the byte axis stays minor
-    (lane-dense on the chip), fields are planes."""
+    8/|bits|`` samples of every stream.  Sample ``per_byte * b + j`` of a
+    stream's block is ``noise[j, b] + planes[j, col0 + b]``: the byte
+    axis stays minor (lane-dense on the chip), fields are planes.
+    Stream 0 draws from ``fold_in(key, index)`` (a one-stream file is
+    what it always was), stream s > 0 from a key of its own; several
+    streams leave the block interleaved in groups of ``group_bytes``."""
     import jax
     import jax.numpy as jnp
 
-    per_byte = 8 // bits
-    levels = 1 << bits
+    width = abs(bits)
+    per_byte = 8 // width
+    levels = 1 << width
     mid = levels / 2
     gain = np.float32((levels / 2 - 0.5) / 3.0 / sigma)
 
-    @jax.jit
-    def block(key, index, planes, col0):
+    def one_stream(key, index, planes, col0):
         k = jax.random.fold_in(key, index)
         sig = jax.random.normal(k, (per_byte, cols), dtype=jnp.float32)
         sig = sig + jax.lax.dynamic_slice(planes, (0, col0),
                                           (per_byte, cols))
         return quantize_pack(sig, bits, gain, mid)
 
+    @jax.jit
+    def block(key, index, planes, col0):
+        if streams == 1:
+            return one_stream(key, index, planes, col0)
+        salted = jax.random.fold_in(key, STREAM_SALT)
+        rows = [one_stream(key if s == 0 else jax.random.fold_in(salted, s),
+                           index, planes, col0) for s in range(streams)]
+        out = jnp.stack(rows).reshape(streams, cols // group_bytes,
+                                      group_bytes)
+        return out.transpose(1, 0, 2).reshape(-1)
+
     return block
 
 
 def quantize_pack(sig, bits: int, gain, mid):
     """[per_byte, cols] floats -> uint8[cols], ``io/synth.quantize`` +
-    ``pack_subbyte`` with the gain given."""
+    ``pack_subbyte`` with the gain given; ``bits`` -8 writes the same
+    level as two's complement (level - 128: the top bit flipped)."""
     import jax.numpy as jnp
 
-    per_byte = 8 // bits
-    q = jnp.clip(jnp.round(sig * gain + mid), 0, (1 << bits) - 1)
+    width = abs(bits)
+    per_byte = 8 // width
+    q = jnp.clip(jnp.round(sig * gain + mid), 0, (1 << width) - 1)
     q = q.astype(jnp.uint8)
-    out = q[0] << (8 - bits)
+    out = q[0] << (8 - width)
     for j in range(1, per_byte):
-        out = out | (q[j] << (8 - bits * (j + 1)))
-    return out
+        out = out | (q[j] << (8 - width * (j + 1)))
+    return out ^ jnp.uint8(0x80) if bits < 0 else out
 
 
 def write_file(path: str, params: dict, lay: Layout, seed: int,
                block_samples: int = 1 << 26) -> dict:
     """Make the file on the device, block by block, and write it.
     Returns the bytes written, the blocks made and the template's
-    seconds."""
+    seconds.  A template that reaches past either end of the file is
+    cut there (the sky before the recording began)."""
     import jax
     import jax.numpy as jnp
 
     bits = lay.bits
-    if bits not in (1, 2, 4):
-        raise ValueError("the generator packs 1/2/4-bit samples")
-    per_byte = 8 // bits
+    per_byte = 8 // abs(bits)
+    group = lay.group_bytes
     blk = min(block_samples, 1 << (lay.total - 1).bit_length())
     at = sorted(lay.pulse_at.values())
     gap = min((b - a for a, b in zip(at, at[1:])), default=None)
     while gap is not None and blk + lay.template_len > gap:
         blk //= 2       # a block meets one pulse template at most
-    if blk < per_byte:
+    if blk < per_byte * group:
         raise ValueError("pulses closer together than their template")
     cols = blk // per_byte
     t0 = time.perf_counter()
@@ -218,12 +251,15 @@ def write_file(path: str, params: dict, lay: Layout, seed: int,
     planes = jax.device_put(planes)
     starts = sorted(g - length // 2 for g in lay.pulse_at.values())
     for s in starts:
-        if s % per_byte or s < 0 or s + length > lay.total:
-            raise ValueError("a pulse template does not fit the file")
-    block = make_block_fn(bits, cols)
+        if s % per_byte:
+            raise ValueError("a pulse template starts inside a byte")
+    block = make_block_fn(bits, cols, streams=lay.streams,
+                          group_bytes=group)
     key = seed_key(seed)
     n_blocks = -(-lay.total // blk)
     total_bytes = lay.bytes_of(lay.total)
+    if total_bytes % (lay.streams * group):
+        raise ValueError("the file does not end on a whole group")
 
     def col0_of(b: int) -> int:
         s0 = b * blk

@@ -15,6 +15,7 @@ class SegRec:
     new_samples: int           # un-overlapped samples it brings
     segment: object = None     # the program's SegmentWork (identity)
     handover: float = 0.0      # perf_counter: bytes in host memory
+    next_pull: float = 0.0     # perf_counter: the program's next pull
     done: float = 0.0          # perf_counter: sinks returned
     fired: bool | None = None  # the program's verdict: candidate or not
     detections: int = 0

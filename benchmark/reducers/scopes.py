@@ -29,7 +29,7 @@ import os
 import re
 
 from benchmark import spec
-from benchmark.trace import DEVICE_PLANE, OPS_LINE
+from benchmark.trace import DEVICE_PLANE, OPS_LINE, short_name
 
 # a component reads ``srtb.chirp``, or ``vmap(srtb.chirp)`` where the
 # stage is traced under a transformation (the grid's trials, a batch plan)
@@ -94,9 +94,10 @@ def _text(view) -> str:
 # stats 5; XStat: metadata_id 1, str_value 5, ref_value 7 (the id of a
 # stat_metadata whose name is the string); XStatMetadata: id 1, name 2.
 
-def _plane(buf: memoryview):
+def _plane(buf: memoryview, names: dict | None = None):
     """-> (name, {event metadata id: scope}, [(offset_ps, duration_ps,
-    metadata id)] of the XLA Ops line)."""
+    metadata id)] of the XLA Ops line).  ``names``, where given, is
+    filled with {event metadata id: the operation's HLO text}."""
     name = ""
     lines, metas, stat_names = [], [], {}
     for number, val in _fields(buf):
@@ -119,6 +120,8 @@ def _plane(buf: memoryview):
         for number, val in _fields(pair.get(2, memoryview(b""))):
             if number == 1:
                 meta_id = val
+            elif number == 2 and names is not None:
+                names[meta_id] = _text(val)
             elif number == 5:
                 stat = dict(_fields(val))
                 text = _text(stat[5]) if 5 in stat \
@@ -180,6 +183,25 @@ def scope_seconds(path: str) -> dict:
     return {k: v / devices for k, v in total.items()}
 
 
+def op_scopes(path: str) -> dict:
+    """{an operation's name as ``trace.short_name`` prints it: its
+    scope}, over the operations that ran on any device plane: the lookup
+    ``Trace.top_ops`` names its rows from.  {} where no operation
+    carries a ``srtb.`` scope (a program without the scopes, the CPU)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: dict = {}
+    for number, val in _fields(space):
+        if number != 1:
+            continue
+        names: dict = {}
+        _name, scopes, events = _plane(val, names)
+        for meta in {m for _off, _dur, m in events}:
+            out.setdefault(short_name(names.get(meta, "")),
+                           scopes.get(meta, UNSCOPED))
+    return {} if set(out.values()) <= {UNSCOPED} else out
+
+
 # ------------------------------------------------------------- the reader
 
 _CACHE: dict = {}
@@ -208,8 +230,8 @@ def scope_ms_per_seg(rec, args):
         _CACHE.clear()
         _CACHE[key] = scope_seconds(path)
     by_scope = _CACHE[key]
-    if not by_scope:
-        return None
+    if not any(s in by_scope for s in args["scopes"]):
+        return None       # no operation carries the scope: nothing to read
     return sum(by_scope.get(s, 0.0) for s in args["scopes"]) \
         / tr.segments * 1e3
 

@@ -54,11 +54,30 @@ def _fft(a, axis, inverse=False, workers=1):
 
 # ------------------------------------------------------------------ params
 
+# Sample formats (``baseband_format_type``): how many data streams a file
+# holds and how many bytes of one stream stand together before the next
+# stream's.  The benchmark's own table, written from the upstream
+# description (SURVEY.md; ref: unpack.hpp:214-283,
+# backend_registry.hpp:36-92), not imported from the program:
+#   simple                 one stream
+#   interleaved_samples_2  two streams, bytes "1 2 1 2" (cpsr2 files)
+#   naocpsr_snap1          two streams, 8-bit samples "1 1 2 2"
+# ``gznupsr_a1`` (VDIF header, 4-sample word groups) waits for the PR
+# that brings a packet source.
+FORMATS = {
+    "simple": {"streams": 1, "group_bytes": 1},
+    "interleaved_samples_2": {"streams": 2, "group_bytes": 1},
+    "naocpsr_snap1": {"streams": 2, "group_bytes": 2},
+}
+# bits of one sample; negative = two's complement
+BITS = (1, 2, 4, 8, -8)
+
 
 def params_from_config(options: dict) -> dict:
     """The numbers the chain needs, from a configuration file's
     ``options`` (the program's option names; values may be expressions
-    such as ``"2 ** 27"`` or ``"1405 + 32"``)."""
+    such as ``"2 ** 27"`` or ``"1405 + 32"``).  Everything counted in
+    samples (``n``, the reserved tail) is per stream."""
     def num(key, default=None):
         v = options.get(key, default)
         if isinstance(v, str):
@@ -68,9 +87,20 @@ def params_from_config(options: dict) -> dict:
     dm_list = options.get("dm_list")
     if isinstance(dm_list, str):
         dm_list = [float(x) for x in dm_list.split(",") if x.strip()]
+    bits = int(num("baseband_input_bits"))
+    name = str(options.get("baseband_format_type", "simple"))
+    if bits not in BITS or name not in FORMATS:
+        raise ValueError(f"the benchmark knows samples of {BITS} bits in "
+                         f"the formats {sorted(FORMATS)}, not {bits} bits "
+                         f"in {name!r}")
+    fmt = FORMATS[name]
+    if name == "naocpsr_snap1" and abs(bits) != 8:
+        raise ValueError("naocpsr_snap1 interleaves pairs of 8-bit samples")
     return {
         "n": int(num("baseband_input_count")),
-        "bits": int(num("baseband_input_bits")),
+        "bits": bits,
+        "streams": fmt["streams"],
+        "group_bytes": fmt["group_bytes"],
         "freq_low": float(num("baseband_freq_low")),
         "bandwidth": float(num("baseband_bandwidth")),
         "sample_rate": float(num("baseband_sample_rate")),
@@ -128,9 +158,34 @@ def ranges_on_threads(fn, n: int, workers: int, align: int = 1) -> list:
         return list(pool.map(lambda r: fn(*r), ranges))
 
 
+def segment_bytes(p: dict) -> int:
+    """Bytes of one segment in the file, all streams."""
+    return p["n"] * abs(p["bits"]) // 8 * p["streams"]
+
+
+def deinterleave(raw: np.ndarray, p: dict) -> list:
+    """The bytes of a file's segment -> the bytes of each stream
+    (ref: unpack.hpp:214-283): stream s owns bytes ``group_bytes * (S * j
+    + s) ...`` of every group j."""
+    streams = p["streams"]
+    rows = np.asarray(raw, dtype=np.uint8).reshape(-1, streams,
+                                                   p["group_bytes"])
+    # one stream: a view, nothing is copied
+    return [np.ascontiguousarray(rows[:, s, :]).reshape(-1)
+            for s in range(streams)]
+
+
+def stream_tag(file_seg: int, stream: int) -> str:
+    """The prefix of a segment's answers in the child's ``.npz``:
+    ``s<k>`` for stream 0 (the only one of a one-stream file),
+    ``s<k>.p<s>`` for stream s > 0."""
+    return f"s{file_seg}" if stream == 0 else f"s{file_seg}.p{stream}"
+
+
 def unpack(raw: np.ndarray, bits: int, workers: int = 1) -> np.ndarray:
     """uint8 bytes -> float64 samples, one stream (ref: unpack.hpp:43-140):
-    1/2/4-bit unsigned fields MSB-first in each byte; 8 unsigned."""
+    1/2/4-bit unsigned fields MSB-first in each byte; 8 unsigned; -8
+    two's complement."""
     b = np.asarray(raw, dtype=np.uint8)
     if bits in (1, 2, 4):
         count = 8 // bits
@@ -147,6 +202,8 @@ def unpack(raw: np.ndarray, bits: int, workers: int = 1) -> np.ndarray:
         return out.reshape(-1)
     if bits == 8:
         return b.astype(np.float64)
+    if bits == -8:
+        return b.view(np.int8).astype(np.float64)
     raise ValueError(f"reference unpack: unsupported bits {bits}")
 
 

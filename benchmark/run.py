@@ -86,9 +86,14 @@ def main(argv=None) -> int:
         say("FAILED (traceback on stderr); no result")
     finally:
         run.cleanup()
-    sys.stderr.flush()
     if out is None:
+        sys.stderr.flush()
         return 1
+    # every number compared beside its limit: the last lines of stderr
+    # (after the program's logger) and, under ``checks``, of the result
+    for line in run.checks.lines():
+        print(line, file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
 

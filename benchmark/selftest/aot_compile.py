@@ -3,8 +3,11 @@
 described ``v5e:2x2``, here, without the chip.
 
     JAX_PLATFORMS=cpu python benchmark/selftest/aot_compile.py \
-        [--log2n N] [cell ...]
+        [--root DIR] [--log2n N] [cell ...]
 
+The cells are those of the root's ``BENCHMARK.json`` (default: the
+repo's; ``--root benchmark/selftest/next`` compiles the rehearsal
+deployment), each compiled as its workload's ``driver`` builds it.
 ``--log2n N`` compiles the same configuration with 2^N-sample segments
 instead of its own size: how the cut of ``baseband_input_count`` is held
 against the sizes between it and the source's (PERF.md section 4).
@@ -99,31 +102,44 @@ def dmgrid(topo, config_file: str) -> None:
     report("dmgrid step, mesh 4x1", proc._step.lower(*args).compile(), t0)
 
 
-CELLS = {
-    "j1644_2p27.replay_quiet": (served, "benchmark/configs/j1644_2p27.json"),
-    "j1644_dmgrid8.replay": (dmgrid, "benchmark/configs/j1644_dmgrid8.json"),
-}
+COMPILERS = {"served": served, "dmgrid": dmgrid}
 
 
 def main(argv) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--root", default=os.path.join(ROOT, "benchmark"))
+    ap.add_argument("--log2n", type=int, default=None)
+    ap.add_argument("cells", nargs="*")
+    args = ap.parse_args(argv[1:])
+
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from benchmark import spec as spec_mod
 
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     global LOG2N
-    names = argv[1:]
-    if names[:1] == ["--log2n"]:
-        LOG2N, names = int(names[1]), names[2:]
-    for name in names or list(CELLS):
-        fn, config_file = CELLS[name]
+    LOG2N = args.log2n
+    root = os.path.abspath(args.root)
+    with open(spec_mod.find_benchmark_json(root)) as f:
+        bench = json.load(f)
+    configs = {c["name"]: c["file"] for c in bench["configs"]}
+    for cell in bench["workloads"]:
+        name = cell["name"]
+        if args.cells and name not in args.cells:
+            continue
+        with open(os.path.join(root, "workloads", f"{name}.json")) as f:
+            driver = json.load(f)["driver"]
         print(f"[aot] {name}" + (f" at 2^{LOG2N}" if LOG2N else ""),
               flush=True)
         try:
-            fn(topo, config_file)
+            COMPILERS[driver](topo, configs[cell["config"]])
         except Exception as e:  # the compiler's refusal is the finding
             text = str(e)
             print(f"[aot] {name}: REFUSED: {type(e).__name__}: "

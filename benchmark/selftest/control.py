@@ -10,8 +10,13 @@ does), takes the warm-up pulse's segment and the window segment a run
 would sample, and prints, for each control, the number the comparison
 would read.  The limit of each number (the workload file's
 ``check.limits``) has to lie below the smallest of these and above the
-largest that sound runs of the program print.  It drives no program and
-measures nothing; run it on the chip so that the data are the run's.
+largest that sound runs of the program print.  Each control's numbers
+also go through the run's own ``Checks`` with the cell's limits, and the
+last line printed is one JSON object with, per control, ``correct``
+(which has to be false) and the ``checks`` a run's result line would
+carry: the failing number beside its limit.  It drives no program and
+measures nothing; run it on the chip so that the data are the run's.  Of
+a file with several streams it reads stream 0.
 """
 
 from __future__ import annotations
@@ -52,6 +57,11 @@ def main(argv=None) -> int:
     near = near_trials(dms, float(sp.workload["pulses"]["dm"]))
     outer = [j for j in range(len(dms)) if j not in near]
     readings = {}
+    limits = sp.workload["check"]["limits"]
+    verdicts = {low: check.Checks(limits) for low in ("chirp_f32", "bf16")}
+    # a run compares the boxcars' S/N in the served cell (pulsed segments
+    # only) and the trials' in the grid
+    snr_row = "snr_gap_trials" if outer else "snr_gap_boxcars"
     try:
         for seed in range(args.first_seed, args.first_seed + args.seeds):
             lay = gen.Layout(p, sp.workload, seed)
@@ -61,6 +71,7 @@ def main(argv=None) -> int:
                 raw = np.fromfile(path, dtype=np.uint8,
                                   count=lay.segment_bytes,
                                   offset=k * lay.stride_bytes)
+                raw = chain.deinterleave(raw, p)[0]
                 spec = chain.cleaned_spectrum(raw, p, workers)
                 sound = [chain.trial(spec, p, d, workers) for d in dms]
                 for low in ("chirp_f32", "bf16"):
@@ -93,11 +104,20 @@ def main(argv=None) -> int:
                                    if n != "sound_peak_snr"}
                     for name, v in row_numbers.items():
                         readings.setdefault((low, kind, name), []).append(v)
+                    where = f"{low}.seed{seed}.file_seg{k}"
+                    for key in limits:
+                        name = snr_row if key == "snr_gap" else key
+                        if key == "series_gap" or kind == "pulse":
+                            verdicts[low].number(f"{key}.{where}",
+                                                 row[name], key)
     finally:
         shutil.rmtree(work, ignore_errors=True)
     for (low, kind, name), vals in sorted(readings.items()):
         print(f"[control] smallest {name} of {low} on {kind} segments "
               f"over {len(vals)} reading(s): {min(vals)!r}")
+    print(json.dumps({"control": {
+        low: {"correct": ck.ok, "checks": ck.summary()}
+        for low, ck in verdicts.items()}}), flush=True)
     return 0
 
 

@@ -47,6 +47,52 @@ def test_unpack_is_msb_first():
     assert got.tolist() == [3.0, 0.0, 0.0, 1.0]
 
 
+@pytest.mark.parametrize("bits, want", [(8, [0.0, 127.0, 128.0, 255.0]),
+                                        (-8, [0.0, 127.0, -128.0, -1.0])])
+def test_unpack_8_bit_unsigned_and_twos_complement(bits, want):
+    raw = np.array([0, 127, 128, 255], dtype=np.uint8)
+    assert chain.unpack(raw, bits).tolist() == want
+
+
+@pytest.mark.parametrize("fmt, want", [
+    ("simple", [[0, 1, 2, 3, 4, 5, 6, 7]]),
+    ("interleaved_samples_2", [[0, 2, 4, 6], [1, 3, 5, 7]]),     # "1212"
+    ("naocpsr_snap1", [[0, 1, 4, 5], [2, 3, 6, 7]]),             # "1122"
+])
+def test_deinterleave_by_the_formats_table(fmt, want):
+    p = chain.params_from_config(dict(OPTIONS, baseband_input_bits=8,
+                                      baseband_format_type=fmt))
+    got = chain.deinterleave(np.arange(8, dtype=np.uint8), p)
+    assert [g.tolist() for g in got] == want
+
+
+@pytest.mark.parametrize("fmt", ["simple", "interleaved_samples_2"])
+def test_child_runs_the_chain_on_every_stream(tmp_path, fmt):
+    """Stream 0 keeps the keys a one-stream file always had
+    (``s<k>.t<i>.*``); stream s > 0 writes ``s<k>.p<s>.t<i>.*``."""
+    from benchmark.reference import child
+
+    opts = dict(OPTIONS, baseband_input_count="2 ** 12",
+                baseband_format_type=fmt, spectrum_channel_count=16)
+    p = chain.params_from_config(opts)
+    data = np.random.default_rng(3).integers(
+        0, 256, size=2 * chain.segment_bytes(p), dtype=np.uint8)
+    path = str(tmp_path / "two_segments.bin")
+    data.tofile(path)
+    out = child.compute({"file": path, "params": p, "segments": [
+        {"file_seg": 1, "offset_bytes": chain.segment_bytes(p),
+         "dms": [p["dm"]]}]})
+    streams = chain.deinterleave(data[chain.segment_bytes(p):], p)
+    assert len(streams) == p["streams"]
+    for s, raw in enumerate(streams):
+        want = chain.segment(raw, p, dms=[p["dm"]])[0]
+        tag = "s1" if s == 0 else f"s1.p{s}"
+        assert np.array_equal(out[f"{tag}.t0.series"], want["time_series"])
+        assert out[f"{tag}.t0.snr_peaks"].tolist() == want["snr_peaks"]
+    assert ("s1.p1.t0.series" in out) == (p["streams"] == 2)
+    assert float(out["s1.seconds"]) > 0
+
+
 def test_reference_agrees_with_the_program(raw):
     from srtb_tpu.config import Config
     from srtb_tpu.pipeline.segment import SegmentProcessor

@@ -168,10 +168,43 @@ def test_scoped_slice_by_stage(tmp_path, monkeypatch):
             metric
     total = sum(info["metrics_ms_per_seg"][m] for m in info["scope_metrics"])
     assert total == pytest.approx(info["busy_s"] / segs * 1e3, rel=1e-5)
-    # the R2C now holds the twiddle and transpose passes: more than the
-    # convolution fusions that ops.fft_mxu_ms_per_seg reads
+    # the R2C holds the twiddle and transpose passes too: more than its
+    # convolution fusions (the DFT stages on the MXU)
     mxu = rec.trace.op_seconds("(?i)fft|^convolution") / segs * 1e3
     assert info["metrics_ms_per_seg"]["ops.fft_r2c_ms_per_seg"] > 2 * mxu
+
+
+def test_breakdown_names_each_operation_by_its_scope(tmp_path):
+    """``breakdown.device_ops`` of a traced run: every row reads
+    ``<scope>/<operation>``, and the scope is the one the scope reducer
+    gives the same operation."""
+    from benchmark.trace import short_name
+
+    info = known("scoped_slice")
+    path = unzip(tmp_path, "scoped_slice")
+    tr = load_trace(path, info)
+    plain = tr.top_ops(40)
+    tr.scopes = scopes.op_scopes(path)
+    named = tr.top_ops(40)
+    # the reducer's own lookup, operation by operation
+    by_op = {}
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    for number, val in scopes._fields(space):
+        if number == 1:
+            names = {}
+            _plane, scope_of, events = scopes._plane(val, names)
+            for _off, _dur, meta in events:
+                by_op[short_name(names[meta])] = scope_of[meta]
+    assert len(named) == 40 and set(by_op.values()) > {"srtb.fft_r2c",
+                                                       scopes.UNSCOPED}
+    for (name, sec), (full, sec_named) in zip(plain, named):
+        assert full == f"{by_op[name]}/{name}" and sec_named == sec
+    assert named[0][0] == "srtb.fft_r2c/fusion.43 f32[128,128,128,64]"
+    assert any(row[0].startswith("unscoped/copy.") for row in named)
+    # a program that names no stage keeps the plain names
+    quiet = unzip(tmp_path, "quiet_slice")
+    assert scopes.op_scopes(quiet) == {}
 
 
 def test_unscoped_trace_and_untraced_run_read_as_nothing(tmp_path,

@@ -4,9 +4,11 @@ same file at its end, as fast as the program pulls (closed loop).
 The wrapper owns nothing of the read path: every segment comes out of
 ``srtb_tpu.io.file_input.make_file_source`` — the reader ``Pipeline``
 builds for ``--input_file_path`` — so ingest, the host-side overlap tail
-and the buffer pool are the program's.  It stamps hand-over times, keeps
-the order of hand-over for the completion stamps, switches between the
-warm-up segments and the replayed ones, and stops at the deadline.
+and the buffer pool are the program's.  It stamps hand-over times and the
+pull that follows each hand-over, keeps the order of hand-over for the
+drivers' completion stamps (``handed``), switches between the warm-up
+segments and the replayed ones, and stops at the deadline.  It stamps no
+completion: that is the driver's, taken where the results arrive.
 
 A pass ends at the last FULL segment: the reader's zero-padded tail is a
 step from noise to zeros, which fires a detection and writes a candidate
@@ -32,7 +34,7 @@ class FileReplay:
         self.lay = layout
         self.record = record
         self.handed = collections.deque()   # handed over, not yet done
-        self.sync = bool(params.get("complete_on_next", False))
+        self._last = None         # the segment handed over last
         self.phase = None
         self.deadline = None
         self.tick = None          # called at every pull of the window
@@ -71,17 +73,16 @@ class FileReplay:
             self._reader.close()
             self._reader = None
 
-    def _complete_previous(self, now: float) -> None:
-        """A synchronous loop asks for the next segment only when the
-        last one's results are written: that is its completion."""
-        if self.sync and self.handed:
-            rec = self.handed.popleft()
-            rec.done = now
+    def _mark_pull(self, now: float) -> None:
+        """The program asks for the next segment (or its loop returned):
+        stamped on the segment handed over before."""
+        if self._last is not None:
+            self._last.next_pull = now
+            self._last = None
 
     def end_phase(self) -> None:
-        """After the program's loop returned: the last segment of a
-        synchronous loop is complete, the reader is closed."""
-        self._complete_previous(time.perf_counter())
+        """After the program's loop returned: the reader is closed."""
+        self._mark_pull(time.perf_counter())
         self._close_reader()
 
     def __iter__(self):
@@ -89,7 +90,7 @@ class FileReplay:
 
     def __next__(self):
         now = time.perf_counter()
-        self._complete_previous(now)
+        self._mark_pull(now)
         if self.phase == "window":
             if self.tick is not None:
                 self.tick(now)
@@ -113,6 +114,7 @@ class FileReplay:
             rec.buffer_address = int(data.ctypes.data)
         self.record.segs.append(rec)
         self.handed.append(rec)
+        self._last = rec
         rec.handover = time.perf_counter()
         return seg
 

@@ -29,7 +29,8 @@ HOST_PLANE = re.compile(r"^/host:CPU$")
 class Tracer:
     """Starts the profiler ``start_at`` seconds into the window and stops
     it ``slice_s`` later; driven by the source's pull of each segment,
-    on the thread that runs the program's loop."""
+    on the thread that runs the program's loop, or (the grid's driver) by
+    each completion, on the thread that stamps it."""
 
     def __init__(self, out_dir: str, t0: float, start_at: float,
                  slice_s: float):
@@ -97,16 +98,25 @@ class Trace:
         self.host = host            # [(name, start, dur)]
         self.window_s = window_s    # length of the traced slice
         self.segments = 0           # segments completed inside it
+        # {operation name: its ``srtb.`` scope or "unscoped"}, from
+        # reducers/scopes.op_scopes; {} = the program names no stage
+        self.scopes: dict = {}
+
+    @staticmethod
+    def newest(trace_dir: str) -> str:
+        """The ``.xplane.pb`` the profiler left under ``trace_dir``."""
+        paths = sorted(glob.glob(os.path.join(
+            trace_dir, "**", "*.xplane.pb"), recursive=True))
+        if not paths:
+            raise RuntimeError(f"the profiler left no trace in {trace_dir}")
+        return paths[-1]
 
     @classmethod
     def load(cls, trace_dir: str, window_s: float) -> "Trace":
         from jax.profiler import ProfileData
 
-        paths = sorted(glob.glob(os.path.join(
-            trace_dir, "**", "*.xplane.pb"), recursive=True))
-        if not paths:
-            raise RuntimeError(f"the profiler left no trace in {trace_dir}")
-        return cls.from_profile(ProfileData.from_file(paths[-1]), window_s)
+        return cls.from_profile(
+            ProfileData.from_file(cls.newest(trace_dir)), window_s)
 
     @classmethod
     def from_profile(cls, data, window_s: float) -> "Trace":
@@ -150,14 +160,18 @@ class Trace:
 
     def top_ops(self, k: int = 10) -> list:
         """[[name, seconds]]: the operations that took most device time
-        (summed by name, averaged over devices)."""
+        (summed by name, averaged over devices), each named
+        ``<scope>/<operation>`` where the program names its stages
+        (``srtb.fft_r2c/fusion.43 f32[...]``, ``unscoped/copy.252 ...``)."""
         total: dict = {}
         n_dev = sum(1 for ops in self.devices.values() if ops) or 1
         for ops in self.devices.values():
             for name, _, d in ops:
                 total[name] = total.get(name, 0.0) + d
         rows = sorted(total.items(), key=lambda kv: -kv[1])[:k]
-        return [[name, sec / n_dev] for name, sec in rows]
+        scoped = self.scopes
+        return [[f"{scoped.get(name, 'unscoped')}/{name}" if scoped else name,
+                 sec / n_dev] for name, sec in rows]
 
     def idle_gaps(self, k: int = 10) -> list:
         """[[what the host was doing, seconds]]: the first device's idle
