@@ -17,9 +17,12 @@ A device operation belongs to the INNERMOST ``srtb.`` component of its
 ``op_name`` (scopes nest: the sub-byte R2C unpacks inside
 ``srtb.fft_r2c``, and those operations are ``srtb.unpack``'s).  A fusion
 is one operation and carries its root's name: where XLA fuses two
-stages, the time goes to the root's stage.  What runs under no scope
-(the ring's concatenate and carry slice, copies, the grid's
-collectives over ``dm``) is reported as unscoped.
+stages, the time goes to the root's stage.  The ingest ring's
+concatenate and carry slice (``pipeline/segment.py``'s ring variants)
+run under ``srtb.ring``; where XLA folds the concatenate into the R2C's
+first pass only the slice is left to carry the name.  What runs under
+no scope (copies the compiler made, the grid's collectives over ``dm``)
+is reported as unscoped.
 ``benchmark/reducers/scopes.py`` sums a trace by these names.
 """
 
@@ -36,6 +39,8 @@ CHIRP = "srtb.chirp"          # the chirp multiply and, where the chirp is
 WATERFALL = "srtb.waterfall"  # the per-channel backward C2C (+ de-window)
 DETECT = "srtb.detect"        # SK zap, time series, boxcars, S/N
 QUALITY = "srtb.quality"      # the data-quality epilogue
+RING = "srtb.ring"            # the ingest ring's own work: carry ++ new
+#                               assembled, the next carry sliced off
 
 
 def scoped(name: str):

@@ -108,6 +108,34 @@ def ring_usable(cfg) -> bool:
     return nres > 0 and (nres * bits) % 8 == 0 and 0 < reserved < seg
 
 
+def refuse_overlong_reserve(cfg) -> None:
+    """The detector trims ``nsamps_reserved // channel_count`` time
+    samples off the waterfall's ``n / 2 / channel_count``; once that is
+    all of them ``ops/detect.trimmed_length`` trims nothing and the
+    dedispersion-corrupted tail is searched (every segment near a pulse
+    fires).  ``SegmentProcessor`` refuses such a configuration here,
+    before it makes the chirp bank."""
+    n = int(cfg.baseband_input_count)
+    channels = min(int(cfg.spectrum_channel_count), n // 2)
+    reserved = int(dd.nsamps_reserved(cfg))
+    time_samples = n // 2 // channels
+    if reserved // channels < time_samples:
+        return
+    # the reserve is twice the sweep, rounded up to whole waterfall
+    # columns: it stays under half the segment up to this DM
+    sweep_per_dm = abs(dd.max_delay_time(
+        cfg.baseband_freq_low, cfg.baseband_bandwidth, 1.0)
+        * cfg.baseband_sample_rate)
+    dm_max = (n // 2 - 2 * channels) / 2 / sweep_per_dm
+    raise ValueError(
+        f"dm {cfg.dm} reserves {reserved} of the {n} samples of a "
+        f"segment (baseband_input_count): the detector would trim "
+        f"{reserved // channels} of {time_samples} time samples, so it "
+        f"trims none and searches the corrupted tail.  A segment of "
+        f"this size serves |dm| up to {dm_max:.4g} at this band; use a "
+        f"longer segment or baseband_reserve_sample 0")
+
+
 def fused_tail_resolves(cfg, staged: bool) -> bool:
     """Resolution of ``Config.fused_tail`` ("auto"/"on"/"off") for a
     plan with the given resolved ``staged`` flag (see
@@ -236,6 +264,7 @@ class SegmentProcessor:
         self.n_spectrum = n // 2  # after R2C + drop-Nyquist
         self.channel_count = min(cfg.spectrum_channel_count, self.n_spectrum)
         self.watfft_len = self.n_spectrum // self.channel_count
+        refuse_overlong_reserve(cfg)
 
         # ---- precomputed constants ----
         self._window_name = window_name  # enters plan_signature: the
@@ -530,16 +559,28 @@ class SegmentProcessor:
     # also emit the carry, so a cold dispatch needs no extra H2D bytes
     # and no separate slice program to re-arm the ring.
 
+    # Both halves of the ring's own work run under ``srtb.ring``
+    # (ops/scopes.py): metadata only, no operation is added or moved.
+
+    @staticmethod
+    @S.scoped(S.RING)
+    def _assemble(carry: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
+        return jnp.concatenate([carry, new])
+
+    @S.scoped(S.RING)
+    def _next_carry(self, raw: jnp.ndarray) -> jnp.ndarray:
+        return raw[self.stride_bytes:]
+
     def _process_ring(self, carry: jnp.ndarray, new: jnp.ndarray,
                       chirp_ri: jnp.ndarray, chirp_w_ri=None):
-        raw = jnp.concatenate([carry, new])
+        raw = self._assemble(carry, new)
         out = self._process(raw, chirp_ri, chirp_w_ri)
-        return out, raw[self.stride_bytes:]
+        return out, self._next_carry(raw)
 
     def _process_cold(self, raw: jnp.ndarray, chirp_ri: jnp.ndarray,
                       chirp_w_ri=None):
         return (self._process(raw, chirp_ri, chirp_w_ri),
-                raw[self.stride_bytes:])
+                self._next_carry(raw))
 
     def _stage_a_with_carry(self, raw: jnp.ndarray):
         """Shared body of the staged ring variants: stage (a) — in
@@ -547,10 +588,10 @@ class SegmentProcessor:
         plus the next carry sliced from the same assembled raw view.
         One home, so the warm/cold twins (and any future variant)
         cannot drift apart."""
-        return self._stage_a(raw), raw[self.stride_bytes:]
+        return self._stage_a(raw), self._next_carry(raw)
 
     def _stage_a_ring(self, carry: jnp.ndarray, new: jnp.ndarray):
-        return self._stage_a_with_carry(jnp.concatenate([carry, new]))
+        return self._stage_a_with_carry(self._assemble(carry, new))
 
     def _stage_a_cold(self, raw: jnp.ndarray):
         return self._stage_a_with_carry(raw)
@@ -562,20 +603,23 @@ class SegmentProcessor:
         carry ++ new_0 ++ ... ++ new_{B-1}); the next carry is the tail
         of the whole window, aliased onto the donated carry."""
         b = new_b.shape[0]
-        full = jnp.concatenate([carry, new_b.reshape(-1)])
         seg = self._segment_bytes
-        raws = jnp.stack([full[i * self.stride_bytes:
-                               i * self.stride_bytes + seg]
-                          for i in range(b)])
+        with jax.named_scope(S.RING):
+            full = jnp.concatenate([carry, new_b.reshape(-1)])
+            raws = jnp.stack([full[i * self.stride_bytes:
+                                   i * self.stride_bytes + seg]
+                              for i in range(b)])
         out = jax.vmap(self._process, in_axes=(0, None, None))(
             raws, chirp_ri, chirp_w_ri)
-        return out, full[full.shape[0] - self.reserved_bytes:]
+        with jax.named_scope(S.RING):
+            return out, full[full.shape[0] - self.reserved_bytes:]
 
     def _process_batch_cold(self, raws: jnp.ndarray,
                             chirp_ri: jnp.ndarray, chirp_w_ri=None):
         out = jax.vmap(self._process, in_axes=(0, None, None))(
             raws, chirp_ri, chirp_w_ri)
-        return out, raws[-1, self.stride_bytes:]
+        with jax.named_scope(S.RING):
+            return out, raws[-1, self.stride_bytes:]
 
     @property
     def plan_name(self) -> str:
@@ -1536,18 +1580,21 @@ class SegmentProcessor:
             raise ValueError(
                 f"segment must be {expected} bytes, got {raw.shape}")
         staged = self._staged_host(raw, owner=raw)
+        from srtb_tpu.utils.metrics import metrics
         if stride_only:
             if not self.ring:
                 raise ValueError("stride_only staging requires the "
                                  "ingest ring (Config.ingest_ring)")
             staged = staged[self.reserved_bytes:]
+            # what the ring saved this dispatch: with h2d_bytes it adds
+            # up to segment_bytes a dispatch (a cold one adds nothing)
+            metrics.add("ring_carry_bytes", self.reserved_bytes)
         elif self.ring:
             # counted HERE, not in the engine, so the count stays one-
             # per-full-upload under retries (a retried dispatch
             # re-stages and re-counts) — the invariant telemetry
             # consumers rely on: h2d_bytes == ring_cold_dispatches *
             # segment_bytes + warm_count * stride_bytes
-            from srtb_tpu.utils.metrics import metrics
             metrics.add("ring_cold_dispatches")
         self._count_h2d(staged.nbytes)
         return jax.device_put(staged)
@@ -1822,6 +1869,8 @@ class SegmentProcessor:
                              "(Config.ingest_ring / no reserved tail)")
         news = self._as_device_bytes(news)
         self._check_batch(news, self.stride_bytes)
+        from srtb_tpu.utils.metrics import metrics
+        metrics.add("ring_carry_bytes", self.reserved_bytes)
         out, next_carry = self._timed_first(
             "batch_ring",
             lambda: self._batch_ring_jit()(carry, news, self.chirp,

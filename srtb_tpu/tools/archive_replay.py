@@ -37,6 +37,8 @@ import tempfile
 import threading
 import time
 
+from srtb_tpu.tools import SOAK_DM
+
 _FIRING_MARK = "[faults] firing"
 CHILD_TIMEOUT_S = 300.0
 
@@ -119,7 +121,7 @@ def _science_cfg(n: int) -> dict:
     return dict(
         baseband_input_count=n, baseband_input_bits=8,
         baseband_freq_low=1405.0, baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6, dm=0.05,
+        baseband_sample_rate=128e6, dm=SOAK_DM,
         spectrum_channel_count=64,
         mitigate_rfi_average_method_threshold=1000.0,
         mitigate_rfi_spectral_kurtosis_threshold=50.0,
@@ -144,7 +146,7 @@ def _make_archive_file(tmp: str, tag: str, n: int, segments: int,
               for i in range((total - reserved) // stride + 1)
               if reserved + i * stride + stride // 2 < total]
     path = os.path.join(tmp, f"{tag}.bin")
-    make_dispersed_baseband(total, 1405.0, 64.0, 0.05,
+    make_dispersed_baseband(total, 1405.0, 64.0, SOAK_DM,
                             pulse_positions=pulses, pulse_amp=40.0,
                             nbits=8, seed=seed).tofile(path)
     return path

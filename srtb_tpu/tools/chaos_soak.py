@@ -68,6 +68,8 @@ import tempfile
 
 import numpy as np
 
+from srtb_tpu.tools import SOAK_DM
+
 # actions the generator may schedule, with rough weights: device
 # faults are the point of this harness, the PR-4 classes keep their
 # recovery paths soaked alongside
@@ -89,7 +91,7 @@ def _base_cfg(tmp: str, n: int, tag: str, **extra):
     return Config(
         baseband_input_count=n, baseband_input_bits=8,
         baseband_freq_low=1405.0, baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6, dm=0.05,
+        baseband_sample_rate=128e6, dm=SOAK_DM,
         input_file_path=os.path.join(tmp, "bb.bin"),
         baseband_output_file_prefix=os.path.join(tmp, tag + "_"),
         spectrum_channel_count=64,
@@ -218,7 +220,7 @@ def run_soak(seed: int = 0, segments: int = 6, faults: int = 4,
     tmp = tmpdir or tempfile.mkdtemp(prefix="srtb_chaos_")
     n = 1 << log2n
     make_dispersed_baseband(
-        n * segments, 1405.0, 64.0, 0.05,
+        n * segments, 1405.0, 64.0, SOAK_DM,
         pulse_positions=[n // 2 + i * n for i in range(segments)],
         pulse_amp=30.0, nbits=8, seed=seed,
     ).tofile(os.path.join(tmp, "bb.bin"))
@@ -394,7 +396,7 @@ def selftest(log2n: int = 12) -> list[str]:
     from srtb_tpu.io.synth import make_dispersed_baseband
     tmp = tempfile.mkdtemp(prefix="srtb_chaos_self_")
     n = 1 << log2n
-    make_dispersed_baseband(n * 3, 1405.0, 64.0, 0.05,
+    make_dispersed_baseband(n * 3, 1405.0, 64.0, SOAK_DM,
                             pulse_positions=n, nbits=8
                             ).tofile(os.path.join(tmp, "bb.bin"))
     try:

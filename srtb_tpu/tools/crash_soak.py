@@ -59,6 +59,8 @@ import tempfile
 import threading
 import time
 
+from srtb_tpu.tools import SOAK_DM
+
 STALL_S = 30.0          # long enough that the parent's kill always lands
 CHILD_TIMEOUT_S = 300.0
 _FIRING_MARK = "[faults] firing"
@@ -153,7 +155,7 @@ def _child_cfg(tmp: str, run_dir: str, n: int, fault_plan: str = "",
     return dict(
         baseband_input_count=n, baseband_input_bits=8,
         baseband_freq_low=1405.0, baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6, dm=0.05,
+        baseband_sample_rate=128e6, dm=SOAK_DM,
         input_file_path=os.path.join(tmp, "bb.bin"),
         baseband_output_file_prefix=os.path.join(run_dir, "out_"),
         spectrum_channel_count=64,
@@ -326,7 +328,7 @@ def run_soak(seed: int = 0, segments: int = 10, kills: int = 5,
               for i in range((total_bytes - reserved) // stride + 1)
               if reserved + i * stride + stride // 2 < total_bytes]
     make_dispersed_baseband(
-        total_bytes, 1405.0, 64.0, 0.05,
+        total_bytes, 1405.0, 64.0, SOAK_DM,
         pulse_positions=pulses,
         pulse_amp=40.0, nbits=8, seed=seed,
     ).tofile(os.path.join(tmp, "bb.bin"))

@@ -74,6 +74,8 @@ import tempfile
 
 import numpy as np
 
+from srtb_tpu.tools import SOAK_DM
+
 
 class SoakFailure(AssertionError):
     """One broken fleet invariant (the gate)."""
@@ -105,7 +107,7 @@ def _cfg(tmp: str, name: str, run_dir: str, n: int, **extra):
     base = dict(
         baseband_input_count=n, baseband_input_bits=8,
         baseband_freq_low=1405.0, baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6, dm=0.05,
+        baseband_sample_rate=128e6, dm=SOAK_DM,
         input_file_path=os.path.join(tmp, f"bb_{name}.bin"),
         baseband_output_file_prefix=os.path.join(run_dir, "out_"),
         spectrum_channel_count=64,
@@ -132,7 +134,7 @@ def _synthesize(tmp: str, names: list[str], n: int, segments: int,
     from srtb_tpu.io.synth import make_dispersed_baseband
     for i, name in enumerate(names):
         make_dispersed_baseband(
-            n * segments, 1405.0, 64.0, 0.05,
+            n * segments, 1405.0, 64.0, SOAK_DM,
             pulse_positions=[n // 2 + j * n for j in range(segments)],
             pulse_amp=30.0, nbits=8, seed=seed * 1000 + i,
         ).tofile(os.path.join(tmp, f"bb_{name}.bin"))

@@ -337,6 +337,8 @@ class Metrics:
         "data_loss_total": "Data-loss-classified faults (retried)",
         "faults_injected": "Deterministic fault-plan firings",
         "h2d_bytes": "Host-to-device bytes staged",
+        "ring_carry_bytes": "Bytes the ingest ring kept on the device "
+                            "instead of receiving them again",
         "ring_cold_dispatches": "Ingest-ring cold (full-upload) "
                                 "dispatches",
         "recovered_segments": "Segments rescued by manifest recovery",

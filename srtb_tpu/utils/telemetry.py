@@ -364,6 +364,9 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         # between consecutive records give per-segment upload bytes —
         # stride_bytes warm, segment_bytes cold)
         "h2d_bytes": int(metrics.get("h2d_bytes")),
+        # bytes the device kept as the ring's carry instead of receiving
+        # them again: reserved_bytes a warm dispatch, 0 a cold one
+        "ring_carry_bytes": int(metrics.get("ring_carry_bytes")),
         "ring_cold_dispatches": int(metrics.get("ring_cold_dispatches")),
         # v4 self-healing compute fields (cumulative counters + the
         # ladder position gauge at drain)

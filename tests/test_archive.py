@@ -15,6 +15,7 @@ from srtb_tpu.io.file_input import (DETERMINISTIC_EPOCH_NS,
 from srtb_tpu.io.synth import make_dispersed_baseband
 from srtb_tpu.pipeline.archive import ArchiveReplay, stream_name_for
 from srtb_tpu.pipeline.runtime import Pipeline
+from srtb_tpu.tools import SOAK_DM
 from srtb_tpu.tools.archive_replay import (_make_archive_file,
                                            _science_cfg, _sha_map)
 from srtb_tpu.utils.metrics import metrics
@@ -79,7 +80,7 @@ def test_pipeline_honors_deterministic_timestamps(tmp_path):
     """Two full pipeline runs of the same file produce the SAME
     artifact names and bytes (the property every replay gate rides)."""
     path = os.path.join(str(tmp_path), "bb.bin")
-    make_dispersed_baseband(N * 2, 1405.0, 64.0, 0.05,
+    make_dispersed_baseband(N * 2, 1405.0, 64.0, SOAK_DM,
                             pulse_positions=[N // 2, N + N // 2],
                             pulse_amp=40.0, nbits=8).tofile(path)
     maps = []

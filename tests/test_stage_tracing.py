@@ -33,6 +33,8 @@ from srtb_tpu.utils.tracing import StageTimer, span
 
 N = 1 << 14
 SIX = {S.UNPACK, S.FFT_R2C, S.RFI_S1, S.CHIRP, S.WATERFALL, S.DETECT}
+# the overlap-save ring's concatenate and carry slice (ISSUE 31)
+RING = SIX | {S.RING}
 
 
 def _cfg(**extra):
@@ -90,15 +92,21 @@ def _grid_program():
 
 FAMILIES = {
     # the quiet cell's plan: monolithic R2C, fused, overlap-save ring
-    "ring": (lambda: _served_programs({"ring"}), SIX),
-    "ring_cold": (lambda: _served_programs({"ring_cold"}), SIX),
+    "ring": (lambda: _served_programs({"ring"}), RING),
+    "ring_cold": (lambda: _served_programs({"ring_cold"}), RING),
+    "staged_ring": (lambda: _served_programs(
+        {"stage_a_ring", "stage_a_cold"}, staged=True), {S.RING}),
+    "batch_ring": (lambda: _served_programs(
+        {"batch_ring", "batch_cold"}, micro_batch_segments=2), RING),
     "staged": (lambda: _served_programs(
         {"stage_a", "stage_b", "stage_c"}, staged=True,
         baseband_reserve_sample=False), SIX),
     "quality": (lambda: _served_programs({"ring"}, quality_stats=True),
-                SIX | {S.QUALITY}),
+                RING | {S.QUALITY}),
     "grid_step": (_grid_program, SIX),
 }
+# no reserve, no ring: these hold no ``srtb.ring``
+RINGLESS = {"staged", "grid_step"}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -108,7 +116,8 @@ def test_scopes_are_metadata_only(family, monkeypatch):
     seen = set().union(*(_scopes_in(loc) for _t, loc in
                          with_scopes.values()))
     assert seen >= expected, f"{family}: missing {expected - seen}"
-    assert seen <= SIX | {S.QUALITY}, f"{family}: unknown {seen}"
+    assert seen <= RING | {S.QUALITY}, f"{family}: unknown {seen}"
+    assert (S.RING in seen) == (family not in RINGLESS), family
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     without = build()

@@ -22,6 +22,7 @@ from srtb_tpu.analysis.tsan import (InstrumentedCondition,
                                     SchedulePerturber, Tsan, TsanError,
                                     install_perturber,
                                     uninstall_perturber)
+from srtb_tpu.tools import SOAK_DM
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -649,12 +650,12 @@ def _tiny_fleet(tmp_path, **cfg_kw):
     for i, name in enumerate(("s0", "s1")):
         bb = os.path.join(str(tmp_path), f"bb_{name}.bin")
         make_dispersed_baseband(
-            n * 2, 1405.0, 64.0, 0.05, pulse_positions=[n // 2],
+            n * 2, 1405.0, 64.0, SOAK_DM, pulse_positions=[n // 2],
             pulse_amp=30.0, nbits=8, seed=i).tofile(bb)
         cfg = dict(
             baseband_input_count=n, baseband_input_bits=8,
             baseband_freq_low=1405.0, baseband_bandwidth=64.0,
-            baseband_sample_rate=128e6, dm=0.05,
+            baseband_sample_rate=128e6, dm=SOAK_DM,
             input_file_path=bb,
             baseband_output_file_prefix=os.path.join(
                 str(tmp_path), f"out_{name}_"),
