@@ -3,7 +3,9 @@
 Mirrors read_file_pipe (ref: pipeline/read_file_pipe.hpp:31-127):
 - skip ``input_file_offset_bytes`` first;
 - each call reads ``baseband_input_count * |bits|/8 * data_stream_count``
-  bytes into a zero-filled buffer (short final reads stay zero-padded);
+  bytes straight into a pooled block (``readinto``: no intermediate
+  ``bytes``, no whole-block zero fill); only what a short final read
+  leaves is zeroed, counted in ``file_zero_fill_bytes``;
 - then seeks back ``nsamps_reserved`` samples' worth of bytes so
   consecutive segments overlap (the overlap-save "long-context" mechanism);
 - a logical byte counter, not the stream position, tracks progress because
@@ -79,35 +81,47 @@ class BasebandFileReader:
     def __next__(self) -> SegmentWork:
         if self._exhausted:
             raise StopIteration
-        buf = self.pool.acquire(self.segment_bytes)
+        # not zeroed: every byte of the segment is written below, by the
+        # retained tail, the file, or the zeroing of a short read's rest
+        buf = self.pool.acquire(self.segment_bytes, zero=False)
         warm = self._skip_read and self._carry.warm
         reserved = self.reserved_bytes if warm else 0
-        try:
-            chunk = self._file.read(self.segment_bytes - reserved)
-        except BaseException:
-            # a failed read may be retried by the pipeline's ingest
-            # guard, which calls __next__ again and acquires a fresh
-            # buffer — this one must go back or every retried
-            # transient strands a segment-sized block in the pool
-            self.pool.release(buf)
-            raise
-        if len(chunk) == 0 and not warm:
-            self.pool.release(buf)
-            log.info(f"[read_file] {self.cfg.input_file_path} has been read")
-            self._exhausted = True
-            raise StopIteration
         if warm:
             # head = retained tail (host memcpy replaces the legacy
             # seek-back disk re-read, bit-identically); with 0 new
             # bytes this still emits the tail + zeros final segment
             # the seek-back path would have produced
             self._carry.head_into(buf)
-        buf[reserved:reserved + len(chunk)] = np.frombuffer(
-            chunk, dtype=np.uint8)
+        new = memoryview(buf)[reserved:]
+        got = 0
+        try:
+            # straight into the block behind the head; a raw file may
+            # hand out fewer bytes than asked for, only 0 is its end
+            while got < len(new):
+                n = self._file.readinto(new[got:])
+                if not n:
+                    break
+                got += n
+        except BaseException:
+            # a failed read may be retried by the pipeline's ingest
+            # guard, which calls __next__ again and acquires a fresh
+            # buffer — this one must go back or every retried
+            # transient strands a segment-sized block in the pool,
+            # and the file must stand where this pull found it
+            self.pool.release(buf)
+            self._file.seek(-got, 1)
+            raise
+        if got == 0 and not warm:
+            self.pool.release(buf)
+            log.info(f"[read_file] {self.cfg.input_file_path} has been read")
+            self._exhausted = True
+            raise StopIteration
+        buf[reserved + got:] = 0  # empty on a full segment
+        metrics.add("file_zero_fill_bytes", len(new) - got)
         # ingest telemetry: windowed read throughput + pool occupancy
         # gauges (the host-buffer analog of the receiver ring gauges)
-        metrics.add("file_bytes_read", len(chunk))
-        metrics.window("file_bytes_read").add(len(chunk))
+        metrics.add("file_bytes_read", got)
+        metrics.window("file_bytes_read").add(got)
         pool_stats = self.pool.stats()
         metrics.set("segment_pool_cached_blocks",
                     pool_stats["cached_blocks"])
@@ -117,7 +131,7 @@ class BasebandFileReader:
         metrics.set("segment_pool_acquires", pool_stats["acquires"])
         metrics.set("segment_pool_new_blocks", pool_stats["new_blocks"])
         self.logical_offset += self.segment_bytes
-        if len(chunk) < self.segment_bytes - reserved:
+        if got < len(new):
             # final partial segment: emit zero-padded, then stop
             # (ref: read_file_pipe.hpp:76-77 memset + short read).
             # Warm short reads land here too: a file ending exactly at

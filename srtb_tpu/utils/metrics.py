@@ -439,7 +439,11 @@ class Metrics:
                                  "(cumulative)",
         "segment_pool_new_blocks": "Reader buffer-pool acquires that "
                                    "allocated a new block (cumulative)",
-        "file_bytes_read": "Bytes read from baseband input files",
+        "file_bytes_read": "Bytes read from baseband input files, "
+                           "straight into the pooled segment block",
+        "file_zero_fill_bytes": "Bytes the file reader zeroed behind a "
+                                "short read (0 on a full segment: the "
+                                "block is not zero-filled first)",
     }
 
     @classmethod

@@ -301,3 +301,69 @@ def test_the_cells_files_load_as_run_py_loads_them():
     assert "ops.unpack_ms_per_seg" not in names
     assert {m["name"] for m, _r in sp.metrics("end_to_end")} \
         == {"rt_factor", "setup_s"}
+
+
+class _CaptureFiles(_Capture):
+    """``_Capture`` that keeps every candidate file's bytes, by
+    extension, before the files go."""
+
+    def push(self, work, has_signal):
+        blobs = {}
+        for name in glob.glob(self.prefix + "*"):
+            with open(name, "rb") as f:
+                blobs[name.split(".", 1)[1]] = f.read()
+        super().push(work, has_signal)
+        self.rows[-1]["blobs"] = blobs
+
+
+def _run_from_pool(seed: int, tmp: str, tag: str, pool) -> tuple:
+    """The five-segment file through ``Pipeline`` with the reader on
+    ``pool``: the capture's rows, and the pulsed segment's bytes as the
+    file holds them."""
+    from srtb_tpu.io.file_input import BasebandFileReader
+    p = chain.params_from_config(TINY)
+    lay = gen.Layout(p, WORKLOAD, seed)
+    path = os.path.join(tmp, f"baseband_pool_{seed}.bin")
+    if not os.path.exists(path):
+        gen.write_file(path, p, lay, seed)
+    prefix = os.path.join(tmp, f"pool_{tag}_{seed}_")
+    cfg = _config(TINY, input_file_path=path,
+                  baseband_output_file_prefix=prefix,
+                  writer_thread_count=0)
+    capture = _CaptureFiles(prefix)
+    metrics.reset()
+    with Pipeline(cfg, source=BasebandFileReader(
+            cfg, buffer_pool=pool)) as pipe:
+        pipe.sinks.append(capture)
+        pipe.run(max_segments=lay.n_segments)
+    metrics.reset()
+    data = np.fromfile(path, dtype=np.uint8)
+    start = PULSED * lay.stride_bytes
+    return capture.rows, data[start:start + lay.segment_bytes].tobytes()
+
+
+def test_a_poisoned_pool_gives_the_fresh_pools_candidates(workdir):
+    """The reader no longer zero-fills its block: from a pool whose
+    blocks come back full of 0xFF the detections and the candidate's
+    bytes (the warm segment's head is dumped from the host block, never
+    uploaded) are those of a fresh pool."""
+    from srtb_tpu.utils.bufferpool import BufferPool
+    seed = SEEDS[0]
+    fresh, segment = _run_from_pool(seed, workdir, "fresh",
+                                    BufferPool("fresh"))
+    pool = BufferPool("poisoned")
+    held = [pool.acquire(len(segment), zero=False) for _ in range(6)]
+    for buf in held:
+        buf[:] = 0xFF
+        pool.release(buf)
+    stale, _ = _run_from_pool(seed, workdir, "stale", pool)
+    assert pool.stats()["new_blocks"] == 6      # every pull was recycled
+    assert [r["fired"] for r in fresh] == [k == PULSED for k in range(5)]
+    assert len(stale) == len(fresh) == 5
+    for k, (a, b) in enumerate(zip(fresh, stale)):
+        assert a["fired"] == b["fired"], k
+        np.testing.assert_array_equal(a["series"], b["series"],
+                                      err_msg=str(k))
+        assert a["blobs"] == b["blobs"], k
+    blobs = stale[PULSED]["blobs"]
+    assert blobs["bin"] == segment and {"0.npy", "1.tim"} <= set(blobs)
