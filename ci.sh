@@ -3,21 +3,21 @@
 # (L8 parity with the reference's CircleCI matrix,
 # ref: /root/reference/.circleci/config.yml — there: 2 toolchains x 2
 # arches of the SYCL build + ctest; here: native build + static checks +
-# the full pytest suite on the virtual 8-device CPU mesh + the bench and
+# the full pytest suite on the virtual 8-device CPU mesh + the
 # multichip dryrun smoke).
 #
 # Usage: ./ci.sh [--fast]   (--fast skips the slowest pytest cases)
 set -euo pipefail
 cd "$(dirname "$0")"
 
-echo "== [1/23] native build =="
+echo "== [1/21] native build =="
 make -C srtb_tpu/native
 
-echo "== [2/23] native sanitizer harness (ASan/UBSan) =="
+echo "== [2/21] native sanitizer harness (ASan/UBSan) =="
 make -C srtb_tpu/native check
 
-echo "== [3/23] static checks (compile + import) =="
-python -m compileall -q srtb_tpu tests bench.py __graft_entry__.py
+echo "== [3/21] static checks (compile + import) =="
+python -m compileall -q srtb_tpu tests __graft_entry__.py
 python - <<'EOF'
 import importlib, pkgutil
 import srtb_tpu
@@ -31,7 +31,7 @@ assert not bad, bad
 print(f"all srtb_tpu modules import cleanly")
 EOF
 
-echo "== [4/23] srtb-lint (static analysis vs baseline) =="
+echo "== [4/21] srtb-lint (static analysis vs baseline) =="
 # fails on findings not in srtb_tpu/analysis/baseline.json; accept an
 # intentional finding with --write-baseline + a note, or a pragma.
 # The machine-readable run lands next to the other CI artifacts.
@@ -40,11 +40,10 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.lint srtb_tpu/ \
   --format json > artifacts/lint.json \
   || { cat artifacts/lint.json; exit 1; }
 
-echo "== [5/23] plan audit (compile-time HLO cards vs baseline) =="
+echo "== [5/21] plan audit (compile-time HLO cards vs baseline) =="
 # AOT-lowers every plan family and audits the compiled artifacts:
-# spectrum-sized HBM sweeps vs the declared hbm_passes floor, donation
-# proven aliased (not silently dropped), no f64/host-callback/
-# collective creep.  Fails on any drift from
+# spectrum-sized HBM sweeps counted, donation proven aliased (not
+# silently dropped), no f64/host-callback/collective creep.  Fails on any drift from
 # srtb_tpu/analysis/plan_cards.json (accept intentional changes with
 # --write-baseline + a note); the selftest then proves the gate still
 # catches a dropped donation and an injected extra spectrum pass.
@@ -52,7 +51,7 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.plan_audit \
   --out artifacts/plan_cards_audit.json
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.plan_audit --selftest
 
-echo "== [6/23] pytest (8-device CPU mesh) =="
+echo "== [6/21] pytest (8-device CPU mesh) =="
 FAST_ARGS=()
 if [ "${1:-}" = "--fast" ]; then
   # one source of truth for what "slow" means: the pytest marker
@@ -61,11 +60,7 @@ if [ "${1:-}" = "--fast" ]; then
 fi
 python -m pytest tests/ -q "${FAST_ARGS[@]}"
 
-echo "== [7/23] bench smoke (with the roofline/audit cross-check) =="
-JAX_PLATFORMS=cpu SRTB_BENCH_LOG2N=16 SRTB_BENCH_AUDIT=1 \
-  python bench.py | tail -1
-
-echo "== [8/23] fused-plan parity (spectrum-pass fusion, Pallas interpret on CPU) =="
+echo "== [7/21] fused-plan parity (spectrum-pass fusion, Pallas interpret on CPU) =="
 JAX_PLATFORMS=cpu python - <<'EOF'
 import numpy as np
 
@@ -90,8 +85,7 @@ raw = make_dispersed_baseband(n, 1405.0, 64.0, 30.0,
 legacy = SegmentProcessor(Config(fused_tail="off", **base))
 fused = SegmentProcessor(Config(fused_tail="on", use_pallas=True,
                                 use_pallas_sk=True, **base))
-assert legacy.hbm_passes == 7 and fused.hbm_passes == 4, (
-    legacy.hbm_passes, fused.hbm_passes)
+assert not legacy.fused_tail and fused.fused_tail
 assert fused._skzap and fused.plan_name.endswith("+ftail+skzap")
 assert legacy.plan_signature() != fused.plan_signature()
 wf_l, res_l = legacy.process(raw)
@@ -104,12 +98,11 @@ a, b = waterfall_to_numpy(wf_l), waterfall_to_numpy(wf_f)
 scale = np.abs(a).max()
 np.testing.assert_allclose(b, a, atol=1e-3 * scale, rtol=0)
 print(f"fused-plan parity OK: plan {fused.plan_name} "
-      f"(hbm_passes {fused.hbm_passes}) matches legacy 7-pass chain, "
-      "detections bit-identical")
+      "matches the legacy unfused chain, detections bit-identical")
 
 # ---- front-fused staged megakernel parity (ISSUE 15): staged_ffuse
-# (raw bytes -> blocked intermediate -> dedispersed spectrum, declared
-# hbm_passes 2) vs the staged+skzap plan it demotes onto (hbm 4) —
+# (raw bytes -> blocked intermediate -> dedispersed spectrum) vs the
+# staged+skzap plan it demotes onto —
 # decisions bit-identical under Pallas interpret.
 import os
 os.environ["SRTB_STAGED_ROWS_IMPL"] = "pallas2"
@@ -122,9 +115,8 @@ fbase = dict(base, fused_tail="on", use_pallas=True,
 ffuse = SegmentProcessor(Config(front_fuse="on", **fbase), staged=True)
 staged = SegmentProcessor(Config(front_fuse="off", **fbase),
                           staged=True)
-assert ffuse.hbm_passes == 2 and staged.hbm_passes == 4, (
-    ffuse.hbm_passes, staged.hbm_passes)
 assert ffuse.front_fuse and "+ffuse" in ffuse.plan_name
+assert not staged.front_fuse and staged._skzap
 assert ffuse.plan_signature() != staged.plan_signature()
 wf_ff, res_ff = ffuse.process(raw2)
 wf_st, res_st = staged.process(raw2)
@@ -136,18 +128,17 @@ a2, b2 = waterfall_to_numpy(wf_st), waterfall_to_numpy(wf_ff)
 scale2 = np.abs(a2).max()
 assert scale2 > 0
 np.testing.assert_allclose(b2, a2, atol=1e-3 * scale2, rtol=0)
-print(f"ffuse parity OK: plan {ffuse.plan_name} (hbm_passes "
-      f"{ffuse.hbm_passes}) vs {staged.plan_name} (hbm_passes "
-      f"{staged.hbm_passes}), decisions bit-identical")
+print(f"ffuse parity OK: plan {ffuse.plan_name} vs "
+      f"{staged.plan_name}, decisions bit-identical")
 EOF
 
-echo "== [9/23] ring parity smoke (incremental H2D ring on vs off, Pallas interpret) =="
+echo "== [8/21] ring parity smoke (incremental H2D ring on vs off, Pallas interpret) =="
 # The ISSUE-8 acceptance gate: ring-on output is bit-identical to
 # ring-off on a Pallas-kernel plan (interpret mode on CPU), and the
 # per-segment h2d_bytes counter equals the stride model exactly — the
 # full segment on the one cold dispatch, stride_bytes (segment minus
 # the reserved overlap tail) on every warm dispatch.  The plan-audit
-# stage [5/20] already proved the carry donation is a real alias for
+# stage 5 already proved the carry donation is a real alias for
 # every ring-v1 family; this proves the runtime keeps its half of the
 # contract.
 JAX_PLATFORMS=cpu python - <<'EOF'
@@ -210,7 +201,7 @@ print(f"ring parity OK: plan {proc.plan_name}, {s_on.segments} segments "
       f"{proc.reserved_bytes / seg_b:.1%} per warm segment)")
 EOF
 
-echo "== [10/23] telemetry + sanitizer smoke (journal + report + /metrics + /healthz + Config.sanitize) =="
+echo "== [9/21] telemetry + sanitizer smoke (journal + report + /metrics + /healthz + Config.sanitize) =="
 JAX_PLATFORMS=cpu python - <<'EOF'
 import json, os, tempfile, urllib.request
 
@@ -243,19 +234,16 @@ assert stats.segments >= 2, stats
 recs = TR.load(journal)
 assert recs, "telemetry journal is empty"
 # v8 span fields (async engine + resilience + perf observatory) on
-# every record: device-time accounting + live roofline + compile/cache
+# every record: device-time accounting + compile/cache
 # books must ride every span, not just /metrics
 for rec in recs:
     assert rec["v"] == 11, rec
     assert "overlap_hidden_ms" in rec and rec["inflight_depth"] >= 1, rec
     for key in ("degrade_level", "retries", "requeues", "restarts",
-                "device_ms", "achieved_msamps",
-                "compile_ms", "plan_compiles", "aot_cache_hits",
-                "aot_cache_misses"):
+                "device_ms", "compile_ms", "plan_compiles",
+                "aot_cache_hits", "aot_cache_misses"):
         assert key in rec, (key, rec)
-    # no roofline share on a device whose kind is not in the HBM peak
-    # table (utils/platform.py) — this CPU included
-    assert rec["device_ms"] > 0 and "roofline_frac" not in rec, rec
+    assert rec["device_ms"] > 0, rec
 # the lazy-jit first dispatch was counted as the run's compile event
 assert recs[-1]["plan_compiles"] >= 1 and recs[-1]["compile_ms"] > 0
 rep = TR.report(journal)
@@ -272,13 +260,11 @@ try:
     assert 'srtb_stage_seconds_bucket{le="+Inf",stage="dispatch"}' in prom
     assert 'srtb_stage_seconds_bucket{le="+Inf",stage="overlap"}' in prom
     assert "srtb_inflight_depth" in prom
-    # perf-observatory families (ISSUE 14): live roofline gauges,
-    # device-time histogram, compile/cache counters all scrapeable
+    # perf-observatory families (ISSUE 14): device-time histogram,
+    # compile/cache counters all scrapeable
     assert "# TYPE srtb_device_seconds histogram" in prom
-    for fam in ("srtb_achieved_msamps",
-                "srtb_achieved_gbps", "srtb_compile_seconds",
-                "srtb_plan_compiles", "srtb_aot_cache_hits",
-                "srtb_aot_cache_misses"):
+    for fam in ("srtb_compile_seconds", "srtb_plan_compiles",
+                "srtb_aot_cache_hits", "srtb_aot_cache_misses"):
         assert f"\n{fam} " in prom or prom.startswith(f"{fam} "), fam
     h = json.loads(urllib.request.urlopen(base + "/healthz").read())
     assert h["ok"] and h["status"] == "ok", h
@@ -304,7 +290,7 @@ print(f"sanitizer smoke OK: {stats_s.segments} segments with "
       "Config.sanitize on, tripwire restored")
 EOF
 
-echo "== [11/23] fault-injection smoke (one transient fault at every site -> recovery + v8 telemetry) =="
+echo "== [10/21] fault-injection smoke (one transient fault at every site -> recovery + v8 telemetry) =="
 JAX_PLATFORMS=cpu python - <<'EOF'
 import json, os, tempfile
 
@@ -382,7 +368,7 @@ print(f"fault-injection smoke OK: {st1.segments} segments recovered "
       "/metrics + v8 journal")
 EOF
 
-echo "== [12/23] chaos smoke (self-healing compute: oom + compile_fail + device_halt in one run) =="
+echo "== [11/21] chaos smoke (self-healing compute: oom + compile_fail + device_halt in one run) =="
 # The ISSUE-9 acceptance gate: a deterministic fault plan injecting all
 # three device-fault classes completes with accounted-only loss,
 # detection decisions identical to the clean run, and the
@@ -396,7 +382,7 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.chaos_soak --segments 6 \
   | tail -1
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.chaos_soak --selftest
 
-echo "== [13/23] crash-soak smoke (SIGKILL exactly-once: manifest recovery + fsck + bit-identical union) =="
+echo "== [12/21] crash-soak smoke (SIGKILL exactly-once: manifest recovery + fsck + bit-identical union) =="
 # The ISSUE-10 acceptance gate, CI-sized: a deterministic two-kill plan
 # — one SIGKILL mid-checkpoint-flush (between sink commit and the
 # checkpoint update, the duplicate-on-resume window) and one mid-
@@ -411,11 +397,11 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.crash_soak --segments 5 \
   --kills 2 --kill-plan "ckpt_stall@1,rename@1" --log2n 13 | tail -1
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fsck --selftest
 
-echo "== [14/23] multichip dryrun (8 virtual devices) =="
+echo "== [13/21] multichip dryrun (8 virtual devices) =="
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-echo "== [15/23] fleet smoke (multi-tenant bulkheads: 3 streams, 1 victim, shared plan cache) =="
+echo "== [14/21] fleet smoke (multi-tenant bulkheads: 3 streams, 1 victim, shared plan cache) =="
 # The ISSUE-11 acceptance gate, CI-sized: 3 seeded streams on one
 # device, a stream-selector fault plan injected into stream0 (oom ->
 # victim-only demotion, plus a transient sink fault and a fetch
@@ -430,7 +416,7 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fleet_soak --streams 3 \
   --segments 4 --log2n 12 | tail -1
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fleet_soak --selftest
 
-echo "== [16/23] fleet-batch smoke (cross-tenant continuous batching: 4 streams, one shared dispatch) =="
+echo "== [15/21] fleet-batch smoke (cross-tenant continuous batching: 4 streams, one shared dispatch) =="
 # The ISSUE-17 acceptance gate, CI-sized: the round-15 fleet soak
 # re-run with the batch former armed (fleet_batch_max=4).  Gate, on
 # top of the bulkhead checks above: the v10 journal records batched
@@ -444,7 +430,7 @@ echo "== [16/23] fleet-batch smoke (cross-tenant continuous batching: 4 streams,
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fleet_soak --streams 4 \
   --segments 5 --log2n 12 --batch 4 | tail -1
 
-echo "== [17/23] race-soak smoke (seeded schedule perturbation + lockdep, Config.tsan) =="
+echo "== [16/21] race-soak smoke (seeded schedule perturbation + lockdep, Config.tsan) =="
 # The ISSUE-18 acceptance gate, CI-sized.  First the selftest: the
 # lockdep layer must TRAP a deliberately injected lock-order inversion
 # (and stay quiet on a consistent global order) — a soak that cannot
@@ -463,7 +449,7 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.race_soak --selftest
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.race_soak --streams 2 \
   --segments 4 --log2n 12 --batch 2 --seed 0 --deadline 240 | tail -1
 
-echo "== [18/23] archive-replay smoke (full-throughput replay: SIGTERM resume + bit-identical union + micro-batch tolerance) =="
+echo "== [17/21] archive-replay smoke (full-throughput replay: SIGTERM resume + bit-identical union + micro-batch tolerance) =="
 # The ISSUE-12 acceptance gate, CI-sized: a 2-file fleet-fanned replay
 # (deterministic timestamps, per-file checkpoint + manifest namespaces)
 # killed by a SIGTERM steered into one lane's sink-write window, then
@@ -475,7 +461,7 @@ echo "== [18/23] archive-replay smoke (full-throughput replay: SIGTERM resume + 
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.archive_replay --selftest \
   --segments 4 --log2n 13 | tail -1
 
-echo "== [19/23] trace/incident smoke (causal tracing + flight recorder + bundle + Chrome-trace export) =="
+echo "== [18/21] trace/incident smoke (causal tracing + flight recorder + bundle + Chrome-trace export) =="
 # The ISSUE-13 acceptance gate, CI-sized: a clean traced run proves
 # every segment leaves a complete ingest->dispatch->fetch->sink causal
 # chain whose export is valid Chrome-trace JSON (schema-checked, flow
@@ -562,7 +548,7 @@ print(f"trace/incident smoke OK: {stats.segments} traced segments "
       f"{meta['trace_id']}")
 EOF
 
-echo "== [20/23] canary + quality smoke (pulse-injection sensitivity gate + quality report artifact) =="
+echo "== [19/21] canary + quality smoke (pulse-injection sensitivity gate + quality report artifact) =="
 # The ISSUE-16 acceptance gate, CI-sized.  Leg 1 (clean): a file-mode
 # run with the canary on and the quality epilogue enabled must inject,
 # recover, and PASS every sensitivity check (auto-calibrated expected
@@ -650,33 +636,7 @@ python -m srtb_tpu.tools.quality_report "$CANARY_JOURNAL" \
 grep -q '"canary"' artifacts/quality_report.json
 grep -q '## Canary' artifacts/quality_report.md
 
-echo "== [21/23] perf-gate smoke (noise-aware regression gate + ledger trajectory) =="
-# The ISSUE-14 acceptance gate: (a) the gate's selftest proves an
-# injected dispatch-path slowdown (Config.fault_plan stall) FAILS the
-# statistical gate while a clean rerun passes within the COMPUTED
-# noise floor; (b) a calibrated mini-bench is compared against the
-# checked-in CPU baseline (PERF_BASELINE.json) — cross-host runs are
-# rescaled by the calibration workload and gated at a generous
-# smoke-alarm effect floor, so CI catches a gross regression without
-# flaking on scheduler noise; (c) perf_report renders the ledger's
-# trajectory.
-JAX_PLATFORMS=cpu python -m srtb_tpu.tools.perf_gate --selftest | tail -1
-JAX_PLATFORMS=cpu python -m srtb_tpu.tools.perf_gate \
-  --baseline PERF_BASELINE.json --min-effect 0.5 \
-  --ledger artifacts/perf_ledger.jsonl | tail -1
-python -m srtb_tpu.tools.perf_report artifacts/perf_ledger.jsonl \
-  --format json > artifacts/perf_trajectory.json
-python - <<'EOF'
-import json
-doc = json.load(open("artifacts/perf_trajectory.json"))
-assert doc["records"] >= 1, doc["records"]
-rows = [r for g in doc["groups"].values() for r in g["rows"]]
-assert any(r["source"] == "gate" for r in rows)
-print(f"perf trajectory OK: {doc['records']} records across "
-      f"{len(doc['groups'])} group(s), gate captures present")
-EOF
-
-echo "== [22/23] migration smoke (elastic pool: scoped device kill + rolling restart, live migration bit-identical) =="
+echo "== [20/21] migration smoke (elastic pool: scoped device kill + rolling restart, live migration bit-identical) =="
 # The ISSUE-19 acceptance gate, CI-sized: 3 seeded streams placed
 # across a 2-member VIRTUAL pool (distinct plan caches / halt domains
 # on one CPU device).  Kill mode: a scheduled mid-run halt of member
@@ -697,7 +657,7 @@ JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fleet_soak --migrate \
 JAX_PLATFORMS=cpu python -m srtb_tpu.tools.fleet_soak --migrate \
   --rolling --streams 3 --segments 6 --log2n 12 --kill-at 2 | tail -1
 
-echo "== [23/23] fleet control tower (aggregator + rollup store + cross-device trace join + console + regression watch) =="
+echo "== [21/21] fleet control tower (aggregator + rollup store + cross-device trace join + console) =="
 # The ISSUE-20 acceptance gate, CI-sized: re-run the 2-member virtual
 # pool migration soak, then drive its three v11 journals + the flight
 # recorder dump through the REAL tower path: aggregator -> rollup
@@ -780,12 +740,5 @@ finally:
     srv.stop()
 print("console + /fleet + pool-aggregated /metrics OK")
 EOF
-# Mid-run regression watch selftest: mini pipeline -> journal ->
-# aggregator rollup -> ledger history -> perf_stats verdict.  The
-# injected dispatch stall must escalate EXACTLY one incident bundle
-# (and latch on the second tick); the clean leg exactly zero.
-JAX_PLATFORMS=cpu python -m srtb_tpu.obs.regression --selftest \
-  2>/dev/null | tail -1 | tee artifacts/obs/regression_selftest.json
-grep -q '"selftest": "ok"' artifacts/obs/regression_selftest.json
 
 echo "CI OK"

@@ -1,15 +1,12 @@
-"""Compile-time HLO plan auditor: prove ``hbm_passes``, donation, and
-transfer-freedom per execution plan, without a device.
+"""Compile-time HLO plan auditor: count spectrum-sized passes and prove
+donation and transfer-freedom per execution plan, without a device.
 
-The pipeline is HBM-bandwidth bound, and PR 5 made the spectrum-pass
-count a first-class *claim* (``SegmentProcessor.hbm_passes``) that
-``bench.py`` feeds straight into the roofline model.  srtb-lint
-(analysis/core.py) checks the Python source; this module checks one
-level down, at the **lowered-HLO / compiled-artifact** level, so a
-regression in bytes moved, aliasing, or dtype is caught on CPU CI
-before a TPU run ever happens (cf. the bandwidth-accounting discipline
-of arXiv:2506.15437 and the stream/overlap audit methodology of
-arXiv:2101.00941).
+The pipeline is HBM-bandwidth bound.  srtb-lint (analysis/core.py)
+checks the Python source; this module checks one level down, at the
+**lowered-HLO / compiled-artifact** level, so a regression in bytes
+moved, aliasing, or dtype is caught on CPU CI before a TPU run ever
+happens (cf. the bandwidth-accounting discipline of arXiv:2506.15437
+and the stream/overlap audit methodology of arXiv:2101.00941).
 
 For every plan family reachable from ``plan_signature()`` the auditor
 AOT-lowers the plan's jitted programs (``SegmentProcessor.lowerables``
@@ -31,10 +28,8 @@ compiled artifact:
   entry-computation instruction's operand and result buffers, in units
   of one spectrum (``8 * n_spectrum`` bytes).  Buffers inside a fusion
   stay in registers/VMEM, so entry-level granularity approximates what
-  actually crosses HBM; the count is compared against the plan's
-  declared ``hbm_passes`` floor (audited >= declared must hold — the
-  declaration is a floor, never an overclaim) and pinned exactly in the
-  baseline so *any* newly materialized spectrum-sized pass fails CI.
+  actually crosses HBM; the count is pinned exactly in the baseline so
+  *any* newly materialized spectrum-sized pass fails CI.
 
 Each plan emits a JSON "plan card"; cards diff against the checked-in
 ``srtb_tpu/analysis/plan_cards.json`` with the same re-baseline
@@ -330,9 +325,6 @@ def audit_processor(proc, keep_text: bool = False) -> dict:
     warm_names = ("ring", "stage_a_ring", "batch_ring")
     warm_progs = {n: p for n, p in programs.items() if n in warm_names}
     checks = {
-        # declared hbm_passes is a FLOOR of real spectrum traffic: the
-        # compiled artifact must sweep at least that much
-        "hbm_floor_ok": total_passes >= proc.hbm_passes,
         # no donation may be dropped while a matching output existed
         "donation_ok": all(not p["donation"]["dropped"]
                            for p in programs.values()),
@@ -353,7 +345,6 @@ def audit_processor(proc, keep_text: bool = False) -> dict:
     }
     return {
         "plan_name": proc.plan_name,
-        "declared_hbm_passes": proc.hbm_passes,
         "fused_tail": bool(proc.fused_tail),
         "staged": bool(proc.staged),
         "ingest": "ring-v1" if ring else "direct",
@@ -383,10 +374,6 @@ def audit_families(keys=None, log2n: int = DEFAULT_LOG2N,
             card = audit_processor(proc)
         card["audit_shape"] = {"log2n": log2n, "channels": channels}
         card["mode"] = spec.mode
-        if spec.hbm_passes is not None:
-            card["checks"]["declared_matches_family"] = (
-                proc.hbm_passes == spec.hbm_passes)
-            card["expected_hbm_passes"] = spec.hbm_passes
         cards[k] = card
     return cards
 
@@ -400,9 +387,9 @@ _DIFF_PROGRAM_KEYS = (
     "spectrum_passes", "entry_copies", "entry_transposes", "collectives",
     "host_transfer_ops", "custom_calls", "host_callbacks", "f64_ops",
     "c128_ops", "donation", "alias_bytes")
-_DIFF_PLAN_KEYS = ("plan_name", "declared_hbm_passes", "fused_tail",
-                   "staged", "ingest", "reserved_bytes", "mode",
-                   "total_spectrum_passes", "checks")
+_DIFF_PLAN_KEYS = ("plan_name", "fused_tail", "staged", "ingest",
+                   "reserved_bytes", "mode", "total_spectrum_passes",
+                   "checks")
 
 
 def stable_view(card: dict) -> dict:
@@ -760,7 +747,7 @@ def selftest(log2n: int = DEFAULT_LOG2N,
             key="__selftest_uncarded",
             desc="selftest: registered but never carded",
             cfg={"fft_strategy": "four_step", "fused_tail": "on"},
-            donate=True, hbm_passes=5)):
+            donate=True)):
         cards = audit_families(["__selftest_uncarded"], log2n=log2n,
                                channels=channels)
         _, new_plans, _ = diff_cards(cards, checked_in)

@@ -207,9 +207,7 @@ class Config:
     # for every plan whose final pass can host the epilogue (four_step /
     # mxu / pallas / pallas2 / staged), off for the monolithic XLA R2C
     # custom call; "on" forces it (errors on monolithic); "off"
-    # restores the legacy 7-pass chain.  SegmentProcessor.hbm_passes
-    # reports the resulting modeled spectrum-pass count (bench.py
-    # roofline).
+    # restores the legacy unfused chain.
     fused_tail: str = "auto"
     # front-fused staged megakernel ("auto" | "on" | "off"): fold the
     # sub-byte unpack + window + even/odd pack + forward-FFT pass 1
@@ -217,11 +215,10 @@ class Config:
     # intermediate out) and the whole spectrum tail — Hermitian
     # post-process, RFI s1, dedispersion chirp — into pass 2's
     # epilogue, so a staged segment's front half completes in 2 HBM
-    # sweeps (SegmentProcessor.hbm_passes = 2; the staged_ffuse plan
-    # family, ops/pallas_fft2).  Requires the staged plan with
-    # SRTB_STAGED_ROWS_IMPL=pallas2, a fusable tail, and an unpack
-    # variant the kernel can spell in-register (simple 1/2/4/8-bit or
-    # 2-pol byte-interleaved).  "auto" = on when all of that holds AND
+    # sweeps (the staged_ffuse plan family, ops/pallas_fft2).  Requires
+    # the staged plan with SRTB_STAGED_ROWS_IMPL=pallas2, a fusable
+    # tail, and an unpack variant the kernel can spell in-register
+    # (simple 1/2/4/8-bit or 2-pol byte-interleaved).  "auto" = on when all of that holds AND
     # the kernels are trusted (the FFUSE_MOSAIC_OK probe flag or
     # SRTB_PALLAS_FFUSE=1 — never implicitly, so existing pallas2
     # configs keep their plan); "on" forces (errors when structurally
@@ -508,8 +505,7 @@ class Config:
     # statistics (trace-relevant).  Telemetry does not need every bin:
     # subsampling scales the epilogue's read volume — and the producer
     # recompute XLA sometimes chooses for a second consumer — down by
-    # k, which is what keeps the epilogue under the perf gate's noise
-    # floor on the CPU path.  1 = exact statistics.
+    # k.  1 = exact statistics.
     quality_subsample: int = 8
     # host-side EWMA drift detector on the bandpass mean: alert when
     # an observation sits more than quality_drift_threshold EWMA
@@ -550,10 +546,6 @@ class Config:
     # join exactly.  0 = off (zero cost).
     profile_capture_segments: int = 0
     profile_capture_dir: str = "artifacts/profile"
-    # append one "steady" perf record per finished run to this perf
-    # ledger (utils/perf_ledger.py JSONL; tools/perf_report.py renders
-    # the trajectory, tools/perf_gate.py gates regressions).  "" = off.
-    perf_ledger_path: str = ""
     # ---- fleet control tower (srtb_tpu/obs/) ----
     # long-horizon rollup store directory the aggregator writes
     # (obs/rollup.py tails the lanes' journals + event dumps into
@@ -567,13 +559,6 @@ class Config:
     # compaction drops rollup rows older than this many minutes
     # behind the newest minute IN THE DATA (0 = keep everything)
     obs_retention_minutes: int = 0
-    # mid-run regression watch (obs/regression.py): both the live
-    # rollup and the ledger history must have at least this many
-    # per-segment samples before a verdict is attempted
-    obs_regression_min_samples: int = 8
-    # extra required effect on top of the computed noise floor
-    # (fractional; 0.0 = the floor alone decides)
-    obs_regression_min_effect: float = 0.0
     # /healthz flips to 503 when the last processed segment is older
     # than this many seconds (gui/server.py staleness detection)
     health_stale_after_s: float = 30.0
@@ -632,7 +617,6 @@ class Config:
         "quality_coarse_bins", "quality_subsample",
         "canary_every_segments", "canary_width",
         "obs_rollup_resolution_s", "obs_retention_minutes",
-        "obs_regression_min_samples",
     })
     _FLOAT_FIELDS = frozenset({
         "baseband_freq_low", "baseband_bandwidth", "baseband_sample_rate",
@@ -653,7 +637,6 @@ class Config:
         "quality_hot_threshold", "quality_drift_threshold",
         "quality_drift_alpha", "canary_amp", "canary_dm",
         "canary_position", "canary_expected_snr", "canary_min_ratio",
-        "obs_regression_min_effect",
     })
     _BOOL_FIELDS = frozenset({
         "baseband_reserve_sample", "baseband_write_all", "gui_enable",

@@ -230,7 +230,7 @@ class _Handler(http.server.BaseHTTPRequestHandler):
             return
         if self.path == "/fleet":
             # the control tower's status snapshot (obs/status.py):
-            # pool member states, per-stream SLO burn, roofline,
+            # pool member states, per-stream SLO burn,
             # batch occupancy, drift — plus the rollup-store tail
             # when the server was started with fleet_store_dir
             from srtb_tpu.obs.status import fleet_status

@@ -2,10 +2,9 @@
 
 Every observability surface below this package is per-process and
 per-lane — v11 span journals (utils/telemetry.py), event rings
-(utils/events.py), /metrics (utils/metrics.py), the perf ledger
-(utils/perf_ledger.py) each tell one lane's story.  This package is
-the monitoring plane OVER them, the "one view over composed modules"
-the FPGA pulsar-search stacks imply (PAPERS.md):
+(utils/events.py), /metrics (utils/metrics.py) each tell one lane's
+story.  This package is the monitoring plane OVER them, the "one view
+over composed modules" the FPGA pulsar-search stacks imply (PAPERS.md):
 
 - :mod:`~srtb_tpu.obs.digest` — mergeable quantile digests
   (DDSketch-style relative-accuracy buckets) so distributions from
@@ -18,15 +17,10 @@ the FPGA pulsar-search stacks imply (PAPERS.md):
 - :mod:`~srtb_tpu.obs.trace_join` — the cross-device Perfetto export:
   one trace with a process-track per pool member, where a migrated
   stream's flow arrows cross device tracks;
-- :mod:`~srtb_tpu.obs.regression` — the mid-run regression watch:
-  rollup medians through perf_stats.compare() against the perf
-  ledger's history, escalating an incident bundle on a confirmed
-  throughput regression;
 - :mod:`~srtb_tpu.obs.status` — the ``/fleet`` payload
   (gui/server.py) and the data behind ``tools/console.py``.
 """
 
 from __future__ import annotations
 
-__all__ = ["digest", "store", "rollup", "trace_join", "regression",
-           "status"]
+__all__ = ["digest", "store", "rollup", "trace_join", "status"]

@@ -14,9 +14,7 @@ event dumps, and maintains:
 - the fleet event timeline — migrations, device halts, device drains
   — as identity-keyed ``fleet_event`` rows (event dumps are full
   rewrites, so rows dedup by identity in the store's last-wins merge
-  instead of by offset);
-- per-plan per-segment host seconds (the regression watch's sample
-  sets, obs/regression.py).
+  instead of by offset).
 
 Resume is BY OFFSET like the manifest WAL: a ``cursor.json`` in the
 store directory records, per journal, the active arm's byte offset +
@@ -116,8 +114,7 @@ class Aggregator:
 
     def __init__(self, store: RollupStore, journals=(),
                  events_dumps=(), resolution_s: int = 60,
-                 digest_alpha: float = 0.01,
-                 max_plan_samples: int = 512):
+                 digest_alpha: float = 0.01):
         if resolution_s <= 0:
             raise ValueError("resolution_s must be positive")
         self.store = store
@@ -125,14 +122,13 @@ class Aggregator:
         self.events_dumps = list(events_dumps)
         self.resolution_s = int(resolution_s)
         self.digest_alpha = float(digest_alpha)
-        self.max_plan_samples = max(8, int(max_plan_samples))
         self.cursor_path = os.path.join(store.directory, CURSOR_NAME)
         self._cursor = self._load_cursor()
         # rollup state (cumulative over this aggregator's lifetime)
         self._minutes: dict[str, dict] = {}
         self._digests: dict[tuple, QuantileDigest] = {}
         self._events: dict[str, dict] = {}
-        self._plan_samples: dict[str, list] = {}
+        self._plans: set[str] = set()
         self._prev: dict[str, dict] = {}  # per-stream previous record
         self._dirty: set = set()
         self.spans = 0
@@ -353,14 +349,8 @@ class Aggregator:
         stage_sum = float(segment_wall(stages))
         if stage_sum > 0.0:
             self._digest(("stage", "segment")).add(stage_sum)
-        if plan and stage_sum > 0.0:
-            # the regression watch's sample set: per-segment host
-            # seconds per plan (the same quantity perf_gate captures),
-            # bounded to the newest max_plan_samples
-            samples = self._plan_samples.setdefault(plan, [])
-            samples.append(round(stage_sum / 1e3, 6))
-            if len(samples) > self.max_plan_samples:
-                del samples[:len(samples) - self.max_plan_samples]
+        if plan:
+            self._plans.add(plan)
         self._dirty.add(k)
 
     def _digest(self, key: tuple) -> QuantileDigest:
@@ -394,21 +384,7 @@ class Aggregator:
         return n
 
     def plans(self) -> list[str]:
-        return sorted(self._plan_samples)
-
-    def segment_seconds(self, plan: str) -> list[float]:
-        """Per-segment host seconds for ``plan`` (newest
-        max_plan_samples) — the regression watch's B side."""
-        return list(self._plan_samples.get(plan, []))
-
-    def rollup_median_s(self, plan: str) -> float:
-        samples = sorted(self._plan_samples.get(plan, []))
-        if not samples:
-            return 0.0
-        mid = len(samples) // 2
-        if len(samples) % 2:
-            return samples[mid]
-        return (samples[mid - 1] + samples[mid]) / 2.0
+        return sorted(self._plans)
 
 
 def main(argv=None) -> int:

@@ -1,9 +1,8 @@
 """Fleet status: one structured snapshot for the operator console.
 
 :func:`fleet_status` assembles everything an operator scans during a
-run — pool member states, per-stream SLO burn, roofline gauges, batch
-occupancy, the migration timeline, drift alerts — into ONE dict, from
-two sources:
+run — pool member states, per-stream SLO burn, batch occupancy, the
+migration timeline, drift alerts — into ONE dict, from two sources:
 
 - the live metrics registry + SLO tracker (in-process state: gauges
   the fleet publishes as it runs);
@@ -58,9 +57,6 @@ def fleet_status(store_dir: str = "") -> dict:
 
     streams = {}
     per_stream = {
-        "roofline_frac": metrics.by_label("roofline_frac"),
-        "achieved_msamps": metrics.by_label("achieved_msamps"),
-        "achieved_gbps": metrics.by_label("achieved_gbps"),
         "segments": metrics.by_label("segments"),
         "dropped": metrics.by_label("segments_dropped"),
         "signals": metrics.by_label("signals"),
@@ -70,8 +66,7 @@ def fleet_status(store_dir: str = "") -> dict:
     for key, by in per_stream.items():
         for stream, val in by.items():
             streams.setdefault(stream, {})[key] = (
-                round(float(val), 4) if key.startswith(
-                    ("roofline", "achieved", "drift"))
+                round(float(val), 4) if key == "drift_score"
                 else int(val))
 
     dispatches = metrics.get("batched_dispatches")
@@ -86,11 +81,6 @@ def fleet_status(store_dir: str = "") -> dict:
         },
         "streams": streams,
         "slo": slo.evaluate() or {},
-        "roofline": {
-            "frac": round(metrics.get("roofline_frac"), 4),
-            "msamps": round(metrics.get("achieved_msamps"), 2),
-            "gbps": round(metrics.get("achieved_gbps"), 3),
-        },
         "batch": {
             "dispatches": int(dispatches),
             "segments": int(segments),

@@ -61,9 +61,8 @@ _VMEM_BLOCK_ELEMS = 1 << 18  # 256K f32 = 1 MB per plane
 # round-5 acceptance run rejected 3-pass bf16 outright
 # ("NotImplementedError: Unsupported dot precision: HIGH"; not
 # re-measured on this JAX) — an error CPU interpret mode cannot
-# surface.  The extra passes run on VMEM-resident blocks in an
-# HBM-bound pipeline (roofline_frac ~0.06), so HIGHEST costs nothing
-# measurable end-to-end.
+# surface.  The extra passes run on VMEM-resident blocks; what
+# HIGHEST costs end to end is not measured on the chip.
 _PRECISION = jax.lax.Precision.HIGHEST
 
 
@@ -245,8 +244,7 @@ def fft_rows_skzap_ri(re: jnp.ndarray, im: jnp.ndarray,
     call so the time series stays per-stream): one HBM read of the
     dedispersed spectrum rows, one write of the zapped waterfall, and
     the SK verdict + zero-channel flags + detection time series come
-    out with the write — ``hbm_passes`` 2 where the jnp chain models 3
-    and really does ~5.
+    out with the write.
 
     Returns ``(re, im, zapf, fs0, ts)``: zapped waterfall planes
     [..., F, L]; ``zapf``/``fs0`` [..., F, 128] lane-broadcast per-row

@@ -26,9 +26,8 @@ conditionally copy" discipline of ops/detect.py): candidates are a
 fixed top-K per stream, folding is a scatter-add over a fixed bin
 count, and the host decides what to write.  All arrays here are
 time-series-sized — ``T`` is ``2^11``-``2^15`` at production shapes —
-so the mode's HBM cost is noise next to the segment FFTs and the
-plan's spectrum-sized ``hbm_passes`` floor is unchanged (the plan
-audit pins that).
+so the mode's HBM cost is noise next to the segment FFTs (the plan
+audit pins the spectrum-sized pass count).
 """
 
 from __future__ import annotations

@@ -12,8 +12,8 @@ modules registered behind one harness — and this registry is the one
 table they all consume from, so the enumerations can never drift:
 
 - :class:`PlanFamily` — one auditable plan family: the config
-  projection that selects it, its declared ``hbm_passes`` floor, its
-  search mode, and whether the demotion ladder may land on it
+  projection that selects it, its search mode, and whether the
+  demotion ladder may land on it
   (``ladder`` eligibility).  ``analysis/hlo_audit.py`` enumerates
   these (``plan_families()``) instead of keeping its own tuple, and
   ``plan_audit --selftest`` proves a family registered here WITHOUT a
@@ -99,7 +99,7 @@ def resolve_mode(cfg) -> SearchMode:
 def build_processor(cfg, **kwargs):
     """Build the segment processor for ``cfg`` through the registry:
     the ONE constructor every consumer (Pipeline, healer plan factory,
-    fleet shared-plan cache, archive engine, HLO auditor, bench) uses,
+    fleet shared-plan cache, archive engine, HLO auditor) uses,
     so a registered mode reaches all of them.  ``kwargs`` pass through
     to the processor constructor (window_name / staged /
     donate_input)."""
@@ -122,8 +122,7 @@ def plan_cache_key(cfg, donate_input: bool = False, **kwargs) -> str:
 @dataclass(frozen=True)
 class PlanFamily:
     """One auditable plan family: the Config/constructor knobs that
-    select it, the declared ``hbm_passes`` floor the family must
-    report, its search mode, and its demotion-ladder eligibility
+    select it, its search mode, and its demotion-ladder eligibility
     (``ladder=False`` families — e.g. the periodicity mode, which the
     ladder demotes OUT of, never INTO — may not be landed on by a
     demotion; ``analysis/hlo_audit.audit_ladder`` enforces it)."""
@@ -134,7 +133,6 @@ class PlanFamily:
     donate: bool = False
     staged: bool | None = None
     env: dict = field(default_factory=dict)
-    hbm_passes: int | None = None
     mode: str = "single_pulse"
     ladder: bool = True
 
@@ -384,70 +382,60 @@ register_step(LadderStep(
 _RING_CFG = {"baseband_reserve_sample": True, "dm": 0.1}
 
 for _fam in (
-    PlanFamily("monolithic", "one XLA R2C custom call, unfused 7-pass "
-               "tail",
-               {"fft_strategy": "monolithic", "fused_tail": "off"},
-               hbm_passes=7),
+    PlanFamily("monolithic", "one XLA R2C custom call, unfused tail",
+               {"fft_strategy": "monolithic", "fused_tail": "off"}),
     PlanFamily("monolithic_donate", "monolithic with the donated raw "
                "input",
                {"fft_strategy": "monolithic", "fused_tail": "off"},
-               donate=True, hbm_passes=7),
+               donate=True),
     PlanFamily("four_step", "Bailey four-step R2C, unfused tail",
-               {"fft_strategy": "four_step", "fused_tail": "off"},
-               hbm_passes=7),
+               {"fft_strategy": "four_step", "fused_tail": "off"}),
     PlanFamily("four_step_ftail", "four-step with the fused RFI+chirp "
                "tail",
-               {"fft_strategy": "four_step", "fused_tail": "on"},
-               hbm_passes=5),
+               {"fft_strategy": "four_step", "fused_tail": "on"}),
     PlanFamily("four_step_ftail_donate", "fused tail + donated raw "
                "input",
                {"fft_strategy": "four_step", "fused_tail": "on"},
-               donate=True, hbm_passes=5),
+               donate=True),
     PlanFamily("four_step_ftail_mb2", "fused tail, micro-batch of 2",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "micro_batch_segments": 2},
-               donate=True, hbm_passes=5),
+               donate=True),
     PlanFamily("mxu_ftail", "radix-128 MXU matmul FFT, fused tail",
-               {"fft_strategy": "mxu", "fused_tail": "on"},
-               hbm_passes=5),
+               {"fft_strategy": "mxu", "fused_tail": "on"}),
     PlanFamily("pallas_ftail", "Pallas unpack/chirp kernels, fused tail",
                {"fft_strategy": "four_step", "fused_tail": "on",
-                "use_pallas": True},
-               hbm_passes=5),
+                "use_pallas": True}),
     PlanFamily("pallas_fft_ftail", "Pallas VMEM row-FFT legs, fused "
                "tail",
                {"fft_strategy": "pallas", "fused_tail": "on",
-                "use_pallas": True},
-               hbm_passes=5),
+                "use_pallas": True}),
     PlanFamily("pallas_skzap", "fully fused: one-kernel "
                "watfft+SK+detect",
                {"fft_strategy": "four_step", "fused_tail": "on",
-                "use_pallas": True, "use_pallas_sk": True},
-               hbm_passes=4),
+                "use_pallas": True, "use_pallas_sk": True}),
     PlanFamily("pallas_skzap_donate", "skzap plan + donated raw input",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "use_pallas": True, "use_pallas_sk": True},
-               donate=True, hbm_passes=4),
+               donate=True),
     PlanFamily("staged", "three-program staged plan, fused tail, "
                "donation",
                {"fft_strategy": "four_step", "fused_tail": "on"},
-               donate=True, staged=True, hbm_passes=5),
+               donate=True, staged=True),
     PlanFamily("staged_unfused", "staged plan with the legacy 7-pass "
                "tail",
                {"fft_strategy": "four_step", "fused_tail": "off"},
-               donate=True, staged=True, hbm_passes=7),
+               donate=True, staged=True),
     PlanFamily("staged_pallas", "staged with Pallas row-FFT legs",
                {"fft_strategy": "four_step", "fused_tail": "on"},
                donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas"},
-               hbm_passes=5),
+               env={"SRTB_STAGED_ROWS_IMPL": "pallas"}),
     PlanFamily("staged_pallas2", "staged with fused two-pass pallas2 "
                "legs (downgrades to pallas legs below the 2^24 leg "
                "window)",
                {"fft_strategy": "four_step", "fused_tail": "on"},
                donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"},
-               hbm_passes=5),
+               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     # ---- ingest-ring (ring-v1) families: overlap-save reserves a
     # tail (baseband_reserve_sample + a small dm keeps 0 < reserved
     # < n at the audit shape), so the two-input carry ++ new assemble
@@ -458,41 +446,40 @@ for _fam in (
                "program",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 **_RING_CFG},
-               donate=True, hbm_passes=5),
+               donate=True),
     PlanFamily("monolithic_ring", "ring on the unfused monolithic "
                "fallback plan",
                {"fft_strategy": "monolithic", "fused_tail": "off",
                 **_RING_CFG},
-               donate=True, hbm_passes=7),
+               donate=True),
     PlanFamily("pallas_skzap_ring", "fully fused 4-pass plan + ring",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "use_pallas": True, "use_pallas_sk": True,
                 **_RING_CFG},
-               donate=True, hbm_passes=4),
+               donate=True),
     PlanFamily("four_step_ftail_ring_mb2", "ring micro-batch: ONE "
                "carry + B stride uploads assemble B overlapped "
                "segments",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "micro_batch_segments": 2, **_RING_CFG},
-               donate=True, hbm_passes=5),
+               donate=True),
     PlanFamily("pallas_skzap_ring_mb2", "the fully-featured single-"
                "pulse plan: skzap + ring + micro-batch of 2 — the "
                "search_mode demotion rung's landing target",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "use_pallas": True, "use_pallas_sk": True,
                 "micro_batch_segments": 2, **_RING_CFG},
-               donate=True, hbm_passes=4),
+               donate=True),
     PlanFamily("staged_ring", "staged plan + ring: stage_a_ring emits "
                "the carry alongside the canonical boundary",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 **_RING_CFG},
-               donate=True, staged=True, hbm_passes=5),
+               donate=True, staged=True),
     # ---- front-fused staged megakernel (staged_ffuse): unpack +
     # window + even/odd pack + FFT pass 1 fold into the pallas2 pass-1
     # kernel (raw bytes in, blocked intermediate out) and the whole
-    # spectrum tail into pass 2's epilogue — the declared floor drops
-    # to 2 (the two megakernel sweeps; pipeline/segment.py documents
-    # the model).  front_fuse="on" forces the kernels so the audit
+    # spectrum tail into pass 2's epilogue (two megakernel
+    # sweeps).  front_fuse="on" forces the kernels so the audit
     # covers them on any backend; the demotion rung (front_fuse, the
     # step right after micro_batch) lands on today's staged plan.
     PlanFamily("staged_ffuse", "front-fused staged pallas2 megakernel: "
@@ -501,32 +488,28 @@ for _fam in (
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "front_fuse": "on"},
                donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"},
-               hbm_passes=2),
+               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     PlanFamily("staged_ffuse_ring", "front-fused staged plan + ingest "
                "ring: the carry alias must survive the front fusion "
                "(the PR-7 aval lesson, re-proven per card)",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "front_fuse": "on", **_RING_CFG},
                donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"},
-               hbm_passes=2),
+               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     # ---- data-quality epilogue (srtb_tpu/quality/): cheap jnp
     # reductions over the spectrum + waterfall ride the detect tail
-    # as a side output.  The extra traffic is coarse-bin-sized, so
-    # the spectrum-sized hbm_passes floor stays the base plan's;
+    # as a side output (coarse-bin-sized extra traffic);
     # ladder=False because the quality rung (FIRST in the order)
     # sheds the epilogue and must never demote INTO it.
     PlanFamily("four_step_ftail_quality", "fused-tail four-step plan "
                "with the data-quality epilogue side output",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "quality_stats": True},
-               donate=True, hbm_passes=5, ladder=False),
+               donate=True, ladder=False),
     # ---- periodicity search mode: the single-pulse chain PLUS the
     # harmonic-summed power spectrum + phase folding over the
     # dedispersed time series (pipeline/periodicity.py).  The extra
-    # passes are time-series-sized (spectrum / channel_count), so the
-    # spectrum-sized hbm_passes floor is the base plan's; ladder=False
+    # passes are time-series-sized (spectrum / channel_count); ladder=False
     # because the demotion ladder sheds the mode (search_mode rung,
     # FIRST in the order) and must never demote INTO it.
     PlanFamily("periodicity_ftail", "periodicity mode on the fused-"
@@ -534,14 +517,14 @@ for _fam in (
                "detection time series",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "search_mode": "periodicity"},
-               donate=True, hbm_passes=5, mode="periodicity",
+               donate=True, mode="periodicity",
                ladder=False),
     PlanFamily("periodicity_ring_mb2", "the archive-replay shape: "
                "periodicity mode + ingest ring + micro-batch of 2",
                {"fft_strategy": "four_step", "fused_tail": "on",
                 "micro_batch_segments": 2, "search_mode": "periodicity",
                 **_RING_CFG},
-               donate=True, hbm_passes=5, mode="periodicity",
+               donate=True, mode="periodicity",
                ladder=False),
 ):
     register_family(_fam)

@@ -81,7 +81,6 @@ from srtb_tpu.resilience.retry import RetryPolicy, retry_call
 from srtb_tpu.utils import events, slo, telemetry
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
-from srtb_tpu.utils.platform import hbm_peak_gbps
 from srtb_tpu.utils.tracing import StageTimer, span as stage_span
 
 
@@ -506,49 +505,6 @@ class Pipeline:
                                  stream=self.stream, seg=index, dur=dt)
         return seg
 
-    def _device_time_account(self, device_s: float,
-                             n_samples: int) -> tuple:
-        """Always-on device-time accounting for one drained segment:
-        the ``device_seconds`` histogram plus the LIVE roofline gauges
-        — achieved Msamples/s and modeled-HBM GB/s over this segment's
-        device wall, and ``roofline_frac`` against the device's HBM
-        peak (``utils.platform.HBM_PEAK_GBPS``, keyed by device_kind;
-        None — no gauge, no journal field — for a kind that is not in
-        the table, the CPU included).  The traffic model is the
-        active plan's audited ``hbm_passes`` floor (the quantity the
-        HLO plan auditor pins in plan_cards.json), so the gauges are
-        per-plan LOWER bounds: device_s is an upper bound on device
-        busy time and hbm_passes a floor on traffic.  Returns
-        (achieved_msamps, roofline_frac) for the journal span (None
-        when the active processor has no plan model — duck-typed
-        stubs)."""
-        metrics.histogram("device_seconds").observe(device_s)
-        if self._stream_labels is not None:
-            metrics.histogram(
-                "device_seconds",
-                labels=self._stream_labels).observe(device_s)
-        proc = self.processor
-        passes = getattr(proc, "hbm_passes", None)
-        n_spec = getattr(proc, "n_spectrum", None)
-        if passes is None or n_spec is None or device_s <= 0:
-            return None, None
-        seg_bytes = getattr(proc, "_segment_bytes",
-                            self.cfg.segment_bytes(1))
-        model_bytes = seg_bytes + 8.0 * n_spec * passes
-        gbps = model_bytes / device_s / 1e9
-        msamps = n_samples / device_s / 1e6
-        peak = hbm_peak_gbps()
-        frac = gbps / peak if peak else None
-        for name, val in (("achieved_msamps", msamps),
-                          ("achieved_gbps", gbps),
-                          ("roofline_frac", frac)):
-            if val is None:
-                continue
-            metrics.set(name, val)
-            if self._stream_labels is not None:
-                metrics.set(name, val, labels=self._stream_labels)
-        return msamps, frac
-
     def _record_segment(self, index: int, seg, det_res, positive: bool,
                         span: dict, queue_depth: int,
                         n_samples: int,
@@ -558,7 +514,7 @@ class Pipeline:
         """Per-drained-segment telemetry: lifetime counters, sliding
         window rates (segments/s and samples/s over the last 10 s — a
         stall is visible immediately, unlike the lifetime average), the
-        /healthz liveness stamp, device-time/roofline accounting, and
+        /healthz liveness stamp, the ``device_seconds`` histogram, and
         one journal span record."""
         metrics.add("segments")
         metrics.add("samples", n_samples)
@@ -571,10 +527,12 @@ class Pipeline:
             metrics.add("samples", n_samples,
                         labels=self._stream_labels)
         telemetry.mark_segment(self.stream or None)
-        msamps = frac = None
         if device_s is not None:
-            msamps, frac = self._device_time_account(device_s,
-                                                     n_samples)
+            metrics.histogram("device_seconds").observe(device_s)
+            if self._stream_labels is not None:
+                metrics.histogram(
+                    "device_seconds",
+                    labels=self._stream_labels).observe(device_s)
         if self.profile_capture is not None:
             # counts drained segments and auto-stops after N; the
             # sidecar records the covered trace_ids so the device
@@ -632,8 +590,6 @@ class Pipeline:
                 stream=self.stream or None,
                 trace_id=getattr(seg, "trace_id", 0) or None,
                 device_s=device_s,
-                achieved_msamps=msamps,
-                roofline_frac=frac,
                 # v10: stamped by the fleet's cross-stream batch
                 # former (pipeline/fleet._BatchFormer); absent on
                 # every solo dispatch — the span omits them
@@ -1142,9 +1098,7 @@ class Pipeline:
         # fetch-complete wall for THIS segment.  The blocking fetch
         # proves device completion, so this is an UPPER bound on the
         # segment's device busy time — exact in serial mode, inflated
-        # by drain-queue wait when the window runs deep — which makes
-        # every gauge derived from it (achieved Msamp/s, roofline
-        # fraction) an honest LOWER bound.
+        # by drain-queue wait when the window runs deep.
         device_s = max(0.0, time.perf_counter() - t_dispatched)
         # the dispatch-order index rides along so the sink-side fault
         # sites (sink_write, checkpoint) address segments in the SAME
@@ -2028,19 +1982,9 @@ class Pipeline:
             self._drain_sinks()
         self.stats.elapsed_s = time.perf_counter() - start
         self.stats.extras["stages"] = self.stage_timer.summary()
-        self._perf_ledger_record()
         log.info(f"[pipeline] {self.stats.segments} segments, "
                  f"{self.stats.msamples_per_sec:.1f} Msamples/s")
         return self.stats
-
-    def _perf_ledger_record(self) -> None:
-        """One "steady" perf-ledger record per finished run
-        (Config.perf_ledger_path; off by default) — steady-state runs
-        feed the same queryable trajectory bench rounds do."""
-        if getattr(self.cfg, "perf_ledger_path", ""):
-            from srtb_tpu.utils import perf_ledger as PL
-            PL.record_steady_state(self.cfg, self.stats,
-                                   self.processor)
 
     def _sanitize_check(self, wf, det_res) -> None:
         """Per-segment sanitizer checks at the drain boundary: NaN/Inf
@@ -2650,7 +2594,6 @@ class ThreadedPipeline(Pipeline):
             self.profile_capture.stop()
         self.stats.elapsed_s = time.perf_counter() - start_t
         self.stats.extras["stages"] = self.stage_timer.summary()
-        self._perf_ledger_record()
         log.info(f"[pipeline threaded] {self.stats.segments} segments, "
                  f"{self.stats.msamples_per_sec:.1f} Msamples/s")
         return self.stats

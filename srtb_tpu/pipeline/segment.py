@@ -296,8 +296,8 @@ class SegmentProcessor:
         self.f_min, self.f_c, self.df = f_min, f_c, df
         self.staged = (self.n >= STAGED_MIN_N) if staged is None else staged
         # fused spectrum tail (Config.fused_tail): RFI s1 + chirp fold
-        # into the forward FFT's final pass; resolved once so the plan,
-        # its signature, and the hbm_passes model can never disagree
+        # into the forward FFT's final pass; resolved once so the plan
+        # and its signature can never disagree
         self.fused_tail = self._resolve_fused_tail()
         # front-fused staged megakernel (Config.front_fuse, the
         # staged_ffuse family): unpack + window + even/odd pack +
@@ -377,35 +377,6 @@ class SegmentProcessor:
         self._skzap = bool(
             self.fused_tail and cfg.use_pallas and cfg.use_pallas_sk
             and _pf.supported(self.watfft_len, self.channel_count))
-        # modeled spectrum-sized HBM sweeps of this plan — the quantity
-        # bench.py's roofline model multiplies by (PERF.md "Roofline").
-        # A FLOOR in units of one spectrum-sized transfer (read or
-        # write), per stage group:
-        #   R2C read+write (2)
-        # + RFI s1 + chirp read+write (2, folded away by the fused tail)
-        # + waterfall FFT read+write (2)
-        # + SK + detect re-read floor (1, folded away by the skzap
-        #   kernel, whose stats/zap/time-series ride the watfft write)
-        # Which kernels execute a group changes real traffic only
-        # UPWARD from this floor (e.g. the unfused pallas_sk pair's zap
-        # rewrite makes the SK group 2 where the floor says 1), so
-        # achieved_gbps stays a lower bound for every plan; only the
-        # fusions above lower the floor itself.
-        self.hbm_passes = (2 + (0 if self.fused_tail else 2) + 2
-                           + (0 if self._skzap else 1))
-        if self.front_fuse:
-            # Front-fused floor (the ISSUE-15 model): the two megakernel
-            # sweeps a segment's front half cannot avoid — pass 1's
-            # blocked-intermediate write (its raw-byte + window reads
-            # are sub-spectrum-sized) and pass 2's intermediate re-read,
-            # whose dedispersed-spectrum emission hands straight to the
-            # waterfall tail.  Deliberately the most conservative floor
-            # on the board: the waterfall tail's traffic rides ABOVE it
-            # (like every kernel-choice cost does for the other plans),
-            # so achieved_gbps / roofline_frac stay honest lower
-            # bounds, and the audited per-program counts in
-            # plan_cards.json pin the true structural traffic.
-            self.hbm_passes = 2
         # XLA FFT row-length cap override (Config.fft_len_cap; None =
         # the ops/fft default), threaded through every FFT entry point
         self._len_cap = cfg.fft_len_cap or None
@@ -413,7 +384,7 @@ class SegmentProcessor:
         # is a fresh device_put the caller never reuses, so donating it
         # lets XLA recycle that HBM as program scratch — steady-state
         # streaming does no net fresh device allocation per segment.
-        # Off by default: external callers (bench.py, A/B tests) legally
+        # Off by default: external callers (A/B tests) legally
         # reuse one device-resident input across calls, which donation
         # would invalidate.
         self._donate_input = bool(donate_input)
@@ -507,8 +478,7 @@ class SegmentProcessor:
                     " — restarts will recompile")
         log.debug(f"[segment] n={n} spectrum={self.n_spectrum} "
                   f"channels={self.channel_count} watfft={self.watfft_len} "
-                  f"reserved={self.nsamps_reserved} plan={self.plan_name} "
-                  f"hbm_passes={self.hbm_passes}")
+                  f"reserved={self.nsamps_reserved} plan={self.plan_name}")
 
     # ------------------------------------------------------------------
     # fused spectrum tail: plan resolution + the epilogue itself
@@ -623,8 +593,8 @@ class SegmentProcessor:
 
     @property
     def plan_name(self) -> str:
-        """Human/bench-readable plan id: base plan + resolved strategy
-        + which fusions are live (bench.py emits this per JSON line)."""
+        """Human-readable plan id: base plan + resolved strategy
+        + which fusions are live."""
         strategy = F.resolve_strategy(self.n, self.cfg.fft_strategy)
         name = ("staged" if self.staged else "fused") + f":{strategy}"
         if self.fused_tail:
@@ -1247,7 +1217,7 @@ class SegmentProcessor:
         plan safety claim ("equal cache keys imply equal signatures")
         can never drift apart by a one-sided edit.  Only SRTB_* env
         prefixes that shape traces are swept: keying on run-local
-        paths (SRTB_BENCH_*, SRTB_WATCH_LOG, the cache dir itself)
+        paths (SRTB_WATCH_LOG, the cache dir itself)
         would silently miss on every deployment-environment
         difference — the exact outage the AOT cache exists to
         prevent."""
@@ -1265,7 +1235,7 @@ class SegmentProcessor:
         """Conservative shared-plan cache key WITHOUT constructing a
         processor: the trace projection + the constructor inputs.
         Equal keys imply equal :meth:`plan_signature` — every derived
-        plan flag (staged, fused_tail, ring, skzap, hbm_passes)
+        plan flag (staged, fused_tail, ring, skzap)
         resolves as a pure function of exactly these inputs and the
         local platform — so the fleet's SharedPlanCache
         (pipeline/fleet.py) can serve one compiled plan family to
@@ -1311,7 +1281,6 @@ class SegmentProcessor:
              # by either spelling must miss cleanly for the other
              "front_fuse": self.front_fuse,
              "skzap": self._skzap,
-             "hbm_passes": self.hbm_passes,
              # resolved ingest plan: the ring's two-input assemble
              # programs (and their carry avals) exist only when it is
              # live, so a restart that resolves differently (e.g. a
@@ -1343,8 +1312,7 @@ class SegmentProcessor:
         # Fresh jit wrappers of the underlying plan functions, NOT the
         # self._jit_* attributes: enable_aot swaps those for loaded
         # Compiled executables, which cannot .lower() again — the
-        # audit must stay lowerable on an AOT-active processor (e.g.
-        # SRTB_BENCH_AOT_DIR together with SRTB_BENCH_AUDIT).  The
+        # audit must stay lowerable on an AOT-active processor.  The
         # per-call wrappers are sanctioned here: this is the audit-only
         # cold path (never the per-segment dispatch), and a cached
         # wrapper would defeat the AOT independence above.

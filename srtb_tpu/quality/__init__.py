@@ -1,7 +1,7 @@
 """Science observatory: on-device data-quality statistics and the
 end-to-end pulse-injection canary.
 
-The observability stack (tracing, incidents, SLO burn, rooflines) says
+The observability stack (tracing, incidents, SLO burn, device time) says
 the engine is *fast and alive*; this package says the science is
 *right*:
 

@@ -1,9 +1,9 @@
 """Live operator console: the fleet status as a terminal dashboard.
 
 Renders :func:`srtb_tpu.obs.status.fleet_status` — pool member
-states, per-stream SLO burn, roofline gauges, batch occupancy, the
-migration timeline, drift alerts — as fixed-width text that reads at
-a glance over ssh.  Two data paths:
+states, per-stream SLO burn, batch occupancy, the migration
+timeline, drift alerts — as fixed-width text that reads at a glance
+over ssh.  Two data paths:
 
 - ``--url http://host:port`` polls a running ``gui/server.py``'s
   ``/fleet`` endpoint (the in-process registry view: live gauges +
@@ -28,14 +28,6 @@ import argparse
 import json
 import sys
 import time
-
-BAR_WIDTH = 24
-
-
-def _bar(frac: float, width: int = BAR_WIDTH) -> str:
-    frac = min(1.0, max(0.0, float(frac)))
-    n = int(round(frac * width))
-    return "[" + "#" * n + "-" * (width - n) + "]"
 
 
 def render(status: dict) -> str:
@@ -68,15 +60,8 @@ def render(status: dict) -> str:
             lines.append(
                 f"  {name:<12} seg={s.get('segments', 0):<6} "
                 f"drop={s.get('dropped', 0):<4} "
-                f"mig={s.get('migrations', 0):<3} "
-                f"roofline={s.get('roofline_frac', 0.0):.3f}"
+                f"mig={s.get('migrations', 0):<3}"
                 f"{burn}")
-
-    roof = status.get("roofline") or {}
-    lines.append(f"ROOFLINE {_bar(roof.get('frac', 0.0))} "
-                 f"{roof.get('frac', 0.0):.1%} of HBM peak  "
-                 f"({roof.get('msamps', 0.0)} Msamp/s, "
-                 f"{roof.get('gbps', 0.0)} GB/s)")
 
     batch = status.get("batch") or {}
     lines.append(f"BATCH occupancy={batch.get('occupancy', 0.0):.2f} "

@@ -4,7 +4,7 @@ The reference runs its whole graph off live packets in one process
 (ref: src/main.cpp:261-271 composes udp_receiver_pipe -> unpack -> fft
 -> rfi -> dedisperse -> ... -> write_signal_pipe; README.md:320-322
 documents the production deployment).  Ingest soak (udp_soak) and
-file-fed compute (bench.py) each prove half of that; this harness
+file-fed compute (benchmark/run.py) each prove half of that; this harness
 proves the composition: a paced loopback sender streams dispersed-pulse
 baseband packets at a multiple of the real-time wire rate, a
 UdpReceiverSource assembles segments, the ThreadedPipeline overlaps

@@ -5,10 +5,10 @@ Usage (CI runs exactly this, plus ``--selftest``)::
     python -m srtb_tpu.tools.plan_audit
 
 AOT-lowers every plan family (``srtb_tpu/analysis/hlo_audit.py``),
-audits the compiled artifacts — spectrum-sized HBM round trips vs the
-declared ``hbm_passes`` floor, donation/aliasing tables, f64/callback/
-collective/copy flags — and diffs the resulting plan cards against the
-checked-in baseline ``srtb_tpu/analysis/plan_cards.json``.
+audits the compiled artifacts — spectrum-sized HBM round trips,
+donation/aliasing tables, f64/callback/collective/copy flags — and
+diffs the resulting plan cards against the checked-in baseline
+``srtb_tpu/analysis/plan_cards.json``.
 
 Exit code 0 when every card matches the baseline and every invariant
 check passes, 1 on any regression or failed check, 2 on usage errors.
@@ -154,7 +154,6 @@ def main(argv=None) -> int:
                 don = {n: p["donation"] for n, p in progs.items()
                        if p["donation"]["declared"]}
                 print(f"{k}: plan={c['plan_name']} "
-                      f"declared={c['declared_hbm_passes']} "
                       f"audited={c['total_spectrum_passes']} ({passes}) "
                       f"donation={don if don else 'none'}")
         summary = (f"plan-audit: {len(cards)} plan(s), "

@@ -32,11 +32,8 @@ journal the pipeline writes (one record per segment) and reports
   tenant's books balance independently); feed it one lane's journal
   or several lanes' merged.
 - device (schema-v8 spans): the performance observatory's device-time
-  accounting — per-segment dispatch->ready wall percentiles,
-  device-time-derived Msamples/s and roofline_frac (lower bounds: the
-  traffic model is the plan's audited hbm_passes floor over an
-  upper-bound device wall), and the cumulative compile / plan-cache /
-  AOT-cache totals.
+  accounting — per-segment dispatch->ready wall percentiles and the
+  cumulative compile / plan-cache / AOT-cache totals.
 - science observatory (schema-v9 spans): the per-segment ``quality``
   and ``canary`` extras are summarized by tools/quality_report.py;
   this report treats them like any other extra payload.
@@ -429,8 +426,7 @@ def fleet_device_stats(records: list[dict]) -> dict:
 def device_stats(records: list[dict]) -> dict:
     """Device-time accounting from v8 spans (performance
     observatory).  ``device_ms`` is per-segment (an upper bound on
-    device busy time — dispatch->drain-head-ready wall), the
-    roofline/throughput fields are per-segment lower bounds, and the
+    device busy time — dispatch->drain-head-ready wall) and the
     compile/cache counters are cumulative (last record = run totals).
     Older records (no device fields) are skipped; empty dict when
     none qualify."""
@@ -440,10 +436,6 @@ def device_stats(records: list[dict]) -> dict:
         return {}
     dev = sorted(float(r["device_ms"]) for r in v8
                  if "device_ms" in r)
-    fracs = [float(r["roofline_frac"]) for r in v8
-             if "roofline_frac" in r]
-    msamps = [float(r["achieved_msamps"]) for r in v8
-              if "achieved_msamps" in r]
     last = v8[-1]
     out = {"records": len(v8)}
     if dev:
@@ -452,13 +444,6 @@ def device_stats(records: list[dict]) -> dict:
             device_p95_ms=round(_percentile(dev, 0.95), 3),
             device_max_ms=round(dev[-1], 3),
             device_total_s=round(sum(dev) / 1e3, 3))
-    if msamps:
-        out["achieved_msamps_median"] = round(
-            _percentile(sorted(msamps), 0.50), 2)
-    if fracs:
-        out["roofline_frac_median"] = round(
-            _percentile(sorted(fracs), 0.50), 4)
-        out["roofline_frac_max"] = round(max(fracs), 4)
     out.update(
         compile_ms=float(last.get("compile_ms", 0.0)),
         plan_compiles=int(last.get("plan_compiles", 0)),
@@ -570,14 +555,6 @@ def _md(rep: dict) -> str:
                 f"p95 {dv['device_p95_ms']} ms, max "
                 f"{dv['device_max_ms']} ms "
                 f"(total {dv['device_total_s']} s; upper bound)")
-        if "roofline_frac_median" in dv:
-            lines.append(
-                f"roofline_frac: median {dv['roofline_frac_median']}, "
-                f"max {dv['roofline_frac_max']} (lower bound vs the "
-                "plan's audited hbm_passes floor)"
-                + (f"; achieved {dv['achieved_msamps_median']} "
-                   "Msamples/s median"
-                   if "achieved_msamps_median" in dv else ""))
         lines.append(
             f"compile: {dv['compile_ms']} ms cumulative over "
             f"{dv['plan_compiles']} first-dispatch compile(s); AOT "

@@ -91,10 +91,6 @@ import time
 #       info = "migrate:src->dst"
 #   incident
 #       an incident bundle was written; info = the bundle dir name
-#   obs.regression
-#       the mid-run regression watch (obs/regression.py) confirmed a
-#       throughput regression against ledger history;
-#       info = "plan=...effect=...p=..."
 #   slo
 #       an SLO objective changed state; info = "objective:state"
 # ---------------------------------------------------------------------

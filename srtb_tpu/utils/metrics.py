@@ -387,12 +387,6 @@ class Metrics:
         "stage_seconds": "Per-stage host wall clock (seconds)",
         "device_seconds": "Per-segment dispatch-to-ready device wall "
                           "(upper bound)",
-        "achieved_msamps": "Last segment device-time Msamples/s "
-                           "(lower bound)",
-        "achieved_gbps": "Last segment modeled HBM GB/s over device "
-                         "time (lower bound)",
-        "roofline_frac": "Last segment achieved_gbps over the "
-                         "configured HBM peak (lower bound)",
         "compile_seconds": "Cumulative trace+compile wall "
                            "(first-dispatch upper bound + AOT-miss "
                            "compiles)",

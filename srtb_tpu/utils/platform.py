@@ -1,29 +1,10 @@
 """Device/platform facts shared by the pipeline: the sanctioned D2H
-fetch, the TPU test that gates Pallas interpret mode, and the per-device
-HBM peak table the roofline share divides by.  The platform itself is
-whatever ``JAX_PLATFORMS`` (or JAX's default) selects — nothing here
-overrides it.
+fetch and the TPU test that gates Pallas interpret mode.  The platform
+itself is whatever ``JAX_PLATFORMS`` (or JAX's default) selects —
+nothing here overrides it.
 """
 
 from __future__ import annotations
-
-# Peak HBM bandwidth in GB/s, keyed by ``jax.Device.device_kind``.
-# v5e (JAX calls it "TPU v5 lite"): 819 GB/s (Google Cloud documentation,
-# "TPU v5e").  A kind that is not listed has no roofline: callers get
-# None, never another chip's peak.
-HBM_PEAK_GBPS = {
-    "TPU v5 lite": 819.0,
-}
-
-
-def hbm_peak_gbps(device_kind: str | None = None) -> float | None:
-    """Peak HBM GB/s of ``device_kind`` (default: device 0 of the
-    default backend), or None when the kind is not in the table."""
-    if device_kind is None:
-        import jax
-
-        device_kind = jax.devices()[0].device_kind
-    return HBM_PEAK_GBPS.get(device_kind)
 
 
 def to_host(x):

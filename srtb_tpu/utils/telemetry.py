@@ -57,13 +57,10 @@ from srtb_tpu.utils.metrics import metrics
 # for the same segment correlate exactly; an incident bundle's
 # spans_tail.jsonl joins its trace.jsonl on this field.
 # v8 (performance observatory): adds per-segment DEVICE-time
-# accounting and live roofline fields — ``device_ms`` (dispatch-return
-# -> drain-head-ready wall clock: an upper bound on device busy time,
-# exact in serial mode; omitted when the engine did not measure it),
-# ``achieved_msamps`` / ``roofline_frac`` (this segment's throughput
-# against its plan's audited hbm_passes traffic floor and the
-# configured HBM peak — both LOWER bounds, since device_ms is an
-# upper bound) — plus the cumulative compile/cache accounting
+# accounting — ``device_ms`` (dispatch-return -> drain-head-ready wall
+# clock: an upper bound on device busy time, exact in serial mode;
+# omitted when the engine did not measure it) — plus the cumulative
+# compile/cache accounting
 # ``compile_ms`` (first-dispatch trace+compile wall, plus AOT-miss
 # compiles), ``plan_compiles``, ``aot_cache_hits`` /
 # ``aot_cache_misses``.
@@ -313,8 +310,6 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
                  stream: str | None = None,
                  trace_id: int | None = None,
                  device_s: float | None = None,
-                 achieved_msamps: float | None = None,
-                 roofline_frac: float | None = None,
                  batch_size: int | None = None,
                  batch_wait_ms: float | None = None,
                  device: str | None = None) -> dict:
@@ -400,8 +395,6 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         # host stages); omitted when unmeasured (ThreadedPipeline) —
         # never a fake 0, same rule as overlap_hidden_ms.
         rec["device_ms"] = round(max(device_s, 0.0) * 1e3, 3)
-    if achieved_msamps is not None:
-        rec["achieved_msamps"] = round(achieved_msamps, 2)
     if batch_size is not None:
         # v10: segments sharing this segment's device dispatch (the
         # cross-stream batch former); omitted on solo dispatches —
@@ -409,8 +402,6 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         rec["batch_size"] = int(batch_size)
     if batch_wait_ms is not None:
         rec["batch_wait_ms"] = round(max(batch_wait_ms, 0.0), 3)
-    if roofline_frac is not None:
-        rec["roofline_frac"] = round(roofline_frac, 4)
     if active_plan is not None:
         # the plan ACTIVE AT DRAIN TIME (like every cumulative field
         # above; in overlapped mode a demotion between this segment's

@@ -94,8 +94,7 @@ def test_plan_signature_keys_on_trace_shape_only(tmp_path, monkeypatch):
                        udp_receiver_rcvbuf_bytes=1 << 20,
                        segment_deadline_s=42.0)
     assert SegmentProcessor(same).plan_signature() == sig
-    # run-local SRTB_ knobs (bench dirs, watcher logs): same signature
-    monkeypatch.setenv("SRTB_BENCH_AOT_DIR", "/tmp/other")
+    # run-local SRTB_ knobs (watcher logs): same signature
     monkeypatch.setenv("SRTB_WATCH_LOG", "/tmp/w.log")
     assert SegmentProcessor(same).plan_signature() == sig
     # trace-shaping changes: different signature

@@ -1,0 +1,146 @@
+"""The benchmark's own selftests (``benchmark/selftest/test_*.py``), run
+where the driver counts: a PR that renames a span, a counter or a journal
+key the benchmark's reducers read fails here, on the CPU, and not on the
+chip as ``output_malformed``.  The cases live with the benchmark; this
+file imports them under their own names and adds one guard of its own.
+
+The selftest directory goes on ``sys.path`` and its modules are imported
+by their top-level names, as ``pytest benchmark/selftest`` imports them:
+``test_naoc_cell`` does ``import test_scopes`` and registers its cell in
+that module's ``CELLS``, so both must see one module object.  The two
+grid cases want four devices where ``tests/conftest.py`` forces eight;
+each runs in a child with the selftests' own ``XLA_FLAGS``.
+
+Two things are repaired here and not in the selftests, which this file
+may not edit: ``test_run.run_cell`` hands the program's logger the
+test's own ``sys.stderr``, which pytest closes with the test, so the
+stream is put back after every case; and the record-drain case reads
+``drain.lines`` after its write, see below.
+"""
+
+import itertools
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SELFTEST = os.path.join(ROOT, "benchmark", "selftest")
+sys.path[:0] = [ROOT, SELFTEST]
+
+import test_gen  # noqa: E402
+import test_naoc_cell  # noqa: E402
+import test_reference  # noqa: E402
+import test_run  # noqa: E402
+import test_scopes  # noqa: E402
+import test_trace  # noqa: E402
+from test_reference import raw  # noqa: E402,F401  (fixture)
+from test_scopes import tiny_with_new_entries  # noqa: E402,F401  (fixture)
+
+# run in a child on four virtual devices
+FOUR_DEVICES = ("test_grid_cell_on_four_virtual_devices",
+                "test_grid_rehearsal_reports_its_five_stages")
+
+DRAIN_CASE = "test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull"
+
+for _mod in (test_gen, test_reference, test_trace, test_scopes,
+             test_naoc_cell, test_run):
+    for _name, _obj in vars(_mod).items():
+        if _name.startswith("test_") and callable(_obj) \
+                and _name not in FOUR_DEVICES + (DRAIN_CASE,):
+            assert _name not in globals(), _name
+            globals()[_name] = _obj
+
+
+@pytest.fixture(autouse=True)
+def _logger_stream_put_back():
+    from srtb_tpu.utils.logging import log
+    stream = log.stream
+    yield
+    log.stream = stream
+
+
+def test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull(
+        tmp_path, monkeypatch):
+    # the case waits for ``drain.lines + 1`` records and reads
+    # ``drain.lines`` after its write: where the drain's thread has
+    # stamped that record already, it waits for one that never comes
+    # (seen under six xdist workers).  Its k-th wait is for k records.
+    from benchmark.drivers.dmgrid import RecordDrain
+
+    wait_for, calls = RecordDrain.wait_for, itertools.count(1)
+    monkeypatch.setattr(
+        RecordDrain, "wait_for",
+        lambda self, lines, timeout=10.0: wait_for(self, next(calls),
+                                                   timeout))
+    getattr(test_run, DRAIN_CASE)(tmp_path)
+
+
+@pytest.mark.parametrize("case", FOUR_DEVICES)
+def test_on_four_virtual_devices(case):
+    # the whole directory is collected (test_naoc_cell registers its
+    # cell at import), one case of it runs
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "no:randomly", SELFTEST, "-k", case],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0 and "1 passed" in r.stdout, \
+        r.stdout[-4000:] + r.stderr[-2000:]
+
+
+# what ``benchmark/reducers/journal*.py`` and ``drivers/served.py`` read
+# from a ``segment_span``, beside the stages and counters that
+# ``benchmark/layer_metrics/*.json`` name
+SPAN_KEYS = ("type", "stages_ms", "device_ms", "compile_ms", "h2d_bytes",
+             "ring_carry_bytes", "ring_cold_dispatches", "dump")
+SERVED_STAGES = ("ingest", "dispatch", "fetch", "sink")
+
+
+def test_every_span_key_the_benchmark_reads_is_journalled(capsys):
+    """A pin, not a repeat of ``test_run.py``: it fails only where a key
+    is missing from the journal of the tiny served cell."""
+    cell = "tiny_j1644.replay_quiet"
+    work = os.path.join(ROOT, ".bench_work", cell)
+    try:
+        from benchmark import run
+        from srtb_tpu.utils import logging as program_logging
+
+        # as test_run.run_cell: the logger bound another test's stream
+        program_logging.log.stream = sys.stderr
+        assert run.main(["--root", test_run.TINY, "--workload", cell,
+                         "--seed", "11", "--seconds", "1", "--trace", "0",
+                         "--allow-cpu", "--keep-work"]) == 0
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert out["correct"] and out["failed"] == 0
+        with open(os.path.join(work, "journal.jsonl")) as f:
+            spans = [json.loads(ln) for ln in f]
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    spans = [s for s in spans if s.get("type") == "segment_span"]
+    assert spans
+    counters, stages = set(), set(SERVED_STAGES)
+    metrics_dir = os.path.join(ROOT, "benchmark", "layer_metrics")
+    for name in sorted(os.listdir(metrics_dir)):
+        with open(os.path.join(metrics_dir, name)) as f:
+            m = json.load(f)
+        if m["reducer"].startswith("journal_"):
+            args = m.get("args", {})
+            if "counter" in args:
+                counters.add(args["counter"])
+            if "stage" in args:
+                stages.add(args["stage"])
+            stages.update(args.get("stages", ()))
+    assert {"h2d_bytes", "ring_carry_bytes"} <= counters
+    for s in spans:
+        missing = (set(SPAN_KEYS) | counters) - set(s)
+        assert not missing, (missing, s)
+        assert s["v"] == 11 and "plan_compiles" in s
+        # the second yardstick's fields do not grow back
+        assert not {"roofline_frac", "achieved_msamps"} & set(s), s
+    journalled = set().union(*(s["stages_ms"] for s in spans))
+    assert stages <= journalled, stages - journalled

@@ -19,15 +19,8 @@ tests pin:
 - the Parseval mean-power identity itself against the direct mean;
 - the in-kernel SK decision of the skzap kernel against the jnp chain,
   including a deliberately-zapped row;
-- plan_signature changes whenever fusion toggles (AOT cache safety);
-- the per-plan hbm_passes model (7 legacy, 5 fused tail, 4 skzap) and
-  bench.roofline_model consuming it.
+- plan_signature changes whenever fusion toggles (AOT cache safety).
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -104,8 +97,9 @@ def test_fused_vs_unfused_four_step(n):
     composition (the production format)."""
     off = _run(_cfg(n=n, fused_tail="off"))
     on = _run(_cfg(n=n, fused_tail="on"))
-    assert off[0].hbm_passes == 7 and on[0].hbm_passes == 5
     assert not off[0].fused_tail and on[0].fused_tail
+    assert on[0].plan_name == "fused:four_step+ftail"
+    assert off[0].plan_name == "fused:four_step"
     _assert_parity(off, on)
 
 
@@ -124,19 +118,20 @@ def test_fused_vs_unfused_staged(monkeypatch):
     off = _run(_cfg(fused_tail="off"), staged=True)
     on = _run(_cfg(fused_tail="on"), staged=True)
     assert off[0].staged and on[0].staged
-    assert off[0].hbm_passes == 7 and on[0].hbm_passes == 5
+    assert not off[0].fused_tail and on[0].fused_tail
+    assert on[0].plan_name == off[0].plan_name + "+ftail"
     assert on[0].chirp is None and on[0].chirp_w is None
     _assert_parity(off, on, atol_scale=1e-3)
 
 
 def test_fused_skzap_vs_unfused(caplog):
     """Fully-fused waterfall tail (one kernel: C2C + dewindow + SK +
-    zap + ts) vs the legacy jnp chain — 4 modeled passes vs 7."""
+    zap + ts) vs the legacy jnp chain."""
     kw = dict(channels=8, use_pallas=True, use_pallas_sk=True)
     off = _run(_cfg(fused_tail="off", **kw))
     on = _run(_cfg(fused_tail="on", **kw))
-    assert on[0]._skzap and on[0].hbm_passes == 4
-    assert off[0].hbm_passes == 7
+    assert on[0]._skzap and on[0].fused_tail
+    assert not off[0]._skzap and not off[0].fused_tail
     assert on[0].plan_name.endswith("+ftail+skzap")
     _assert_parity(off, on, atol_scale=1e-3)
 
@@ -232,24 +227,6 @@ def test_plan_signature_changes_when_fusion_toggles():
         _cfg(fused_tail="on", chirp_exact=True)).plan_signature() != sig_on
 
 
-def test_hbm_passes_model():
-    """The per-plan modeled pass counts and their roofline consumption."""
-    import bench
-
-    assert SegmentProcessor(
-        _cfg(fft_strategy="monolithic")).hbm_passes == 7
-    assert SegmentProcessor(_cfg(fused_tail="off")).hbm_passes == 7
-    assert SegmentProcessor(_cfg(fused_tail="auto")).hbm_passes == 5
-    assert SegmentProcessor(
-        _cfg(fused_tail="auto", channels=8, use_pallas=True,
-             use_pallas_sk=True)).hbm_passes == 4
-    n, ch = 1 << 20, 1 << 8
-    _, legacy = bench.roofline_model(n, ch, 2, hbm_passes=7)
-    _, fused = bench.roofline_model(n, ch, 2, hbm_passes=4)
-    spectrum_bytes = 8.0 * (n // 2)
-    np.testing.assert_allclose(legacy - fused, 3 * spectrum_bytes)
-
-
 def test_fused_tail_auto_gates_bankless_sizes(monkeypatch):
     """auto keeps bankless plans (in-trace df64 chirp) unfused above
     the proven size range; bank plans carry no gate; "on" overrides
@@ -257,7 +234,7 @@ def test_fused_tail_auto_gates_bankless_sizes(monkeypatch):
     import srtb_tpu.pipeline.segment as seg
     monkeypatch.setattr(seg, "FUSED_TAIL_DF64_MAX_SPECTRUM", 1 << 10)
     gated = SegmentProcessor(_cfg(use_pallas=True))   # n_spec 2^15 > 2^10
-    assert not gated.fused_tail and gated.hbm_passes == 7
+    assert not gated.fused_tail and "+ftail" not in gated.plan_name
     bank = SegmentProcessor(_cfg())                   # bank plan: no gate
     assert bank.fused_tail
     forced = SegmentProcessor(_cfg(use_pallas=True, fused_tail="on"))
@@ -283,33 +260,3 @@ def test_chirp_exact_escape_hatch_matches_anchored():
     np.testing.assert_allclose(exact[1], on[1], atol=1e-5 * scale, rtol=0)
     np.testing.assert_array_equal(np.asarray(on[2].signal_counts),
                                   np.asarray(exact[2].signal_counts))
-
-
-@pytest.mark.slow
-def test_bench_emits_plan_and_hbm_passes():
-    """bench.py artifact lines are self-describing: plan + hbm_passes,
-    7 on the legacy leg, 4 on the fully-fused leg (CPU interpret)."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "SRTB_BENCH_LOG2N": "16",
-           "SRTB_BENCH_REPS": "1"}
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--fused-tail", "off"],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["hbm_passes"] == 7 and rec["fused_tail"] == "off"
-    assert rec["plan"].startswith("fused:")
-
-    env.update({"SRTB_BENCH_FFT_STRATEGY": "four_step",
-                "SRTB_BENCH_LOG2CHAN": "3", "SRTB_BENCH_USE_PALLAS": "1",
-                "SRTB_BENCH_USE_PALLAS_SK": "1"})
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--fused-tail", "on"],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["hbm_passes"] == 4 and rec["fused_tail"] == "on"
-    assert rec["plan"].endswith("+ftail+skzap")
-    # model_hbm_gb really is computed from the per-plan count
-    m = (1 << 16) // 2
-    expect = ((1 << 16) * 2 / 8.0 + 8.0 * m * 4) / 1e9
-    np.testing.assert_allclose(rec["model_hbm_gb"], expect, atol=5e-4)

@@ -1,5 +1,5 @@
 """Fleet control tower (srtb_tpu/obs/): digests, store, aggregator,
-cross-device trace join, regression watch, status + console, /fleet."""
+cross-device trace join, status + console, /fleet."""
 
 import gzip
 import json
@@ -155,9 +155,7 @@ def test_aggregator_rollup_counters_and_digests(tmp_path):
     dig = QuantileDigest.from_dict(
         state["d:stage:dispatch"]["digest"])
     assert dig.count == 5
-    # per-plan samples feed the regression watch: stage sums in s
     assert agg.plans() == ["p1"]
-    assert agg.segment_seconds("p1") == pytest.approx([3.5e-3] * 5)
 
 
 def test_aggregator_resumes_active_journal_by_offset(tmp_path):
@@ -304,72 +302,6 @@ def test_trace_join_cli(tmp_path, capsys):
     assert doc["traceEvents"]
 
 
-# --------------------------------------------------- regression watch
-
-
-def test_regression_watch_trips_once_and_latches(tmp_path):
-    from srtb_tpu.obs.regression import RegressionWatch
-    from srtb_tpu.utils import perf_ledger as PL
-    ledger = str(tmp_path / "ledger.jsonl")
-    rng = np.random.default_rng(0)
-    base = (0.010 + rng.normal(0, 2e-4, 24)).tolist()
-    PL.PerfLedger(ledger).append(PL.make_record(
-        "test", 0.01, "s/segment", plan="p1", samples_s=base,
-        host_fp="", git_sha_value=""))
-    inc = str(tmp_path / "incidents")
-    watch = RegressionWatch(ledger, incident_dir=inc, host_fp="")
-    slow = (0.020 + rng.normal(0, 2e-4, 24)).tolist()
-    v = watch.check("p1", slow)
-    assert v["checked"] and v["regression"] and v["escalated"]
-    bundles = [n for n in os.listdir(inc)
-               if os.path.isdir(os.path.join(inc, n))]
-    assert len(bundles) == 1  # exactly one incident bundle
-    # the latch: a sustained regression is ONE incident, not one/tick
-    v2 = watch.check("p1", slow)
-    assert v2["regression"] and v2["escalated"] is False
-    assert len([n for n in os.listdir(inc)
-                if os.path.isdir(os.path.join(inc, n))]) == 1
-    # clean samples against the same baseline: no trip
-    clean = (0.010 + rng.normal(0, 2e-4, 24)).tolist()
-    watch2 = RegressionWatch(ledger,
-                             incident_dir=str(tmp_path / "inc2"),
-                             host_fp="")
-    vc = watch2.check("p1", clean)
-    assert vc["checked"] and not vc["regression"]
-    assert not os.path.isdir(str(tmp_path / "inc2")) or not os.listdir(
-        str(tmp_path / "inc2"))
-
-
-def test_regression_watch_needs_enough_samples(tmp_path):
-    from srtb_tpu.obs.regression import RegressionWatch
-    watch = RegressionWatch(str(tmp_path / "none.jsonl"), host_fp="")
-    v = watch.check("p1", [0.01] * 3)
-    assert v["checked"] is False and "3 live samples" in v["reason"]
-    v = watch.check("p1", [0.01] * 24)
-    assert v["checked"] is False and "ledger" in v["reason"]
-
-
-def test_perf_ledger_history_filters(tmp_path):
-    from srtb_tpu.utils import perf_ledger as PL
-    recs = [
-        PL.make_record("t", 1.0, "u", plan="p1", samples_s=[1.0, 2.0],
-                       host_fp="hostA", git_sha_value=""),
-        PL.make_record("t", 1.0, "u", plan="p1", samples_s=[3.0],
-                       host_fp="hostB", git_sha_value=""),
-        PL.make_record("t", 1.0, "u", plan="p2", samples_s=[9.0],
-                       host_fp="hostA", git_sha_value=""),
-        PL.make_record("t", 1.0, "u", plan="p1",
-                       host_fp="hostA", git_sha_value=""),  # no samples
-    ]
-    assert PL.history(recs, "p1", host_fp="hostA") == [1.0, 2.0]
-    assert PL.history(recs, "p1") == [1.0, 2.0, 3.0]
-    assert PL.history(recs, "p2", host_fp="hostB") == []
-    many = [PL.make_record("t", 1.0, "u", plan="p1",
-                           samples_s=[float(i)], host_fp="",
-                           git_sha_value="") for i in range(6)]
-    assert PL.history(many, "p1", max_records=3) == [3.0, 4.0, 5.0]
-
-
 # --------------------------------------- status, console, /fleet
 
 
@@ -385,7 +317,6 @@ def test_fleet_status_and_console_render(tmp_path):
         metrics.add("migrations", 2)
         metrics.add("migrations", labels={"device": "dev0"}, value=2)
         metrics.add("device_drains", labels={"device": "dev1"})
-        metrics.set("roofline_frac", 0.062)
         metrics.add("batched_dispatches", 4)
         metrics.add("batched_segments", 10)
         # a store with a migration timeline row

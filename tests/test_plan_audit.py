@@ -1,7 +1,7 @@
 """Compile-time HLO plan auditor (srtb_tpu/analysis/hlo_audit.py +
 python -m srtb_tpu.tools.plan_audit): donation proven honored vs
-visibly dropped, audited spectrum passes vs the declared hbm_passes
-floor, dtype/transfer flags, baseline accept/reject, CLI exit codes.
+visibly dropped, audited spectrum passes, dtype/transfer flags,
+baseline accept/reject, CLI exit codes.
 
 Everything here lowers + compiles on the CPU backend; no program is
 ever executed (the auditor's contract: no device required).
@@ -56,15 +56,18 @@ class TestDonation:
             assert prog["alias_bytes"] >= boundary_bytes, (name, prog)
         assert card["checks"]["donation_ok"]
 
-    def test_raw_input_donation_is_structural_no_candidate(self):
-        """The fused plan's donated raw uint8 buffer can never alias an
-        f32 output — the audit records that honestly instead of calling
-        it honored OR failing the plan."""
+    def test_raw_input_donation_is_classed_never_dropped(self):
+        """The fused plan's donated raw uint8 buffer: whether XLA finds
+        an output to alias it to is the compiler's call — the audit
+        classes it aliased or no_candidate, never dropped, and does not
+        fail the plan."""
         proc = HA.build_plan(_spec("four_step_ftail_donate"))
         card = HA.audit_processor(proc)
         don = card["programs"]["fused"]["donation"]
         assert don["declared"] == [0]
-        assert don["no_candidate"] == [0] and don["aliased"] == []
+        assert don["declared"] == sorted(don["aliased"]
+                                         + don["no_candidate"])
+        assert don["dropped"] == []
         assert card["checks"]["donation_ok"]  # no_candidate != dropped
 
     def test_dropped_donation_is_visible(self, staged_proc):
@@ -88,12 +91,12 @@ class TestDonation:
     def test_aot_active_processor_still_audits(self, tmp_path):
         """enable_aot swaps the _jit_* attributes for Compiled
         executables (no .lower()); lowerables() must keep handing the
-        auditor lowerable wrappers (SRTB_BENCH_AOT_DIR +
-        SRTB_BENCH_AUDIT together)."""
+        auditor lowerable wrappers."""
         proc = HA.build_plan(_spec("four_step_ftail"))
         assert proc.enable_aot(str(tmp_path), allow_cpu=True)
         card = HA.audit_processor(proc)
-        assert card["checks"]["hbm_floor_ok"]
+        assert card["total_spectrum_passes"] > 0
+        assert all(card["checks"].values()), card["checks"]
 
     def test_non_dividing_channel_count_staged(self):
         """channel_count that does not divide n_spectrum (waterfall
@@ -119,23 +122,19 @@ class TestDonation:
         assert wf.shape[2] == 12  # truncated waterfall, F=12
 
 
-# ----------------------------------------------- hbm_passes agreement
+# ------------------------------------------- audited spectrum passes
 
 
-class TestHbmPasses:
-    def test_declared_floor_per_family(self, family_cards):
-        """The plan families declare the documented spectrum-pass
-        floors (monolithic 7, fused tail 5, fully fused skzap 4) and
-        the compiled artifacts sweep at least that much."""
-        declared = {k: c["declared_hbm_passes"]
-                    for k, c in family_cards.items()}
-        assert declared == {"monolithic": 7, "four_step_ftail": 5,
-                            "pallas_skzap": 4}
+class TestSpectrumPasses:
+    def test_audited_count_is_the_sum_of_its_programs(self, family_cards):
+        """Every check holds and a plan's count is its programs' sum.
+        No order between families is asserted: the compiler decides it
+        (the numbers themselves are pinned by the baseline diff)."""
         for key, card in family_cards.items():
-            assert card["checks"]["hbm_floor_ok"], (key, card)
-            assert card["checks"]["declared_matches_family"], key
-            assert card["total_spectrum_passes"] >= \
-                card["declared_hbm_passes"]
+            assert all(card["checks"].values()), (key, card["checks"])
+            assert card["total_spectrum_passes"] == sum(
+                p["spectrum_passes"]
+                for p in card["programs"].values()) > 0, (key, card)
 
     def test_extra_pass_moves_the_count(self):
         proc = HA.build_plan(_spec("four_step_ftail"))
@@ -293,7 +292,7 @@ class TestCli:
         data = json.loads(capsys.readouterr().out)
         assert rc == 0
         assert data["regressions"] == [] and data["failed_checks"] == []
-        assert data["cards"]["monolithic"]["declared_hbm_passes"] == 7
+        assert data["cards"]["monolithic"]["total_spectrum_passes"] > 0
 
     def test_regression_exit_one(self, tmp_path, capsys):
         src = json.load(open(CHECKED_IN))

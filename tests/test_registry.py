@@ -78,9 +78,6 @@ def test_family_roundtrip_key_signature_consistency():
         assert seen[1] == sig, \
             (f"families {seen[0]} and {spec.key} share a cache key "
              "but resolve different plan signatures")
-        # declared floor must match what the built plan reports
-        if spec.hbm_passes is not None:
-            assert proc.hbm_passes == spec.hbm_passes, spec.key
         # the mode's processor class really implements the mode
         assert proc.MODE == spec.mode, spec.key
 
@@ -123,7 +120,7 @@ def test_family_added_to_only_one_consumer_fails():
     with registry.temp_family(registry.PlanFamily(
             key="__test_orphan", desc="t",
             cfg={"fft_strategy": "four_step", "fused_tail": "on"},
-            donate=True, hbm_passes=5)):
+            donate=True)):
         assert "__test_orphan" in registry.plan_keys()
         assert "__test_orphan" in tuple(s.key for s in HA.PLAN_FAMILIES)
         cards = HA.audit_families(["__test_orphan"])
