@@ -130,9 +130,9 @@ def chirp_factor_df64_ri(n: int, f_min: float, df: float, f_c: float,
 # ---- anchored-Taylor fast path for the on-device df64 phase ----
 #
 # The exact per-element df64 evaluation of k spends ~3 df64 divisions per
-# channel (measured 6.6x the cost of the precomputed-bank multiply at
-# 2^27 on a v5e).  But k is an extremely smooth function of the channel
-# index: expanding
+# channel (measured 6.6x the precomputed-bank multiply at 2^27 on a v5e;
+# paid per segment only in-step: staged, Pallas, a DM grid past its bank
+# rule).  But k is an extremely smooth function of the channel index:
 #
 #     k(f) = A (f - f_c)^2 / (f_c^2 f) = C1*f - C2 + A/f,
 #     A = D*1e6*dm,  C1 = A/f_c^2,  C2 = 2A/f_c

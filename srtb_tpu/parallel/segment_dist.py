@@ -2,7 +2,7 @@
 
 This is the multi-chip version of pipeline.segment.SegmentProcessor: one
 ``shard_map`` program covering unpack -> distributed R2C FFT -> RFI s1 ->
-DM-trial chirp -> waterfall FFT -> RFI s2 -> detection, with
+DM-trial chirp multiply -> waterfall FFT -> RFI s2 -> detection, with
 
 - ``seq``: the segment's samples/channels sharded over chips (sequence /
   context parallelism; all_to_all transposes inside the distributed FFT,
@@ -10,6 +10,13 @@ DM-trial chirp -> waterfall FFT -> RFI s2 -> detection, with
 - ``dm``:  independent DM trials replicating the sequence work (data
   parallelism; the cleaned spectrum is computed once per seq-shard and
   reused by every local trial).
+
+The trials' chirps depend on the DM and the channel, never on the
+segment: they are a bank [n_dm, 2, n_spectrum] sharded (dm, -, seq),
+made once at construction (on the device with df64, or on the host in
+float64) and read by every step.  Only a grid whose bank would crowd
+the chip's memory (``holds_chirp_bank``) evaluates the df64 phase
+inside the step instead.
 
 Collective inventory per segment: 3 all_to_all (FFT transposes, seq) +
 2 ppermute (Hermitian mirror, seq) + 3 psum over seq (mean power, zero
@@ -35,6 +42,7 @@ from srtb_tpu.config import Config
 from srtb_tpu.io import formats
 from srtb_tpu.ops import dedisperse as dd
 from srtb_tpu.ops import detect as det
+from srtb_tpu.ops import df64 as ds
 from srtb_tpu.ops import fft as F
 from srtb_tpu.ops import rfi
 from srtb_tpu.ops import scopes as S
@@ -42,6 +50,7 @@ from srtb_tpu.ops import unpack as U
 from srtb_tpu.ops import window as W
 from srtb_tpu.parallel import dist_fft as DF
 from srtb_tpu.parallel import dm_grid
+from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
 
 
@@ -58,6 +67,47 @@ def _put_sharded(host_array: np.ndarray, sharding: NamedSharding):
     data), unlike a plain ``jax.device_put``."""
     return jax.make_array_from_callback(
         host_array.shape, sharding, lambda idx: host_array[idx])
+
+
+# The largest share of a chip's memory the resident df64 chirp bank may
+# take.  Compiled for a described v5e, the step with its bank is 4.0x
+# (eight trials a chip) to 4.6x (two) the bank's bytes, so the two fit
+# while the bank is under ~1/4.6 of the chip; 0.2 leaves the step a
+# twelfth of room.  The in-step evaluation needs as much at two trials
+# a chip and half a bank less at eight: past the share it is the arm
+# that may still fit.  PERF.md section 4 has the reckoning.
+CHIRP_BANK_HBM_SHARE = 0.2
+
+
+def chirp_bank_bytes_per_chip(trials_local: int, n_spectrum: int,
+                              n_seq: int) -> int:
+    """Bytes one chip holds of a [n_dm, 2, n_spectrum] float32 bank
+    sharded (dm, -, seq)."""
+    return trials_local * 2 * (n_spectrum // n_seq) * 4
+
+
+def holds_chirp_bank(bank_bytes: int, bytes_limit: int | None) -> bool:
+    """Whether the df64 chirp is kept as a resident bank (made once) or
+    evaluated inside every step: the bank, unless it would take more
+    than ``CHIRP_BANK_HBM_SHARE`` of the chip's memory.  A platform that
+    reports no limit (the CPU) holds the bank."""
+    return not bytes_limit \
+        or bank_bytes <= CHIRP_BANK_HBM_SHARE * bytes_limit
+
+
+def _device_bytes_limit(mesh: Mesh) -> int | None:
+    """What one chip of the mesh may allocate, where the platform says."""
+    stats = mesh.local_devices[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def _trial_chirp(dm_pair, *, n_local, f_min, df, f_c, anchor_consts):
+    """One trial's df64 chirp planes [2, n_local] for this chip's seq
+    shard, from the trial's (dm_hi, dm_lo) pair.  Inside shard_map."""
+    return dd.chirp_factor_df64_ri(
+        n_local, f_min, df, f_c, dm_pair[0],
+        i0=jax.lax.axis_index("seq") * n_local, dm_lo=dm_pair[1],
+        anchor_consts=anchor_consts)
 
 
 class DistSegmentProcessor:
@@ -101,23 +151,29 @@ class DistSegmentProcessor:
 
         f_min, f_c, df = dd.spectrum_frequencies(cfg, self.n_spectrum)
         self.f_min, self.f_c, self.df = f_min, f_c, df
-        # chirp either streams from an HBM bank [n_dm, 2, n_spec] sharded
-        # (dm, -, seq), or is generated per trial inside the step with
-        # df64 (no bank resident in HBM — the better choice when
-        # n_trials * n_spec gets large; default follows use_emulated_fp64)
+        # how the trials' chirp phase is evaluated: df64 on the device
+        # (default follows use_emulated_fp64) or float64 on the host.
+        # Either way the step reads a bank [n_dm, 2, n_spec] sharded
+        # (dm, -, seq) made here, once; only a df64 bank that would
+        # crowd the chip (holds_chirp_bank) is left out and the phase
+        # evaluated per trial inside every step
         if chirp_on_device is None:
             chirp_on_device = cfg.use_emulated_fp64
         self.chirp_on_device = chirp_on_device
+        bank_sharding = NamedSharding(mesh, P("dm", None, "seq"))
+        bank_bytes = chirp_bank_bytes_per_chip(
+            len(self.dm_list) // self.n_dm_devices, self.n_spectrum,
+            self.n_seq)
+        chirp_in_step = None
         if chirp_on_device:
-            from srtb_tpu.ops import df64 as ds
             dm_hi, dm_lo = ds.from_float64(self.dm_list)
-            self.chirp_bank = _put_sharded(
+            dm_pairs = _put_sharded(
                 np.stack([dm_hi, dm_lo], axis=1),    # [n_dm, 2]
                 NamedSharding(mesh, P("dm", None)))
             # dm-linear anchored-Taylor coefficients (validated at the
-            # grid's max |dm|): turns the per-trial in-step chirp from
-            # ~3 df64 divisions/channel into one anchored update —
-            # None (exact path) when the bound can't be proven or the
+            # grid's max |dm|): turns the per-trial chirp from ~3 df64
+            # divisions/channel into one anchored update — None (exact
+            # path) when the bound can't be proven or the
             # Config.chirp_exact escape hatch is set
             dm_absmax = max((abs(float(d)) for d in self.dm_list),
                             default=0.0) or 1.0
@@ -126,11 +182,36 @@ class DistSegmentProcessor:
                 else dd.anchored_chirp_consts(
                     self.n_spectrum, f_min, df, f_c, dm_absmax,
                     unit_dm=True)
+            trial_chirp = partial(
+                _trial_chirp, n_local=self.n_spectrum // self.n_seq,
+                f_min=f_min, df=df, f_c=f_c,
+                anchor_consts=self.chirp_anchor_consts)
+            if holds_chirp_bank(bank_bytes, _device_bytes_limit(mesh)):
+                # a program of its own, run once: the same function
+                # with the same arguments the in-step arm evaluates on
+                # every segment.  One local trial after another
+                # (lax.map): vmapped, this program alone compiles for
+                # the chip in 200 s at 2^27 where the loop takes 3
+                self.chirp_bank = jax.jit(shard_map(
+                    lambda pairs: jax.lax.map(trial_chirp, pairs),
+                    mesh=mesh, in_specs=P("dm", None),
+                    out_specs=bank_sharding.spec))(dm_pairs)
+            else:
+                self.chirp_bank, chirp_in_step = dm_pairs, trial_chirp
+                bank_bytes = 0
         else:
             self.chirp_bank = _put_sharded(
                 np.asarray(dm_grid.build_chirp_bank(
                     self.dm_list, self.n_spectrum, f_min, df, f_c)),
-                NamedSharding(mesh, P("dm", None, "seq")))
+                bank_sharding)
+        metrics.set("chirp_bank_bytes", bank_bytes)
+        how = "df64 on the device" if chirp_on_device \
+            else "float64 on the host"
+        where = "generated in the step" if chirp_in_step \
+            else "a resident bank"
+        log.info(f"[dist] mesh dm={self.n_dm_devices} seq={self.n_seq}, "
+                 f"{len(self.dm_list)} trials; chirp phase {how}, "
+                 f"{where}: chirp_bank_bytes {bank_bytes} a chip")
 
         mask = rfi.rfi_ranges_to_mask(
             rfi.eval_rfi_ranges(cfg.mitigate_rfi_freq_list), self.n_spectrum,
@@ -167,12 +248,9 @@ class DistSegmentProcessor:
             variant=self.fmt.unpack_variant,
             nbits=cfg.baseband_input_bits,
             n=self.n, n_seq=self.n_seq, n_dm_dev=self.n_dm_devices,
-            chirp_on_device=chirp_on_device,
+            chirp_in_step=chirp_in_step,
             has_window=self.window is not None,
             watfft_dewindow=watfft_dewindow,
-            f_min=f_min, f_c=f_c, df=df,
-            chirp_anchor_consts=(self.chirp_anchor_consts
-                                 if chirp_on_device else None),
             n_spectrum=self.n_spectrum,
             channel_count=self.channel_count,
             norm_coeff=self.norm_coeff,
@@ -185,8 +263,8 @@ class DistSegmentProcessor:
         # trial summaries leave the step replicated (all_gather over dm in
         # the body) so every controller process can read them; the bulky
         # time series stays dm-sharded
-        chirp_spec = P("dm", None) if chirp_on_device \
-            else P("dm", None, "seq")
+        chirp_spec = P("dm", None) if chirp_in_step \
+            else bank_sharding.spec
         in_specs = [P("seq"), chirp_spec, P("seq")]
         if self.window is not None:
             in_specs.append(P("seq"))
@@ -205,9 +283,8 @@ class DistSegmentProcessor:
 
     @staticmethod
     def _body(raw_block, chirp_block, mask_block, *rest, variant, nbits, n,
-              rows_impl, len_cap, n_seq, n_dm_dev, chirp_on_device,
-              f_min, f_c, df,
-              chirp_anchor_consts, n_spectrum, channel_count, norm_coeff,
+              rows_impl, len_cap, n_seq, n_dm_dev, chirp_in_step,
+              n_spectrum, channel_count, norm_coeff,
               avg_threshold, sk_threshold, time_reserved_count,
               snr_threshold, max_boxcar_length,
               has_window=False, watfft_dewindow=None):
@@ -285,18 +362,7 @@ class DistSegmentProcessor:
             return (zero_count, jnp.stack(counts, axis=-1),
                     jnp.stack(peaks, axis=-1), ts)
 
-        def one_trial(chirp_in):
-            if chirp_on_device:
-                # generate this trial's chirp block in-place with df64
-                # (chirp_in is the (dm_hi, dm_lo) pair; no HBM bank)
-                n_local = n_spectrum // n_seq
-                seq_idx = jax.lax.axis_index("seq")
-                chirp_ri = dd.chirp_factor_df64_ri(
-                    n_local, f_min, df, f_c, chirp_in[0],
-                    i0=seq_idx * n_local, dm_lo=chirp_in[1],
-                    anchor_consts=chirp_anchor_consts)
-            else:
-                chirp_ri = chirp_in
+        def one_trial(chirp_ri):
             with jax.named_scope(S.CHIRP):
                 s = spec_all * jax.lax.complex(chirp_ri[0], chirp_ri[1])
             # local channels are complete contiguous sub-bands
@@ -308,6 +374,11 @@ class DistSegmentProcessor:
             return detect_trial(
                 rfi.mitigate_rfi_spectral_kurtosis(wf, sk_threshold))
 
+        if chirp_in_step is not None:
+            # the grid's bank was too large to keep: chirp_block holds
+            # the trials' (dm_hi, dm_lo) pairs and the planes are made
+            # here, on every segment
+            chirp_block = jax.vmap(chirp_in_step)(chirp_block)
         zc, counts, peaks, ts = jax.vmap(one_trial)(chirp_block)
 
         # replicate the small per-trial summaries across the dm axis

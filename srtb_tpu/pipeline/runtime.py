@@ -2213,9 +2213,9 @@ class Pipeline:
 
 class DMSearchPipeline:
     """Streaming DM search: every segment runs the full multi-chip
-    (dm x seq)-sharded step (parallel.segment_dist) over a DM trial grid;
-    per-trial summaries are appended to ``<prefix>dm_trials.jsonl`` and the
-    best trial per segment is logged.  This is the capability the
+    (dm x seq)-sharded step (parallel.segment_dist) over a DM trial grid
+    (its chirp bank is made once, at construction); per-trial summaries go
+    to ``<prefix>dm_trials.jsonl``, the best is logged.  The capability the
     reference leaves as a TODO ("DM search list for unknown source",
     ref: config.hpp:129-132), made practical by chip-parallel trials.
 

@@ -339,6 +339,8 @@ class Metrics:
         "h2d_bytes": "Host-to-device bytes staged",
         "ring_carry_bytes": "Bytes the ingest ring kept on the device "
                             "instead of receiving them again",
+        "chirp_bank_bytes": "DM-grid chirp bank bytes resident a chip "
+                            "(0 = generated in every step)",
         "ring_cold_dispatches": "Ingest-ring cold (full-upload) "
                                 "dispatches",
         "recovered_segments": "Segments rescued by manifest recovery",

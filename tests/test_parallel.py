@@ -7,10 +7,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from srtb_tpu.config import Config
 from srtb_tpu.ops import dedisperse as dd
-from srtb_tpu.parallel import dm_grid, mesh as M
+from srtb_tpu.parallel import dm_grid, mesh as M, segment_dist
 from srtb_tpu.parallel.segment_dist import DistSegmentProcessor
 from srtb_tpu.pipeline.segment import SegmentProcessor
 from srtb_tpu.io.synth import make_dispersed_baseband
@@ -395,18 +396,39 @@ def test_dist_segment_window_matches_single_device(raw_segment):
                                rtol=2e-3, atol=1e-2)
 
 
-def test_dist_segment_chirp_on_device_matches_bank(raw_segment):
-    """On-the-fly df64 chirp generation inside the sharded step (no HBM
-    chirp bank) must reproduce the host-f64 bank's detections."""
+def _force_chirp_in_step(monkeypatch):
+    """A chip so small that no bank is under the rule's share of it:
+    the df64 phase is then evaluated inside every step."""
+    monkeypatch.setattr(segment_dist, "_device_bytes_limit",
+                        lambda mesh: 1)
+
+
+CHIRP_WAYS = {
+    # how the phase is evaluated, and where the planes then live
+    "host_f64_bank": dict(chirp_on_device=False),
+    "device_df64_bank": dict(chirp_on_device=True),
+    "in_step_df64": dict(chirp_on_device=True),
+}
+
+
+@pytest.mark.parametrize("way", sorted(CHIRP_WAYS))
+def test_dist_segment_chirp_on_device_matches_bank(raw_segment, way,
+                                                   monkeypatch):
+    """Each way of making the trials' chirp (float64 on the host, df64
+    on the device once, df64 inside every step) must reproduce the
+    host-f64 bank's detections."""
     cfg = _cfg()
     mesh = M.make_mesh(n_dm=2, n_seq=4)
     dms = [0.0, 15.0, 30.0, 45.0]
     bank = DistSegmentProcessor(cfg, mesh, dm_list=dms,
                                 chirp_on_device=False)
-    otf = DistSegmentProcessor(cfg, mesh, dm_list=dms,
-                               chirp_on_device=True)
+    if way == "in_step_df64":
+        _force_chirp_in_step(monkeypatch)
+    other = DistSegmentProcessor(cfg, mesh, dm_list=dms, **CHIRP_WAYS[way])
+    in_step = np.shape(other.chirp_bank) == (len(dms), 2)
+    assert in_step == (way == "in_step_df64"), np.shape(other.chirp_bank)
     res_a = bank.process(raw_segment)
-    res_b = otf.process(raw_segment)
+    res_b = other.process(raw_segment)
     np.testing.assert_array_equal(np.asarray(res_a.zero_count),
                                   np.asarray(res_b.zero_count))
     np.testing.assert_allclose(np.asarray(res_a.time_series),
@@ -414,6 +436,129 @@ def test_dist_segment_chirp_on_device_matches_bank(raw_segment):
                                rtol=2e-3, atol=2e-2)
     np.testing.assert_array_equal(np.asarray(res_a.signal_counts),
                                   np.asarray(res_b.signal_counts))
+
+
+def test_device_made_bank_is_the_df64_chirp_shard_by_shard():
+    """The bank made at construction is ``chirp_factor_df64_ri`` with
+    the arguments the in-step arm passes, on every (dm, seq) shard: the
+    same planes, bit for bit, each seq shard at its own ``i0``."""
+    from srtb_tpu.ops import df64 as ds
+
+    cfg = _cfg()
+    mesh = M.make_mesh(n_dm=2, n_seq=4)
+    dms = [0.0, 15.0, 30.0, 45.0]
+    proc = DistSegmentProcessor(cfg, mesh, dm_list=dms,
+                                chirp_on_device=True)
+    bank = np.asarray(proc.chirp_bank)
+    assert bank.shape == (len(dms), 2, proc.n_spectrum)
+    assert bank.dtype == np.float32
+    assert proc.chirp_bank.sharding.spec == P("dm", None, "seq")
+    n_local = proc.n_spectrum // 4
+    one = jax.jit(lambda hi, lo, i0: dd.chirp_factor_df64_ri(
+        n_local, proc.f_min, proc.df, proc.f_c, hi, i0=i0, dm_lo=lo,
+        anchor_consts=proc.chirp_anchor_consts))
+    dm_hi, dm_lo = ds.from_float64(np.asarray(dms, np.float64))
+    for t in range(len(dms)):
+        for k in range(4):
+            want = np.asarray(one(dm_hi[t], dm_lo[t],
+                                  jnp.int32(k * n_local)))
+            np.testing.assert_array_equal(
+                bank[t, :, k * n_local:(k + 1) * n_local], want,
+                err_msg=f"trial {t}, seq shard {k}")
+    # and the shards differ: an i0 stuck at 0 would repeat shard 0
+    assert not np.array_equal(bank[2, :, :n_local],
+                              bank[2, :, n_local:2 * n_local])
+
+
+def _iter_eqns(jaxpr):
+    """Every equation of a jaxpr tree, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for item in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(item, "jaxpr"):
+                    yield from _iter_eqns(item.jaxpr)
+                elif hasattr(item, "eqns"):
+                    yield from _iter_eqns(item)
+
+
+@pytest.mark.parametrize("in_step", [False, True],
+                         ids=["banked", "in_step"])
+def test_banked_step_evaluates_no_phase(in_step, monkeypatch):
+    """With the bank resident the step evaluates no chirp phase: no
+    ``sin`` / ``cos`` under ``srtb.chirp`` (the R2C's twiddles are the
+    only trigonometry left), where the in-step arm holds both."""
+    from srtb_tpu.ops import scopes as S
+
+    cfg = _cfg()
+    mesh = M.make_mesh(n_dm=2, n_seq=4)
+    if in_step:
+        _force_chirp_in_step(monkeypatch)
+    dist = DistSegmentProcessor(cfg, mesh, dm_list=[cfg.dm, 0.0],
+                                chirp_on_device=True)
+    raw = jax.device_put(np.zeros(cfg.segment_bytes(1), np.uint8),
+                         NamedSharding(mesh, P("seq")))
+    jaxpr = jax.make_jaxpr(dist._step)(raw, dist.chirp_bank, dist.rfi_mask)
+    trig = [(eqn.primitive.name, str(eqn.source_info.name_stack))
+            for eqn in _iter_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name in ("sin", "cos")]
+    assert trig, "the R2C's twiddles should be here"
+    in_chirp = {name for name, stack in trig if S.CHIRP in stack}
+    assert in_chirp == ({"sin", "cos"} if in_step else set()), trig
+    elsewhere = {stack for _n, stack in trig if S.CHIRP not in stack}
+    assert all(S.FFT_R2C in stack for stack in elsewhere), elsewhere
+
+
+V5E_BYTES_LIMIT = 16_911_433_728     # 15.75 GiB, a v5e's memory_stats
+
+
+@pytest.mark.parametrize("trials_local,log2n,n_seq,limit,bank", [
+    # the grid cell: 2^27 samples, two trials a chip, 1.07 GB of 15.75
+    (2, 27, 1, V5E_BYTES_LIMIT, True),
+    # one trial a chip (a v5e-8), and a seq axis over 2: smaller still
+    (1, 27, 1, V5E_BYTES_LIMIT, True),
+    (2, 27, 2, V5E_BYTES_LIMIT, True),
+    # 2^28, two and three trials a chip: 2.15 GB, 12.7 %; 3.22 GB, 19 %
+    (2, 28, 1, V5E_BYTES_LIMIT, True),
+    (3, 28, 1, V5E_BYTES_LIMIT, True),
+    # over the share: 4.29 GB, 25 % of the chip, however it is made up
+    (4, 28, 1, V5E_BYTES_LIMIT, False),
+    (2, 29, 1, V5E_BYTES_LIMIT, False),
+    (8, 27, 1, V5E_BYTES_LIMIT, False),
+    # a platform that reports no limit (the CPU meshes) holds the bank
+    (8, 30, 1, None, True),
+    (8, 30, 1, 0, True),
+])
+def test_chirp_bank_rule(trials_local, log2n, n_seq, limit, bank):
+    """The rule reads shapes and the device only: the bank's bytes a
+    chip against a fixed share of what the chip may allocate."""
+    nbytes = segment_dist.chirp_bank_bytes_per_chip(
+        trials_local, (1 << log2n) // 2, n_seq)
+    assert nbytes == trials_local * 2 * ((1 << log2n) // 2 // n_seq) * 4
+    assert segment_dist.holds_chirp_bank(nbytes, limit) is bank
+    if (trials_local, log2n, n_seq) == (2, 27, 1):
+        assert nbytes == 1_073_741_824
+
+
+@pytest.mark.parametrize("way", sorted(CHIRP_WAYS))
+def test_chirp_bank_bytes_gauge(way, monkeypatch):
+    """``chirp_bank_bytes``: the bank's bytes resident a chip, 0 where
+    the phase is generated in the step."""
+    from srtb_tpu.utils.metrics import metrics
+
+    cfg = _cfg()
+    mesh = M.make_mesh(n_dm=2, n_seq=4)
+    dms = [0.0, 15.0, 30.0, 45.0]
+    if way == "in_step_df64":
+        _force_chirp_in_step(monkeypatch)
+    metrics.set("chirp_bank_bytes", -1)
+    proc = DistSegmentProcessor(cfg, mesh, dm_list=dms, **CHIRP_WAYS[way])
+    got = metrics.get("chirp_bank_bytes")
+    if way == "in_step_df64":
+        assert got == 0
+    else:
+        shard = proc.chirp_bank.addressable_shards[0].data
+        assert got == shard.nbytes == 2 * 2 * (proc.n_spectrum // 4) * 4
 
 
 def test_dist_rejects_non_dividing_channel_count():
@@ -471,19 +616,13 @@ def test_dist_rows_impl_knob(raw_segment, monkeypatch):
 
 def _collect_collectives(jaxpr, out):
     """(primitive name, mesh axes) of every collective in a jaxpr tree."""
-    for eqn in jaxpr.eqns:
+    for eqn in _iter_eqns(jaxpr):
         name = eqn.primitive.name
         if name in ("all_to_all", "ppermute", "all_gather",
                     "reduce_scatter") or "psum" in name:
             ax = eqn.params.get("axes") or eqn.params.get("axis_name")
             ax = (ax,) if isinstance(ax, str) else tuple(ax)
             out.append((name.replace("psum_invariant", "psum"), ax))
-        for v in eqn.params.values():
-            for item in (v if isinstance(v, (list, tuple)) else [v]):
-                if hasattr(item, "jaxpr"):
-                    _collect_collectives(item.jaxpr, out)
-                elif hasattr(item, "eqns"):
-                    _collect_collectives(item, out)
     return out
 
 
