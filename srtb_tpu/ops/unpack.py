@@ -129,19 +129,37 @@ def samples_per_byte(nbits: int) -> float:
 # de-interleave variants (multi-stream packet formats)
 # ----------------------------------------------------------------
 
+# Bytes of one row of the "1212" split.  The split reads the segment as
+# rows of this many bytes and takes every other byte of a row, so the
+# byte axis stays minor and 128 lanes wide on both sides of it; spelled
+# ``reshape(-1, 2)[:, k]`` the minor dimension of 2 is padded to 128
+# lanes on the chip (a ``u8[2^25, 2]`` tiled to 4.3 GB at 2^27 samples
+# a stream: 50.0 ms a segment where this spelling takes 5.6, PERF.md
+# section 6, PR 36).  ``x[:, k::2]`` lowers to a gather, which the chip
+# runs as one pass; as a strided ``lax.slice`` it read 19.5 ms.
+_SPLIT_ROW_BYTES = 1024
+
+
 @S.scoped(S.UNPACK)
 def unpack_interleaved_2pol(data: jnp.ndarray, nbits: int,
-                            window: jnp.ndarray | None = None):
-    """"1212" byte-interleaved 2 polarizations -> 2 streams
+                            window: jnp.ndarray | None = None
+                            ) -> jnp.ndarray:
+    """"1212" byte-interleaved 2 polarizations -> float32 ``[2, n]``
     (ref: unpack.hpp:214-244; dispatch unpack_pipe.hpp:146-260).
 
-    Input element type is given by nbits (8/-8 supported, as snap-style
-    boards emit 8-bit); returns (out1, out2) float32.
+    The two streams alternate BYTE by byte: stream k owns bytes k, k + 2,
+    k + 4, ...  At 8 / -8 bits that is sample by sample; below 8 bits
+    each byte holds 8 / nbits samples of ONE stream, MSB first (cpsr2's
+    two-polarisation files at 2 bits).  Any ``SUPPORTED_BITS`` width is
+    passed on to :func:`unpack`; above 8 bits the bytes of a sample are
+    dealt out alternately as they always were, which no format asks for.
+    Returns one ``[2, n]`` array, row k = stream k (it still unpacks as
+    a pair).
     """
-    x = data.reshape(-1, 2)
-    out1 = unpack(x[:, 0].reshape(-1), nbits, window)
-    out2 = unpack(x[:, 1].reshape(-1), nbits, window)
-    return out1, out2
+    row = int(np.gcd(data.shape[-1], _SPLIT_ROW_BYTES))
+    x = data.reshape(-1, row)
+    return jnp.stack([unpack(x[:, k::2].reshape(-1), nbits, window)
+                      for k in range(2)])
 
 
 @S.scoped(S.UNPACK)

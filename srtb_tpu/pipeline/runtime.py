@@ -71,6 +71,7 @@ import jax
 import numpy as np
 
 from srtb_tpu.config import Config
+from srtb_tpu.io import formats
 from srtb_tpu.io.file_input import BasebandFileReader
 from srtb_tpu.io.writers import WriteAllSink, WriteSignalSink
 from srtb_tpu.pipeline.segment import SegmentProcessor
@@ -257,6 +258,8 @@ class Pipeline:
             processor = registry.build_processor(
                 cfg, donate_input=on_accelerator())
         self.processor = processor
+        metrics.set("data_streams", formats.resolve(
+            cfg.baseband_format_type).data_stream_count)
         self._owned_writer_pool = None
         # causal tracing + flight recorder (utils/events.py): arm the
         # process-global hub from this config and hold the None-hook
@@ -547,9 +550,13 @@ class Pipeline:
             self.slo.note_segment(self.stream,
                                   telemetry.segment_wall(span))
         det_count = 0
+        det_by_stream = None
         counts = getattr(det_res, "signal_counts", None)
         if counts is not None:
-            det_count = int(np.asarray(counts).sum())
+            counts = np.asarray(counts)
+            det_count = int(counts.sum())
+            if counts.ndim == 2:   # [streams, boxcars]
+                det_by_stream = counts.sum(axis=-1)
         # quality epilogue -> gauges + drift detector (journal or not:
         # /metrics must carry the quality state of a journal-less run)
         quality_extra = None
@@ -600,7 +607,8 @@ class Pipeline:
                 # v11: the pool member this lane dispatches through
                 # (stamped by the fleet at placement and re-stamped
                 # by a live migration); absent outside a fleet
-                device=getattr(self, "device_label", None)))
+                device=getattr(self, "device_label", None),
+                detections_by_stream=det_by_stream))
 
     # ---------------------------------------------- async segment engine
 

@@ -45,7 +45,7 @@ def unpack_streams(raw: jnp.ndarray, variant: str, nbits: int,
     if variant == "simple":
         return U.unpack(raw, nbits, window)[None, :]
     if variant == "interleaved_samples_2":
-        return jnp.stack(U.unpack_interleaved_2pol(raw, nbits, window))
+        return U.unpack_interleaved_2pol(raw, nbits, window)
     if variant == "naocpsr_snap1":
         return jnp.stack(U.unpack_naocpsr_snap1(raw, nbits, window))
     if variant == "gznupsr_a1":

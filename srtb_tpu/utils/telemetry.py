@@ -97,9 +97,16 @@ from srtb_tpu.utils.metrics import metrics
 # which leaves a child out where its parent is there.  The DM-search
 # loop journals the same record with five flat stages (``ingest``,
 # ``h2d``, ``enqueue``, ``fetch``, ``record``).
-# Readers must tolerate mixed v1-v11 journals: rotation can leave an
+# v12 (data streams): adds ``streams`` (S, the data streams of the
+# segment: polarisations split on the device from one byte-interleaved
+# input, io/formats.py) and ``detections_by_stream`` (S integers, the
+# boxcar firings of each stream; ``detections`` stays their sum), so a
+# candidate that only one polarisation sees says which.  Both OMITTED
+# where the writer does not count by stream (the DM-search loop, whose
+# record is per trial).
+# Readers must tolerate mixed v1-v12 journals: rotation can leave an
 # older-schema tail in the previous generation after an upgrade.
-SPAN_SCHEMA_VERSION = 11
+SPAN_SCHEMA_VERSION = 12
 
 # child stage -> the stage it is timed inside (see the note above)
 CHILD_STAGES = {"h2d": "dispatch", "enqueue": "dispatch",
@@ -312,7 +319,8 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
                  device_s: float | None = None,
                  batch_size: int | None = None,
                  batch_wait_ms: float | None = None,
-                 device: str | None = None) -> dict:
+                 device: str | None = None,
+                 detections_by_stream=None) -> dict:
     """One journal record.  ``stages_s`` maps stage name -> seconds for
     THIS segment; loss/drop counters are the cumulative registry values
     at drain time (deltas between consecutive records localize a loss
@@ -438,6 +446,13 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         # the boundary).  Omitted outside a fleet — never a fake
         # placeholder.
         rec["device"] = str(device)
+    if detections_by_stream is not None:
+        # v12: the segment's data streams and each one's firings
+        # (``detections`` is their sum); omitted where the writer does
+        # not count by stream
+        by_stream = [int(c) for c in detections_by_stream]
+        rec["streams"] = len(by_stream)
+        rec["detections_by_stream"] = by_stream
     if trace_id:
         # v7: joins this span to its flight-recorder events (omitted
         # when tracing is off — never a fake 0)

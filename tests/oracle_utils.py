@@ -85,6 +85,16 @@ def oracle_stream_chain(x: np.ndarray, cfg):
     coeff = rfi.normalization_coefficient(n_spec, cfg.spectrum_channel_count)
     spec = np.where(zap1, 0.0, spec * coeff)
 
+    # manual zap list "lo-hi,lo-hi" in MHz: bin = round((f - f_low) / bw *
+    # (N - 1)), both ends zapped, the pair swapped where the band is
+    # inverted (ref: spectrum/rfi_mitigation.hpp:102-143)
+    for pair in filter(None, str(cfg.mitigate_rfi_freq_list).split(",")):
+        f0, f1 = (float(v) for v in pair.split("-"))
+        k0, k1 = sorted(int(round((f - cfg.baseband_freq_low)
+                                  / cfg.baseband_bandwidth * (n_spec - 1)))
+                        for f in (f0, f1))
+        spec[k0:k1 + 1] = 0.0
+
     # coherent dedispersion chirp (ref: coherent_dedispersion.hpp:133-150,
     # Jiang 2022): k = D*1e6*dm/f*((f-f_c)/f_c)^2, phase = -2*pi*frac(k)
     f_min, f_c, df = dd.spectrum_frequencies(cfg, n_spec)
@@ -105,12 +115,16 @@ def oracle_stream_chain(x: np.ndarray, cfg):
         wlen, cfg.mitigate_rfi_spectral_kurtosis_threshold)
     p = wf.real**2 + wf.imag**2
     s2, s4 = p.sum(axis=-1), (p * p).sum(axis=-1)
-    sk = wlen * s4 / (s2 * s2)
+    with np.errstate(invalid="ignore"):   # a row the zap list emptied
+        sk = wlen * s4 / (s2 * s2)
     zap2 = (sk > hi) | (sk < lo)
     wf = np.where(zap2[:, None], 0.0, wf)
 
-    # detect: power time series over the untrimmed window, mean-subtracted
-    # (ref: signal_detect_pipe.hpp:305-334; reserve disabled in this cfg)
-    ts = (wf.real**2 + wf.imag**2).sum(axis=0)
+    # detect: power time series, mean-subtracted, over the window less
+    # the overlap-save tail: nsamps_reserved / channel_count time samples
+    # where a reserve is configured and leaves any
+    # (ref: signal_detect_pipe.hpp:289-334)
+    t = wlen - dd.nsamps_reserved(cfg) // ch
+    ts = (wf.real**2 + wf.imag**2).sum(axis=0)[:t if t > 0 else wlen]
     ts = ts - ts.mean()
     return wf, ts, int(zap2.sum())

@@ -6,8 +6,9 @@ file imports them under their own names and adds one guard of its own.
 
 The selftest directory goes on ``sys.path`` and its modules are imported
 by their top-level names, as ``pytest benchmark/selftest`` imports them:
-``test_naoc_cell`` does ``import test_scopes`` and registers its cell in
-that module's ``CELLS``, so both must see one module object.  The two
+``test_naoc_cell`` and ``test_2pol_cell`` do ``import test_scopes`` and
+register their cells in that module's ``CELLS``, so all must see one
+module object.  The two
 grid cases want four devices where ``tests/conftest.py`` forces eight;
 each runs in a child with the selftests' own ``XLA_FLAGS``.
 
@@ -31,6 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SELFTEST = os.path.join(ROOT, "benchmark", "selftest")
 sys.path[:0] = [ROOT, SELFTEST]
 
+import test_2pol_cell  # noqa: E402
 import test_gen  # noqa: E402
 import test_naoc_cell  # noqa: E402
 import test_reference  # noqa: E402
@@ -47,7 +49,7 @@ FOUR_DEVICES = ("test_grid_cell_on_four_virtual_devices",
 DRAIN_CASE = "test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull"
 
 for _mod in (test_gen, test_reference, test_trace, test_scopes,
-             test_naoc_cell, test_run):
+             test_naoc_cell, test_2pol_cell, test_run):
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_") and callable(_obj) \
                 and _name not in FOUR_DEVICES + (DRAIN_CASE,):
@@ -139,7 +141,7 @@ def test_every_span_key_the_benchmark_reads_is_journalled(capsys):
     for s in spans:
         missing = (set(SPAN_KEYS) | counters) - set(s)
         assert not missing, (missing, s)
-        assert s["v"] == 11 and "plan_compiles" in s
+        assert s["v"] == 12 and "plan_compiles" in s
         # the second yardstick's fields do not grow back
         assert not {"roofline_frac", "achieved_msamps"} & set(s), s
     journalled = set().union(*(s["stages_ms"] for s in spans))

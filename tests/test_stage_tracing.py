@@ -51,6 +51,11 @@ def _cfg(**extra):
     return Config(**kw)
 
 
+# 2-bit samples, streams "1212" by whole bytes: the J1644-4559 cpsr2 file
+TWO_POL = dict(baseband_format_type="interleaved_samples_2",
+               baseband_input_bits=2)
+
+
 # ------------------------------------------------- (a) scopes in the HLO
 
 def _hlo(fn, avals) -> tuple:
@@ -104,6 +109,9 @@ FAMILIES = {
     "quality": (lambda: _served_programs({"ring"}, quality_stats=True),
                 RING | {S.QUALITY}),
     "grid_step": (_grid_program, SIX),
+    # two polarisations byte-interleaved in one segment, split on the
+    # device (ISSUE 36): the two-stream cell's plan
+    "ring_2pol": (lambda: _served_programs({"ring"}, **TWO_POL), RING),
 }
 # no reserve, no ring: these hold no ``srtb.ring``
 RINGLESS = {"staged", "grid_step"}
@@ -138,6 +146,72 @@ def test_staged_stages_carry_their_own_scopes():
     assert S.FFT_R2C in locs["stage_b"]
     assert {S.WATERFALL, S.DETECT} <= locs["stage_c"]
     assert S.FFT_R2C not in locs["stage_c"]
+
+
+def test_the_split_and_the_stream_stack_are_the_unpacks():
+    """Two streams from one segment (ISSUE 36): every operation that
+    touches bytes after the ring has assembled them (the rows of 1024,
+    every other byte of a row for each stream, the fields' shifts and
+    masks) and the concatenate that stacks the streams' samples into
+    ``[2, n]``
+    carries ``srtb.unpack``; the only other byte operations are the
+    ring's own two."""
+    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', located,
+                            flags=re.M))
+    ops = re.findall(r"^\s+%\S+ = \"?(stablehlo\.\w+)\"?.*?: (.*) "
+                     r"loc\((#loc\d+)\)$", located, flags=re.M)
+    byte_ops = [(op, names[loc]) for op, types, loc in ops
+                if "ui8>" in types and op != "stablehlo.constant"]
+    split = [(op, name) for op, name in byte_ops if S.RING not in name]
+    assert sorted(op for op, name in byte_ops if S.RING in name) \
+        == ["stablehlo.concatenate", "stablehlo.slice"]
+    assert {op for op, _n in split} >= {
+        "stablehlo.reshape", "stablehlo.gather",
+        "stablehlo.shift_right_logical", "stablehlo.and",
+        "stablehlo.convert"}
+    assert all(S.UNPACK in name for _op, name in split), split
+    # each stream is taken once from rows of 1024 bytes, every other byte
+    taken = [types for op, types, _l in ops if op == "stablehlo.gather"]
+    assert len(taken) == 2 and all(
+        "(tensor<8x1024xui8>" in t and "-> tensor<8x512xui8>" in t
+        for t in taken)
+    assert "x2xui8>" not in located        # no minor dimension of 2
+    stack = [names[loc] for op, types, loc in ops
+             if op == "stablehlo.concatenate"
+             and f"-> tensor<2x{N}xf32>" in types]
+    assert len(stack) == 1 and S.UNPACK in stack[0]
+    assert S.FFT_R2C not in stack[0]
+
+
+def _complex_stacks(located: str) -> list:
+    """The name stacks of the concatenates that make a complex array."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', located,
+                            flags=re.M))
+    return [(types, names[loc]) for types, loc in re.findall(
+        r"^\s+%\S+ = \"?stablehlo\.concatenate\"?.*?: (.*) "
+        r"loc\((#loc\d+)\)$", located, flags=re.M)
+        if "-> tensor<" in types and "complex<f32>>" in types.split("->")[1]]
+
+
+def test_the_waterfalls_stream_stack_is_the_waterfalls():
+    """Several streams' backward C2C run one after the other and are
+    stacked into ``[S, channels, time]`` (``ops/fft.waterfall_c2c``, ISSUE
+    36): that stack carries ``srtb.waterfall`` and nothing else, so
+    ``ops.waterfall_ms_per_seg`` reads it.  (What the chip's compiler
+    adds on its own, a copy that turns the program's output row-major,
+    has no name to carry: ``tests/test_tpu_compile.py``.)  One stream
+    traces no stack at all, as before ISSUE 36."""
+    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+    channels = 64
+    stacks = _complex_stacks(located)
+    assert len(stacks) == 1, stacks
+    types, name = stacks[0]
+    assert f"-> tensor<2x{channels}x{N // 2 // channels}xcomplex<f32>>" \
+        in types
+    assert _scopes_in(name) == {S.WATERFALL}, name
+    _text, located = _served_programs({"ring"})["ring"]
+    assert _complex_stacks(located) == []
 
 
 # ------------------------------------------ (b) the served path's journal
@@ -281,6 +355,8 @@ def test_dm_search_loop_spans_and_journal(tmp_path):
         assert rec["v"] == telemetry.SPAN_SCHEMA_VERSION
         assert set(rec["stages_ms"]) == set(five)
         assert rec["samples"] == N
+        # v12's fields are the served path's: this loop counts by trial
+        assert "streams" not in rec and "detections_by_stream" not in rec
     upload = [b["h2d_bytes"] - a["h2d_bytes"]
               for a, b in zip(recs, recs[1:])]
     # the segment is replicated over the four dm-rows of the mesh

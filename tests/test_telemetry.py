@@ -270,13 +270,20 @@ def test_span_journal_roundtrip_and_rotation(tmp_path):
     recs = TR.load(path)
     assert len(recs) == 3
     r = recs[-1]
-    assert r["type"] == "segment_span" and r["v"] == 11
+    assert r["type"] == "segment_span" and r["v"] == 12
     assert r["segment"] == 2 and r["detections"] == 2 and r["dump"]
     assert r["samples"] == 1 << 16 and r["timestamp_ns"] == 123
     assert r["queue_depth"] == 1
     assert set(r["stages_ms"]) == {"ingest", "dispatch", "fetch", "sink"}
     assert r["stages_ms"]["fetch"] == 100.0
     assert "ts" in r and "packets_lost" in r
+    # v12: the by-stream fields are omitted where the writer gave none,
+    # and written as plain integers where it did
+    assert "streams" not in r and "detections_by_stream" not in r
+    two = segment_span(0, {"sink": 0.001}, 0, 5, True, 1,
+                       detections_by_stream=np.array([0, 5]))
+    assert two["streams"] == 2 and two["detections_by_stream"] == [0, 5]
+    assert json.loads(json.dumps(two))["detections_by_stream"] == [0, 5]
 
     # rotation: a tiny cap forces the previous generation out — gzip'd
     # to <path>.1.gz by default; load() reads both transparently

@@ -74,15 +74,16 @@ def _j1644(log2n: int, **over) -> Config:
     return Config(**kw)
 
 
-def _compile_all(proc, one_chip) -> dict:
-    """Compile every program of the plan for the described chip;
-    {name: compiled}."""
+def _compile_all(proc, one_chip, only=None) -> dict:
+    """Compile every program of the plan (or those named in ``only``)
+    for the described chip; {name: compiled}."""
     def on_chip(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
     out = {}
     for name, fn, avals, _donated in proc.lowerables():
-        out[name] = fn.lower(*jax.tree.map(on_chip, avals)).compile()
+        if only is None or name in only:
+            out[name] = fn.lower(*jax.tree.map(on_chip, avals)).compile()
     return out
 
 
@@ -104,6 +105,62 @@ def test_j1644_default_plan_compiles_and_fits(one_chip):
     for name, c in compiled.items():
         assert _device_bytes(c) < V5E_HBM_BYTES, (name,
                                                   c.memory_analysis())
+
+
+@pytest.fixture(scope="module")
+def two_pol_ring(one_chip):
+    """The served ``ring`` program of the ``j1644_2pol_2p27`` deployment
+    (both polarisations byte-interleaved, ``interleaved_samples_2``) at
+    2 x 2^27 samples, compiled for the described chip."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    proc = SegmentProcessor(
+        _j1644(27, baseband_format_type="interleaved_samples_2"),
+        donate_input=True)
+    assert proc.ring and proc._segment_bytes == 1 << 26
+    return _compile_all(proc, one_chip, only={"ring"})["ring"]
+
+
+def test_j1644_two_polarisation_plan_splits_lane_dense(two_pol_ring):
+    """The two-stream program fits a v5e twice over (two segments in
+    flight), and the split leaves no byte array with a minor dimension
+    of 2, which the chip pads to 128 lanes (``u8[33554432,2]`` tiled to
+    4.3 GB was 50 ms a segment, PR 36)."""
+    import re
+
+    assert 2 * _device_bytes(two_pol_ring) < V5E_HBM_BYTES, \
+        two_pol_ring.memory_analysis()
+    text = two_pol_ring.as_text()
+    assert "u8[" in text and not re.search(r"u8\[\d+,2\]", text)
+
+
+def test_j1644_two_polarisation_unscoped_work_is_the_compilers(two_pol_ring):
+    """What ``ops.unscoped_ms_per_seg`` reads in the two-stream cell
+    (5.3 ms a segment on the chip, PR 36) is nothing the program traced
+    and left unnamed: every instruction of weight without an ``srtb.``
+    scope carries no ``op_name`` at all, it is a copy or a fusion the
+    chip's compiler put in for a layout of its own.  The heaviest is the
+    copy that turns the program's output, the two streams' waterfalls,
+    row-major; what it copies is the waterfall's own stack."""
+    import re
+
+    lines = two_pol_ring.as_text().splitlines()
+    unscoped = []
+    for line in lines:
+        cycles = re.search(r'"estimated_cycles":"?(\d+)', line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if cycles and int(cycles.group(1)) > 100_000 \
+                and not (name and "srtb." in name.group(1)):
+            unscoped.append((int(cycles.group(1)), line.strip()))
+    assert unscoped and all('op_name="' not in ln for _c, ln in unscoped), \
+        [ln[:120] for _c, ln in unscoped if 'op_name="' in ln]
+    heaviest = max(unscoped)[1]
+    out = re.match(r"%\S+ = f32\[2,2,2048,32768\]\{3,2,1,0[^}]*\} "
+                   r"copy\(%([\w.\-]+)\)", heaviest)
+    assert out, heaviest[:200]
+    made_by = [ln for ln in lines
+               if ln.lstrip().startswith(f"%{out.group(1)} = ")]
+    assert len(made_by) == 1 and "srtb.waterfall" in made_by[0], made_by
 
 
 def test_j1644_pallas_plan_lowers_through_mosaic(one_chip, monkeypatch):
