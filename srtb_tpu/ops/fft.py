@@ -23,8 +23,6 @@ once.
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,24 +67,7 @@ def waterfall_c2c(spectrum: jnp.ndarray, channel_count: int,
     x = x.reshape(*spectrum.shape[:-1], channel_count, watfft_len)
     # row lengths beyond the XLA cap (coarse channelizations of long
     # segments, e.g. [2048, 2^17]) go through the four-step path
-    if math.prod(x.shape[:-2]) > 1:
-        # several streams: one stream's [channel_count, watfft_len]
-        # transform after the other, inside the program.  As ONE batched
-        # FFT of [2 * 2048, 32768] the chip's compiler took 139 s a
-        # program (6 s for [2048, 32768]) and the chip 41.2 ms a segment;
-        # one after the other 15 s and 35.3 ms, plus 5.2 ms that read as
-        # ``unscoped``: each stream's transform leaves its result with
-        # the channel axis minor, as the one-stream program's does, and
-        # the compiler turns the program's output row-major in a copy of
-        # its own that carries no name (PERF.md section 6, PR 36).  A single
-        # stream traces what it always did, and so does a caller that
-        # brings its batch by ``vmap`` (the DM grid's trials, the
-        # fleet's beams): the mapped axis is not in ``x.shape`` here.
-        streams = x.reshape(-1, channel_count, watfft_len)
-        wf = jnp.stack([_fft_minor(streams[s], inverse=True, len_cap=len_cap)
-                        for s in range(streams.shape[0])]).reshape(x.shape)
-    else:
-        wf = _fft_minor(x, inverse=True, len_cap=len_cap)
+    wf = _fft_minor(x, inverse=True, len_cap=len_cap)
     if dewindow is not None:
         wf = wf / dewindow
     return wf

@@ -141,67 +141,84 @@ _SPLIT_ROW_BYTES = 1024
 
 
 @S.scoped(S.UNPACK)
+def stream_bytes(data: jnp.ndarray, variant: str) -> tuple:
+    """The segment's bytes dealt out to its data streams: one uint8
+    array a stream, each holding that stream's own bytes in order
+    (:func:`unpack_stream` makes its samples of them).
+
+    - ``interleaved_samples_2`` "1212": stream k owns bytes k, k + 2,
+      ...  At 8 / -8 bits that is sample by sample; below 8 bits each
+      byte holds 8 / nbits samples of ONE stream, MSB first (cpsr2's
+      two-polarisation files at 2 bits); above 8 bits the bytes of a
+      sample are dealt out alternately as they always were, which no
+      format asks for (ref: unpack.hpp:214-244; dispatch
+      unpack_pipe.hpp:146-260).
+    - ``naocpsr_snap1`` "1122": pairs of bytes (ref: unpack.hpp:253-283).
+    - ``gznupsr_a1``: four streams, four bytes of each in a 16-byte word
+      group (ref: unpack.hpp:291-328); ``gznupsr_a1_v2_1``: two
+      (ref: unpack.hpp:336-369).
+    """
+    if variant == "simple":
+        return (data,)
+    if variant == "interleaved_samples_2":
+        row = int(np.gcd(data.shape[-1], _SPLIT_ROW_BYTES))
+        x = data.reshape(-1, row)
+        return tuple(x[:, k::2].reshape(-1) for k in range(2))
+    if variant == "naocpsr_snap1":
+        x = data.reshape(-1, 4)
+        return tuple(x[:, 2 * k:2 * k + 2].reshape(-1) for k in range(2))
+    if variant in ("gznupsr_a1", "gznupsr_a1_v2_1"):
+        streams = 4 if variant == "gznupsr_a1" else 2
+        x = data.reshape(-1, streams, 4)  # [word, stream, sample-in-word]
+        return tuple(x[:, i, :].reshape(-1) for i in range(streams))
+    raise ValueError(f"unknown unpack variant {variant!r}")
+
+
+@S.scoped(S.UNPACK)
+def unpack_stream(data: jnp.ndarray, variant: str, nbits: int,
+                  window: jnp.ndarray | None = None) -> jnp.ndarray:
+    """One stream's own bytes (:func:`stream_bytes`) -> its float32
+    samples.  The ``gznupsr_a1`` formats are int8 whatever ``nbits``
+    says, the first with the XOR 0x80 unsigned -> signed trick."""
+    if variant == "gznupsr_a1":
+        return unpack(jnp.bitwise_xor(data, jnp.uint8(0x80)), -8, window)
+    if variant == "gznupsr_a1_v2_1":
+        return unpack(data, -8, window)
+    return unpack(data, nbits, window)
+
+
+def _unpack_streams(data, variant: str, nbits: int, window) -> tuple:
+    return tuple(unpack_stream(own, variant, nbits, window)
+                 for own in stream_bytes(data, variant))
+
+
 def unpack_interleaved_2pol(data: jnp.ndarray, nbits: int,
                             window: jnp.ndarray | None = None
                             ) -> jnp.ndarray:
-    """"1212" byte-interleaved 2 polarizations -> float32 ``[2, n]``
-    (ref: unpack.hpp:214-244; dispatch unpack_pipe.hpp:146-260).
-
-    The two streams alternate BYTE by byte: stream k owns bytes k, k + 2,
-    k + 4, ...  At 8 / -8 bits that is sample by sample; below 8 bits
-    each byte holds 8 / nbits samples of ONE stream, MSB first (cpsr2's
-    two-polarisation files at 2 bits).  Any ``SUPPORTED_BITS`` width is
-    passed on to :func:`unpack`; above 8 bits the bytes of a sample are
-    dealt out alternately as they always were, which no format asks for.
-    Returns one ``[2, n]`` array, row k = stream k (it still unpacks as
-    a pair).
-    """
-    row = int(np.gcd(data.shape[-1], _SPLIT_ROW_BYTES))
-    x = data.reshape(-1, row)
-    return jnp.stack([unpack(x[:, k::2].reshape(-1), nbits, window)
-                      for k in range(2)])
+    """"1212" byte-interleaved 2 polarizations -> float32 ``[2, n]``,
+    row k = stream k (it still unpacks as a pair)."""
+    with jax.named_scope(S.UNPACK):
+        return jnp.stack(_unpack_streams(data, "interleaved_samples_2",
+                                         nbits, window))
 
 
-@S.scoped(S.UNPACK)
 def unpack_naocpsr_snap1(data: jnp.ndarray, nbits: int = -8,
                          window: jnp.ndarray | None = None):
-    """"1122" pair-interleaved 2 polarizations -> 2 streams
-    (ref: unpack.hpp:253-283).  Samples are int8."""
-    x = data.reshape(-1, 4)
-    out1 = unpack(x[:, 0:2].reshape(-1), nbits, window)
-    out2 = unpack(x[:, 2:4].reshape(-1), nbits, window)
-    return out1, out2
+    """"1122" pair-interleaved 2 polarizations -> 2 streams.  Samples
+    are int8."""
+    return _unpack_streams(data, "naocpsr_snap1", nbits, window)
 
 
-@S.scoped(S.UNPACK)
 def unpack_gznupsr_a1(data: jnp.ndarray,
                       window: jnp.ndarray | None = None):
-    """4-way word-interleaved (4 samples per stream per 16-byte word group),
-    uint8 with XOR 0x80 -> int8 conversion (ref: unpack.hpp:291-328)."""
-    x = data.reshape(-1, 4, 4)  # [word, stream, sample-in-word]
-    x = jnp.bitwise_xor(x, jnp.uint8(0x80)).view(jnp.int8)
-    outs = []
-    for i in range(4):
-        out = x[:, i, :].reshape(-1).astype(jnp.float32)
-        if window is not None:
-            out = out * window
-        outs.append(out)
-    return tuple(outs)
+    """4-way word-interleaved, uint8 with XOR 0x80 -> int8."""
+    return _unpack_streams(data, "gznupsr_a1", -8, window)
 
 
-@S.scoped(S.UNPACK)
 def unpack_gznupsr_a1_v2_1(data: jnp.ndarray,
                            window: jnp.ndarray | None = None):
-    """2-way word-interleaved variant, int8 without the XOR trick
-    (ref: unpack.hpp:336-369)."""
-    x = data.reshape(-1, 2, 4).view(jnp.int8)
-    outs = []
-    for i in range(2):
-        out = x[:, i, :].reshape(-1).astype(jnp.float32)
-        if window is not None:
-            out = out * window
-        outs.append(out)
-    return tuple(outs)
+    """2-way word-interleaved variant, int8 without the XOR trick."""
+    return _unpack_streams(data, "gznupsr_a1_v2_1", -8, window)
 
 
 # ----------------------------------------------------------------

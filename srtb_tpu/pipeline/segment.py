@@ -42,17 +42,12 @@ def unpack_streams(raw: jnp.ndarray, variant: str, nbits: int,
                    window: jnp.ndarray | None) -> jnp.ndarray:
     """Dispatch to the right unpack kernel and stack the resulting data
     streams into [S, n] (ref dispatch: unpack_pipe.hpp:46-136, 392-413)."""
-    if variant == "simple":
-        return U.unpack(raw, nbits, window)[None, :]
-    if variant == "interleaved_samples_2":
-        return U.unpack_interleaved_2pol(raw, nbits, window)
-    if variant == "naocpsr_snap1":
-        return jnp.stack(U.unpack_naocpsr_snap1(raw, nbits, window))
-    if variant == "gznupsr_a1":
-        return jnp.stack(U.unpack_gznupsr_a1(raw, window))
-    if variant == "gznupsr_a1_v2_1":
-        return jnp.stack(U.unpack_gznupsr_a1_v2_1(raw, window))
-    raise ValueError(f"unknown unpack variant {variant!r}")
+    streams = [U.unpack_stream(own, variant, nbits, window)
+               for own in U.stream_bytes(raw, variant)]
+    if len(streams) == 1:
+        return streams[0][None, :]
+    with jax.named_scope(S.UNPACK):
+        return jnp.stack(streams)
 
 
 # Segments at or above this sample count execute as three XLA programs
@@ -709,17 +704,75 @@ class SegmentProcessor:
                                   len_cap=self._len_cap,
                                   epilogue=epilogue,
                                   premul=premul)[None, :]
-        else:
-            x = self._unpack(raw)
-            spec = F.segment_rfft(x, strategy,
-                                  len_cap=self._len_cap,
-                                  epilogue=epilogue,
-                                  premul=premul)   # [S, n/2]
+            return self._spectrum_to_results(spec, chirp_ri)
+
+        def chain(x):                                   # x [1, n]
+            return self._spectrum_to_results(F.segment_rfft(
+                x, strategy, len_cap=self._len_cap,
+                epilogue=epilogue, premul=premul), chirp_ri)
+        own = U.stream_bytes(raw, self.fmt.unpack_variant)
+        if len(own) == 1:
+            return chain(self._unpack(raw))
+        with jax.named_scope(S.UNPACK):
+            own = jnp.stack(own)                        # [S, bytes]
+        return self._stream_after_stream(
+            lambda b: chain(U.unpack_stream(
+                b, self.fmt.unpack_variant, self.cfg.baseband_input_bits,
+                self.window)[None, :]), own)
+
+    def _spectrum_to_results(self, spec: jnp.ndarray, chirp_ri):
+        """From the R2C's spectrum ``[S, n/2]`` to the program's
+        outputs."""
         if self.fused_tail:
             # the spectrum left the FFT already zapped/normalized/
             # masked/chirped — straight to the waterfall tail
             return self._waterfall_detect(spec)
         return self._spectrum_tail(spec, chirp_ri)
+
+    @staticmethod
+    def _stream_after_stream(chain, streams: jnp.ndarray):
+        """A segment of several streams as one-stream chains one after
+        the other inside the program: ONE loop over the leading axis of
+        ``streams`` whose body is ``chain`` on one stream's row (the
+        very shapes, and so the very transforms, of a one-stream
+        deployment), the outputs joined once at the end — the
+        waterfalls along their stream axis, every array of the detect
+        result along its leading one.
+
+        No transform receives the stream axis as a batch dimension:
+        handed ``f32[2, 2^27]`` the chip's compiler puts the 2 into the
+        minor tile and relays the stack out twice, and one batched
+        waterfall C2C of ``[2 * 2048, 32768]`` costs 3.05x one stream's
+        and 139 s of compile.  And a loop, not the chains traced one
+        behind the other: with two segment-sized FFTs in one
+        computation the compiler no longer carries the transform's
+        layout back into a sub-byte unpack (it leaves ``f32[2^25, 4]``
+        padded to 16 GB), and an unpack made to stand alone costs each
+        R2C a relayout of its input: 144.3 ms a two-stream segment
+        where the loop takes 135.4 and the batch took 155.4 (PERF.md
+        section 6, PRs 36 and 38).  A caller that brings its batch by
+        ``vmap`` (the micro-batch's segments, the fleet's beams) maps
+        the loop as a whole."""
+        static = []
+
+        def body(x):
+            wf_ri, result = chain(x)
+            # the static fields (the boxcar lengths; ``quality`` when
+            # it is off) are every stream's alike and stay outside
+            static[:] = [type(result), *(
+                None if isinstance(f, jax.Array) else (f,) for f in result)]
+            return wf_ri, [f for f in result if isinstance(f, jax.Array)]
+        # the loop's own work (a stream's row sliced out, each output
+        # written into its stack) and the join read as the waterfall's;
+        # every operation of the body keeps its stage's inner scope
+        with jax.named_scope(S.WATERFALL):
+            wf_ri, arrays = jax.lax.map(body, streams)   # [S, 2, 1, F, T]
+            wf_ri = jnp.moveaxis(wf_ri[:, :, 0], 0, 1)
+            arrays = iter(f.reshape(-1, *f.shape[2:]) for f in arrays)
+            kind, *fields = static
+            result = kind(*(next(arrays) if f is None else f[0]
+                            for f in fields))
+        return wf_ri, result
 
     # ---- staged plan: three programs with (re, im) f32 boundaries ----
 
@@ -949,10 +1002,11 @@ class SegmentProcessor:
     def _stage_c_nat(self, spec_ri: jnp.ndarray):
         """RFI s1 + in-step chirp + waterfall + RFI s2 + detect (the s1
         + chirp front half lives in stage (b) when the tail is fused)."""
-        spec = jax.lax.complex(spec_ri[0], spec_ri[1])
-        if self.fused_tail:
-            return self._waterfall_detect(spec)
-        return self._spectrum_tail(spec, None)
+        spec = jax.lax.complex(spec_ri[0], spec_ri[1])       # [S, n/2]
+        if spec.shape[0] == 1:
+            return self._spectrum_to_results(spec, None)
+        return self._stream_after_stream(
+            lambda row: self._spectrum_to_results(row[None, :], None), spec)
 
     def _spectrum_tail(self, spec: jnp.ndarray, chirp_ri):
         """Legacy (unfused-tail) device chain from the raw spectrum
@@ -1263,8 +1317,17 @@ class SegmentProcessor:
         import json
 
         cfg_d, knobs = self._trace_projection(self.cfg)
+        # the stream plan: a several-stream segment runs as one-stream
+        # chains one after the other (_stream_after_stream: the fused
+        # plan's whole chain, the staged plan's stage (c)); before, the
+        # transforms took the stream axis as a batch, in programs of
+        # the same avals.  A one-stream plan has no such entry: its
+        # signature and its cache keys stay what they were
+        streams = ({"streams": "looped-v1"}
+                   if self.fmt.data_stream_count > 1 else {})
         return json.dumps(
-            {"cfg": cfg_d, "env": knobs, "mode": self.MODE,
+            {**streams,
+             "cfg": cfg_d, "env": knobs, "mode": self.MODE,
              "staged": self.staged,
              "interp": self._pallas_interpret,
              "window": self._window_name,

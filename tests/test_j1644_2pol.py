@@ -301,6 +301,88 @@ def test_stream_0_is_the_one_stream_run_bit_for_bit(workdir):
 
 # ---- (c) a pulse in one polarisation only ------------------------------
 
+# ---- (b2) several streams are one stream several times (ISSUE 38) ------
+
+def _stream_bytes(fmt: str, raw: np.ndarray) -> list:
+    """Each stream's own bytes of an interleaved segment."""
+    if fmt == "interleaved_samples_2":          # "1212" by bytes
+        return [raw[k::2].copy() for k in range(2)]
+    pairs = raw.reshape(-1, 4)                  # naocpsr_snap1: "1122"
+    return [pairs[:, 2 * k:2 * k + 2].reshape(-1).copy() for k in range(2)]
+
+
+@pytest.mark.parametrize("quality", [False, True],
+                         ids=["plain", "quality_stats"])
+@pytest.mark.parametrize("fmt,bits", [("interleaved_samples_2", 2),
+                                      ("naocpsr_snap1", 8)])
+def test_several_streams_are_one_stream_several_times(fmt, bits, quality):
+    """``SegmentProcessor`` runs a segment of S streams as S one-stream
+    chains (ISSUE 38), so on the interleaved segment it gives, a stream,
+    what a ``simple``-format processor gives on that stream's own bytes:
+    the same calls of the same transforms on the same shapes, so the
+    waterfall, the series, the counts and the peaks bit for bit.  (The
+    boxcars' own rows and the quality vector to the series' tolerance:
+    XLA:CPU recomputes the mean-subtracted series inside the fusion
+    that pads the rows, in another order in the longer program.)"""
+    extra = dict(baseband_input_bits=bits, quality_stats=quality,
+                 ingest_ring="off")
+    two = SegmentProcessor(_config(TINY, baseband_format_type=fmt, **extra))
+    one = SegmentProcessor(_config(TINY, baseband_format_type="simple",
+                                   **extra))
+    assert two.fmt.data_stream_count == 2 and one.plan_name == two.plan_name
+    rng = np.random.default_rng(38)
+    raw = rng.integers(0, 256, size=two._segment_bytes, dtype=np.uint8)
+    wf2, det2 = two._jit_process(jnp.asarray(raw), two.chirp, two.chirp_w)
+    assert wf2.shape == (2, 2, 64, 512)
+    assert (det2.quality is not None) == quality
+    for s, own in enumerate(_stream_bytes(fmt, raw)):
+        wf1, det1 = one._jit_process(jnp.asarray(own), one.chirp,
+                                     one.chirp_w)
+        np.testing.assert_array_equal(np.asarray(wf1)[:, 0],
+                                      np.asarray(wf2)[:, s], err_msg=str(s))
+        for field in ("zero_count", "time_series", "signal_counts",
+                      "snr_peaks"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(det1, field))[0],
+                np.asarray(getattr(det2, field))[s], err_msg=field)
+        close = ["boxcar_series"] + (["quality"] if quality else [])
+        for field in close:
+            assert _gap(np.asarray(getattr(det2, field))[s],
+                        np.asarray(getattr(det1, field))[0]) < SERIES_TOL, \
+                field
+    assert tuple(int(b) for b in det2.boxcar_lengths) \
+        == tuple(int(b) for b in det1.boxcar_lengths)
+
+
+# every key of a one-stream plan's signature: ISSUE 38 adds none to it
+SIGNATURE_KEYS = {"cfg", "env", "mode", "staged", "interp", "window",
+                  "has_chirp", "donate_input", "fused_tail", "front_fuse",
+                  "skzap", "ingest", "boundary"}
+
+
+def test_the_plan_signature_names_the_stream_plan():
+    """The two-stream programs changed with their avals unchanged
+    (ISSUE 38), so their signature says which spelling they are and an
+    AOT cache written by the batched one misses cleanly; a one-stream
+    plan's signature is what it was, key for key."""
+    one = json.loads(SegmentProcessor(
+        _config(TINY, baseband_format_type="simple")).plan_signature())
+    two = json.loads(SegmentProcessor(_config(TINY)).plan_signature())
+    assert set(one) == SIGNATURE_KEYS
+    assert set(two) == SIGNATURE_KEYS | {"streams"}
+    assert two["streams"] == "looped-v1"
+    # the parent's signature of the same plan is this one less the entry
+    parents = {k: v for k, v in two.items() if k != "streams"}
+    assert json.dumps(parents, sort_keys=True) \
+        != json.dumps(two, sort_keys=True)
+    assert {k: v for k, v in parents.items() if k != "cfg"} \
+        == {k: v for k, v in one.items() if k != "cfg"}
+    # the staged plan's stage (c) runs a stream at a time as well
+    staged = json.loads(SegmentProcessor(
+        _config(TINY), staged=True).plan_signature())
+    assert staged["streams"] == "looped-v1" and staged["staged"] is True
+
+
 def test_a_pulse_in_stream_1_only_names_its_stream(workdir):
     raw = _file_bytes(37, (1,))
     run = _run(workdir, "pol1", raw)

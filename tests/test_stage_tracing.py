@@ -148,70 +148,153 @@ def test_staged_stages_carry_their_own_scopes():
     assert S.FFT_R2C not in locs["stage_c"]
 
 
-def test_the_split_and_the_stream_stack_are_the_unpacks():
-    """Two streams from one segment (ISSUE 36): every operation that
-    touches bytes after the ring has assembled them (the rows of 1024,
-    every other byte of a row for each stream, the fields' shifts and
-    masks) and the concatenate that stacks the streams' samples into
-    ``[2, n]``
-    carries ``srtb.unpack``; the only other byte operations are the
-    ring's own two."""
-    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+def _located_ops(located: str, within: str = "") -> tuple:
+    """([(operation, its types, its name stack)], {loc id: name stack})
+    of a lowered module printed with its locations; the operations of
+    the part ``within`` only, where one is given."""
     names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', located,
                             flags=re.M))
-    ops = re.findall(r"^\s+%\S+ = \"?(stablehlo\.\w+)\"?.*?: (.*) "
-                     r"loc\((#loc\d+)\)$", located, flags=re.M)
-    byte_ops = [(op, names[loc]) for op, types, loc in ops
+    ops = re.findall(r"^\s+%\S+ = \"?((?:stablehlo\.\w+)|(?:call @\w+))"
+                     r"\"?.*?: (.*) loc\((#loc\d+)\)$", within or located,
+                     flags=re.M)
+    return [(op, types, names.get(loc, "")) for op, types, loc in ops], \
+        names
+
+
+def test_the_split_is_the_unpacks_and_no_sample_stack_is_traced():
+    """Two streams from one segment (ISSUE 36): every operation that
+    touches bytes after the ring has assembled them (the rows of 1024,
+    every other byte of a row for each stream, the stack of the two
+    streams' BYTES that the loop runs over, the fields' shifts and
+    masks in the loop's body) carries ``srtb.unpack``; the only other
+    byte operations are the ring's own two and the loop's slice of one
+    stream's row, which reads as the waterfall's with the rest of the
+    loop's own work.  Since ISSUE 38 each stream's samples exist only
+    inside its own pass of the loop, as ``[1, n]``: no ``[2, n]`` array
+    of samples is traced at all."""
+    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+    ops, _names = _located_ops(located)
+    byte_ops = [(op, types, name) for op, types, name in ops
                 if "ui8>" in types and op != "stablehlo.constant"]
-    split = [(op, name) for op, name in byte_ops if S.RING not in name]
-    assert sorted(op for op, name in byte_ops if S.RING in name) \
+    assert sorted(op for op, _t, name in byte_ops if S.RING in name) \
         == ["stablehlo.concatenate", "stablehlo.slice"]
-    assert {op for op, _n in split} >= {
-        "stablehlo.reshape", "stablehlo.gather",
-        "stablehlo.shift_right_logical", "stablehlo.and",
-        "stablehlo.convert"}
-    assert all(S.UNPACK in name for _op, name in split), split
+    loop = [(op, name) for op, _t, name in byte_ops
+            if S.RING not in name and S.UNPACK not in name]
+    assert loop and all(
+        _scopes_in(name) == {S.WATERFALL} and "/while/" in name
+        and op in ("stablehlo.dynamic_slice", "stablehlo.reshape")
+        for op, name in loop), loop
+    split = {op for op, _t, name in byte_ops if S.UNPACK in name}
+    assert split >= {"stablehlo.reshape", "stablehlo.gather",
+                     "stablehlo.concatenate",
+                     "stablehlo.shift_right_logical", "stablehlo.and",
+                     "stablehlo.convert"}
     # each stream is taken once from rows of 1024 bytes, every other byte
-    taken = [types for op, types, _l in ops if op == "stablehlo.gather"]
+    taken = [types for op, types, _n in ops if op == "stablehlo.gather"]
     assert len(taken) == 2 and all(
         "(tensor<8x1024xui8>" in t and "-> tensor<8x512xui8>" in t
         for t in taken)
     assert "x2xui8>" not in located        # no minor dimension of 2
-    stack = [names[loc] for op, types, loc in ops
-             if op == "stablehlo.concatenate"
-             and f"-> tensor<2x{N}xf32>" in types]
-    assert len(stack) == 1 and S.UNPACK in stack[0]
-    assert S.FFT_R2C not in stack[0]
+    stacks = [types for op, types, _n in byte_ops
+              if op == "stablehlo.concatenate" and "-> tensor<2x" in types]
+    assert stacks == [f"(tensor<1x{N // 4}xui8>, tensor<1x{N // 4}xui8>)"
+                      f" -> tensor<2x{N // 4}xui8>"], stacks
+    assert f"tensor<2x{N}xf32>" not in located
+    assert f"-> tensor<1x{N}xf32>" in located
 
 
-def _complex_stacks(located: str) -> list:
-    """The name stacks of the concatenates that make a complex array."""
-    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', located,
-                            flags=re.M))
-    return [(types, names[loc]) for types, loc in re.findall(
-        r"^\s+%\S+ = \"?stablehlo\.concatenate\"?.*?: (.*) "
-        r"loc\((#loc\d+)\)$", located, flags=re.M)
-        if "-> tensor<" in types and "complex<f32>>" in types.split("->")[1]]
-
-
-def test_the_waterfalls_stream_stack_is_the_waterfalls():
-    """Several streams' backward C2C run one after the other and are
-    stacked into ``[S, channels, time]`` (``ops/fft.waterfall_c2c``, ISSUE
-    36): that stack carries ``srtb.waterfall`` and nothing else, so
-    ``ops.waterfall_ms_per_seg`` reads it.  (What the chip's compiler
-    adds on its own, a copy that turns the program's output row-major,
-    has no name to carry: ``tests/test_tpu_compile.py``.)  One stream
-    traces no stack at all, as before ISSUE 36."""
-    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+@pytest.mark.parametrize("program", ["ring", "ring_cold"])
+def test_no_transform_takes_the_stream_axis(program):
+    """ISSUE 38: a segment of two streams is two one-stream chains one
+    after the other inside the program: ONE ``stablehlo.while`` whose
+    body is the one-stream program's chain.  No ``stablehlo.fft`` has
+    an operand with a leading dimension of 2; the segment R2C is
+    called once, on ``[1, n]`` under ``srtb.fft_r2c``, and the
+    waterfall's backward C2C once, on ``[channels, time]`` under
+    ``srtb.waterfall``, exactly as in the one-stream program, which
+    has no loop."""
     channels = 64
-    stacks = _complex_stacks(located)
-    assert len(stacks) == 1, stacks
-    types, name = stacks[0]
-    assert f"-> tensor<2x{channels}x{N // 2 // channels}xcomplex<f32>>" \
-        in types
-    assert _scopes_in(name) == {S.WATERFALL}, name
+    want = {
+        f"(tensor<1x{N}xf32>) -> tensor<1x{N // 2 + 1}xcomplex<f32>>":
+            S.FFT_R2C,
+        f"(tensor<{channels}x{N // 2 // channels}xcomplex<f32>>) -> "
+        f"tensor<{channels}x{N // 2 // channels}xcomplex<f32>>":
+            S.WATERFALL}
+    for streams, extra in ((2, TWO_POL), (1, {})):
+        _text, located = _served_programs({program}, **extra)[program]
+        assert located.count("stablehlo.while") == streams - 1
+        ffts = re.findall(r"stablehlo\.fft .*?: \((tensor<[^>]*>+)\)",
+                          located)
+        assert ffts and not any(t.startswith("tensor<2x") for t in ffts), \
+            ffts
+        ops, _names = _located_ops(located)
+        calls = [(types, name) for op, types, name in ops
+                 if op.startswith("call @fft")]
+        assert sorted(t for t, _n in calls) == sorted(want), calls
+        for types, name in calls:
+            assert re.findall(r"srtb\.[a-z0-9_]+", name)[-1] \
+                == want[types], name
+
+
+def test_the_streams_join_is_the_waterfalls():
+    """The streams' outputs are joined once, at the end
+    (``SegmentProcessor._stream_after_stream``, ISSUE 38): the loop
+    stacks each output along a new leading axis, the waterfalls
+    ``[S, 2, 1, channels, time]`` are turned to ``[2, S, channels,
+    time]`` and every array of the detect result loses its axis of 1.
+    Every value the two-stream program returns (but the ring's carry)
+    is made under ``srtb.waterfall`` and nothing else, so
+    ``ops.waterfall_ms_per_seg`` reads the join and nothing the
+    program traced is ``unscoped``; every operation of the loop that
+    carries no stage's scope of its own (its counter, its slices, its
+    stacks) carries that one as well.  One stream traces no join at
+    all, as before ISSUE 36."""
+    channels, time = 64, N // 2 // 64
+
+    def returned(located):
+        """(operation, types, name stack) of each array ``main``
+        returns (the boxcar lengths are constants)."""
+        main = located[located.index("func.func public @main"):]
+        main = main[:main.index("\n  }")]
+        ids = re.search(r"^\s+return (.*?) :", main, flags=re.M) \
+            .group(1).split(", ")
+        _ops, names = _located_ops(located)
+        out = []
+        for i in ids:
+            m = re.search(rf"^\s+{re.escape(i)} = \"?(\S+?)\"? .*?: (.*) "
+                          rf"loc\((#loc\d*)\)$", main, flags=re.M)
+            if m.group(1) != "stablehlo.constant":
+                out.append((m.group(1), m.group(2),
+                            names.get(m.group(3), "")))
+        return out
+
+    _text, located = _served_programs({"ring"}, **TWO_POL)["ring"]
+    outs = returned(located)
+    joins, carry = outs[:-1], outs[-1]
+    assert S.RING in carry[2], carry
+    assert len(joins) == 6
+    for op, types, name in joins:
+        assert op in ("stablehlo.transpose", "stablehlo.reshape"), op
+        assert "-> tensor<2x" in types, types
+        assert _scopes_in(name) == {S.WATERFALL}, name
+    assert joins[0][:2] == (
+        "stablehlo.transpose",
+        f"(tensor<2x2x{channels}x{time}xf32>) -> "
+        f"tensor<2x2x{channels}x{time}xf32>"), joins[0]
+    # ``main`` holds the loop; the stages' own functions follow it
+    main = located[located.index("func.func public @main"):]
+    main = main[:main.index("\n  }")]
+    ops, _names = _located_ops(located, within=main)
+    unnamed = [(op, name) for op, _t, name in ops
+               if op != "stablehlo.constant" and not _scopes_in(name)]
+    assert len(ops) > 20 and not unnamed, unnamed[:5]
     _text, located = _served_programs({"ring"})["ring"]
-    assert _complex_stacks(located) == []
+    one = returned(located)
+    assert f"-> tensor<2x1x{channels}x{time}xf32>" in one[0][1]
+    assert "stablehlo.while" not in located
+    # the detect result leaves as the detector made it
+    assert all(re.findall(r"srtb\.[a-z0-9_]+", name)[-1] == S.DETECT
+               for _op, _t, name in one[1:-1]), one[1:-1]
 
 
 # ------------------------------------------ (b) the served path's journal
