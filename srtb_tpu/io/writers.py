@@ -206,6 +206,35 @@ def _npy_bytes(arr: np.ndarray) -> np.ndarray:
     return np.frombuffer(bio.getvalue(), dtype=np.uint8)
 
 
+def _payload(data: np.ndarray):
+    """What ``file.write`` takes of an array without a copy of it (a
+    4.29 GB waterfall's ``tobytes()`` is one more 4.29 GB on the host)."""
+    return memoryview(np.ascontiguousarray(data)).cast("B")
+
+
+def _npy_complex64(re: np.ndarray, im: np.ndarray) -> np.ndarray:
+    """``_npy_bytes(re + 1j * im)`` as complex64, byte for byte, made in
+    ONE buffer of the file's size: the two float32 planes are written
+    straight into the body behind the header.  The spelled-out form
+    holds a complex128 sum, its complex64 cast, ``np.save``'s stream and
+    the stream's copy at once: 5.5 times the file, 23 GB for the 4.29 GB
+    waterfall of a 2^30-sample segment, beside whatever else the host
+    holds."""
+    import io as _io
+    fmt = np.lib.format
+    head = _io.BytesIO()
+    fmt.write_array_header_1_0(head, {
+        "descr": fmt.dtype_to_descr(np.dtype(np.complex64)),
+        "fortran_order": False, "shape": tuple(re.shape)})
+    header = head.getvalue()
+    buf = np.empty(len(header) + 8 * re.size, dtype=np.uint8)
+    buf[:len(header)] = np.frombuffer(header, dtype=np.uint8)
+    body = buf[len(header):].view(np.complex64).reshape(re.shape)
+    body.real = re
+    body.imag = im
+    return buf
+
+
 @dataclass
 class CandidateFiles:
     """Paths written for one positive segment."""
@@ -440,8 +469,11 @@ class WriteSignalSink:
 
         npy_paths = []
         if wf is not None:
-            if wf.ndim == 4:  # stacked (re, im) boundary representation
-                wf = (wf[0] + 1j * wf[1]).astype(np.complex64)
+            # stacked (re, im) boundary representation [2, S, F, T]: each
+            # stream's file is made from its two planes (_npy_complex64)
+            planes = wf if wf.ndim == 4 else None
+            if planes is not None:
+                wf = planes[0]
             if wf.ndim == 2:
                 wf = wf[None]
             for i in range(wf.shape[0]):
@@ -460,7 +492,10 @@ class WriteSignalSink:
                         j += 1
                     path = f"{base}.{j}.npy"
                     self._inflight_npy[i] = path
-                self._write_bytes(path, _npy_bytes(wf[i].astype(np.complex64)))
+                self._write_bytes(
+                    path, _npy_complex64(planes[0, i], planes[1, i])
+                    if planes is not None
+                    else _npy_bytes(wf[i].astype(np.complex64)))
                 npy_paths.append(path)
 
         tim_paths = []
@@ -546,7 +581,7 @@ class WriteSignalSink:
                                 path, data)
         barrier = self.manifest.sync if commit is not None else None
         if self._tx_staged is not None:
-            tmp = stage_write(path, data.tobytes(), fsync=fsync)
+            tmp = stage_write(path, _payload(data), fsync=fsync)
             self._tx_staged.append((path, tmp, fsync, commit))
             return
         if self.pool is not None:
@@ -562,7 +597,7 @@ class WriteSignalSink:
             return
         # crash-consistent: a crash mid-write leaves an orphan temp
         # (swept at startup), never a torn candidate file
-        atomic_write(path, data.tobytes(), fsync=fsync,
+        atomic_write(path, _payload(data), fsync=fsync,
                      pre_rename=barrier)
         if commit is not None:
             commit()

@@ -393,3 +393,26 @@ def dedisperse(spectrum: jnp.ndarray, chirp: jnp.ndarray) -> jnp.ndarray:
     """Apply the chirp: one complex multiply per channel
     (ref: coherent_dedispersion.hpp:223-248)."""
     return spectrum * chirp
+
+
+@S.scoped(S.CHIRP)
+def chirp_block_df64_ri(n_block: int, n_total: int, f_min: float,
+                        df: float, f_c: float, dm, i0,
+                        exact: bool = False) -> jnp.ndarray:
+    """:func:`chirp_factor_df64_ri` for the ``n_block`` channels from
+    global index ``i0`` of an ``n_total``-channel spectrum, ``i0`` a
+    traced scalar: the body of a loop over blocks of channels (the staged
+    plan's stage (c)), whose df64 hi/lo planes are then a block's size
+    and not the spectrum's.  The anchored-Taylor constants are validated
+    over the whole spectrum, once, on the host; a block that starts on an
+    anchor (``i0`` a multiple of the anchor spacing, which ``n_block``
+    being one guarantees) evaluates exactly what the whole-spectrum call
+    evaluates there.  Float64-accurate phase either way."""
+    consts = None if exact else anchored_chirp_consts(
+        n_total, f_min, df, f_c, dm)
+    if consts is not None and n_block % min(consts["block"], n_block) == 0:
+        phase = _chirp_phase_df64_anchored(n_block, consts, i0=i0)
+    else:
+        phase = _chirp_phase_df64(n_block, f_min, df, f_c, dm, i0=i0,
+                                  exact=True)
+    return jnp.stack([jnp.cos(phase), jnp.sin(phase)])

@@ -182,8 +182,22 @@ def _twiddle(n1: int, n2: int, inverse: bool) -> jnp.ndarray:
 
 
 def _split_factor(n: int) -> int:
-    """Pick n1 ~ sqrt(n), a power of two (n must be a power of two)."""
+    """n1 of the four-step split n = n1 * n2 (n a power of two).
+
+    Where the other leg then fits XLA's own transform (n / 128 at most
+    ``_XLA_FFT_LEN_CAP``: rows of 2^17 to 2^23 points) n1 = 128: one
+    DFT-matrix pass on the MXU and one direct transform.  The balanced
+    split of 2^18 is 512 x 512, and XLA transforms 512 points as
+    128 x 4 with the 4 in the minor dimension, twice.  Measured on a
+    v5e on the 2^29 points of a 2^30-sample segment, in blocks of 64
+    rows of 2^18 (PERF.md section 6, PR 40; ms a segment, forward in
+    stage (a) / backward in stage (c)): 128 x 2048 **234.6 / 343.8**,
+    2048 x 128 228.1 / 405.3, 512 x 512 337.5 / 476.0, 2^14 x 16
+    267.6 / 623.6, 16 x 2^14 300.2 / 583.5, 4 x 2^16 319.6 / 1027.8.
+    Longer transforms keep n1 ~ sqrt(n)."""
     log2n = n.bit_length() - 1
+    if 128 < n // 128 <= _XLA_FFT_LEN_CAP:
+        return 128
     return 1 << (log2n // 2)
 
 
@@ -197,6 +211,14 @@ def _split_factor(n: int) -> int:
 # dryruns forcing the in-shard recursion; future hardware A/Bs) pass it
 # explicitly / via Config.fft_len_cap.
 _XLA_FFT_LEN_CAP = 1 << 16
+# ... and the longest FORWARD row handed to it where the rows are a block
+# of a loop (a few dozen rows, not a plane): the 8x-padded [..., 128,
+# 128, 16] form of 2^18 points is then a block's size.  Stage (a) of the
+# staged plan in blocks of 64 rows of 2^18 points, v5e: 207.8 ms a
+# segment direct against 234.6 through the 128 x 2048 four-step (the
+# backward transform of stage (c) reads the other way, 441.4 against
+# 343.8, and keeps the four-step; PERF.md section 6, PR 40)
+_XLA_FFT_BLOCK_LEN_CAP = 1 << 18
 
 
 def _fft_minor(x: jnp.ndarray, inverse: bool,
@@ -594,3 +616,173 @@ def segment_rfft(x: jnp.ndarray, strategy: str = "auto",
     if strategy == "monolithic":
         return rfft_drop_nyquist(x)
     raise ValueError(f"unknown fft strategy {strategy!r}")
+
+
+# Points a block of the staged plan's loops holds (stage (b), stage (c))
+# and twice that (stage (a)): measured on a v5e on the 2^29 points of a
+# 2^30-sample segment, boundary [2, 1, 2048, 2^18] (PERF.md section 6,
+# PR 40; ms a segment by points a block, 2^24 / 2^23 / 2^22 / 2^21 /
+# 2^20): stage (a) 207.9 / **191.0** / 229.5 / 361.7 / 816.5; stage (b)
+# 210.9 / 179.8 / **134.9** / 153.9 / 343.5 (its column blocks; 2^22:
+# 2048 columns); stage (c) 343.9 / 233.9 / **176.4** / 197.4 / 275.3.
+# A block of 2^22 points is 32 MB as (re, im) float32.
+BLOCK_POINTS = 1 << 22
+
+
+def block_count(extent: int, points: int, block_points: int | None = None,
+                pairs: bool = False, unit: int = 1) -> int:
+    """How many blocks a loop walks ``extent`` rows (or columns) of an
+    array of ``points`` points in: about ``block_points`` a block
+    (``BLOCK_POINTS`` where none is given), and
+    at least eight where the extent allows (a small shape still takes
+    the loop its big relative takes); the blocks divide the extent,
+    each a multiple of ``unit`` (the vector lanes, for columns), an even
+    number of them where the loop takes them in ``pairs``.  0 where the
+    extent cannot be walked so (one row, for pairs): the caller keeps
+    the whole-plane spelling."""
+    blocks = min(max(8, points // (block_points or BLOCK_POINTS)),
+                 extent // unit)
+    while blocks > 1 and (extent % blocks or (extent // blocks) % unit
+                          or (pairs and blocks % 2)):
+        blocks -= 1
+    return blocks if blocks >= (2 if pairs else 1) else 0
+
+
+@S.scoped(S.FFT_R2C)
+def hermitian_rfft_post_rows(z_ri: jnp.ndarray, blocks: int) -> jnp.ndarray:
+    """:func:`hermitian_rfft_post` (drop-Nyquist form) over mirrored
+    pairs of row blocks, in place.
+
+    ``z_ri`` is the packed half-size C2C's output as stacked (re, im)
+    float32 ``[2, ..., R, C]``, bin ``k = r*C + c``; the result is the
+    spectrum in the same shape.  Bin ``k`` needs ``F[k]`` and
+    ``F[(m-k) mod m]``, and ``m - k = (R-1-r)*C + (C-c)`` for ``c >= 1``:
+    row ``r``'s partner is row ``R-1-r`` reversed and rolled by one
+    along its own axis; only column 0 reaches into another row,
+    ``(R-r) mod R``: the next row of the partner block, and for a
+    block's first row the first row of the block behind the partner,
+    one number kept from the iteration before.  So block ``p`` of
+    ``R/blocks`` rows and block ``blocks-1-p`` are read, combined and
+    written back together, one pair an iteration: what is alive beside
+    the carried buffer is a few blocks, where the whole-plane spelling
+    holds the reversed, rolled and negated planes at once (six planes of
+    2 GB at 2^30 samples: the program was refused by 258 MB).  The
+    arithmetic is the whole-plane function's; the twiddle
+    ``exp(-2*pi*i*k/n)`` is the product of a row factor
+    ``exp(-2*pi*i*r*C/n)`` and a column table (both with exact integer
+    arguments, as :func:`_iota_phase`)."""
+    rows, cols = z_ri.shape[-2], z_ri.shape[-1]
+    m = rows * cols
+    n = 2 * m
+    if blocks < 2 or blocks % 2 or rows % blocks:
+        raise ValueError(f"{rows} rows do not pair into {blocks} blocks")
+    rb = rows // blocks
+    axis_r = z_ri.ndim - 2
+    v = _iota_phase(cols, n, -1.0)
+    v_re, v_im = jnp.real(v), jnp.imag(v)
+    col0 = jax.lax.iota(jnp.int32, cols) == 0
+
+    def half(x, y, fix, r0):
+        """The spectrum of the rows ``r0 .. r0+rb`` from their own block
+        ``x``, their partner block ``y`` and their column-0 partners."""
+        y = jnp.roll(jnp.flip(y, axis=(-2, -1)), 1, axis=-1)
+        y = jnp.where(col0, fix[..., None], y)
+        fm_re, fm_im = y[0], -y[1]                       # conj(F[m-k])
+        even_re, even_im = 0.5 * (x[0] + fm_re), 0.5 * (x[1] + fm_im)
+        # odd = -0.5j * (F[k] - conj(F[m-k]))
+        odd_re, odd_im = 0.5 * (x[1] - fm_im), -0.5 * (x[0] - fm_re)
+        r = (r0 + jax.lax.iota(jnp.int32, rb)) * cols      # exact, < m
+        u = _phase_exp(r, n, -1.0)[:, None]
+        w_re = jnp.real(u) * v_re - jnp.imag(u) * v_im
+        w_im = jnp.real(u) * v_im + jnp.imag(u) * v_re
+        return jnp.stack([even_re + (w_re * odd_re - w_im * odd_im),
+                          even_im + (w_re * odd_im + w_im * odd_re)])
+
+    def partners0(own0, first):
+        """Column 0's partners of a block's rows: row ``i >= 1`` pairs
+        with row ``rb - i`` of the partner block (``own0`` is that
+        block's column 0), row 0 with the first row of the block behind
+        the partner (``first``)."""
+        rolled = jnp.roll(jnp.flip(own0, axis=-1), 1, axis=-1)
+        return jnp.where(jax.lax.iota(jnp.int32, rb) == 0,
+                         first[..., None], rolled)
+
+    def body(p, carry):
+        buf, behind = carry
+        q = blocks - 1 - p
+        r_p, r_q = p * rb, q * rb
+        x = jax.lax.dynamic_slice_in_dim(buf, r_p, rb, axis_r)
+        y = jax.lax.dynamic_slice_in_dim(buf, r_q, rb, axis_r)
+        x0, y0 = x[..., 0], y[..., 0]
+        # behind block q lies block q + 1, written an iteration ago: its
+        # first row's F[., 0] was kept then (block 0's own where p = 0:
+        # bin 0 pairs with itself); ahead of block p lies block p + 1,
+        # still as the transform left it (the partner itself at the end)
+        behind = jnp.where(p == 0, x0[..., 0], behind)
+        ahead = jax.lax.dynamic_slice_in_dim(
+            buf, r_p + rb, 1, axis_r)[..., 0, 0]
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, half(x, y, partners0(y0, behind), r_p), r_p, axis_r)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, half(y, x, partners0(x0, ahead), r_q), r_q, axis_r)
+        return buf, y0[..., 0]
+
+    buf, _ = jax.lax.fori_loop(
+        0, blocks // 2, body, (z_ri, jnp.zeros(z_ri.shape[:-2], z_ri.dtype)))
+    return buf
+
+
+@S.scoped(S.FFT_R2C)
+def four_step_stage2_cols(a_ri: jnp.ndarray, blocks: int,
+                          len_cap: int | None = None) -> jnp.ndarray:
+    """:func:`four_step_stage2` in place, over blocks of columns.
+
+    ``a_ri`` is ``A[j2, k1]`` of the first half as stacked (re, im)
+    float32 ``[2, ..., n2, n1]``; the result is ``X[k]`` in the same
+    shape, ``k = k2*n1 + k1`` at ``[k2, k1]``.  Column ``k1`` of A is
+    transformed along ``j2`` into column ``k1`` of the result, so a
+    block of ``n1/blocks`` columns is read, twiddled, transposed,
+    transformed, transposed back and written where it was read: beside
+    the carried buffer a few blocks are alive, where the whole-plane
+    spelling holds the twiddled, the transposed and the transformed
+    plane (10.7 GB of temporaries at 2^29 points).  The twiddle
+    ``exp(-2*pi*i*j2*k1/n)`` of a block is the product of its first
+    column's (a vector) and a table of the offsets inside a block made
+    once ahead of the loop, both from exact integer residues as
+    :func:`_phase_exp` takes them."""
+    n2, n1 = a_ri.shape[-2], a_ri.shape[-1]
+    if n1 % blocks:
+        raise ValueError(f"{n1} columns do not divide into {blocks} blocks")
+    n = n1 * n2
+    w = n1 // blocks
+    axis_c = a_ri.ndim - 1
+    j2 = jax.lax.iota(jnp.int32, n2)
+    # exp(-2*pi*i*j2*kk/n) for the offsets kk inside a block
+    inner = _phase_exp((j2[:, None] * jax.lax.iota(jnp.int32, w)[None, :])
+                       % n, n, -1.0)
+
+    def body(q, buf):
+        x = jax.lax.dynamic_slice_in_dim(buf, q * w, w, axis_c)
+        first = _phase_exp((j2 * (q * w)) % n, n, -1.0)[:, None]
+        x = jax.lax.complex(x[0], x[1]) * (first * inner)
+        y = _fft_minor(jnp.swapaxes(x, -1, -2), inverse=False,
+                       len_cap=len_cap)                   # [.., w, k2]
+        y = jnp.swapaxes(y, -1, -2)                       # [.., k2, w]
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, jnp.stack([jnp.real(y), jnp.imag(y)]), q * w, axis_c)
+
+    return jax.lax.fori_loop(0, blocks, body, a_ri)
+
+
+@S.scoped(S.FFT_R2C)
+def four_step_stage1_cols(z_cols: jnp.ndarray,
+                          len_cap: int | None = None) -> jnp.ndarray:
+    """:func:`four_step_stage1` for a block of the columns of its
+    ``[n1, n2]`` view: ``z_cols[..., j1, j]`` = ``x[j1*n2 + j2_0 + j]``
+    -> ``A[j2_0 + j, k1]`` as ``[..., j, k1]``, the rows ``j2_0 ...`` of
+    the whole transform's ``A[j2, k1]``.  Meant for a block of rows: a
+    row goes to XLA's own transform up to ``_XLA_FFT_BLOCK_LEN_CAP``
+    points where the caller sets no cap."""
+    return _fft_minor(jnp.swapaxes(z_cols, -1, -2), inverse=False,
+                      len_cap=len_cap or _XLA_FFT_BLOCK_LEN_CAP)
+

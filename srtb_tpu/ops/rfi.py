@@ -120,21 +120,18 @@ def eval_rfi_ranges(mitigate_rfi_freq_list: str) -> list[tuple[float, float]]:
     return ranges
 
 
-def rfi_ranges_to_mask(ranges, n_channels: int, baseband_freq_low: float,
-                       baseband_bandwidth: float) -> np.ndarray | None:
-    """Host-side: turn frequency ranges into a boolean zap mask over bins.
+def rfi_ranges_to_bins(ranges, n_channels: int, baseband_freq_low: float,
+                       baseband_bandwidth: float) -> list[tuple[int, int]]:
+    """Host-side: frequency ranges -> inclusive ``(lo, hi)`` bin ranges.
 
     Bin mapping matches the reference: bin = round((f - f_low) / bw * (N-1)),
     inclusive on both ends, with range order flipped when the band is
-    inverted (ref: spectrum/rfi_mitigation.hpp:102-143).  Returns None when
-    there is nothing to zap (lets jit skip the multiply).
+    inverted (ref: spectrum/rfi_mitigation.hpp:102-143).  A range that
+    leaves the band is warned about and left out.
     """
-    if not ranges:
-        return None
-    mask = np.zeros(n_channels, dtype=bool)
+    bins = []
     bw_sign = np.signbit(baseband_bandwidth)
     freq_high = baseband_freq_low + baseband_bandwidth
-    any_zap = False
     for rfi_low, rfi_high in ranges:
         if np.signbit(rfi_high - rfi_low) != bw_sign:
             rfi_low, rfi_high = rfi_high, rfi_low
@@ -143,13 +140,28 @@ def rfi_ranges_to_mask(ranges, n_channels: int, baseband_freq_low: float,
         hi = int(round((rfi_high - baseband_freq_low) / baseband_bandwidth
                        * (n_channels - 1)))
         if 0 <= lo <= hi < n_channels:
-            mask[lo:hi + 1] = True
-            any_zap = True
+            bins.append((lo, hi))
         else:
             log.warning(
                 f"[mitigate_rfi_manual] RFI range {rfi_low} - {rfi_high} MHz "
                 f"out of baseband range {baseband_freq_low} - {freq_high} MHz")
-    return mask if any_zap else None
+    return bins
+
+
+def rfi_ranges_to_mask(ranges, n_channels: int, baseband_freq_low: float,
+                       baseband_bandwidth: float) -> np.ndarray | None:
+    """Host-side: turn frequency ranges into a boolean zap mask over bins
+    (:func:`rfi_ranges_to_bins`).  Returns None when there is nothing to
+    zap (lets jit skip the multiply).
+    """
+    bins = rfi_ranges_to_bins(ranges, n_channels, baseband_freq_low,
+                              baseband_bandwidth)
+    if not bins:
+        return None
+    mask = np.zeros(n_channels, dtype=bool)
+    for lo, hi in bins:
+        mask[lo:hi + 1] = True
+    return mask
 
 
 @S.scoped(S.RFI_S1)
@@ -198,3 +210,18 @@ def mitigate_rfi_spectral_kurtosis(waterfall: jnp.ndarray,
     zap = (sk > thr_high_) | (sk < thr_low_)
     return jnp.where(zap[..., None], jnp.zeros((), dtype=waterfall.dtype),
                      waterfall)
+
+
+@S.scoped(S.RFI_S1)
+def mitigate_rfi_manual_bins(spectrum: jnp.ndarray, bins, k0) -> jnp.ndarray:
+    """:func:`mitigate_rfi_manual` for a block of the spectrum whose first
+    bin is the (traced) global index ``k0``: the zap ranges are compared
+    with the bins' own indices, so a loop over blocks holds no mask the
+    spectrum's size."""
+    if not bins:
+        return spectrum
+    k = k0 + jnp.arange(spectrum.shape[-1], dtype=jnp.int32)
+    zap = jnp.zeros(k.shape, dtype=bool)
+    for lo, hi in bins:
+        zap = zap | ((k >= lo) & (k <= hi))
+    return jnp.where(zap, jnp.zeros((), dtype=spectrum.dtype), spectrum)

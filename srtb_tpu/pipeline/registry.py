@@ -422,8 +422,9 @@ for _fam in (
                "donation",
                {"fft_strategy": "four_step", "fused_tail": "on"},
                donate=True, staged=True),
-    PlanFamily("staged_unfused", "staged plan with the legacy 7-pass "
-               "tail",
+    PlanFamily("staged_unfused", "the plain staged plan (what a 2^30 "
+               "segment resolves to): every stage in blocks of the "
+               "boundary, unfused tail",
                {"fft_strategy": "four_step", "fused_tail": "off"},
                donate=True, staged=True),
     PlanFamily("staged_pallas", "staged with Pallas row-FFT legs",
@@ -434,6 +435,13 @@ for _fam in (
                "legs (downgrades to pallas legs below the 2^24 leg "
                "window)",
                {"fft_strategy": "four_step", "fused_tail": "on"},
+               donate=True, staged=True,
+               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
+    PlanFamily("staged_pallas2_unfused", "the whole-plane staged plan "
+               "with the legacy 7-pass tail (what staged_unfused was "
+               "before its stages walked the boundary in blocks): where "
+               "the front-fused chain's fused_tail rung lands",
+               {"fft_strategy": "four_step", "fused_tail": "off"},
                donate=True, staged=True,
                env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     # ---- ingest-ring (ring-v1) families: overlap-save reserves a

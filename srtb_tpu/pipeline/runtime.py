@@ -949,6 +949,11 @@ class Pipeline:
         with stage_span("enqueue", self.stage_timer, tid) as sp:
             out = self._op("dispatch", index, run_it)
         span["enqueue"] = sp.seconds
+        # a plan of several programs says what each jit call took
+        # (the staged plan: enqueue_a / _b / _c)
+        take = getattr(self.processor, "take_stage_spans", None)
+        if take is not None:
+            span.update(take())
         return out
 
     def _dispatch_segment(self, seg, ingest_s: float,
@@ -1706,6 +1711,24 @@ class Pipeline:
                     self.stats.segments += 1
                     self.stats.samples += n_samples_per_seg
 
+        def sink_slot_about_to_free() -> bool:
+            """The segment just fetched is still with the sink thread,
+            whose return frees its window slot: for a quiet segment
+            tens of microseconds after the push.  Give it a few
+            milliseconds before blocking on the NEXT fetch: that fetch
+            lasts a whole device period with nothing queued behind it,
+            so the device then idles for the next segment's pull and
+            upload (+145 ms on a 759 ms period at 2^30 samples, four or
+            five times in a 20 s window whenever this thread won the
+            race; PERF.md section 6, PR 40).  A sink that takes longer
+            (a candidate's files) leaves the loop as it was: block on
+            the oldest, the in-order point."""
+            t_wait = time.perf_counter()
+            while live_count() > len(pending) and sink_alive() \
+                    and time.perf_counter() - t_wait < 0.005:
+                time.sleep(0.0002)
+            return live_count() + cur_unit() <= window
+
         requeue_counts: dict[int, int] = {}
 
         def watchdog_wait() -> bool:
@@ -1883,6 +1906,8 @@ class Pipeline:
                 # point where overlap is actually earned
                 if live_count() + cur_unit() > window \
                         or not want_more():
+                    if want_more() and sink_slot_about_to_free():
+                        continue
                     if not drain_oldest():
                         break
             while pending and sink_alive():

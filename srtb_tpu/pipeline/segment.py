@@ -35,6 +35,7 @@ from srtb_tpu.ops import rfi
 from srtb_tpu.ops import scopes as S
 from srtb_tpu.ops import unpack as U
 from srtb_tpu.ops import window as W
+from srtb_tpu.utils import tracing
 from srtb_tpu.utils.logging import log
 
 
@@ -64,10 +65,12 @@ STAGED_MIN_N = 1 << 30
 # cheap, but its per-element update still runs through ops/df64's
 # EFT optimization_barriers, which block XLA fusion — a handful of
 # spectrum-sized f32 intermediates materialize (~2 GB each at
-# n_spectrum = 2^29).  Harmless through 2^27 (n = 2^28), an unproven
-# peak-HBM risk at the 2^30 staged scale until a real-chip run retires
-# it (fused_tail="on" overrides this gate).  Bank plans are exempt:
-# their chirp rides the precombined (c, cw) banks, no in-trace df64.
+# n_spectrum = 2^29).  Harmless through 2^27 (n = 2^28); past it the
+# staged plan keeps the tail unfused and makes the chirp a block of
+# channels at a time inside stage (c) (_stage_c_rows: the spelling a
+# v5e runs at 2^30; fused_tail="on" overrides this gate and was never
+# compiled for one at that size).  Bank plans are exempt: their chirp
+# rides the precombined (c, cw) banks, no in-trace df64.
 FUSED_TAIL_DF64_MAX_SPECTRUM = 1 << 27
 
 
@@ -226,7 +229,11 @@ class SegmentProcessor:
     - **staged** (n >= STAGED_MIN_N, or ``staged=True``): three jitted
       programs — (a) unpack + pack + four-step first half, (b) four-step
       second half + Hermitian post-process, (c) RFI + in-step df64 chirp
-      + waterfall + detect.  Boundaries are stacked (re, im) float32 in
+      + waterfall + detect.  The plain spelling (``+rows`` in the plan
+      name: what a 2^30-sample segment resolves to) walks the boundary
+      in blocks inside each program, see ``_stage_a_rows`` and below;
+      the Pallas / fused-tail variants keep whole-plane stages.
+      Boundaries are stacked (re, im) float32 in
       the CANONICAL shape [2, S, channel_count, watfft_len]: XLA only
       honors ``donate_argnums`` when an output aval exactly matches the
       donated input's aval, so every stage boundary (and the waterfall
@@ -300,6 +307,9 @@ class SegmentProcessor:
         # in, blocked intermediate out) and the Hermitian + RFI-s1 +
         # chirp tail into pass 2's epilogue
         self.front_fuse = front_fuse_resolves(cfg, self.staged)
+        # the staged plan's default spelling walks its boundary in this
+        # many blocks of rows (0: the whole-plane spellings)
+        self.staged_rows = self._resolve_staged_rows()
         # the chirp crosses the host->device boundary as stacked (re, im)
         # float32 [2, n]: some TPU runtimes can't transfer complex buffers,
         # and split re/im is the natural VPU layout anyway; complex exists
@@ -330,9 +340,18 @@ class SegmentProcessor:
                 # a captured 2 GB bank would bake into the program)
                 self.chirp_w = jax.jit(self._premul_bank)(self.chirp)
 
-        mask = rfi.rfi_ranges_to_mask(
-            rfi.eval_rfi_ranges(cfg.mitigate_rfi_freq_list), self.n_spectrum,
-            cfg.baseband_freq_low, cfg.baseband_bandwidth)
+        zap_ranges = rfi.eval_rfi_ranges(cfg.mitigate_rfi_freq_list)
+        if self.staged_rows:
+            # a block compares the zap ranges with its own bin indices:
+            # no mask the spectrum's size (0.5 GB at 2^29 bins) is made
+            self.rfi_bins = rfi.rfi_ranges_to_bins(
+                zap_ranges, self.n_spectrum, cfg.baseband_freq_low,
+                cfg.baseband_bandwidth)
+            mask = None
+        else:
+            mask = rfi.rfi_ranges_to_mask(
+                zap_ranges, self.n_spectrum,
+                cfg.baseband_freq_low, cfg.baseband_bandwidth)
         self.rfi_mask = None if mask is None else jnp.asarray(mask)
 
         self.norm_coeff = rfi.normalization_coefficient(
@@ -459,6 +478,8 @@ class SegmentProcessor:
         # aot_cache.get_or_compile instead).  Per-stream labeled twins
         # when this processor serves a named fleet lane.
         self._dispatched_programs: set[str] = set()
+        # host seconds of the staged plan's three jit calls, by span
+        self._stage_spans: dict[str, float] = {}
         self._metric_labels = ({"stream": cfg.stream_name}
                                if getattr(cfg, "stream_name", "")
                                else None)
@@ -491,6 +512,31 @@ class SegmentProcessor:
         level :func:`fused_tail_resolves` (shared with the demotion
         ladder)."""
         return fused_tail_resolves(self.cfg, self.staged)
+
+    def _resolve_staged_rows(self) -> int:
+        """How many blocks of rows the staged plan's stages (b) and (c)
+        walk the canonical boundary ``[2, S, F, T]`` in
+        (``_stage_b_rows``, ``_stage_c_rows``); 0 for the whole-plane
+        spellings.  The plain staged plan (XLA legs, no fused tail, no
+        Pallas kernel, no quality epilogue: what a 2^30-sample segment
+        resolves to by itself) takes the blocks at every size: at 2^30
+        the whole-plane Hermitian post and the whole-spectrum RFI s1 /
+        df64 chirp / waterfall are each refused by one v5e (16.00 and
+        19.00 GB of 15.75), and nothing else chooses between them, so
+        the small forced-staged shapes the tests run are the same
+        programs.  The variants that bring their own kernels or fold the
+        tail into stage (b) keep their spellings."""
+        cfg = self.cfg
+        plain = (self.staged and not self.front_fuse
+                 and not self.fused_tail
+                 and not cfg.use_pallas and not cfg.use_pallas_sk
+                 and not getattr(cfg, "quality_stats", False)
+                 and os.environ.get("SRTB_STAGED_ROWS_IMPL", "xla") == "xla"
+                 and not self._staged_blocked
+                 and self.channel_count * self.watfft_len
+                 == self.n_spectrum)
+        return F.block_count(self.channel_count, self.n_spectrum,
+                             pairs=True) if plain else 0
 
     def _resolve_ring(self) -> bool:
         """Resolve Config.ingest_ring ("auto"/"on"/"off") against the
@@ -592,6 +638,8 @@ class SegmentProcessor:
         + which fusions are live."""
         strategy = F.resolve_strategy(self.n, self.cfg.fft_strategy)
         name = ("staged" if self.staged else "fused") + f":{strategy}"
+        if self.staged_rows:
+            name += "+rows"
         if self.fused_tail:
             name += "+ftail"
         if self.front_fuse:
@@ -851,13 +899,37 @@ class SegmentProcessor:
     def _stage_a(self, raw: jnp.ndarray):
         if self.front_fuse:
             return self._stage_a_front(raw)
+        if self.staged_rows:
+            return self._stage_a_rows(raw)
         return self._boundary_canon(self._stage_a_nat(raw))
 
     def _stage_b(self, a_ri, aux=None):
         if self.front_fuse:
             return self._stage_b_front(a_ri, aux)
+        if self.staged_rows:
+            return self._stage_b_rows(a_ri)
         return self._boundary_canon(
             self._stage_b_nat(a_ri.reshape(self._a_nat_shape)))
+
+    def _enqueue_stage(self, key: str, fn, *args):
+        """One of the staged plan's three jit calls, under a host span of
+        its own: ``srtb:enqueue_a`` / ``_b`` / ``_c`` on the profiler's
+        host plane, and the seconds kept for the segment's journal
+        record (``take_stage_spans``: children of ``enqueue`` in
+        ``stages_ms``), so a trace and a journal tell the three
+        dispatches apart as the device plane's ``jit(_stage_a)`` ...
+        tell the three programs apart."""
+        with tracing.span(f"enqueue_{key}") as sp:
+            out = fn(*args)
+        self._stage_spans[sp.name] = \
+            self._stage_spans.get(sp.name, 0.0) + sp.seconds
+        return out
+
+    def take_stage_spans(self) -> dict:
+        """Seconds per staged dispatch since the last call ({} for a
+        one-program plan)."""
+        spans, self._stage_spans = self._stage_spans, {}
+        return spans
 
     def _run_stage_b(self, a):
         """Dispatch the stage-(a) boundary into the jitted stage (b).
@@ -866,10 +938,12 @@ class SegmentProcessor:
         — donating the [S, 3, 128] aux (which has no output aval to
         alias) would be a dropped-donation warning on every compile."""
         if self.front_fuse:
-            return self._jit_stage_b(*a)
-        return self._jit_stage_b(a)
+            return self._enqueue_stage("b", self._jit_stage_b, *a)
+        return self._enqueue_stage("b", self._jit_stage_b, a)
 
     def _stage_c(self, spec_ri: jnp.ndarray):
+        if self.staged_rows:
+            return self._stage_c_rows(spec_ri)
         x = spec_ri.reshape(2, spec_ri.shape[1], -1)
         if self.front_fuse:
             # the front-fused stage (b) emits the dedispersed spectrum
@@ -1008,6 +1082,156 @@ class SegmentProcessor:
         return self._stream_after_stream(
             lambda row: self._spectrum_to_results(row[None, :], None), spec)
 
+    # ---- the plain staged plan, in blocks of the boundary's rows ----
+    #
+    # The segment's half-size C2C is split n/2 = F x T, the boundary's
+    # own two axes (a four-step's split is free; the whole-plane stages
+    # take sqrt(n/2) squared): stage (a)'s A[j2, k1] IS the boundary
+    # [2, S, F, T], row by row, stage (b) transforms its columns and
+    # pairs its rows, stage (c) takes its rows as channels.  Each stage
+    # carries the boundary through loops whose body reads a block,
+    # computes and writes it back in place, so beside the 4 GB boundary
+    # of a 2^30 segment a program holds a few blocks (16 channels or
+    # 2048 columns: ops/fft.BLOCK_POINTS = 2^22 points, 32 MB) and never
+    # a plane of the spectrum's size, and no stage reshapes the boundary
+    # across its lanes.
+
+    def _stage_a_block_rows(self) -> int:
+        """Rows of the boundary a block of ``_stage_a_rows`` makes: twice
+        ``ops/fft.BLOCK_POINTS`` points (32 rows of 2^18 at 2^30 / 2^11:
+        191.0 ms a segment on a v5e where 64 rows take 207.9 and 16 take
+        229.5), or as many more rows as it takes for their packed points
+        to fill whole bytes in a row of the bytes' view (two 2-bit points
+        a byte); 0 where the whole-plane stage (a) stays (several streams
+        in one byte stream, or fewer channels than that)."""
+        if not self.staged_rows or self.fmt.unpack_variant != "simple":
+            return 0
+        blocks = F.block_count(self.channel_count, self.n_spectrum,
+                               2 * F.BLOCK_POINTS)
+        rows = self.channel_count // blocks
+        bits = abs(int(self.cfg.baseband_input_bits))
+        while (rows * 2 * bits) % 8:
+            rows *= 2
+        return rows if self.channel_count % rows == 0 else 0
+
+    @S.scoped(S.FFT_R2C)
+    def _stage_a_rows(self, raw: jnp.ndarray):
+        """unpack + even/odd pack + segment-FFT first half over blocks of
+        the boundary's rows.  Row ``j2`` of ``A[j2, k1]`` transforms the
+        points ``x[j1*F + j2]``: a block of rows reads one strip of
+        columns of the bytes' ``[T, bytes a row]`` view, unpacks and
+        packs it, transforms it and writes its rows, so the unpacked
+        float32 samples (4 GB at 2^30) and the packed plane exist a
+        block at a time."""
+        cfg = self.cfg
+        n2, n1 = self.channel_count, self.watfft_len
+        jw = self._stage_a_block_rows()
+        if not jw:
+            z = self._staged_pack(raw)                        # [S, n/2]
+            # the rows are a plane here: XLA's own cap, not a block's
+            a = F.four_step_stage1_cols(
+                z.reshape(z.shape[0], n1, n2),
+                len_cap=self._len_cap or F._XLA_FFT_LEN_CAP)
+            return jnp.stack([jnp.real(a), jnp.imag(a)])
+        bits = abs(int(cfg.baseband_input_bits))
+        wb = jw * 2 * bits // 8              # a block's bytes in a row
+        raw2 = raw.reshape(n1, n2 * 2 * bits // 8)
+        win2 = None if self.window is None \
+            else self.window.reshape(n1, 2 * n2)
+
+        def body(b, out):
+            x = jax.lax.dynamic_slice_in_dim(raw2, b * wb, wb, 1)
+            win = None if win2 is None else jax.lax.dynamic_slice_in_dim(
+                win2, b * 2 * jw, 2 * jw, 1).reshape(-1)
+            x = unpack_streams(x.reshape(-1), self.fmt.unpack_variant,
+                               cfg.baseband_input_bits, win)  # [1, 2*n1*jw]
+            z = F.pack_even_odd(x).reshape(1, n1, jw)
+            a = F.four_step_stage1_cols(z, len_cap=self._len_cap)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.stack([jnp.real(a), jnp.imag(a)]), b * jw, 2)
+
+        return jax.lax.fori_loop(
+            0, n2 // jw, body, jnp.zeros((2, 1, n2, n1), jnp.float32))
+
+    @S.scoped(S.FFT_R2C)
+    def _stage_b_rows(self, a_ri: jnp.ndarray):
+        """segment-FFT second half over blocks of columns, then the
+        Hermitian post over mirrored pairs of row blocks, both in place
+        on the boundary (ops/fft.four_step_stage2_cols,
+        hermitian_rfft_post_rows)."""
+        # blocks of whole vector lanes of columns
+        col_blocks = F.block_count(self.watfft_len, self.n_spectrum,
+                                   unit=min(128, self.watfft_len))
+        z_ri = F.four_step_stage2_cols(a_ri, col_blocks,
+                                       len_cap=self._len_cap)
+        return F.hermitian_rfft_post_rows(z_ri, self.staged_rows)
+
+    def _stage_c_rows(self, spec_ri: jnp.ndarray):
+        """RFI s1 + in-step df64 chirp + waterfall + RFI s2 + detect over
+        blocks of channels.  RFI s1's mean power is the one reduction
+        over the whole spectrum; after it the zap, the manual mask, the
+        chirp, the backward C2C, the de-window and the SK zap are each
+        channel's own, and the detector's series is a sum over channels:
+        a block's share of it is kept, and the blocks' shares are summed
+        by the same pairwise tree (ops/detect.tree_sum_freq over the
+        blocks continues the tree inside each), so the series is the
+        whole-plane spelling's to the last bit on the same waterfall."""
+        cfg = self.cfg
+        n_streams, f_cnt, t_len = spec_ri.shape[1:]
+        blocks = self.staged_rows
+        cb = f_cnt // blocks
+        t = det.trimmed_length(t_len, self.time_reserved_count)
+        with jax.named_scope(S.RFI_S1):
+            mean_power = jnp.mean(spec_ri[0] ** 2 + spec_ri[1] ** 2,
+                                  axis=(-2, -1))                  # [S]
+
+        def body(i, carry):
+            buf, ts_parts, zero_count = carry
+            s, b = i // blocks, i % blocks
+            c0 = b * cb
+            x = jax.lax.dynamic_slice(buf, (0, s, c0, 0),
+                                      (2, 1, cb, t_len))
+            spec = jax.lax.complex(x[0], x[1]).reshape(1, cb * t_len)
+            spec = rfi.mitigate_rfi_s1_given_mean(
+                spec, mean_power[s],
+                cfg.mitigate_rfi_average_method_threshold, self.norm_coeff)
+            k0 = c0 * t_len                  # the block's first bin
+            spec = rfi.mitigate_rfi_manual_bins(spec, self.rfi_bins, k0)
+            c_ri = dd.chirp_block_df64_ri(
+                cb * t_len, self.n_spectrum, self.f_min, self.df,
+                self.f_c, cfg.dm, k0,
+                exact=getattr(cfg, "chirp_exact", False))
+            spec = dd.dedisperse(spec, jax.lax.complex(c_ri[0], c_ri[1]))
+            wf = F.waterfall_c2c(spec, cb, self.watfft_dewindow,
+                                 len_cap=self._len_cap)       # [1, cb, T]
+            wf = rfi.mitigate_rfi_spectral_kurtosis(
+                wf, cfg.mitigate_rfi_spectral_kurtosis_threshold)
+            with jax.named_scope(S.DETECT):
+                # ops/detect.detect's two reductions, a block's share
+                power = jnp.real(wf) ** 2 + jnp.imag(wf) ** 2
+                zero_count = zero_count.at[s].add(jnp.sum(
+                    (power[0, :, 0] == 0).astype(jnp.int32)))
+                ts = det.tree_sum_freq(power[..., :t])            # [1, t]
+            buf = jax.lax.dynamic_update_slice(
+                buf, jnp.stack([jnp.real(wf), jnp.imag(wf)]),
+                (0, s, c0, 0))
+            ts_parts = jax.lax.dynamic_update_slice(
+                ts_parts, ts[:, None], (s, b, 0))
+            return buf, ts_parts, zero_count
+
+        # the loop's own work (a block sliced out and written back)
+        # reads as the waterfall's; the body's keep their stages' scopes
+        with jax.named_scope(S.WATERFALL):
+            wf_ri, ts_parts, zero_count = jax.lax.fori_loop(
+                0, n_streams * blocks, body,
+                (spec_ri, jnp.zeros((n_streams, blocks, t), jnp.float32),
+                 jnp.zeros((n_streams,), jnp.int32)))
+        result = det.detect_from_time_series(
+            det.tree_sum_freq(ts_parts), zero_count,
+            cfg.signal_detect_signal_noise_threshold,
+            cfg.signal_detect_max_boxcar_length)
+        return wf_ri, result
+
     def _spectrum_tail(self, spec: jnp.ndarray, chirp_ri):
         """Legacy (unfused-tail) device chain from the raw spectrum
         onward: RFI s1 + chirp as their own sweeps, then the waterfall
@@ -1055,12 +1279,13 @@ class SegmentProcessor:
         spec = rfi.mitigate_rfi_manual(spec, self.rfi_mask)
         qtap = spec  # pre-chirp: bin powers/zeros identical post-chirp
         if chirp_ri is None:
-            # In-step df64 chirp without Pallas (staged plan on the
-            # jnp path).  The XLA df64 chirp's optimization_barriers
-            # block fusion, so its ~12 error-free-transform
-            # intermediates each materialize a plane (24 GB peak at
-            # 2^30) — the Pallas kernel is the form that scales;
-            # this branch serves CPU tests and small segments.
+            # In-step df64 chirp of a WHOLE-PLANE staged stage (c): the
+            # Pallas kernel over the whole spectrum.  At 2^30 its
+            # program was refused by a v5e (19.00G of 15.75G with the
+            # whole-spectrum RFI s1 beside it); the plain staged plan
+            # makes the chirp in XLA a block of channels at a time
+            # instead (_stage_c_rows), so this branch serves the
+            # forced-staged variants at small sizes and the CPU tests.
             outs = []
             for s in range(n_streams):
                 spec_ri = jnp.stack([jnp.real(spec[s]),
@@ -1738,9 +1963,11 @@ class SegmentProcessor:
             def _run_staged():
                 # the fused branch above returned, so its donation
                 # can never reach this chain's read
-                a = self._jit_stage_a(
+                a = self._enqueue_stage(
+                    "a", self._jit_stage_a,
                     raw)  # srtb-lint: disable=use-after-donate
-                return self._jit_stage_c(self._run_stage_b(a))
+                return self._enqueue_stage(
+                    "c", self._jit_stage_c, self._run_stage_b(a))
 
             return self._timed_first("staged", _run_staged)
 
@@ -1749,7 +1976,7 @@ class SegmentProcessor:
             # input (it expires it); the fused branch above returned,
             # so its lambda-wrapped donation never reaches this read
             a = self._staged_a_checks(
-                self._jit_stage_a(raw),
+                self._enqueue_stage("a", self._jit_stage_a, raw),
                 raw)  # srtb-lint: disable=use-after-donate
             return self._staged_tail(a)
 
@@ -1788,7 +2015,7 @@ class SegmentProcessor:
         S.check_contract("stage_b boundary", b, lead=2,
                          dtype=jnp.float32)
         S.check_finite("stage_b boundary", b)
-        return self._jit_stage_c(b)
+        return self._enqueue_stage("c", self._jit_stage_c, b)
 
     # ------------------------------------------- ring execution paths
 
@@ -1808,9 +2035,12 @@ class SegmentProcessor:
             def _run_ring():
                 # whole chain under one timer (see run_device): the
                 # b/c stages compile on first dispatch too
-                a, nc = self._jit_stage_a_ring(carry, new)
+                a, nc = self._enqueue_stage(
+                    "a", self._jit_stage_a_ring, carry, new)
                 if not self._sanitize:
-                    return self._jit_stage_c(self._run_stage_b(a)), nc
+                    return self._enqueue_stage(
+                        "c", self._jit_stage_c,
+                        self._run_stage_b(a)), nc
                 # sanctioned holder: _staged_a_checks expires the
                 # carry, which is donated UNCONDITIONALLY (unlike the
                 # raw input)
@@ -1845,9 +2075,12 @@ class SegmentProcessor:
         if self.staged:
             def _run_cold():
                 # whole chain under one timer (see run_device)
-                a, nc = self._jit_stage_a_cold(raw)
+                a, nc = self._enqueue_stage(
+                    "a", self._jit_stage_a_cold, raw)
                 if not self._sanitize:
-                    return self._jit_stage_c(self._run_stage_b(a)), nc
+                    return self._enqueue_stage(
+                        "c", self._jit_stage_c,
+                        self._run_stage_b(a)), nc
                 # sanctioned holder: _staged_a_checks expires the
                 # donated input
                 return self._staged_tail(self._staged_a_checks(

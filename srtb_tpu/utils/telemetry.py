@@ -93,7 +93,9 @@ from srtb_tpu.utils.metrics import metrics
 # call), and on segments that dump ``d2h`` / ``write`` / ``publish``
 # inside ``sink`` (the lazy waterfall fetch; the writers, with the wait
 # for the writer pool where a sink drained it; manifest barrier +
-# renames).  Whoever sums a record's stages uses ``segment_wall``,
+# renames); a plan of three programs a segment (the staged plan) adds
+# ``enqueue_a`` / ``enqueue_b`` / ``enqueue_c`` inside ``enqueue`` (its
+# three jit calls).  Whoever sums a record's stages uses ``segment_wall``,
 # which leaves a child out where its parent is there.  The DM-search
 # loop journals the same record with five flat stages (``ingest``,
 # ``h2d``, ``enqueue``, ``fetch``, ``record``).
@@ -110,6 +112,8 @@ SPAN_SCHEMA_VERSION = 12
 
 # child stage -> the stage it is timed inside (see the note above)
 CHILD_STAGES = {"h2d": "dispatch", "enqueue": "dispatch",
+                "enqueue_a": "enqueue", "enqueue_b": "enqueue",
+                "enqueue_c": "enqueue",
                 "d2h": "sink", "write": "sink", "publish": "sink"}
 
 

@@ -6,9 +6,10 @@ file imports them under their own names and adds one guard of its own.
 
 The selftest directory goes on ``sys.path`` and its modules are imported
 by their top-level names, as ``pytest benchmark/selftest`` imports them:
-``test_naoc_cell`` and ``test_2pol_cell`` do ``import test_scopes`` and
-register their cells in that module's ``CELLS``, so all must see one
-module object.  The two
+``test_naoc_cell``, ``test_2pol_cell`` and ``test_2p30_cell`` do ``import
+test_scopes`` (its hand-built profile messages; since PR 39 a tiny cell
+names the cell it stands for in its own file and none registers in that
+module any more), so all must see one module object.  The two
 grid cases want four devices where ``tests/conftest.py`` forces eight;
 each runs in a child with the selftests' own ``XLA_FLAGS``.
 
@@ -32,6 +33,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SELFTEST = os.path.join(ROOT, "benchmark", "selftest")
 sys.path[:0] = [ROOT, SELFTEST]
 
+import test_2p30_cell  # noqa: E402
 import test_2pol_cell  # noqa: E402
 import test_gen  # noqa: E402
 import test_naoc_cell  # noqa: E402
@@ -39,6 +41,7 @@ import test_reference  # noqa: E402
 import test_run  # noqa: E402
 import test_scopes  # noqa: E402
 import test_trace  # noqa: E402
+from test_2p30_cell import staged_at_2p16  # noqa: E402,F401  (fixture)
 from test_reference import raw  # noqa: E402,F401  (fixture)
 from test_scopes import tiny_with_new_entries  # noqa: E402,F401  (fixture)
 
@@ -49,7 +52,7 @@ FOUR_DEVICES = ("test_grid_cell_on_four_virtual_devices",
 DRAIN_CASE = "test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull"
 
 for _mod in (test_gen, test_reference, test_trace, test_scopes,
-             test_naoc_cell, test_2pol_cell, test_run):
+             test_naoc_cell, test_2pol_cell, test_2p30_cell, test_run):
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_") and callable(_obj) \
                 and _name not in FOUR_DEVICES + (DRAIN_CASE,):

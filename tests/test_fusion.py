@@ -119,7 +119,10 @@ def test_fused_vs_unfused_staged(monkeypatch):
     on = _run(_cfg(fused_tail="on"), staged=True)
     assert off[0].staged and on[0].staged
     assert not off[0].fused_tail and on[0].fused_tail
-    assert on[0].plan_name == off[0].plan_name + "+ftail"
+    # the unfused staged plan walks its boundary in blocks of rows
+    # ("+rows"); the fused tail keeps the whole-plane stage (b)
+    assert off[0].plan_name.endswith("+rows")
+    assert on[0].plan_name == off[0].plan_name[:-len("+rows")] + "+ftail"
     assert on[0].chirp is None and on[0].chirp_w is None
     _assert_parity(off, on, atol_scale=1e-3)
 

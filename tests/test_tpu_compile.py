@@ -239,3 +239,45 @@ def test_staged_plan_three_programs_compile(one_chip):
     assert set(compiled) == {"stage_a", "stage_b", "stage_c"}
     for name, c in compiled.items():
         assert _device_bytes(c) < V5E_HBM_BYTES, name
+
+
+def test_the_2p30_plan_holds_no_temporary_of_a_whole_plane(one_chip,
+                                                           monkeypatch):
+    """The plan a 2^30-sample segment resolves to by itself
+    (``staged:...+rows``), at 2^22 samples / 2^5 channels with the size
+    rules patched down and ``fft_len_cap`` lowered so a row's transform
+    is the four-step inside a block that the big shape's is: each of the
+    three programs walks the boundary in blocks, so its temporaries stay
+    under ONE float32 plane of the spectrum (n/2 x 4 bytes; the
+    whole-plane spellings held six and seven of them at 2^30 and were
+    refused), stages (b) and (c) alias the donated boundary, and every
+    program compiles in seconds (at 2^30 / 2^11: 0.41 / 0.04 / 0.40 GB
+    of temporaries against a 2.15 GB plane, 5 / 4 / 9 s;
+    ``benchmark/selftest/aot_compile.py j1644_2p30.replay_quiet``)."""
+    import time
+
+    from srtb_tpu.pipeline import segment
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    log2n = 22
+    monkeypatch.setattr(segment, "STAGED_MIN_N", 1 << log2n)
+    monkeypatch.setattr(segment, "FUSED_TAIL_DF64_MAX_SPECTRUM", 1 << 10)
+    proc = SegmentProcessor(
+        _j1644(log2n, spectrum_channel_count=1 << 5, fft_len_cap=1 << 9,
+               baseband_reserve_sample=False,
+               mitigate_rfi_freq_list="1418-1422"),
+        donate_input=True)
+    assert proc.plan_name == "staged:monolithic+rows"
+    assert proc.staged_rows == 8 and proc.rfi_mask is None
+    plane = (1 << (log2n - 1)) * 4
+    t0 = time.perf_counter()
+    compiled = _compile_all(proc, one_chip)
+    assert time.perf_counter() - t0 < 120
+    assert set(compiled) == {"stage_a", "stage_b", "stage_c"}
+    for name, c in compiled.items():
+        m = c.memory_analysis()
+        assert m.temp_size_in_bytes < plane, (name, m)
+    for name in ("stage_b", "stage_c"):
+        m = compiled[name].memory_analysis()
+        assert m.alias_size_in_bytes >= 2 * plane, (name, m)
+
