@@ -2244,6 +2244,23 @@ class Pipeline:
         self.close()
 
 
+@dataclass
+class _GridStep:
+    """One step of the ``--dm_list`` loop between its pull and its
+    record: the loop owns ``seg``'s host buffer for as long as the step
+    is in ``DMSearchPipeline.run``'s window."""
+    index: int
+    seg: SegmentWork
+    pool: object            # where seg.data goes back to; None: nowhere
+    trace_id: int
+    stages: dict            # this segment's own five spans, seconds
+    result: object = None   # device handles, once the step is enqueued
+
+    def release(self) -> None:
+        if self.pool is not None:
+            self.pool.release(self.seg.data)
+
+
 class DMSearchPipeline:
     """Streaming DM search: every segment runs the full multi-chip
     (dm x seq)-sharded step (parallel.segment_dist) over a DM trial grid
@@ -2252,14 +2269,29 @@ class DMSearchPipeline:
     reference leaves as a TODO ("DM search list for unknown source",
     ref: config.hpp:129-132), made practical by chip-parallel trials.
 
+    The loop keeps a window of ``Config.inflight_segments`` steps (the
+    option ``Pipeline`` reads for its own window; 2 by default): segment
+    k+1 is pulled, uploaded and its step enqueued while the chips run
+    step k, and only then are step k's results fetched, its record
+    written and flushed, its span journalled.  The jit call returns with
+    the step still running, so the pull (``readinto`` gives up the
+    interpreter lock) and the upload run under the chips' time on the
+    loop's own thread.  Records come one a segment, in hand-over order,
+    each flushed as it is written.  ``inflight_segments = 1`` is the
+    serial loop, step for step: ingest -> h2d -> enqueue -> fetch ->
+    record with nothing overlapped.
+
     Who owns a segment's host buffer: the loop, from the pull to the
-    end of that segment's fetch.  ``stage_input`` returns with the
-    uploads pending and they read the buffer; once the step's results
-    are back every chip has consumed its input, and the loop returns
-    the buffer to the source's pool (file input with a pooled reader,
-    the condition ``Pipeline`` uses) so the next pull fills a warm
-    block.  Every way out of the step gives the buffer back: the
-    segment ``max_segments`` drops, and a step that raises.
+    end of *that segment's* fetch, so up to ``inflight_segments``
+    buffers are out at once.  ``stage_input`` returns with the uploads
+    pending and they read the buffer; once the step's results are back
+    every chip has consumed its input, and the loop returns the buffer
+    to the source's pool (file input with a pooled reader, the
+    condition ``Pipeline`` uses) so a later pull fills a warm block.
+    Every way out gives every buffer back: the segment ``max_segments``
+    drops, the steps in flight at the source's end or at
+    ``max_segments`` (retired: fetched and recorded), and the steps in
+    flight when any of them raises (abandoned).
     """
 
     def __init__(self, cfg: Config, source=None, mesh=None):
@@ -2288,116 +2320,150 @@ class DMSearchPipeline:
         self.trials_path = cfg.baseband_output_file_prefix + \
             "dm_trials.jsonl"
         self.stats = PipelineStats()
-        # the same spans, timer and journal as Pipeline: the loop's
-        # five host stages (ingest, h2d, enqueue, fetch, record) are
-        # flat siblings — nothing here overlaps
+        # the same spans, timer and journal as Pipeline: each segment
+        # has its own five host stages (ingest, h2d, enqueue, fetch,
+        # record), flat siblings on the loop's thread; what overlaps is
+        # the chips' step k with the first three of segment k+1
         self.stage_timer = _metrics_stage_timer()
         self.journal = telemetry.SpanJournal.from_config(cfg)
 
     def run(self, max_segments: int | None = None) -> PipelineStats:
-        import json
-
         cfg = self.cfg
         timer = self.stage_timer
         proc = self.processor
         start = time.perf_counter()
+        depth = max(1, int(cfg.inflight_segments))
         # multi-controller runs: summaries are replicated, so only the
-        # first process records them (all write identical content)
+        # first process records them (all write identical content);
+        # every process makes the same device calls in the same order
         write_records = jax.process_index() == 0
         it = iter(self.source)
+        window: collections.deque[_GridStep] = collections.deque()
+        before = self.stats.segments
+        ahead = 0
+        pool = None
         with open(self.trials_path if write_records else os.devnull,
                   "a") as trials_file:
-            pool = None
-            for i in itertools.count():
-                with stage_span("ingest", timer) as sp:
-                    seg = next(it, None)
+            try:
+                for i in itertools.count():
+                    with stage_span("ingest", timer) as sp:
+                        seg = next(it, None)
+                        if seg is None:
+                            sp.cancel()
                     if seg is None:
-                        sp.cancel()
-                if seg is None:
-                    break
-                # looked up at the pull: a source may swap its reader
-                # between passes and drop it when it ends
-                pool = getattr(self.source, "pool", None) \
-                    if cfg.input_file_path else None
-                try:
-                    if max_segments is not None and i >= max_segments:
                         break
-                    stages = {"ingest": sp.seconds}
-                    tid = _stamp_trace_id(seg)
+                    # looked up at the pull: a source may swap its
+                    # reader between passes and drop it when it ends
+                    pool = getattr(self.source, "pool", None) \
+                        if cfg.input_file_path else None
+                    step = _GridStep(i, seg, pool, _stamp_trace_id(seg),
+                                     {"ingest": sp.seconds})
+                    if max_segments is not None and i >= max_segments:
+                        step.release()
+                        break
+                    window.append(step)
+                    tid = step.trace_id
                     with stage_span("h2d", timer, tid) as sp:
                         staged = proc.stage_input(seg.data)
-                    stages["h2d"] = sp.seconds
+                    step.stages["h2d"] = sp.seconds
                     with stage_span("enqueue", timer, tid) as sp:
-                        res = proc.process(staged)
-                    stages["enqueue"] = sp.seconds
-                    # reduce over (stream, boxcar) axes -> per-dm
-                    # quantities; every device transfer runs under the
-                    # fail-fast deadline (a wedged device blocks
-                    # transfers, not just compute)
-                    with stage_span("fetch", timer, tid) as sp:
-                        peaks, counts, zero = sync_with_deadline(
-                            cfg.segment_deadline_s,
-                            lambda: (jax.device_get(res.snr_peaks),
-                                     jax.device_get(res.signal_counts),
-                                     jax.device_get(res.zero_count)))
-                    stages["fetch"] = sp.seconds
-                finally:
-                    # not before the fetch: until the results are back
-                    # a pending upload may still read the buffer, and
-                    # the next pull would zero and refill it
-                    if pool is not None:
-                        pool.release(seg.data)
-                n_dm = len(self.dm_list)
-                peaks = peaks.reshape(n_dm, -1)
-                counts = counts.reshape(n_dm, -1)
-                zero = zero.reshape(n_dm, -1).max(axis=-1)
-                ok = zero < (cfg.signal_detect_channel_threshold
-                             * cfg.spectrum_channel_count)
-                fired = counts.sum(axis=-1) > 0
-                # rank trials by raw peak SNR: a matched trial concentrates
-                # the pulse and may trip the SK zap gate, which only means
-                # "be cautious", not "not the best DM"
-                best = int(np.argmax(peaks.max(axis=-1)))
-                record = {
-                    "segment": i,
-                    "timestamp": seg.timestamp,
-                    "best_dm": self.dm_list[best],
-                    "best_snr": float(peaks[best].max()),
-                    "dm_list": self.dm_list,
-                    "peak_snr": peaks.max(axis=-1).tolist(),
-                    "signal_counts": counts.sum(axis=-1).tolist(),
-                    "zero_counts": zero.tolist(),
-                }
-                with stage_span("record", timer, tid) as sp:
-                    trials_file.write(json.dumps(record) + "\n")
-                    trials_file.flush()
-                stages["record"] = sp.seconds
-                positive = bool((ok & fired).any())
-                if positive:
-                    self.stats.signals += 1
-                    log.info(f"[dm_search] segment {i}: best dm "
-                             f"{record['best_dm']} "
-                             f"snr {record['best_snr']:.1f}")
-                self.stats.segments += 1
-                self.stats.samples += cfg.baseband_input_count
-                metrics.add("segments")
-                metrics.add("samples", cfg.baseband_input_count)
-                metrics.window("segments").add(1)
-                metrics.window("samples").add(cfg.baseband_input_count)
-                telemetry.mark_segment()  # /healthz liveness
-                if self.journal is not None:
-                    self.journal.write(telemetry.segment_span(
-                        i, stages, 0, int(counts.sum()), positive,
-                        cfg.baseband_input_count,
-                        timestamp_ns=getattr(seg, "timestamp", 0),
-                        trace_id=tid))
+                        step.result = proc.process(staged)
+                    step.stages["enqueue"] = sp.seconds
+                    if len(window) > 1:
+                        # the chips still hold an earlier step: this
+                        # one waits in their queue, not for the host
+                        ahead += 1
+                        metrics.add("grid_steps_ahead")
+                    while len(window) >= depth:
+                        self._retire(window.popleft(), trials_file)
+                while window:
+                    self._retire(window.popleft(), trials_file)
+            finally:
+                # the steps in flight when one raised: abandoned (a
+                # step _retire took has had its buffer given back)
+                for step in window:
+                    step.release()
         self.stats.elapsed_s = time.perf_counter() - start
+        line = (f"[dm_search] {self.stats.segments} segments, "
+                f"{self.stats.segments - before} of them in this run at "
+                f"window {depth}: grid_steps_ahead {ahead}")
         if pool is not None:
             ps = pool.stats()
-            log.info(f"[dm_search] {self.stats.segments} segments; "
-                     f"reader pool {pool.name}: {ps['acquires']} "
+            line += (f"; reader pool {pool.name}: {ps['acquires']} "
                      f"acquires, {ps['new_blocks']} new blocks")
+        log.info(line)
         return self.stats
+
+    def _retire(self, step: _GridStep, trials_file) -> None:
+        """The oldest step of the window: its results fetched, its host
+        buffer given back, its record written and flushed, its span
+        journalled."""
+        import json
+
+        cfg = self.cfg
+        timer = self.stage_timer
+        seg, tid, stages, res = (step.seg, step.trace_id, step.stages,
+                                 step.result)
+        try:
+            # reduce over (stream, boxcar) axes -> per-dm quantities;
+            # every device transfer runs under the fail-fast deadline
+            # (a wedged device blocks transfers, not just compute)
+            with stage_span("fetch", timer, tid) as sp:
+                peaks, counts, zero = sync_with_deadline(
+                    cfg.segment_deadline_s,
+                    lambda: (jax.device_get(res.snr_peaks),
+                             jax.device_get(res.signal_counts),
+                             jax.device_get(res.zero_count)))
+            stages["fetch"] = sp.seconds
+        finally:
+            # not before its own fetch: until the results are back a
+            # pending upload may still read the buffer, and a later
+            # pull would refill it
+            step.release()
+        n_dm = len(self.dm_list)
+        peaks = peaks.reshape(n_dm, -1)
+        counts = counts.reshape(n_dm, -1)
+        zero = zero.reshape(n_dm, -1).max(axis=-1)
+        ok = zero < (cfg.signal_detect_channel_threshold
+                     * cfg.spectrum_channel_count)
+        fired = counts.sum(axis=-1) > 0
+        # rank trials by raw peak SNR: a matched trial concentrates
+        # the pulse and may trip the SK zap gate, which only means
+        # "be cautious", not "not the best DM"
+        best = int(np.argmax(peaks.max(axis=-1)))
+        record = {
+            "segment": step.index,
+            "timestamp": seg.timestamp,
+            "best_dm": self.dm_list[best],
+            "best_snr": float(peaks[best].max()),
+            "dm_list": self.dm_list,
+            "peak_snr": peaks.max(axis=-1).tolist(),
+            "signal_counts": counts.sum(axis=-1).tolist(),
+            "zero_counts": zero.tolist(),
+        }
+        with stage_span("record", timer, tid) as sp:
+            trials_file.write(json.dumps(record) + "\n")
+            trials_file.flush()
+        stages["record"] = sp.seconds
+        positive = bool((ok & fired).any())
+        if positive:
+            self.stats.signals += 1
+            log.info(f"[dm_search] segment {step.index}: best dm "
+                     f"{record['best_dm']} "
+                     f"snr {record['best_snr']:.1f}")
+        self.stats.segments += 1
+        self.stats.samples += cfg.baseband_input_count
+        metrics.add("segments")
+        metrics.add("samples", cfg.baseband_input_count)
+        metrics.window("segments").add(1)
+        metrics.window("samples").add(cfg.baseband_input_count)
+        telemetry.mark_segment()  # /healthz liveness
+        if self.journal is not None:
+            self.journal.write(telemetry.segment_span(
+                step.index, stages, 0, int(counts.sum()), positive,
+                cfg.baseband_input_count,
+                timestamp_ns=getattr(seg, "timestamp", 0),
+                trace_id=tid))
 
     def close(self) -> None:
         if self.journal is not None:

@@ -341,6 +341,8 @@ class Metrics:
                             "instead of receiving them again",
         "chirp_bank_bytes": "DM-grid chirp bank bytes resident a chip "
                             "(0 = generated in every step)",
+        "grid_steps_ahead": "DM-grid steps enqueued while an earlier "
+                            "step's results were not yet fetched",
         "data_streams": "Data streams (polarisations) split from each "
                         "segment on the device",
         "ring_cold_dispatches": "Ingest-ring cold (full-upload) "

@@ -254,18 +254,31 @@ def _pooled_reader(search, pool):
     return BasebandFileReader(search.cfg, buffer_pool=pool)
 
 
-def test_dm_search_returns_reader_buffers(grid, tmp_path):
+def _at_depth(monkeypatch, search, depth):
+    """``inflight_segments`` for the runs of one test: ``run()`` reads
+    it off ``search.cfg`` when it starts."""
+    monkeypatch.setattr(search, "cfg",
+                        search.cfg.replace(inflight_segments=depth))
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_dm_search_returns_reader_buffers(grid, tmp_path, monkeypatch,
+                                          depth):
     """A file of 6 segments through a pool of its own: the loop hands
-    every buffer back, so the reader allocates once and fills the same
-    warm block again; the records are those of a source with no pool
-    (which runs as it always did), timestamps apart."""
+    every buffer back, so the reader allocates one block for every step
+    of its window and fills the same warm blocks again; the records are
+    those of a source with no pool (which runs as it always did),
+    timestamps apart."""
+    _at_depth(monkeypatch, grid, depth)
     pool = BufferPool("t")
     got = _grid_run(grid, _pooled_reader(grid, pool), tmp_path / "a")
     stats = pool.stats()
     assert grid.stats.segments == GRID_SEGMENTS
     # one per pull and one for the reader's look past the file's end
     assert stats["acquires"] == GRID_SEGMENTS + 1
-    assert stats["new_blocks"] <= 2
+    # one block a step of the window: the pull comes with one step
+    # fewer in flight than the window holds
+    assert stats["new_blocks"] == depth
     assert stats["in_use"] == 0 and stats["cached_blocks"] >= 1
     want = _grid_run(grid, _PoolLessSource(grid.cfg), tmp_path / "b")
     assert grid.stats.segments == GRID_SEGMENTS
@@ -276,13 +289,39 @@ def test_dm_search_returns_reader_buffers(grid, tmp_path):
         assert a == b
 
 
+def test_dm_search_window_writes_the_serial_loops_records(
+        grid, tmp_path, monkeypatch):
+    """The records of the 6-segment file with one step in flight are
+    those of the serial loop field for field (timestamps apart), in
+    hand-over order, one a segment."""
+    runs = {}
+    for depth in (1, 2):
+        _at_depth(monkeypatch, grid, depth)
+        runs[depth] = _grid_run(grid, _pooled_reader(grid, BufferPool("t")),
+                                tmp_path / f"depth{depth}")
+        assert grid.stats.segments == GRID_SEGMENTS
+    for recs in runs.values():
+        assert [r.pop("segment") for r in recs] == list(
+            range(GRID_SEGMENTS))
+        stamps = [r.pop("timestamp") for r in recs]
+        assert stamps == sorted(stamps)
+    assert runs[2] == runs[1]
+    assert set(runs[1][0]) == {"best_dm", "best_snr", "dm_list",
+                               "peak_snr", "signal_counts", "zero_counts"}
+
+
+@pytest.mark.parametrize("depth", [1, 2])
 def test_dm_search_releases_after_its_own_fetch(grid, tmp_path,
-                                                monkeypatch):
+                                                monkeypatch, depth):
     """Order: the uploads ``stage_input`` starts may still be reading
     the buffer until the step's results are back, so each segment's
-    release follows the return of its own fetch and nothing of the pool
-    is touched between the pull and that return."""
+    release follows the return of its own fetch, and a buffer is never
+    handed out again between its pull and that return.  In the serial
+    loop nothing of the pool is touched in between; with a step in
+    flight the one thing in between is the next segment's pull, into
+    another block."""
     from srtb_tpu.pipeline import runtime
+    _at_depth(monkeypatch, grid, depth)
     events = []
     real = runtime.sync_with_deadline
 
@@ -295,26 +334,54 @@ def test_dm_search_releases_after_its_own_fetch(grid, tmp_path,
     monkeypatch.setattr(runtime, "sync_with_deadline", spy_sync)
     _grid_run(grid, _pooled_reader(grid, _SpyPool(events)),
               tmp_path / "a")
-    # the reader's last acquire finds the file read and gives it back
-    tail = events[-2:]
-    assert [e for e, _ in tail] == ["acquire", "release"]
-    steps = events[:-2]
-    assert len(steps) == 4 * GRID_SEGMENTS
-    for k in range(GRID_SEGMENTS):
-        (e0, a0), (e1, _), (e2, _), (e3, a3) = steps[4 * k:4 * k + 4]
-        assert (e0, e1, e2, e3) == ("acquire", "fetch", "fetched",
-                                    "release"), (k, steps)
-        assert a0 == a3
+    assert len(events) == 4 * GRID_SEGMENTS + 2
+    # the reader's last acquire finds the file read and gives it back:
+    # the last two events in the serial loop, ahead of the last step's
+    # fetch where that step waits in the window
+    look = next(k for k in range(len(events) - 1)
+                if events[k][0] == "acquire"
+                and events[k + 1] == ("release", events[k][1]))
+    assert look == len(events) - 2 - 3 * (depth - 1)
+    steps = events[:look] + events[look + 2:]
+    names = [e for e, _ in steps]
+    if depth == 1:
+        assert names == ["acquire", "fetch", "fetched",
+                         "release"] * GRID_SEGMENTS
+    else:
+        assert names == ["acquire"] + ["acquire", "fetch", "fetched",
+                                       "release"] * (GRID_SEGMENTS - 1) \
+            + ["fetch", "fetched", "release"]
+    # segment k's block is the k-th acquired and the k-th released, and
+    # it is out (not acquired again) until its own fetch has returned
+    acquired = [a for e, a in steps if e == "acquire"]
+    released = [a for e, a in steps if e == "release"]
+    assert acquired == released
+    out = set()
+    fetched = 0
+    for e, a in steps:
+        if e == "acquire":
+            assert a not in out, steps
+            out.add(a)
+        elif e == "fetched":
+            fetched += 1
+        elif e == "release":
+            assert a == acquired[fetched - 1]
+            out.discard(a)
+    assert not out and len(set(acquired)) == depth
 
 
+@pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("way_out", ["max_segments", "stage_input",
                                      "process", "fetch"])
 def test_dm_search_every_way_out_gives_the_buffer_back(
-        grid, tmp_path, monkeypatch, way_out):
-    """The segment pulled and dropped by ``max_segments``, and a segment
-    in hand when the upload, the step or the fetch raises: the buffer
-    is back in the pool and the exception is the caller's."""
+        grid, tmp_path, monkeypatch, way_out, depth):
+    """The segment pulled and dropped by ``max_segments`` (with a step
+    in flight, which is retired: exactly that many records), and the
+    segments in hand when the upload, the step or the fetch raises
+    (with a step in flight there are two: both go back): every buffer
+    is in the pool again and the exception is the caller's."""
     from srtb_tpu.pipeline import runtime
+    _at_depth(monkeypatch, grid, depth)
 
     class Boom(RuntimeError):
         pass
@@ -334,6 +401,7 @@ def test_dm_search_every_way_out_gives_the_buffer_back(
     if way_out == "max_segments":
         recs = _grid_run(grid, reader, tmp_path / "a", max_segments=2)
         assert len(recs) == 2 and grid.stats.segments == 2
+        assert [r["segment"] for r in recs] == [0, 1]
         # the third segment was pulled, dropped and handed back
         assert pool.stats()["acquires"] == 3
     else:
@@ -347,10 +415,91 @@ def test_dm_search_every_way_out_gives_the_buffer_back(
                                     getattr(grid.processor, way_out)))
         with pytest.raises(Boom, match=way_out):
             _grid_run(grid, reader, tmp_path / "a")
-        assert grid.stats.segments == 1
-        assert pool.stats()["acquires"] == 2
+        with open(tmp_path / "a") as f:
+            written = len(f.readlines())
+        if way_out == "fetch" or depth == 1:
+            # the second fetch is segment 1's: segment 0 is on record;
+            # with a step in flight segment 2 is in hand beside it
+            assert grid.stats.segments == written == 1
+            assert pool.stats()["acquires"] == depth + 1
+        else:
+            # segment 1's upload or step raises with segment 0's step
+            # still in flight: abandoned with it, nothing on record
+            assert grid.stats.segments == written == 0
+            assert pool.stats()["acquires"] == 2
     stats = pool.stats()
-    assert stats["in_use"] == 0 and stats["new_blocks"] == 1
+    assert stats["in_use"] == 0 and stats["new_blocks"] == depth
+
+
+class _RecordingProcessor:
+    """Stands in for the grid's processor: writes ("process", k) when
+    step k is enqueued and ("fetched", k) when something first reads one
+    of step k's results, which the loop does in its fetch."""
+
+    class _Handle:
+        def __init__(self, events, k, value):
+            self._events, self._k, self._value = events, k, value
+
+        def __array__(self, dtype=None, copy=None):
+            if ("fetched", self._k) not in self._events:
+                self._events.append(("fetched", self._k))
+            return self._value
+
+    def __init__(self, events, n_dm):
+        self.events, self.n_dm, self._k = events, n_dm, 0
+
+    def stage_input(self, raw):
+        return raw
+
+    def process(self, staged):
+        from srtb_tpu.parallel.segment_dist import DistSegmentResult
+        k, self._k = self._k, self._k + 1
+        self.events.append(("process", k))
+        zeros = np.zeros((self.n_dm, 1, 1), np.float32)
+        return DistSegmentResult(
+            zero_count=self._Handle(self.events, k, zeros[..., 0]),
+            signal_counts=self._Handle(self.events, k, zeros),
+            snr_peaks=self._Handle(self.events, k, zeros + k),
+            time_series=None)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_dm_search_enqueues_ahead_of_the_fetch(grid, tmp_path, monkeypatch,
+                                               depth):
+    """With one step in flight ``process(k+1)`` is called before step
+    k's results are fetched; in the serial loop after.  The counter
+    ``grid_steps_ahead`` says how often a step was enqueued behind one
+    not yet fetched: segments - 1 a ``run()``, and 0."""
+    import io
+
+    from srtb_tpu.utils.logging import LEVEL_INFO, log
+    from srtb_tpu.utils.metrics import metrics
+    _at_depth(monkeypatch, grid, depth)
+    events = []
+    monkeypatch.setattr(grid, "processor",
+                        _RecordingProcessor(events, len(grid.dm_list)))
+    before = metrics.get("grid_steps_ahead")
+    lines = io.StringIO()
+    monkeypatch.setattr(log, "stream", lines)
+    monkeypatch.setattr(log, "level", max(log.level, LEVEL_INFO))
+    recs = _grid_run(grid, _PoolLessSource(grid.cfg), tmp_path / "a")
+    assert [r["segment"] for r in recs] == list(range(GRID_SEGMENTS))
+    assert [r["best_snr"] for r in recs] == list(range(GRID_SEGMENTS))
+    for k in range(GRID_SEGMENTS - 1):
+        enqueued_first = events.index(("process", k + 1)) \
+            < events.index(("fetched", k))
+        assert enqueued_first == (depth == 2), (k, events)
+        # never two steps ahead: step k's results are back before
+        # segment k+2 is enqueued
+        if k + 2 < GRID_SEGMENTS:
+            assert events.index(("fetched", k)) \
+                < events.index(("process", k + 2))
+    ahead = (GRID_SEGMENTS - 1) * (depth - 1)
+    assert metrics.get("grid_steps_ahead") - before == ahead
+    # the loop's closing line carries the run's count
+    assert lines.getvalue().splitlines()[-1].endswith(
+        f"[dm_search] {GRID_SEGMENTS} segments, {GRID_SEGMENTS} of them "
+        f"in this run at window {depth}: grid_steps_ahead {ahead}")
 
 
 def test_dist_segment_two_streams():

@@ -404,11 +404,13 @@ def test_writer_pool_wait_is_the_candidates_write_time(tmp_path):
 
 # ------------------------------------------------ (c) the DM search loop
 
-def test_dm_search_loop_spans_and_journal(tmp_path):
+@pytest.mark.parametrize("depth", [1, 2])
+def test_dm_search_loop_spans_and_journal(tmp_path, depth):
     metrics.reset()
     journal = str(tmp_path / "grid.jsonl")
     segments = 3
     cfg = _cfg(
+        inflight_segments=depth,
         baseband_reserve_sample=False, dm=30.0, use_emulated_fp64=True,
         dm_list=[0.0, 10.0, 20.0, 30.0, 40.0, 50.0, 60.0, 70.0],
         n_devices=4,
@@ -440,10 +442,13 @@ def test_dm_search_loop_spans_and_journal(tmp_path):
         assert rec["samples"] == N
         # v12's fields are the served path's: this loop counts by trial
         assert "streams" not in rec and "detections_by_stream" not in rec
-    upload = [b["h2d_bytes"] - a["h2d_bytes"]
-              for a, b in zip(recs, recs[1:])]
-    # the segment is replicated over the four dm-rows of the mesh
-    assert upload == [4 * N] * (segments - 1)
+    # the segment is replicated over the four dm-rows of the mesh; the
+    # counter is read when a record is written, and with a step in
+    # flight the next segment is uploaded before that: every record
+    # but the last then counts one upload more than its own
+    assert [r["h2d_bytes"] for r in recs] == [
+        4 * N * min(k + depth, segments) for k in range(segments)]
+    assert metrics.get("grid_steps_ahead") == (segments - 1) * (depth - 1)
     with open(search.trials_path) as f:
         trials = [json.loads(ln) for ln in f]
     assert len(trials) == segments and trials[0]["best_dm"] == 30.0
