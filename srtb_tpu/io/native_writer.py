@@ -27,6 +27,7 @@ import numpy as np
 
 from srtb_tpu.utils import termination
 from srtb_tpu.utils.logging import log
+from srtb_tpu.utils.tracing import span
 
 _LIB_PATH = os.path.join(os.path.dirname(__file__), "..", "native",
                          "libsrtb_writer.so")
@@ -44,11 +45,18 @@ def _load_native():
         ctypes.c_void_p, ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint8),
         ctypes.c_uint64, ctypes.c_int32, ctypes.c_int32]
     lib.srtb_writer_drain.argtypes = [ctypes.c_void_p]
-    for name in ("srtb_writer_jobs_done", "srtb_writer_bytes_written",
-                 "srtb_writer_errors"):
-        fn = getattr(lib, name)
-        fn.restype = ctypes.c_uint64
-        fn.argtypes = [ctypes.c_void_p]
+    try:
+        for name in ("srtb_writer_jobs_done", "srtb_writer_bytes_written",
+                     "srtb_writer_errors", "srtb_writer_write_ns"):
+            fn = getattr(lib, name)
+            fn.restype = ctypes.c_uint64
+            fn.argtypes = [ctypes.c_void_p]
+    except AttributeError:
+        # a library built from an older file_writer.cpp: the Python
+        # pool serves until ``make -C srtb_tpu/native`` rebuilds it
+        log.warning(f"[writer_pool] {_LIB_PATH} is stale (no "
+                    "srtb_writer_write_ns): using the Python pool")
+        return None
     lib.srtb_writer_destroy.argtypes = [ctypes.c_void_p]
     return lib
 
@@ -145,6 +153,7 @@ class AsyncWriterPool:
         self._py_errors = 0
         self._py_jobs = 0
         self._py_bytes = 0
+        self._py_file_s = 0.0
         # native pool only: manifest commit callbacks deferred to the
         # drain barrier (see submit); _done_err_base is the error
         # count the pending batch started from
@@ -180,7 +189,7 @@ class AsyncWriterPool:
 
     def submit(self, path: str, data, *, fsync: bool = False,
                append: bool = False, on_done=None,
-               pre_publish=None) -> None:
+               pre_publish=None, timer=None, trace_id: int = 0) -> None:
         """Queue one write. ``data`` is bytes or a numpy array; it is
         copied at submission, so the caller may reuse its buffer.
 
@@ -209,7 +218,14 @@ class AsyncWriterPool:
         ``RunManifest.sync``) runs between the worker's temp write and
         its atomic rename on the Python pool; the native C++ pool
         renames in C++, so the barrier runs AT SUBMIT instead — the
-        intent is durable before the job exists."""
+        intent is durable before the job exists.
+
+        ``timer`` / ``trace_id`` (the submitting pipeline's StageTimer
+        and the segment's causal id) go to the span ``file`` that the
+        Python pool's thread opens around the job's open, write, flush,
+        fdatasync and rename.  The native pool's threads open no span:
+        they add their seconds to a counter, and ``stats()`` gives the
+        sum under ``file_seconds`` for either pool."""
         if append and self.n_threads > 1:
             raise ValueError(
                 "append=True needs n_threads=1 (ordered appends)")
@@ -246,31 +262,33 @@ class AsyncWriterPool:
             self._futures = [f for f in self._futures
                              if not f.done() or f.exception() is not None]
             fut = self._pool.submit(self._py_write, path, payload, fsync,
-                                    append, on_done, pre_publish)
+                                    append, on_done, pre_publish,
+                                    span("file", timer, trace_id))
             self._futures.append(fut)
 
     def _py_write(self, path: str, payload: bytes, fsync: bool,
-                  append: bool, on_done=None, pre_publish=None) -> None:
+                  append: bool, on_done, pre_publish, file_span) -> None:
         # accounting must run for ANY exception type, or the backpressure
         # window shrinks permanently and later submits block forever
         ok = False
         try:
-            if append:
-                with open(path, "ab") as f:
-                    f.write(payload)
-                    f.flush()
-                    if fsync:
-                        os.fdatasync(f.fileno())
-            else:
-                # crash-consistent like the synchronous writer path
-                # (shared helper: temp + flush (+ fdatasync) + atomic
-                # rename, torn temp dropped on failure) so a worker
-                # dying mid-write leaves an orphan temp (swept at
-                # startup by io.writers.recover_orphan_temps), not a
-                # torn file.  Appends stay in-place by nature.
-                from srtb_tpu.io.writers import atomic_write
-                atomic_write(path, payload, fsync=fsync,
-                             pre_rename=pre_publish)
+            with file_span:
+                if append:
+                    with open(path, "ab") as f:
+                        f.write(payload)
+                        f.flush()
+                        if fsync:
+                            os.fdatasync(f.fileno())
+                else:
+                    # crash-consistent like the synchronous writer path
+                    # (shared helper: temp + flush (+ fdatasync) + atomic
+                    # rename, torn temp dropped on failure) so a worker
+                    # dying mid-write leaves an orphan temp (swept at
+                    # startup by io.writers.recover_orphan_temps), not a
+                    # torn file.  Appends stay in-place by nature.
+                    from srtb_tpu.io.writers import atomic_write
+                    atomic_write(path, payload, fsync=fsync,
+                                 pre_rename=pre_publish)
             # manifest commit, only once the bytes durably landed; a
             # failing commit (the WAL append itself errored) leaves
             # the artifact uncommitted — rolled back + regenerated on
@@ -286,6 +304,7 @@ class AsyncWriterPool:
         finally:
             with self._space:
                 self._py_jobs += 1
+                self._py_file_s += file_span.seconds
                 if ok:
                     self._py_bytes += len(payload)
                 else:
@@ -352,11 +371,14 @@ class AsyncWriterPool:
                 "jobs_done": self._lib.srtb_writer_jobs_done(self._h),
                 "bytes_written": self._lib.srtb_writer_bytes_written(self._h),
                 "errors": self._lib.srtb_writer_errors(self._h),
+                "file_seconds":
+                    self._lib.srtb_writer_write_ns(self._h) * 1e-9,
             }
         with self._lock:
             return {"jobs_done": self._py_jobs,
                     "bytes_written": self._py_bytes,
-                    "errors": self._py_errors}
+                    "errors": self._py_errors,
+                    "file_seconds": self._py_file_s}
 
     def close(self, drain: bool = True) -> None:
         """``drain=False`` abandons queued/stuck writes instead of

@@ -301,11 +301,20 @@ class WriteSignalSink:
         # the candidate path's spans (utils/tracing.span): the
         # pipeline binds its StageTimer and takes, after each push,
         # the seconds this sink spent in ``d2h`` / ``write`` /
-        # ``publish`` for the segment's journal record; a quiet push
-        # opens none
+        # ``publish`` for the segment's journal record, and inside
+        # ``write`` in its children: ``format`` (making each payload),
+        # then with a pool ``submit`` (the pool's copy of the payload
+        # and its wait for queue space) and ``drain`` (the wait for the
+        # pool's threads), without one ``file`` (temp, write, flush,
+        # fdatasync, rename: the pool's threads open the same span
+        # where they write).  A quiet push opens none
         self.stage_timer = None
         self._spans: dict[str, float] = {}
         self._span_tid = 0
+        # bytes this push handed to the writers, and the pool's summed
+        # ``file`` seconds when it began to (take_candidate)
+        self._candidate_bytes = 0
+        self._file_s_mark = 0.0
         # check directory writability up front (ref: write_signal_pipe.hpp:62-75)
         check_path = cfg.baseband_output_file_prefix + ".check"
         with open(check_path, "wb"):
@@ -334,6 +343,24 @@ class WriteSignalSink:
         spans, self._spans = self._spans, {}
         return spans
 
+    def take_candidate(self) -> dict:
+        """For the dumping segment's journal record, beside its
+        ``stages_ms``: ``candidate_bytes``, the bytes this push handed
+        to the writers, and with a pool ``writer_file_ms``, the seconds
+        its threads spent writing files since this push began to hand
+        them over, summed over the threads (concurrent with the
+        segment's stages, like ``device_ms``; complete where something
+        drained the pool before the record was taken, else what has
+        landed so far).  {} after a quiet push."""
+        if not self._candidate_bytes:
+            return {}
+        out = {"candidate_bytes": self._candidate_bytes}
+        self._candidate_bytes = 0
+        if self.pool is not None:
+            out["writer_file_ms"] = 1e3 * (
+                self.pool.stats()["file_seconds"] - self._file_s_mark)
+        return out
+
     # ------------------------------------------------------------------
 
     def _overlap_window_ns(self) -> float:
@@ -352,6 +379,7 @@ class WriteSignalSink:
         # a wait that a drain outside any push left behind (the
         # checkpoint's flush) is in the timer, not in a later record
         self._spans = {}
+        self._candidate_bytes = 0
         self._span_tid = getattr(work.segment, "trace_id", 0)
         real_time = self.cfg.input_file_path == ""
         w = self._overlap_window_ns()
@@ -428,6 +456,8 @@ class WriteSignalSink:
             self._inflight_npy = {}
         self.last_push_wrote = True
         log.info(f"[write_signal] begin writing, file_counter = {counter}")
+        if self.pool is not None:
+            self._file_s_mark = self.pool.stats()["file_seconds"]
 
         # open the segment transaction: synchronous manifest-armed
         # writes stage temps and publish together after one barrier
@@ -463,9 +493,9 @@ class WriteSignalSink:
         """``wf``: the segment's waterfall already on the host, or
         None where the segment has none."""
         bin_path = base + ".bin"
-        self._write_bytes(bin_path,
-                          np.ascontiguousarray(work.segment.data),
-                          fsync=self.fdatasync)
+        with self._span("format"):
+            payload = np.ascontiguousarray(work.segment.data)
+        self._write_bytes(bin_path, payload, fsync=self.fdatasync)
 
         npy_paths = []
         if wf is not None:
@@ -492,10 +522,11 @@ class WriteSignalSink:
                         j += 1
                     path = f"{base}.{j}.npy"
                     self._inflight_npy[i] = path
-                self._write_bytes(
-                    path, _npy_complex64(planes[0, i], planes[1, i])
-                    if planes is not None
-                    else _npy_bytes(wf[i].astype(np.complex64)))
+                with self._span("format"):
+                    payload = _npy_complex64(planes[0, i], planes[1, i]) \
+                        if planes is not None \
+                        else _npy_bytes(wf[i].astype(np.complex64))
+                self._write_bytes(path, payload)
                 npy_paths.append(path)
 
         tim_paths = []
@@ -516,8 +547,9 @@ class WriteSignalSink:
                         path = (f"{base}.s{s}.{b}.tim" if multi
                                 else f"{base}.{b}.tim")
                         valid = series.shape[-1] - (b if b > 1 else 0)
-                        self._write_bytes(
-                            path, series[s, bi, :valid].astype("<f4"))
+                        with self._span("format"):
+                            payload = series[s, bi, :valid].astype("<f4")
+                        self._write_bytes(path, payload)
                         tim_paths.append(path)
 
         # registered-mode hook (the registry contract): a detect
@@ -532,7 +564,8 @@ class WriteSignalSink:
         if extra is not None:
             for path, payload in extra(base):
                 if path.endswith(".npy"):
-                    payload = _npy_bytes(payload)
+                    with self._span("format"):
+                        payload = _npy_bytes(payload)
                 self._write_bytes(path, payload)
                 fold_paths.append(path)
 
@@ -577,11 +610,13 @@ class WriteSignalSink:
 
     def _write_bytes(self, path: str, data: np.ndarray, *,
                      fsync: bool = False) -> None:
+        self._candidate_bytes += int(data.nbytes)
         commit = manifest_stage(self.manifest, self._manifest_key,
                                 path, data)
         barrier = self.manifest.sync if commit is not None else None
         if self._tx_staged is not None:
-            tmp = stage_write(path, _payload(data), fsync=fsync)
+            with self._span("file"):
+                tmp = stage_write(path, _payload(data), fsync=fsync)
             self._tx_staged.append((path, tmp, fsync, commit))
             return
         if self.pool is not None:
@@ -589,16 +624,21 @@ class WriteSignalSink:
                 # same target queued again (e.g. a piggybacked segment
                 # sharing a packet counter): flush first so the later
                 # write deterministically wins instead of racing
-                self.pool.drain()
+                with self._span("drain"):
+                    self.pool.drain()
                 self._assigned_paths.clear()
             self._assigned_paths.add(path)
-            self.pool.submit(path, data, fsync=fsync, on_done=commit,
-                             pre_publish=barrier)
+            with self._span("submit"):
+                self.pool.submit(path, data, fsync=fsync, on_done=commit,
+                                 pre_publish=barrier,
+                                 timer=self.stage_timer,
+                                 trace_id=self._span_tid)
             return
         # crash-consistent: a crash mid-write leaves an orphan temp
         # (swept at startup), never a torn candidate file
-        atomic_write(path, _payload(data), fsync=fsync,
-                     pre_rename=barrier)
+        with self._span("file"):
+            atomic_write(path, _payload(data), fsync=fsync,
+                         pre_rename=barrier)
         if commit is not None:
             commit()
 
@@ -612,11 +652,14 @@ class WriteSignalSink:
         """
         if self.pool is not None:
             # the wait for the pool's writers is the candidate's
-            # write time too: it goes to the segment whose push (or a
-            # later sink of the same push) drains.  Nothing queued,
-            # no span: quiet segments pay nothing
-            with self._span("write") if self._assigned_paths \
-                    else contextlib.nullcontext():
+            # write time too (its child ``drain``): it goes to the
+            # segment whose push (or a later sink of the same push)
+            # drains.  Nothing queued, no span: quiet segments pay
+            # nothing
+            if self._assigned_paths:
+                with self._span("write"), self._span("drain"):
+                    self.pool.drain()
+            else:
                 self.pool.drain()
             self._assigned_paths.clear()
             self.pool.raise_new_errors(

@@ -17,6 +17,7 @@
 #include <unistd.h>
 
 #include <atomic>
+#include <chrono>
 #include <cstdio>
 #include <condition_variable>
 #include <cstdint>
@@ -53,6 +54,10 @@ struct WriterPool {
   std::atomic<uint64_t> jobs_done{0};
   std::atomic<uint64_t> bytes_written{0};
   std::atomic<uint64_t> errors{0};
+  // wall-clock nanoseconds the workers spent inside write_one (open,
+  // write, fdatasync, close, rename), summed over the threads: what the
+  // Python pool's threads record as their ``file`` spans
+  std::atomic<uint64_t> write_ns{0};
 
   void worker() {
     for (;;) {
@@ -64,7 +69,12 @@ struct WriterPool {
         job = std::move(jobs.front());
         jobs.pop_front();
       }
+      const auto t0 = std::chrono::steady_clock::now();
       if (!write_one(job)) errors.fetch_add(1);
+      write_ns.fetch_add((uint64_t)std::chrono::duration_cast<
+                         std::chrono::nanoseconds>(
+                             std::chrono::steady_clock::now() - t0)
+                             .count());
       jobs_done.fetch_add(1);
       {
         std::lock_guard<std::mutex> lk(mu);
@@ -196,6 +206,9 @@ uint64_t srtb_writer_bytes_written(WriterPool* pool) {
   return pool->bytes_written.load();
 }
 uint64_t srtb_writer_errors(WriterPool* pool) { return pool->errors.load(); }
+uint64_t srtb_writer_write_ns(WriterPool* pool) {
+  return pool->write_ns.load();
+}
 
 // Drain, stop the workers and free the pool.
 //

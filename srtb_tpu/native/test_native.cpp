@@ -50,6 +50,7 @@ int32_t srtb_writer_submit(WriterPool*, const char*, const uint8_t*,
 void srtb_writer_drain(WriterPool*);
 uint64_t srtb_writer_bytes_written(WriterPool*);
 uint64_t srtb_writer_errors(WriterPool*);
+uint64_t srtb_writer_write_ns(WriterPool*);
 void srtb_writer_destroy(WriterPool*);
 }
 
@@ -162,6 +163,7 @@ int test_writer() {
   srtb_writer_drain(w);
   CHECK(srtb_writer_errors(w) == 0);
   CHECK(srtb_writer_bytes_written(w) == 16 * data.size());
+  CHECK(srtb_writer_write_ns(w) > 0);  // the workers' summed write time
   srtb_writer_destroy(w);
   FILE* f = std::fopen(path, "rb");
   CHECK(f);

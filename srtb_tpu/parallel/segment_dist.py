@@ -50,6 +50,7 @@ from srtb_tpu.ops import unpack as U
 from srtb_tpu.ops import window as W
 from srtb_tpu.parallel import dist_fft as DF
 from srtb_tpu.parallel import dm_grid
+from srtb_tpu.utils import tracing
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
 
@@ -116,9 +117,17 @@ class DistSegmentProcessor:
 
     def __init__(self, cfg: Config, mesh: Mesh, dm_list=None,
                  chirp_on_device: bool | None = None,
-                 window_name: str = W.DEFAULT_WINDOW):
+                 window_name: str = W.DEFAULT_WINDOW,
+                 stage_timer: "tracing.StageTimer | None" = None):
         self.cfg = cfg
         self.mesh = mesh
+        # the loop's timer (None standing alone): ``chirp_bank`` and
+        # the two programs' ``first_dispatch`` spans go to it
+        self.stage_timer = stage_timer
+        # program -> seconds of its first dispatch, as the served
+        # processor keeps them (``grid_bank``, ``grid_step``): the
+        # books of utils/tracing.first_dispatch
+        self.first_dispatch_s: dict[str, float] = {}
         self.fmt = formats.resolve(cfg.baseband_format_type)
         self.n_seq = mesh.shape["seq"]
         self.n_dm_devices = mesh.shape["dm"]
@@ -191,19 +200,28 @@ class DistSegmentProcessor:
                 # with the same arguments the in-step arm evaluates on
                 # every segment.  One local trial after another
                 # (lax.map): vmapped, this program alone compiles for
-                # the chip in 200 s at 2^27 where the loop takes 3
-                self.chirp_bank = jax.jit(shard_map(
+                # the chip in 200 s at 2^27 where the loop takes 3.
+                # Waited for inside its spans, so ``chirp_bank`` and
+                # ``compile_seconds{program="grid_bank"}`` hold its
+                # compile AND its run
+                bank_program = jax.jit(shard_map(
                     lambda pairs: jax.lax.map(trial_chirp, pairs),
                     mesh=mesh, in_specs=P("dm", None),
-                    out_specs=bank_sharding.spec))(dm_pairs)
+                    out_specs=bank_sharding.spec))
+                with tracing.span("chirp_bank", stage_timer):
+                    self.chirp_bank = tracing.first_dispatch(
+                        self.first_dispatch_s, "grid_bank",
+                        lambda: jax.block_until_ready(
+                            bank_program(dm_pairs)), stage_timer)
             else:
                 self.chirp_bank, chirp_in_step = dm_pairs, trial_chirp
                 bank_bytes = 0
         else:
-            self.chirp_bank = _put_sharded(
-                np.asarray(dm_grid.build_chirp_bank(
-                    self.dm_list, self.n_spectrum, f_min, df, f_c)),
-                bank_sharding)
+            with tracing.span("chirp_bank", stage_timer):
+                self.chirp_bank = jax.block_until_ready(_put_sharded(
+                    np.asarray(dm_grid.build_chirp_bank(
+                        self.dm_list, self.n_spectrum, f_min, df, f_c)),
+                    bank_sharding))
         metrics.set("chirp_bank_bytes", bank_bytes)
         how = "df64 on the device" if chirp_on_device \
             else "float64 on the host"
@@ -420,5 +438,6 @@ class DistSegmentProcessor:
         args = [raw, self.chirp_bank, self.rfi_mask]
         if self.window is not None:
             args.append(self.window)
-        out = self._step(*args)
-        return DistSegmentResult(*out)
+        return DistSegmentResult(*tracing.first_dispatch(
+            self.first_dispatch_s, "grid_step",
+            lambda: self._step(*args), self.stage_timer))

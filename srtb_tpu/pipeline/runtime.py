@@ -61,6 +61,7 @@ journal field ``active_plan`` (schema v4).
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import os
 import threading
@@ -91,6 +92,44 @@ def _metrics_stage_timer() -> StageTimer:
     return StageTimer(
         on_stage=lambda name, dt: metrics.histogram(
             "stage_seconds", labels={"stage": name}).observe(dt))
+
+
+def _under_construct_span(init):
+    """An ``__init__`` that runs whole under the span ``construct`` of a
+    timer made first (``self.stage_timer``: every completed timing also
+    lands in a bounded histogram, so /metrics carries live p50/p95/p99
+    per stage), so what the constructor builds can open spans of its
+    own inside it: the processor's ``chirp_bank``."""
+    @functools.wraps(init)
+    def construct(self, *args, **kwargs):
+        self.stage_timer = _metrics_stage_timer()
+        self._setup_logged = False
+        with stage_span("construct", self.stage_timer):
+            init(self, *args, **kwargs)
+    return construct
+
+
+def _log_setup_once(pipe) -> None:
+    """At the end of the first ``run()``, ``[setup] construct X s
+    (chirp_bank Y s); first dispatches: ring_cold A s, ring B s``: the
+    line an operator reads after a restart to see which program
+    recompiled.  The seconds are the timer's ``construct`` /
+    ``chirp_bank`` spans and the processor's books of its programs'
+    first dispatches; they add up to the journal's ``compile_ms`` where
+    one processor served the run."""
+    if pipe._setup_logged:
+        return
+    pipe._setup_logged = True
+    timer = pipe.stage_timer
+    bank = timer.totals.get("chirp_bank")
+    first = getattr(pipe.processor, "first_dispatch_s", {})
+    log.info(
+        f"[setup] construct {timer.totals.get('construct', 0.0):.2f} s ("
+        + ("no chirp_bank: the plan makes its chirp in the step"
+           if bank is None else f"chirp_bank {bank:.2f} s")
+        + "); first dispatches: "
+        + (", ".join(f"{k} {v:.2f} s" for k, v in first.items())
+           or "none"))
 
 
 def _stamp_trace_id(seg) -> int:
@@ -242,6 +281,7 @@ class _DeadlineArray:
 class Pipeline:
     """File (or any SegmentWork iterator) to sinks."""
 
+    @_under_construct_span
     def __init__(self, cfg: Config, source=None, sinks=None,
                  keep_waterfall: bool = True, processor=None):
         self.cfg = cfg
@@ -256,7 +296,12 @@ class Pipeline:
             from srtb_tpu.pipeline import registry
             from srtb_tpu.utils.platform import on_accelerator
             processor = registry.build_processor(
-                cfg, donate_input=on_accelerator())
+                cfg, donate_input=on_accelerator(),
+                stage_timer=self.stage_timer)
+        elif getattr(processor, "stage_timer", False) is None:
+            # a processor built elsewhere with no timer of its own: its
+            # first dispatches are this pipeline's to account
+            processor.stage_timer = self.stage_timer
         self.processor = processor
         metrics.set("data_streams", formats.resolve(
             cfg.baseband_format_type).data_stream_count)
@@ -343,6 +388,11 @@ class Pipeline:
                     from srtb_tpu.io.native_writer import AsyncWriterPool
                     self._owned_writer_pool = AsyncWriterPool(
                         cfg.writer_thread_count)
+                    log.info(
+                        "[writer_pool] candidates go to "
+                        f"{self._owned_writer_pool.n_threads} "
+                        + ("native" if self._owned_writer_pool.is_native
+                           else "python") + " writer thread(s)")
                 sinks = [WriteSignalSink(
                     cfg, writer_pool=self._owned_writer_pool)]
         self.sinks = sinks
@@ -422,9 +472,6 @@ class Pipeline:
         if cfg.baseband_output_file_prefix:
             from srtb_tpu.io.writers import recover_orphan_temps
             recover_orphan_temps(cfg.baseband_output_file_prefix)
-        # every completed host-stage timing also lands in a bounded
-        # histogram, so /metrics carries live p50/p95/p99 per stage
-        self.stage_timer = _metrics_stage_timer()
         for s in self.sinks:
             bind = getattr(s, "bind_stage_timer", None)
             if bind is not None:
@@ -513,7 +560,8 @@ class Pipeline:
                         n_samples: int,
                         overlap_hidden_s: float | None = None,
                         inflight_depth: int | None = None,
-                        device_s: float | None = None) -> None:
+                        device_s: float | None = None,
+                        candidate: dict | None = None) -> None:
         """Per-drained-segment telemetry: lifetime counters, sliding
         window rates (segments/s and samples/s over the last 10 s — a
         stall is visible immediately, unlike the lifetime average), the
@@ -608,7 +656,11 @@ class Pipeline:
                 # (stamped by the fleet at placement and re-stamped
                 # by a live migration); absent outside a fleet
                 device=getattr(self, "device_label", None),
-                detections_by_stream=det_by_stream))
+                detections_by_stream=det_by_stream,
+                # v13: ``candidate_bytes`` / ``writer_file_ms``, what
+                # the candidate writer counted on a segment that dumped
+                # (_take_sink_spans); {} on a quiet one
+                **(candidate or {})))
 
     # ---------------------------------------------- async segment engine
 
@@ -661,7 +713,8 @@ class Pipeline:
                                 W.DEFAULT_WINDOW),
             staged=staged,
             donate_input=bool(getattr(self.processor, "_donate_input",
-                                      False)))
+                                      False)),
+            stage_timer=self.stage_timer)
 
     def _swap_processor(self, newp) -> None:
         """Install a replacement plan (demotion / promotion / reinit).
@@ -1189,7 +1242,7 @@ class Pipeline:
                                               done=sinks_done,
                                               seg_key=mkey))
         span["sink"] = sp.seconds
-        self._take_sink_spans(span)
+        candidate = self._take_sink_spans(span)
         if self.events is not None:
             self.events.emit("stage.sink",
                              trace=getattr(seg, "trace_id", 0),
@@ -1230,7 +1283,7 @@ class Pipeline:
                              n_samples=cfg.baseband_input_count,
                              overlap_hidden_s=hidden,
                              inflight_depth=live,
-                             device_s=device_s)
+                             device_s=device_s, candidate=candidate)
         if self.checkpoint is not None:
             # a checkpointed segment must be durable: flush queued
             # async candidate writes before recording it as done.
@@ -1252,10 +1305,13 @@ class Pipeline:
         sanitizer scope: implicit-transfer tripwire armed, thread
         owners tracked, and a leaked-thread check after the sink pipe
         joins."""
-        if self.sanitizer is None:
-            return self._run_engine(max_segments)
-        with self.sanitizer.run_scope():
-            return self._run_engine(max_segments)
+        try:
+            if self.sanitizer is None:
+                return self._run_engine(max_segments)
+            with self.sanitizer.run_scope():
+                return self._run_engine(max_segments)
+        finally:
+            _log_setup_once(self)
 
     def _run_engine(self, max_segments: int | None = None) \
             -> PipelineStats:
@@ -2139,17 +2195,23 @@ class Pipeline:
             if done is not None:
                 done.add(i)
 
-    def _take_sink_spans(self, span: dict) -> None:
+    def _take_sink_spans(self, span: dict) -> dict:
         """Add what the sinks timed inside this segment's "sink" stage
-        (a candidate writer's ``d2h`` / ``write`` / ``publish``) to its
-        ``stages_ms``.  Only a sink that wrote has any: a quiet
-        segment's record carries none."""
+        (a candidate writer's ``d2h`` / ``write`` / ``publish`` and the
+        children of ``write``) to its ``stages_ms``, and return what a
+        candidate writer counted beside them (``candidate_bytes``,
+        ``writer_file_ms``) for the record.  Only a sink that wrote has
+        any: a quiet segment's record carries none."""
+        candidate: dict = {}
         for sink in self.sinks:
             take = getattr(sink, "take_spans", None)
             if take is None:
                 continue
             for name, seconds in take().items():
                 span[name] = span.get(name, 0.0) + seconds
+            for name, value in sink.take_candidate().items():
+                candidate[name] = candidate.get(name, 0) + value
+        return candidate
 
     def _on_segment_deadline(self) -> None:  # pragma: no cover - aborts
         _abort_on_deadline(self.cfg.segment_deadline_s)
@@ -2294,6 +2356,7 @@ class DMSearchPipeline:
     flight when any of them raises (abandoned).
     """
 
+    @_under_construct_span
     def __init__(self, cfg: Config, source=None, mesh=None):
         import jax as _jax
 
@@ -2313,18 +2376,20 @@ class DMSearchPipeline:
                     break
             mesh = M.make_mesh(n_dm=n_dm, n_seq=1)
         self.mesh = mesh
-        self.processor = DistSegmentProcessor(cfg, mesh, self.dm_list)
+        self.processor = DistSegmentProcessor(
+            cfg, mesh, self.dm_list, stage_timer=self.stage_timer)
         if source is None:
             source = BasebandFileReader(cfg)
         self.source = source
         self.trials_path = cfg.baseband_output_file_prefix + \
             "dm_trials.jsonl"
         self.stats = PipelineStats()
-        # the same spans, timer and journal as Pipeline: each segment
-        # has its own five host stages (ingest, h2d, enqueue, fetch,
-        # record), flat siblings on the loop's thread; what overlaps is
-        # the chips' step k with the first three of segment k+1
-        self.stage_timer = _metrics_stage_timer()
+        # the same spans, timer and journal as Pipeline: ``construct``
+        # around this constructor (the grid processor's ``chirp_bank``
+        # inside it), then each segment's own five host stages (ingest,
+        # h2d, enqueue, fetch, record), flat siblings on the loop's
+        # thread; what overlaps is the chips' step k with the first
+        # three of segment k+1
         self.journal = telemetry.SpanJournal.from_config(cfg)
 
     def run(self, max_segments: int | None = None) -> PipelineStats:
@@ -2392,6 +2457,7 @@ class DMSearchPipeline:
             line += (f"; reader pool {pool.name}: {ps['acquires']} "
                      f"acquires, {ps['new_blocks']} new blocks")
         log.info(line)
+        _log_setup_once(self)
         return self.stats
 
     def _retire(self, step: _GridStep, trials_file) -> None:
@@ -2483,10 +2549,13 @@ class ThreadedPipeline(Pipeline):
         # (transfer tripwire + leaked-thread check); the per-stage
         # thread-ownership guards don't apply to this engine — every
         # stage owning its own thread IS the design here
-        if self.sanitizer is None:
-            return self._run_threaded(max_segments)
-        with self.sanitizer.run_scope():
-            return self._run_threaded(max_segments)
+        try:
+            if self.sanitizer is None:
+                return self._run_threaded(max_segments)
+            with self.sanitizer.run_scope():
+                return self._run_threaded(max_segments)
+        finally:
+            _log_setup_once(self)
 
     def _run_threaded(self, max_segments: int | None = None) \
             -> PipelineStats:
@@ -2617,7 +2686,7 @@ class ThreadedPipeline(Pipeline):
                                                   positive, done=done,
                                                   seg_key=mkey))
             span["sink"] = sp.seconds
-            self._take_sink_spans(span)
+            candidate = self._take_sink_spans(span)
             if self.events is not None:
                 self.events.emit("stage.sink",
                                  trace=getattr(seg, "trace_id", 0),
@@ -2632,7 +2701,8 @@ class ThreadedPipeline(Pipeline):
             # so qsize() alone would understate the in-flight depth
             self._record_segment(drained[0] - 1, seg, det_res, positive,
                                  span, queue_depth=q_res.qsize() + 1,
-                                 n_samples=cfg.baseband_input_count)
+                                 n_samples=cfg.baseband_input_count,
+                                 candidate=candidate)
             if self.checkpoint is not None:
                 self._op("checkpoint", seg_index,
                          lambda: (self._drain_sinks(),

@@ -20,7 +20,6 @@ Everything is batched over data streams (polarizations): shape [S, ...].
 from __future__ import annotations
 
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -256,8 +255,13 @@ class SegmentProcessor:
     def __init__(self, cfg: Config, window_name: str = W.DEFAULT_WINDOW,
                  compute_chirp_on_device: bool | None = None,
                  staged: bool | None = None,
-                 donate_input: bool = False):
+                 donate_input: bool = False,
+                 stage_timer: "tracing.StageTimer | None" = None):
         self.cfg = cfg
+        # the owning pipeline's timer (None standing alone): the spans
+        # this processor opens outside any segment go to it,
+        # ``chirp_bank`` below and each program's ``first_dispatch``
+        self.stage_timer = stage_timer
         self.fmt = formats.resolve(cfg.baseband_format_type)
         n = cfg.baseband_input_count
         if n & (n - 1):
@@ -324,21 +328,30 @@ class SegmentProcessor:
         else:
             if compute_chirp_on_device is None:
                 compute_chirp_on_device = cfg.use_emulated_fp64
-            if compute_chirp_on_device:
-                self.chirp = jax.jit(
-                    lambda: dd.chirp_factor_df64_ri(
-                        self.n_spectrum, f_min, df, f_c, cfg.dm,
-                        exact=getattr(cfg, "chirp_exact", False)))()
-            else:
-                self.chirp = jnp.asarray(dd.chirp_factor_host_ri(
-                    self.n_spectrum, f_min, df, f_c, cfg.dm))
-            if self.fused_tail:
-                # chirp·twiddle precombination: cw = chirp · w folds the
-                # Hermitian twiddle into the bank once, so the fused
-                # final pass costs one complex mul per bin and zero
-                # in-trace trig (explicit arg, not a closure capture —
-                # a captured 2 GB bank would bake into the program)
-                self.chirp_w = jax.jit(self._premul_bank)(self.chirp)
+            # one span around what makes the bank, whichever arm: the
+            # float64 phase on one host thread and its upload (14-29 s
+            # at the cells' sizes) or the df64 program, then the fused
+            # tail's precombination.  It ends when the bank is on the
+            # device, so the uploads' and the programs' time is in it
+            # and not in whatever first waits for them
+            with tracing.span("chirp_bank", stage_timer):
+                if compute_chirp_on_device:
+                    self.chirp = jax.jit(
+                        lambda: dd.chirp_factor_df64_ri(
+                            self.n_spectrum, f_min, df, f_c, cfg.dm,
+                            exact=getattr(cfg, "chirp_exact", False)))()
+                else:
+                    self.chirp = jnp.asarray(dd.chirp_factor_host_ri(
+                        self.n_spectrum, f_min, df, f_c, cfg.dm))
+                if self.fused_tail:
+                    # chirp·twiddle precombination: cw = chirp · w folds
+                    # the Hermitian twiddle into the bank once, so the
+                    # fused final pass costs one complex mul per bin and
+                    # zero in-trace trig (explicit arg, not a closure
+                    # capture — a captured 2 GB bank would bake into the
+                    # program)
+                    self.chirp_w = jax.jit(self._premul_bank)(self.chirp)
+                jax.block_until_ready((self.chirp, self.chirp_w))
 
         zap_ranges = rfi.eval_rfi_ranges(cfg.mitigate_rfi_freq_list)
         if self.staged_rows:
@@ -477,7 +490,9 @@ class SegmentProcessor:
         # execution's dispatch; the AOT protocol measures exactly in
         # aot_cache.get_or_compile instead).  Per-stream labeled twins
         # when this processor serves a named fleet lane.
-        self._dispatched_programs: set[str] = set()
+        # program family -> the seconds of its first dispatch (0.0
+        # where the AOT cache had compiled it: marked, not counted)
+        self.first_dispatch_s: dict[str, float] = {}
         # host seconds of the staged plan's three jit calls, by span
         self._stage_spans: dict[str, float] = {}
         self._metric_labels = ({"stream": cfg.stream_name}
@@ -1911,32 +1926,17 @@ class SegmentProcessor:
         first dispatch of program family ``name`` on this processor is
         where lazy jit traces+compiles, so its wall clock feeds the
         ``compile_seconds`` / ``plan_compiles`` / ``last_compile_ms``
-        metrics (per-stream twins when labeled).  An AOT-active
+        metrics (per-stream twins when labeled) and, with the family
+        kept, ``compile_seconds{program="<name>"}``
+        (utils/tracing.first_dispatch).  An AOT-active
         processor compiled in ``enable_aot`` (counted exactly there by
         the cache), so its first dispatch is marked but not counted.
         Steady-state dispatches pay one set-membership check."""
-        if name in self._dispatched_programs:
-            return fn()
-        if self.aot_active:
-            self._dispatched_programs.add(name)
-            return fn()
-        from srtb_tpu.utils.metrics import metrics
-        t0 = time.perf_counter()
-        out = fn()
-        dt = time.perf_counter() - t0
-        # marked only AFTER fn() returned: a transient failure inside
-        # the first dispatch leaves the family unmarked, so the retry
-        # (where the trace+compile actually completes) is the timed
-        # compile event instead of slipping past the books
-        self._dispatched_programs.add(name)
-        metrics.add("plan_compiles")
-        metrics.add("compile_seconds", dt)
-        metrics.set("last_compile_ms", dt * 1e3)
-        if self._metric_labels is not None:
-            metrics.add("plan_compiles", labels=self._metric_labels)
-            metrics.add("compile_seconds", dt,
-                        labels=self._metric_labels)
-        return out
+        if self.aot_active and name not in self.first_dispatch_s:
+            self.first_dispatch_s[name] = 0.0
+        return tracing.first_dispatch(
+            self.first_dispatch_s, name, fn, self.stage_timer,
+            self._metric_labels)
 
     def run_device(self, raw: jnp.ndarray):
         """Run one segment on an already-device-resident byte array,

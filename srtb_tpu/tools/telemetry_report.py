@@ -452,6 +452,29 @@ def device_stats(records: list[dict]) -> dict:
     return out
 
 
+def candidate_stats(records: list[dict]) -> dict:
+    """What the segments that dumped handed to the writers, from v13
+    spans: bytes (``candidate_bytes``), the writer pool's threads' file
+    time (``writer_file_ms``, summed over the threads) and the loop's
+    own ``write`` stage by child.  Older records carry neither field
+    and are skipped; empty dict when none qualify."""
+    v13 = [r for r in records if "candidate_bytes" in r]
+    if not v13:
+        return {}
+    out = {"records": len(v13),
+           "bytes": sum(int(r["candidate_bytes"]) for r in v13)}
+    file_ms = [float(r["writer_file_ms"]) for r in v13
+               if "writer_file_ms" in r]
+    if file_ms:
+        out["writer_file_s"] = round(sum(file_ms) / 1e3, 3)
+    for stage in ("write", "format", "submit", "drain", "file"):
+        vals = [float(r["stages_ms"][stage]) for r in v13
+                if stage in (r.get("stages_ms") or {})]
+        if vals:
+            out[f"{stage}_s"] = round(sum(vals) / 1e3, 3)
+    return out
+
+
 def report(path: str, bin_s: float = 10.0) -> dict:
     records = load(path)
     return {
@@ -465,6 +488,7 @@ def report(path: str, bin_s: float = 10.0) -> dict:
         "fleet": fleet_stats(records),
         "fleet_devices": fleet_device_stats(records),
         "device": device_stats(records),
+        "candidates": candidate_stats(records),
         "timeline": timeline(records, bin_s),
     }
 
@@ -560,6 +584,17 @@ def _md(rep: dict) -> str:
             f"{dv['plan_compiles']} first-dispatch compile(s); AOT "
             f"cache {dv['aot_cache_hits']} hit(s) / "
             f"{dv['aot_cache_misses']} miss(es)")
+    cd = rep.get("candidates") or {}
+    if cd:
+        lines += ["", "## Candidates (what the writers were handed)", "",
+                  f"{cd['records']} segment(s) dumped {cd['bytes']} "
+                  "bytes; the loop's write stage "
+                  + ", ".join(f"{k[:-2]} {cd[k]} s" for k in (
+                      "write_s", "format_s", "submit_s", "drain_s",
+                      "file_s") if k in cd)
+                  + (f"; writer threads' file time "
+                     f"{cd['writer_file_s']} s (summed, concurrent)"
+                     if "writer_file_s" in cd else "")]
     lines += ["", "## Throughput timeline", "",
               "| t (s) | segments | seg/s | Msamples/s | detections | "
               "dumps | pkts lost |", "|---|---|---|---|---|---|---|"]

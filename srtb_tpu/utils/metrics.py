@@ -390,15 +390,22 @@ class Metrics:
         "fleet_restores": "Fleet fairness restore transitions",
         "fleet_shed_streams": "Streams currently force-shed",
         "fleet_streams_total": "Streams submitted to the fleet",
-        "stage_seconds": "Per-stage host wall clock (seconds)",
+        "stage_seconds": "Per-stage host wall clock (seconds); "
+                         "outside any segment: construct, chirp_bank, "
+                         "first_dispatch",
         "device_seconds": "Per-segment dispatch-to-ready device wall "
                           "(upper bound)",
         "compile_seconds": "Cumulative trace+compile wall "
                            "(first-dispatch upper bound + AOT-miss "
-                           "compiles)",
+                           "compiles); {program=...}: the same by "
+                           "jitted program family (ring, ring_cold, "
+                           "fused, staged, grid_step, grid_bank ...), "
+                           "first dispatches only",
         "last_compile_ms": "Most recent trace+compile event "
                            "(milliseconds)",
-        "plan_compiles": "First-dispatch trace+compile events",
+        "plan_compiles": "First-dispatch trace+compile events; "
+                         "{program=...}: 1 once that program family "
+                         "was first dispatched",
         "aot_cache_hits": "AOT executable cache loads (no compile)",
         "aot_cache_misses": "AOT executable cache misses (compiled + "
                             "persisted)",

@@ -106,15 +106,32 @@ from srtb_tpu.utils.metrics import metrics
 # candidate that only one polarisation sees says which.  Both OMITTED
 # where the writer does not count by stream (the DM-search loop, whose
 # record is per trial).
-# Readers must tolerate mixed v1-v12 journals: rotation can leave an
+# v13 (the candidate's write, by child): a segment that dumps journals
+# inside ``write`` what the writers did there: ``format`` (making each
+# artifact's payload), and then either ``submit`` (the writer pool's
+# copy of the payload and its wait for queue space) and ``drain`` (the
+# wait for the pool's threads, where a sink drained it before the record
+# was taken), or without a pool (``writer_thread_count 0``) ``file``
+# (temp, write, flush, fdatasync, rename on the sink's own thread).
+# Two fields beside ``stages_ms`` on the same records:
+# ``candidate_bytes`` (bytes handed to the writers) and, with a pool,
+# ``writer_file_ms`` (seconds the pool's threads spent writing this
+# segment's files, summed over the threads: concurrent with the stages,
+# like ``device_ms``, and never inside ``stages_ms``).  All OMITTED on a
+# segment that dumps nothing.  ``compile_ms`` is unchanged; which
+# program paid it is in the registry (``compile_seconds{program=...}``)
+# and in the run's ``[setup]`` log line, not in a record.
+# Readers must tolerate mixed v1-v13 journals: rotation can leave an
 # older-schema tail in the previous generation after an upgrade.
-SPAN_SCHEMA_VERSION = 12
+SPAN_SCHEMA_VERSION = 13
 
 # child stage -> the stage it is timed inside (see the note above)
 CHILD_STAGES = {"h2d": "dispatch", "enqueue": "dispatch",
                 "enqueue_a": "enqueue", "enqueue_b": "enqueue",
                 "enqueue_c": "enqueue",
-                "d2h": "sink", "write": "sink", "publish": "sink"}
+                "d2h": "sink", "write": "sink", "publish": "sink",
+                "format": "write", "submit": "write", "drain": "write",
+                "file": "write"}
 
 
 def segment_wall(stages: dict) -> float:
@@ -324,7 +341,9 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
                  batch_size: int | None = None,
                  batch_wait_ms: float | None = None,
                  device: str | None = None,
-                 detections_by_stream=None) -> dict:
+                 detections_by_stream=None,
+                 candidate_bytes: int | None = None,
+                 writer_file_ms: float | None = None) -> dict:
     """One journal record.  ``stages_s`` maps stage name -> seconds for
     THIS segment; loss/drop counters are the cumulative registry values
     at drain time (deltas between consecutive records localize a loss
@@ -457,6 +476,13 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         by_stream = [int(c) for c in detections_by_stream]
         rec["streams"] = len(by_stream)
         rec["detections_by_stream"] = by_stream
+    if candidate_bytes is not None:
+        # v13: bytes the segment handed to the candidate writers
+        rec["candidate_bytes"] = int(candidate_bytes)
+    if writer_file_ms is not None:
+        # v13: the writer pool's threads' summed ``file`` time for this
+        # segment; beside stages_ms like device_ms, never inside it
+        rec["writer_file_ms"] = round(max(writer_file_ms, 0.0), 3)
     if trace_id:
         # v7: joins this span to its flight-recorder events (omitted
         # when tracing is off — never a fake 0)

@@ -8,7 +8,6 @@ harness in test-fft_wrappers, hand-recorded kernel timings — SURVEY.md
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 
@@ -66,23 +65,43 @@ class span:
             self.timer.record(self.name, self.seconds)
 
 
-@contextlib.contextmanager
-def device_trace(trace_dir: str):
-    """Capture a jax profiler trace to ``trace_dir`` (xprof format)."""
-    import jax
+def first_dispatch(books: dict, program: str, fn,
+                   timer: "StageTimer | None" = None,
+                   stream_labels: dict | None = None):
+    """``fn()``, with first-call accounting: ``books`` (a processor's
+    ``first_dispatch_s``) maps each jitted program family already
+    dispatched to the seconds its first call took, and a family in it
+    pays one membership check.  The first call of ``program`` (where
+    lazy jit traces, compiles or loads from the persistent cache, and
+    enqueues the first run) runs under a span ``first_dispatch`` and
+    its wall clock is booked: the unlabelled ``compile_seconds`` /
+    ``plan_compiles`` / ``last_compile_ms`` as ever, the same two
+    counters under ``{program=...}`` (their sum is the unlabelled total
+    less the AOT cache's exact compiles), and the per-stream twins of a
+    named fleet lane.  The span goes to ``timer``
+    (``stage_seconds{stage="first_dispatch"}``) and not into a record's
+    ``stages_ms``: the journal's cumulative ``compile_ms`` already says
+    which segment paid.  A call that raises is neither booked nor
+    marked: the retry, where the compile completes, is."""
+    if program in books:
+        return fn()
+    from srtb_tpu.utils.metrics import metrics
 
-    try:
-        jax.profiler.start_trace(trace_dir)
-        started = True
-        log.info(f"[tracing] jax profiler trace -> {trace_dir}")
-    except Exception as e:  # backend without profiler support
-        log.warning(f"[tracing] profiler unavailable: {e}")
-        started = False
-    try:
-        yield
-    finally:
-        if started:
-            jax.profiler.stop_trace()
+    with span("first_dispatch", timer) as sp:
+        try:
+            out = fn()
+        except BaseException:
+            sp.cancel()
+            raise
+    books[program] = dt = sp.seconds
+    metrics.set("last_compile_ms", dt * 1e3)
+    series = [None, {"program": program}]
+    if stream_labels:
+        series.append(stream_labels)
+    for labels in series:
+        metrics.add("plan_compiles", labels=labels)
+        metrics.add("compile_seconds", dt, labels=labels)
+    return out
 
 
 class ProfileCapture:
@@ -194,12 +213,13 @@ class StageTimer:
     """Accumulates wall-clock per named stage; the per-pipe-timestamp logs
     of the reference, queryable instead of grep-able.
 
-    Integrated into pipeline/runtime.py (each host stage of every
-    segment runs under ``stage()``): ``last`` holds the most recent
-    duration per stage so the caller can assemble a per-segment span,
-    and ``on_stage(name, seconds)`` (when set) feeds every completed
-    timing to the metrics histograms.  Thread-safe — the threaded
-    pipeline runs each stage on its own thread.
+    Fed by ``span`` (the one way in: ``Pipeline`` and the DM-search
+    loop hand their timer to every span they open, and to the processor
+    and the sinks they own, from ``construct`` to a candidate's
+    ``file``): ``last`` holds the most recent duration per stage, and
+    ``on_stage(name, seconds)`` (when set) feeds every completed timing
+    to the metrics histograms.  Thread-safe: the sink thread and the
+    writer pool's threads record beside the loop's.
     """
 
     def __init__(self, on_stage=None):
@@ -220,14 +240,6 @@ class StageTimer:
             self.last[name] = dt
         if self.on_stage is not None:
             self.on_stage(name, dt)
-
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, time.perf_counter() - t0)
 
     def summary(self) -> dict:
         with self._lock:

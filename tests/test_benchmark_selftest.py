@@ -24,7 +24,11 @@ k+1 comes a step BEFORE k's record by design (3 ms of this CPU alone,
 10-70 ms beside five busy workers), so this file's own
 ``test_grid_cell_on_four_virtual_devices`` runs the same cell the same
 way and asserts what the selftest asserts but for that line, until a
-``benchmark`` PR rewords it.
+``benchmark`` PR rewords it.  And three cases hold a cell's per-layer
+metrics against another cell's (``METRIC_SET_CASES``; the third against
+its tiny relative's, whose ``BENCHMARK.json`` a PR that changes the
+program may not edit): they hold as written over the metrics they were
+written for, and this file says what PR 42's eight add to each cell.
 """
 
 import itertools
@@ -47,24 +51,37 @@ import test_naoc_cell  # noqa: E402
 import test_reference  # noqa: E402
 import test_run  # noqa: E402
 import test_scopes  # noqa: E402
+import test_setup_spans  # noqa: E402
 import test_trace  # noqa: E402
 from test_2p30_cell import staged_at_2p16  # noqa: E402,F401  (fixture)
 from test_reference import raw  # noqa: E402,F401  (fixture)
 from test_scopes import tiny_with_new_entries  # noqa: E402,F401  (fixture)
+from test_setup_spans import (  # noqa: E402,F401  (fixtures)
+    empty_registry, staged_root_listing_the_bank)
 
 # run in a child on four virtual devices
-FOUR_DEVICES = ("test_grid_rehearsal_reports_its_five_stages",)
+FOUR_DEVICES = ("test_grid_rehearsal_reports_its_five_stages",
+                "test_the_grid_reports_construction_bank_and_first_dispatches")
 
 # this file's own spellings of two selftest cases (the docstring)
 DRAIN_CASE = "test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull"
 GRID_CELL_CASE = "test_grid_cell_on_four_virtual_devices"
 
+# the cases that pin one cell's set of per-layer metrics to another's
+METRIC_SET_CASES = {
+    "test_the_two_stream_cells_files_load_through_spec": test_2pol_cell,
+    "test_the_2p30_cells_files_load_through_spec": test_2p30_cell,
+    "test_the_tiny_relative_stands_for_the_cell": test_2p30_cell,
+}
+
 for _mod in (test_gen, test_reference, test_trace, test_scopes,
-             test_naoc_cell, test_2pol_cell, test_2p30_cell, test_run):
+             test_naoc_cell, test_2pol_cell, test_2p30_cell, test_run,
+             test_setup_spans):
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_") and callable(_obj) \
                 and _name not in FOUR_DEVICES + (DRAIN_CASE,
-                                                 GRID_CELL_CASE):
+                                                 GRID_CELL_CASE) \
+                and _name not in METRIC_SET_CASES:
             assert _name not in globals(), _name
             globals()[_name] = _obj
 
@@ -91,6 +108,32 @@ def test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull(
         lambda self, lines, timeout=10.0: wait_for(self, next(calls),
                                                    timeout))
     getattr(test_run, DRAIN_CASE)(tmp_path)
+
+
+@pytest.mark.parametrize("case", sorted(METRIC_SET_CASES))
+def test_a_cells_metrics_against_another_cells(case, monkeypatch):
+    """The selftest's own case over the 28 metrics it was written for
+    (it counts 21 for the two-stream cell, and for the 2^30 cell the
+    flagship's less the ring's two plus the three programs; the tiny
+    staged root lists what the 2^30 cell listed then), then what PR 42
+    appended: the two-stream cell gets what the one-stream cell
+    gets, the 2^30 cell everything the flagship gets but
+    ``plan.chirp_bank_s``, since its plan holds no bank."""
+    from benchmark import spec as spec_mod
+
+    new = test_setup_spans.NEW
+    metrics = spec_mod.Spec.metrics
+    with monkeypatch.context() as patch:
+        patch.setattr(
+            spec_mod.Spec, "metrics",
+            lambda self, kind: [(m, r) for m, r in metrics(self, kind)
+                                if m["name"] not in new])
+        getattr(METRIC_SET_CASES[case], case)()
+    flagship, two_pol, big = (test_setup_spans.listed(cell) for cell in (
+        "j1644_2p27.replay_quiet", "j1644_2pol_2p27.replay_quiet",
+        "j1644_2p30.replay_quiet"))
+    assert len(flagship) == 7 and two_pol == flagship
+    assert big == flagship - {"plan.chirp_bank_s"}
 
 
 def _four_device_env() -> dict:
@@ -189,7 +232,7 @@ def test_every_span_key_the_benchmark_reads_is_journalled(capsys):
     for s in spans:
         missing = (set(SPAN_KEYS) | counters) - set(s)
         assert not missing, (missing, s)
-        assert s["v"] == 12 and "plan_compiles" in s
+        assert s["v"] == 13 and "plan_compiles" in s
         # the second yardstick's fields do not grow back
         assert not {"roofline_frac", "achieved_msamps"} & set(s), s
     journalled = set().union(*(s["stages_ms"] for s in spans))

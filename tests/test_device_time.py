@@ -54,7 +54,7 @@ def test_device_accounting_v8_spans_and_gauges(tmp_path):
     recs = TR.load(journal)
     assert len(recs) == 4
     for r in recs:
-        assert r["v"] == 12
+        assert r["v"] == 13
         assert r["device_ms"] > 0
         assert not set(GONE) & set(r), r
         assert r["aot_cache_hits"] == 0 and r["aot_cache_misses"] == 0
@@ -122,7 +122,7 @@ def test_threaded_pipeline_omits_unmeasured_device_time(tmp_path):
     recs = TR.load(journal)
     assert len(recs) == stats.segments >= 2
     for r in recs:
-        assert r["v"] == 12
+        assert r["v"] == 13
         assert "device_ms" not in r
         assert "compile_ms" in r and "plan_compiles" in r
 

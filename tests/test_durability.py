@@ -596,7 +596,7 @@ def test_py_pool_fires_on_done_after_write(tmp_path):
 def test_telemetry_v5_and_report(crash_env, tmp_path):
     from srtb_tpu.tools import telemetry_report as TR
     from srtb_tpu.utils.telemetry import SPAN_SCHEMA_VERSION
-    assert SPAN_SCHEMA_VERSION == 12
+    assert SPAN_SCHEMA_VERSION == 13
     tmp, proc, n, segments, _golden = crash_env
     journal = str(tmp_path / "j.jsonl")
     cfg = _cfg(tmp, "tele", telemetry_journal_path=journal)
@@ -604,7 +604,7 @@ def test_telemetry_v5_and_report(crash_env, tmp_path):
     recs = TR.load(journal)
     assert recs
     for r in recs:
-        assert r["v"] == 12
+        assert r["v"] == 13
         for k in ("recovered_segments", "replayed_skips",
                   "rolled_back_intents"):
             assert k in r, (k, r)

@@ -172,7 +172,7 @@ def test_pipeline_causal_chain_across_threads(tmp_path):
         assert chain[3]["thread"].startswith("sink_drain")
     # v8 journal spans join the recorder on trace_id
     recs = TR.load(journal)
-    assert [r["v"] for r in recs] == [12] * stats.segments
+    assert [r["v"] for r in recs] == [13] * stats.segments
     assert sorted(r["trace_id"] for r in recs) == sorted(by_trace)
     # the run-end dump landed for the exporter
     assert os.path.exists(str(tmp_path / "events.jsonl"))

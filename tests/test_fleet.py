@@ -326,7 +326,7 @@ def test_fleet_victim_oom_isolated(tmp_path):
     # v7 journals: stream-stamped; per-stream attribution fields
     for t in bbs:
         recs = [json.loads(line) for line in open(jp[t])]
-        assert all(r["v"] == 12 and r["stream"] == t for r in recs)
+        assert all(r["v"] == 13 and r["stream"] == t for r in recs)
         want = 1 if t == "s1" else 0
         assert recs[-1]["plan_demotions"] == want, t
 
@@ -549,9 +549,9 @@ def test_fleet_prometheus_labels(tmp_path):
 def test_span_schema_v7_stream_field():
     from srtb_tpu.utils.telemetry import (SPAN_SCHEMA_VERSION,
                                           segment_span)
-    assert SPAN_SCHEMA_VERSION == 12
+    assert SPAN_SCHEMA_VERSION == 13
     rec = segment_span(0, {"ingest": 0.01}, 1, 0, False, 4)
-    assert rec["v"] == 12 and "stream" not in rec
+    assert rec["v"] == 13 and "stream" not in rec
     metrics.set("plan_demotions", 7)  # global; must NOT leak into a
     metrics.add("plan_demotions", 2, labels={"stream": "x"})
     rec = segment_span(0, {"ingest": 0.01}, 1, 0, False, 4,

@@ -156,7 +156,7 @@ def test_batched_fleet_matches_solo_within_vmap_tolerance(tmp_path):
     sizes = []
     for t in tags:
         for r in _journal(jp[t]):
-            assert r["v"] == 12 and r["stream"] == t
+            assert r["v"] == 13 and r["stream"] == t
             if "batch_size" in r:
                 sizes.append(r["batch_size"])
                 assert r["batch_size"] == 2
@@ -423,7 +423,7 @@ def test_shed_prefers_unbatched_within_band():
 
 
 def test_span_v10_batch_fields_omitted_when_solo():
-    assert telemetry.SPAN_SCHEMA_VERSION == 12
+    assert telemetry.SPAN_SCHEMA_VERSION == 13
     rec = telemetry.segment_span(0, {"dispatch": 0.1}, 0, 0, False,
                                  1024)
     assert "batch_size" not in rec and "batch_wait_ms" not in rec

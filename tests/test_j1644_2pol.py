@@ -259,7 +259,7 @@ def test_both_streams_against_the_float64_oracle(workdir, plan):
         assert (row["counts"].sum(axis=-1) > 0).tolist() \
             == [k == PULSED] * 2
     span = run["spans"][PULSED]
-    assert span["v"] == 12 and span["streams"] == 2
+    assert span["v"] == 13 and span["streams"] == 2
     assert span["detections_by_stream"] \
         == run["rows"][PULSED]["counts"].sum(axis=-1).tolist()
     assert span["detections"] == sum(span["detections_by_stream"]) > 0

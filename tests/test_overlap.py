@@ -120,7 +120,7 @@ def test_overlapped_engine_bit_identical_to_serial(synth_file, tmp_path):
     # v2+v3+v4 schema fields present (v4 adds the compute-health
     # counters)
     for r in o_recs:
-        assert r["v"] == 12
+        assert r["v"] == 13
         assert "overlap_hidden_ms" in r
         assert r["inflight_depth"] >= 1
         assert r["degrade_level"] == 0 and r["retries"] == 0

@@ -536,7 +536,7 @@ def test_quality_journal_and_report_tools(tmp_path, capsys):
     with Pipeline(cfg, sinks=[]) as pipe:
         pipe.run()
     spans = _journal_spans(journal)
-    assert all(r["v"] == 12 and "quality" in r for r in spans)
+    assert all(r["v"] == 13 and "quality" in r for r in spans)
     q = spans[0]["quality"]
     assert set(q) >= {"zap_frac", "bandpass_mean", "sk_max",
                       "drift_score", "occupancy", "bandpass"}

@@ -404,7 +404,7 @@ def test_all_six_sites_one_run_acceptance(synth_file, shared_processor,
     recs = TR.load(cfg.telemetry_journal_path)
     assert len(recs) == stats.segments
     for r in recs:
-        assert r["v"] == 12
+        assert r["v"] == 13
         for key in ("degrade_level", "retries", "requeues", "restarts",
                     "shed_waterfalls", "shed_baseband"):
             assert key in r, (key, r)

@@ -331,7 +331,7 @@ def test_oom_at_dispatch_demotes_and_recovers(synth_file, tmp_path,
     assert metrics.get("plan_ladder_level") == 1
     # v4 journal: counters + the active-plan timeline
     recs = TR.load(jpath)
-    assert recs and all(r["v"] == 12 for r in recs)
+    assert recs and all(r["v"] == 13 for r in recs)
     assert recs[-1]["plan_demotions"] == 1
     assert recs[-1]["plan_ladder_level"] == 1
     plans = {r.get("active_plan") for r in recs}

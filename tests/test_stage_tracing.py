@@ -9,11 +9,19 @@ search loop, journaled under the same names.
 (c) the DM search loop records its five stages once a segment, and one
     ``segment_span`` a segment where it has a journal path; the grid's
     processor takes a segment the loop already uploaded;
-(d) a span outside a profiler session costs microseconds.
+(d) a span outside a profiler session costs microseconds;
+(e) the set-up path is named too (ISSUE 42): ``construct`` with
+    ``chirp_bank`` inside it, each program's ``first_dispatch`` booked
+    under its family, the candidate's ``write`` by child with the bytes
+    handed over; and the window's segments open the spans they opened
+    before, no more.
 """
 
+import collections
 import contextlib
+import glob
 import json
+import os
 import re
 import time
 
@@ -382,16 +390,8 @@ def test_writer_pool_wait_is_the_candidates_write_time(tmp_path):
                telemetry_journal_path=journal, writer_thread_count=2,
                signal_detect_signal_noise_threshold=8.0)
 
-    class Drainer:
-        def __init__(self, pipe):
-            self.pipe = pipe
-
-        def push(self, work, positive):
-            if positive:
-                self.pipe.sinks[0].drain()
-
     with Pipeline(cfg) as pipe:
-        pipe.sinks.append(Drainer(pipe))
+        pipe.sinks.append(_Drainer(pipe))
         pipe.run()
         assert pipe.stage_timer.summary()["write"]["count"] >= 2
     recs = TR.load(journal)
@@ -428,7 +428,8 @@ def test_dm_search_loop_spans_and_journal(tmp_path, depth):
     assert stats.segments == segments
     timer = search.stage_timer.summary()
     five = ("ingest", "h2d", "enqueue", "fetch", "record")
-    assert set(timer) == set(five)
+    assert set(timer) == set(five) | {"construct", "chirp_bank",
+                                      "first_dispatch"}
     for stage in five:
         assert timer[stage]["count"] == segments, stage
     recs = TR.load(journal)
@@ -550,3 +551,310 @@ def test_span_is_cheap_outside_a_profiler_session():
                 pass
         best = min(best, (time.perf_counter() - t0) / 1000)
     assert best < 20e-6, f"{best * 1e6:.1f} us a span"
+
+
+# ------------------------------------------- (e) the set-up path's spans
+
+@pytest.fixture()
+def logged(monkeypatch):
+    """What the program's logger wrote, as one string when called."""
+    import io
+
+    from srtb_tpu.utils.logging import log
+
+    stream = io.StringIO()
+    monkeypatch.setattr(log, "stream", stream)
+    return stream.getvalue
+
+
+def _pulsed_cfg(tmp_path, segments=3, **extra):
+    return _cfg(input_file_path=_baseband(tmp_path, segments, 0.05,
+                                          pulse_at=N // 2),
+                baseband_output_file_prefix=str(tmp_path / "out_"),
+                telemetry_journal_path=str(tmp_path / "journal.jsonl"),
+                signal_detect_signal_noise_threshold=8.0, **extra)
+
+
+class _Drainer:
+    """A last sink that waits for the candidate's files, as the
+    checkpoint does and the benchmark's last sink."""
+
+    def __init__(self, pipe):
+        self.pipe = pipe
+
+    def push(self, work, positive):
+        if positive:
+            self.pipe.sinks[0].drain()
+
+
+WRITE_PATHS = {
+    # how the files are written -> (options, the children of ``write``)
+    "native_pool": (dict(writer_thread_count=2),
+                    {"format", "submit", "drain"}),
+    "python_pool": (dict(writer_thread_count=2),
+                    {"format", "submit", "drain"}),
+    "no_pool": (dict(writer_thread_count=0), {"format", "file"}),
+    # the staged transaction: temps first, one barrier, then renames
+    "no_pool_manifest": (dict(writer_thread_count=0), {"format", "file"}),
+}
+
+
+@pytest.mark.parametrize("how", sorted(WRITE_PATHS))
+def test_the_candidates_write_by_child(tmp_path, monkeypatch, how):
+    from srtb_tpu.io import native_writer
+
+    options, children = WRITE_PATHS[how]
+    if how == "native_pool" and not native_writer.native_available():
+        pytest.skip("libsrtb_writer.so not built")
+    if how == "python_pool":
+        monkeypatch.setattr(native_writer, "_NATIVE", None)
+    if how == "no_pool_manifest":
+        options = dict(options,
+                       run_manifest_path=str(tmp_path / "manifest.wal"))
+    metrics.reset()
+    cfg = _pulsed_cfg(tmp_path, **options)
+    with Pipeline(cfg) as pipe:
+        pool = pipe._owned_writer_pool
+        assert (pool is not None) == (options["writer_thread_count"] > 0)
+        if pool is not None:
+            assert pool.is_native == (how == "native_pool")
+            pipe.sinks.append(_Drainer(pipe))
+        pipe.run()
+        timer = pipe.stage_timer.summary()
+        written = list(pipe.sinks[0].written)
+        jobs = pool.stats()["jobs_done"] if pool is not None else None
+    recs = TR.load(cfg.telemetry_journal_path)
+    dumped = [r for r in recs if r["dump"]]
+    quiet = [r for r in recs if not r["dump"]]
+    assert len(dumped) == len(written) == 1 and quiet
+    files = [written[0].bin_path, *written[0].npy_paths,
+             *written[0].tim_paths]
+    assert len(files) >= 3 and all(os.path.exists(p) for p in files)
+    rec = dumped[0]
+    ms = rec["stages_ms"]
+    new = {"format", "submit", "drain", "file"}
+    assert new & set(ms) == children, sorted(ms)
+    assert all(ms[k] >= 0 for k in children) and ms["format"] > 0
+    assert sum(ms[k] for k in children) <= ms["write"] + 1e-3
+    assert telemetry.segment_wall(ms) == pytest.approx(
+        ms["ingest"] + ms["dispatch"] + ms["fetch"] + ms["sink"])
+    assert rec["candidate_bytes"] == sum(os.path.getsize(p)
+                                         for p in files)
+    assert rec["v"] == telemetry.SPAN_SCHEMA_VERSION == 13
+    for r in quiet:
+        assert not new & set(r["stages_ms"])
+        assert "candidate_bytes" not in r and "writer_file_ms" not in r
+    assert timer["format"]["count"] == len(files)
+    if pool is None:
+        # the sink's own thread writes: ``file`` is the fourth child
+        assert "writer_file_ms" not in rec
+        assert timer["file"]["count"] == len(files)
+        assert "submit" not in timer and "drain" not in timer
+    else:
+        assert timer["submit"]["count"] == len(files) == jobs
+        assert timer["drain"]["count"] == 1
+        assert rec["writer_file_ms"] > 0
+        if how == "python_pool":
+            # its threads open the span, under the segment's trace id
+            assert timer["file"]["count"] == len(files)
+            assert rec["writer_file_ms"] == pytest.approx(
+                timer["file"]["total_s"] * 1e3, abs=0.01)
+        else:
+            # the C++ threads open none: stats() gives their seconds
+            assert "file" not in timer
+    report = TR.report(cfg.telemetry_journal_path)["candidates"]
+    assert report["records"] == 1
+    assert report["bytes"] == rec["candidate_bytes"]
+    metrics.reset()
+
+
+def test_report_tolerates_records_without_the_candidate_fields(tmp_path):
+    """A v12 journal (no ``candidate_bytes``) has no candidates section,
+    and a mixed one counts the v13 records only."""
+    old = {"type": "segment_span", "v": 12, "segment": 0, "ts": 1.0,
+           "dump": True, "samples": N,
+           "stages_ms": {"ingest": 1.0, "sink": 5.0, "write": 4.0}}
+    new = dict(old, v=13, segment=1, candidate_bytes=1000,
+               writer_file_ms=2.5,
+               stages_ms=dict(old["stages_ms"], format=1.0, submit=1.0,
+                              drain=1.5))
+    assert TR.candidate_stats([old]) == {}
+    got = TR.candidate_stats([old, new])
+    assert got["records"] == 1 and got["bytes"] == 1000
+    assert got["writer_file_s"] == 0.003 and got["drain_s"] == 0.002
+    path = tmp_path / "mixed.jsonl"
+    path.write_text(json.dumps(old) + "\n" + json.dumps(new) + "\n")
+    assert "## Candidates" in TR._md(TR.report(str(path)))
+    assert TR.stage_stats([old, new])["segment"]["max_ms"] == 6.0
+
+
+def test_construct_holds_the_chirp_bank_once(tmp_path):
+    metrics.reset()
+    with Pipeline(_pulsed_cfg(tmp_path)) as pipe:
+        assert pipe.processor.chirp is not None
+        assert pipe.processor.stage_timer is pipe.stage_timer
+        before = pipe.stage_timer.summary()
+        pipe.run()
+        after = pipe.stage_timer.summary()
+    assert set(before) == {"construct", "chirp_bank"}
+    for timer in (before, after):
+        assert timer["construct"]["count"] == 1
+        assert timer["chirp_bank"]["count"] == 1
+        assert 0 < timer["chirp_bank"]["total_s"] \
+            <= timer["construct"]["total_s"]
+    # the same family feeds the registry: one reducer reads both loops
+    for stage in ("construct", "chirp_bank"):
+        h = metrics.histogram("stage_seconds", labels={"stage": stage})
+        assert h.count == 1
+        assert h.sum == pytest.approx(after[stage]["total_s"], abs=1e-6)
+    metrics.reset()
+
+
+def test_a_plan_that_makes_its_chirp_in_the_step_has_no_bank(
+        tmp_path, monkeypatch, logged):
+    """The tiny staged plan (what a 2^30 segment resolves to) and the
+    grid's in-step arm: ``construct`` and no ``chirp_bank``."""
+    from srtb_tpu.parallel import segment_dist
+    from srtb_tpu.pipeline import segment
+
+    monkeypatch.setattr(segment, "STAGED_MIN_N", N)
+    with Pipeline(_pulsed_cfg(tmp_path)) as pipe:
+        assert pipe.processor.staged and pipe.processor.chirp is None
+        pipe.run(max_segments=1)
+        timer = pipe.stage_timer.summary()
+    assert timer["construct"]["count"] == 1 and "chirp_bank" not in timer
+    assert "no chirp_bank: the plan makes its chirp in the step" \
+        in logged()
+    monkeypatch.setattr(segment_dist, "_device_bytes_limit",
+                        lambda mesh: 1 << 10)
+    cfg = _cfg(baseband_reserve_sample=False, dm=30.0,
+               use_emulated_fp64=True, dm_list=[0.0, 30.0], n_devices=2,
+               input_file_path=_baseband(tmp_path, 2, 30.0),
+               baseband_output_file_prefix=str(tmp_path / "dm_"))
+    search = DMSearchPipeline(cfg)
+    assert metrics.get("chirp_bank_bytes") == 0
+    search.run(max_segments=1)
+    search.close()
+    timer = search.stage_timer.summary()
+    assert timer["construct"]["count"] == 1 and "chirp_bank" not in timer
+    assert set(search.processor.first_dispatch_s) == {"grid_step"}
+    metrics.reset()
+
+
+def _by_program(name: str) -> dict:
+    return {d["program"]: v for d, v in metrics.labeled_series(name)
+            if "program" in d}
+
+
+def test_first_dispatches_by_program_add_up_served(tmp_path, logged):
+    metrics.reset()
+    cfg = _pulsed_cfg(tmp_path)
+    with Pipeline(cfg) as pipe:
+        pipe.run()
+        first = dict(pipe.processor.first_dispatch_s)
+        timer = pipe.stage_timer.summary()
+    by_program = _by_program("compile_seconds")
+    assert list(first) == ["ring_cold", "ring"] and by_program == first
+    total = metrics.get("compile_seconds")
+    assert total > 0 and sum(by_program.values()) == pytest.approx(total)
+    assert _by_program("plan_compiles") == {"ring_cold": 1, "ring": 1}
+    assert metrics.get("plan_compiles") == 2
+    # the span goes to the timer, the journal keeps its cumulative field
+    assert timer["first_dispatch"]["count"] == 2
+    assert timer["first_dispatch"]["total_s"] == pytest.approx(
+        total, abs=1e-5)
+    recs = TR.load(cfg.telemetry_journal_path)
+    assert all("first_dispatch" not in r["stages_ms"] for r in recs)
+    assert recs[-1]["compile_ms"] == pytest.approx(total * 1e3, abs=0.06)
+    line = [ln for ln in logged().splitlines()
+            if "[setup] construct" in ln]
+    assert len(line) == 1 and "(chirp_bank " in line[0]
+    said = {k: float(v) for k, v in re.findall(
+        r"(\w+) ([0-9.]+) s(?:,|$)", line[0].split("dispatches: ")[1])}
+    assert list(said) == ["ring_cold", "ring"]
+    assert sum(said.values()) == pytest.approx(total, abs=0.011)
+    metrics.reset()
+
+
+def test_first_dispatches_by_program_add_up_grid(tmp_path, logged):
+    metrics.reset()
+    cfg = _cfg(baseband_reserve_sample=False, dm=30.0,
+               use_emulated_fp64=True, dm_list=[0.0, 10.0, 20.0, 30.0],
+               n_devices=4, input_file_path=_baseband(tmp_path, 3, 30.0),
+               baseband_output_file_prefix=str(tmp_path / "dm_"))
+    search = DMSearchPipeline(cfg)
+    assert _by_program("compile_seconds").keys() == {"grid_bank"}
+    search.run()
+    search.run()        # a second run pays and says nothing more
+    search.close()
+    by_program = _by_program("compile_seconds")
+    assert by_program == search.processor.first_dispatch_s
+    assert list(by_program) == ["grid_bank", "grid_step"]
+    total = metrics.get("compile_seconds")
+    assert sum(by_program.values()) == pytest.approx(total)
+    assert metrics.get("plan_compiles") == 2
+    timer = search.stage_timer.summary()
+    assert timer["first_dispatch"]["count"] == 2
+    # the bank's program is compiled AND run inside its spans
+    assert by_program["grid_bank"] <= timer["chirp_bank"]["total_s"] \
+        <= timer["construct"]["total_s"]
+    lines = [ln for ln in logged().splitlines()
+             if "[setup] construct" in ln]
+    assert len(lines) == 1 and "grid_bank" in lines[0] \
+        and "grid_step" in lines[0]
+    metrics.reset()
+
+
+@pytest.fixture()
+def span_openings(monkeypatch):
+    """{span name: times opened} by whoever opens one, on any thread."""
+    opened = collections.Counter()
+    enter = span.__enter__
+
+    def counting(self):
+        opened[self.name] += 1
+        return enter(self)
+
+    monkeypatch.setattr(span, "__enter__", counting)
+    return opened
+
+
+# outside any segment: construction, the bank, the two ring programs'
+# first dispatches, and the read that finds the source at its end
+SETUP_SPANS = {"construct": 1, "chirp_bank": 1, "first_dispatch": 2,
+               "ingest": 1}
+
+
+def test_a_quiet_served_segment_opens_the_parents_six_spans(
+        tmp_path, span_openings):
+    """THE GUARD ON THE HOT PATH: a quiet segment of the served path
+    opens ingest, dispatch, h2d, enqueue, fetch and sink, once each, as
+    it did before the set-up path got its spans.  A PR that puts a span
+    into the window changes this number and has to say so."""
+    cfg = _cfg(input_file_path=_baseband(tmp_path, 5, 0.05),
+               baseband_output_file_prefix=str(tmp_path / "out_"),
+               writer_thread_count=2, inflight_segments=2)
+    with Pipeline(cfg) as pipe:
+        assert pipe._owned_writer_pool is not None
+        stats = pipe.run()
+    # five strides and the overlap they leave: warm ring steps
+    segments = stats.segments
+    assert segments >= 5 and stats.signals == 0
+    assert not glob.glob(str(tmp_path / "out_*"))
+    per_segment = span_openings - collections.Counter(SETUP_SPANS)
+    assert per_segment == {stage: segments for stage in (
+        "ingest", "dispatch", "h2d", "enqueue", "fetch", "sink")}
+
+
+def test_a_grid_segment_opens_its_five_spans(tmp_path, span_openings):
+    segments = 4
+    cfg = _cfg(baseband_reserve_sample=False, dm=30.0,
+               use_emulated_fp64=True, dm_list=[0.0, 30.0], n_devices=2,
+               input_file_path=_baseband(tmp_path, segments, 30.0),
+               baseband_output_file_prefix=str(tmp_path / "dm_"))
+    search = DMSearchPipeline(cfg)
+    assert search.run().segments == segments
+    search.close()
+    per_segment = span_openings - collections.Counter(SETUP_SPANS)
+    assert per_segment == {stage: segments for stage in (
+        "ingest", "h2d", "enqueue", "fetch", "record")}

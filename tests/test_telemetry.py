@@ -270,7 +270,7 @@ def test_span_journal_roundtrip_and_rotation(tmp_path):
     recs = TR.load(path)
     assert len(recs) == 3
     r = recs[-1]
-    assert r["type"] == "segment_span" and r["v"] == 12
+    assert r["type"] == "segment_span" and r["v"] == 13
     assert r["segment"] == 2 and r["detections"] == 2 and r["dump"]
     assert r["samples"] == 1 << 16 and r["timestamp_ns"] == 123
     assert r["queue_depth"] == 1
