@@ -492,18 +492,75 @@ def rfft_subbyte(data: jnp.ndarray, nbits: int, strategy: str = "four_step",
                                premul=premul)
 
 
+def own_tail_shape(n: int, nbits: int):
+    """(p, n1, n2) where the repo's own transform takes a segment of n
+    samples whole, Hermitian post included (ops/pallas_fft2: the
+    column-native passes and :func:`~srtb_tpu.ops.pallas_fft2.post_spectrum`),
+    else None.  Sub-byte samples go as p = 4/nbits pairs of blocked
+    field planes of n*nbits/8 points (``unpack_subbyte_planes``), whole
+    bytes as one packed transform of n/2 (:func:`pack_even_odd`)."""
+    from srtb_tpu.ops import pallas_fft2 as pf2
+    nbits = abs(nbits)
+    if nbits not in (2, 4, 8):
+        return None
+    p = 4 // nbits if nbits < 8 else 1
+    if n % (2 * p):
+        return None
+    fac = pf2.cols_factor(n // (2 * p))
+    if fac is None or not pf2.post_supported(p, *fac):
+        return None
+    return (p, *fac)
+
+
+@S.scoped(S.FFT_R2C)
+def own_spectrum(planes: jnp.ndarray, legs: tuple, bank: jnp.ndarray, *,
+                 threshold: float, norm: float, bins,
+                 interpret: bool = False) -> jnp.ndarray:
+    """Planes ``[2p, ..., M]`` of M = n1 * n2 points, ``legs`` (n1, n2)
+    of :func:`own_tail_shape` (transform b's real part in plane 2b, its
+    imaginary part in 2b+1) -> the dedispersed, zapped spectrum
+    ``[p*M]`` complex: two kernel passes and the post pass, no XLA FFT
+    and no spectrum-sized XLA pass but RFI s1's mean power.
+    ``bank``: ``pallas_fft2.post_bank``'s; ``threshold`` and ``norm`` of
+    RFI s1, ``bins`` of the manual zap."""
+    from srtb_tpu.ops import pallas_fft2 as pf2
+    p = planes.shape[0] // 2
+    n1, n2 = legs
+    y_re, y_im = pf2.fft2_cols_planes(planes.reshape(2 * p, n1, n2),
+                                      interpret=interpret)
+    s_re, s_im = pf2.post_spectrum(
+        y_re.reshape(p, n2, n1), y_im.reshape(p, n2, n1), bank,
+        threshold=threshold, norm=norm, bins=bins, interpret=interpret)
+    return jax.lax.complex(s_re, s_im)
+
+
 def _pallas2_or_fallback(z: jnp.ndarray, strategy: str,
                          len_cap: int | None = None) -> jnp.ndarray:
-    """The fused two-pass Pallas C2C (ops/pallas_fft2) on [..., L] complex
-    z, falling back to the four-step-with-Pallas-legs form for lengths
-    outside its [2^24, 2^29] window (tiny test configs)."""
+    """The two-pass Pallas C2C (ops/pallas_fft2) on [..., L] complex z:
+    the column-native passes at the lengths they take (2^24 to 2^26),
+    and the four-step-with-Pallas-legs form below 2^24 (tiny test
+    configs).  The lengths between, 2^27 to 2^29, have only the first
+    spelling of the passes (``fft2_c2c``), which Mosaic refuses for a
+    v5e: in interpret mode it runs, on a chip it is an error here."""
     from srtb_tpu.ops import pallas_fft2 as pf2
     interp = strategy.endswith("interpret")
-    if pf2.supported(z.shape[-1]):
+    length = z.shape[-1]
+    if pf2.cols_factor(length) is not None:
+        return pf2.fft2_cols(z, inverse=False, interpret=interp)
+    if pf2.supported(length):
+        from srtb_tpu.utils.platform import on_accelerator
+        if not interp and on_accelerator():
+            raise ValueError(
+                f"fft_strategy pallas2 has no transform of {length} "
+                f"points on a chip: the column-native passes take 2^24 "
+                f"to 2^26 points, and Mosaic refuses the first spelling "
+                f"of the passes at these sizes (96.14 MB of scoped VMEM "
+                f"against 80 at 2^26 points on a v5e; PERF.md section "
+                f"6, PR 43)")
         return pf2.fft2_c2c(z, inverse=False, interpret=interp)
     # loud when an explicit SRTB_PALLAS2_N1 pin is why we're falling
     # back — the A/B knob must not silently measure the wrong path
-    pf2.require_pin_fit(z.shape[-1])
+    pf2.require_pin_fit(length)
     return _fft_minor(z, inverse=False,
                       rows_impl="pallas_interpret" if interp else "pallas",
                       len_cap=len_cap)
@@ -544,20 +601,63 @@ def finish_rfft_subbyte(a: jnp.ndarray,
                                premul=premul)
 
 
-# Threshold (packed C2C length, = n/2) above which the segment R2C
-# switches to the four-step path.  Tuned on a v5e: the monolithic XLA R2C
-# works and wins through n = 2^29; at n = 2^30 XLA's compile OOMs
-# (not re-measured on this JAX), so only 2^30+ takes the four-step.
+# Packed C2C length (= n/2) above which the segment R2C is the four-step
+# path of the staged plan: the monolithic XLA R2C of a 2^30-sample
+# segment does not compile for a v5e (PERF.md section 4).
 LARGE_FFT_THRESHOLD = 1 << 28
 
+# What the plan with the repo's own transform holds on the chip, in
+# bytes a sample: the chirp bank and the post pass's (chirp, chirp *
+# twiddle) bank, 4 + 8, and one stream's temporaries, 12 (the four
+# planes between the passes, the unpacked planes: 1.16 GB at 2^27
+# samples compiled for a described v5e), whatever the streams; and a
+# stream's waterfall of each of two segments in flight, 2 x 4.  A floor:
+# the chip's peak read 4.51 GB at 2^27 samples in one stream, 7.20 in two
+# (PERF.md section 5, PR 43).  ``auto`` keeps XLA's transform where the
+# chip's ``bytes_limit`` is under the sum.
+OWN_R2C_BYTES_PER_SAMPLE = 24
+OWN_R2C_BYTES_PER_STREAM_SAMPLE = 8
 
-def resolve_strategy(n: int, strategy: str) -> str:
-    """Resolve "auto" to a concrete segment-R2C strategy for n samples
-    (monolithic XLA R2C wins through n = 2^29 on a v5e; above, four-step
-    is the only one that fits — see LARGE_FFT_THRESHOLD)."""
-    if strategy == "auto":
-        return "four_step" if n // 2 > LARGE_FFT_THRESHOLD else "monolithic"
-    return strategy
+# The shapes (:func:`own_tail_shape`) at which a v5e has read the repo's
+# own transform faster than XLA's, parent beside change: 2-bit samples,
+# two pairs of blocked planes through legs 4096 x 8192 (2^27 samples a
+# stream; one stream 65.28 -> 33.93 ms busy a segment, two 134.88 ->
+# 74.76: PERF.md section 5, PR 43).  The other shapes the kernels take
+# (whole bytes and 4-bit samples through legs 8192 x 8192 with one plane
+# pair, 2^25 and 2^26 samples) compile for a described v5e and no chip
+# has run them: ``auto`` leaves them to XLA until one has.
+OWN_R2C_READ_FASTER = frozenset({(2, 4096, 8192)})
+
+
+def resolve_strategy(n: int, strategy: str, bits: int = 8,
+                     streams: int = 1, on_tpu: bool = False,
+                     bytes_limit: int | None = None,
+                     own_plan: bool = True) -> str:
+    """Resolve "auto" to a concrete segment-R2C strategy for segments of
+    n samples of ``bits`` bits in ``streams`` streams.
+
+    Above ``LARGE_FFT_THRESHOLD`` "four_step" (the staged plan).  Below
+    it the repo's own transform, "pallas2" with the tail in its post
+    pass, where all of this holds: the backend is a TPU (``on_tpu``),
+    the caller's plan runs that transform whole when it is given
+    "pallas2" (``own_plan``: ``pipeline/segment.own_r2c_hostable``), the
+    shape is one a chip has read faster (``OWN_R2C_READ_FASTER``) and
+    the chip holds the plan twice (``bytes_limit`` of ``memory_stats``;
+    None where the platform reports none).  Else "monolithic", XLA's own
+    R2C: off the chip, at 2^28 samples of whole bytes, and wherever the
+    kernel has not been read faster (PERF.md section 6, PR 43, has the
+    table)."""
+    if strategy != "auto":
+        return strategy
+    if n // 2 > LARGE_FFT_THRESHOLD:
+        return "four_step"
+    if (on_tpu and own_plan
+            and own_tail_shape(n, bits) in OWN_R2C_READ_FASTER
+            and (not bytes_limit or n * (
+                OWN_R2C_BYTES_PER_SAMPLE
+                + streams * OWN_R2C_BYTES_PER_STREAM_SAMPLE) <= bytes_limit)):
+        return "pallas2"
+    return "monolithic"
 
 
 @S.scoped(S.FFT_R2C)
@@ -572,23 +672,25 @@ def segment_rfft(x: jnp.ndarray, strategy: str = "auto",
     them (the spectrum is produced inside XLA's R2C custom call) and
     raises rather than silently running unfused.
 
-    strategy:
-    - "auto": monolithic below the four-step threshold, four_step above
-      it ("mxu" is opt-in until validated end-to-end on hardware);
+    strategy (what a v5e read for each at 2^27 samples is the table of
+    PERF.md section 6, PR 43):
+    - "auto": here, with no plan around it, "monolithic" below the
+      staged plan's sizes and "four_step" from there; a served plan
+      resolves it before it calls (:func:`resolve_strategy`);
     - "monolithic": one XLA R2C op;
     - "four_step": half-size packed C2C via the Bailey decomposition +
       Hermitian post-process — two large *batched* XLA FFTs instead of
       one huge 1-D FFT;
     - "mxu": the packed C2C executed as radix-128 DFT-matrix matmuls on
-      the systolic array (ops/mxu_fft.py) — measured ~25% faster than
-      the monolithic XLA R2C at the 2^27 bench size on a v5e;
+      the systolic array (ops/mxu_fft.py);
     - "pallas" ("pallas_interpret" off-TPU): the four-step decomposition
       with its batched row FFTs executed by the VMEM Pallas kernel
       (ops/pallas_fft) — one HBM read+write per point per leg;
-    - "pallas2" ("pallas2_interpret" off-TPU): the fused two-pass
-      four-step (ops/pallas_fft2) — transposes and twiddles absorbed
-      into the two leg kernels, two HBM round trips for the whole C2C
-      and no XLA FFT op anywhere.
+    - "pallas2" ("pallas2_interpret" off-TPU): two kernel passes for the
+      whole C2C and no XLA FFT op anywhere (ops/pallas_fft2), the
+      Hermitian post in XLA; a served plan that gives the post to a
+      third kernel pass with RFI s1 and the chirp in it calls
+      :func:`own_spectrum` and not this function.
     """
     strategy = resolve_strategy(x.shape[-1], strategy)
     if strategy == "monolithic" and (epilogue is not None

@@ -1,8 +1,8 @@
 """FFT on the MXU: radix-128 DFT stages as systolic-array matmuls.
 
-XLA's TPU FFT runs the pipeline's dominant op — the segment C2C — at
-~8x off the HBM roof (measured: 47 ms for 2^27-sample R2C on a v5e,
-PERF.md).  The FLOPs of an FFT are tiny (5 n log2 n), so on a machine
+XLA's TPU FFT runs the segment R2C far off the HBM roof (a v5e: 44.7 ms
+for 2^27 samples alone, 1.3 ms of traffic; PERF.md section 6, PR 43).
+The FLOPs of an FFT are tiny (5 n log2 n), so on a machine
 whose matmul throughput is nearly free relative to HBM bandwidth, the
 TPU-native formulation is the classic one from the supercomputing
 literature: factor the DFT into radix-r stages and execute each stage as

@@ -62,6 +62,17 @@ def mitigate_rfi_s1_given_mean(spectrum: jnp.ndarray, mean_power,
 
 
 @S.scoped(S.RFI_S1)
+def s1_zap(spectrum: jnp.ndarray, mean_power, threshold: float,
+           zap_mask: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Which bins RFI stage 1 and the manual mask zap, as booleans: the
+    decisions of :func:`mitigate_rfi_s1_given_mean` and
+    :func:`mitigate_rfi_manual` for a caller that applies them in a
+    select of its own (the fused tail's final one)."""
+    zap = _norm(spectrum) > threshold * mean_power
+    return zap if zap_mask is None else zap | zap_mask
+
+
+@S.scoped(S.RFI_S1)
 def mean_power_packed(zf: jnp.ndarray) -> jnp.ndarray:
     """Mean ``|X_k|^2`` over the m dropped-Nyquist rfft bins, computed
     from the packed half-size C2C output ``zf [..., m]`` WITHOUT forming

@@ -331,12 +331,10 @@ def _apply_staged(cfg, staged):
 
 
 def _apply_monolithic(cfg, staged):
-    from srtb_tpu.ops import fft as F
+    from srtb_tpu.pipeline.segment import segment_strategy
     n = int(getattr(cfg, "baseband_input_count", 0) or 0)
     already = (not _resolved_staged(cfg, staged) and n > 0
-               and F.resolve_strategy(
-                   n, getattr(cfg, "fft_strategy", "auto"))
-               == "monolithic")
+               and segment_strategy(cfg, False) == "monolithic")
     if already:
         return None
     return _drop_forced_front_fuse(cfg).replace(

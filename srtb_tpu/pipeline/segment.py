@@ -133,6 +133,66 @@ def refuse_overlong_reserve(cfg) -> None:
         f"longer segment or baseband_reserve_sample 0")
 
 
+def _stream_bytes_subbyte(cfg) -> bool:
+    """Whether each stream's own bytes are sub-byte samples MSB first
+    (``ops/unpack.stream_bytes``): what the blocked field planes of
+    ``ops/fft.rfft_subbyte`` are made of."""
+    fmt = formats.resolve(cfg.baseband_format_type)
+    return (cfg.baseband_input_bits in (1, 2, 4) and fmt.unpack_variant
+            in ("simple", "interleaved_samples_2"))
+
+
+def _r2c_sample_bits(cfg) -> int:
+    """The bits ``ops/fft.own_tail_shape`` is asked with: the samples'
+    own where a stream's bytes go to the R2C as blocked field planes,
+    8 where it takes samples in order."""
+    return cfg.baseband_input_bits if _stream_bytes_subbyte(cfg) else 8
+
+
+def own_r2c_hostable(cfg, staged: bool) -> bool:
+    """Whether this configuration's plan, given the strategy "pallas2",
+    runs the repo's own transform whole (``ops/fft.own_spectrum``: two
+    kernel passes and the post pass that carries RFI s1 and the chirp):
+    a fused plan and not the staged one, a tail that may fold in
+    (``fused_tail`` not off), a chirp bank to fold (``use_pallas`` plans
+    keep none), one segment a program (the micro-batch and the fleet
+    ``vmap`` it, which no chip run has measured), and a shape the
+    kernels take (``ops/fft.own_tail_shape``).  Any other plan given
+    "pallas2" runs the two passes with XLA's Hermitian post and tail,
+    which a v5e read slower than XLA's R2C (PERF.md section 6, PR 43)."""
+    batched = (int(getattr(cfg, "micro_batch_segments", 1) or 1) > 1
+               or int(getattr(cfg, "fleet_batch_max", 0) or 0) > 1)
+    return bool(
+        not staged and not batched
+        and not getattr(cfg, "use_pallas", False)
+        and str(getattr(cfg, "fused_tail", "auto")).lower() != "off"
+        and F.own_tail_shape(int(cfg.baseband_input_count),
+                             _r2c_sample_bits(cfg)) is not None)
+
+
+def segment_strategy(cfg, staged: bool) -> str:
+    """The segment R2C's strategy for a configuration and its resolved
+    ``staged`` flag: ``fft_strategy`` with "auto" resolved by
+    ``ops/fft.resolve_strategy`` from what the configuration and the
+    platform show (the segment's length, its samples' bits and streams,
+    whether the plan would run the own transform whole, the backend, the
+    chip's ``bytes_limit``).  The single home: the fused tail, the own
+    transform, the plan's name and signature, the ladder's
+    ``monolithic`` rung and ``_process`` all ask here, so "auto" names
+    "pallas2" only for the plan that was measured."""
+    strategy = getattr(cfg, "fft_strategy", "auto")
+    if strategy != "auto":
+        return strategy
+    from srtb_tpu.utils import platform
+    fmt = formats.resolve(cfg.baseband_format_type)
+    return F.resolve_strategy(
+        int(cfg.baseband_input_count), strategy,
+        bits=_r2c_sample_bits(cfg), streams=fmt.data_stream_count,
+        on_tpu=platform.on_accelerator(),
+        bytes_limit=platform.device_bytes_limit(),
+        own_plan=own_r2c_hostable(cfg, staged))
+
+
 def fused_tail_resolves(cfg, staged: bool) -> bool:
     """Resolution of ``Config.fused_tail`` ("auto"/"on"/"off") for a
     plan with the given resolved ``staged`` flag (see
@@ -144,8 +204,7 @@ def fused_tail_resolves(cfg, staged: bool) -> bool:
     if mode == "off":
         return False
     n = int(cfg.baseband_input_count)
-    hostable = staged or F.resolve_strategy(
-        n, cfg.fft_strategy) != "monolithic"
+    hostable = staged or segment_strategy(cfg, staged) != "monolithic"
     if mode == "on":
         if not hostable:
             raise ValueError(
@@ -301,10 +360,27 @@ class SegmentProcessor:
         f_min, f_c, df = dd.spectrum_frequencies(cfg, self.n_spectrum)
         self.f_min, self.f_c, self.df = f_min, f_c, df
         self.staged = (self.n >= STAGED_MIN_N) if staged is None else staged
+        # the segment R2C's strategy, "auto" resolved once: the plan's
+        # name and what it traces cannot disagree
+        self.strategy = segment_strategy(cfg, self.staged)
         # fused spectrum tail (Config.fused_tail): RFI s1 + chirp fold
         # into the forward FFT's final pass; resolved once so the plan
         # and its signature can never disagree
         self.fused_tail = self._resolve_fused_tail()
+        # ... and where the segment R2C is the repo's own transform
+        # whole (ops/fft.own_spectrum): the same question "auto" was
+        # resolved with, so a plan "auto" names pallas2 always runs it
+        self.own_tail = (self.strategy == "pallas2"
+                         and own_r2c_hostable(cfg, self.staged))
+        assert self.fused_tail or not self.own_tail
+        if (self.own_tail and win is not None and self.window_planes is None
+                and _stream_bytes_subbyte(cfg)):
+            # each of several streams of sub-byte samples goes to the
+            # own transform as blocked planes too
+            self.window_planes = jnp.asarray(F.subbyte_window_planes(
+                win, cfg.baseband_input_bits))
+        from srtb_tpu.utils.metrics import metrics
+        metrics.set("segment_r2c_own", int(self.own_tail))
         # front-fused staged megakernel (Config.front_fuse, the
         # staged_ffuse family): unpack + window + even/odd pack +
         # FFT pass 1 fold into the pallas2 pass-1 kernel (raw bytes
@@ -351,10 +427,14 @@ class SegmentProcessor:
                     # capture — a captured 2 GB bank would bake into the
                     # program)
                     self.chirp_w = jax.jit(self._premul_bank)(self.chirp)
+                if self.own_tail:
+                    from srtb_tpu.ops import pallas_fft2 as pf2
+                    self.chirp_w = jax.jit(pf2.post_bank)(
+                        self.chirp, self.chirp_w)
                 jax.block_until_ready((self.chirp, self.chirp_w))
 
         zap_ranges = rfi.eval_rfi_ranges(cfg.mitigate_rfi_freq_list)
-        if self.staged_rows:
+        if self.staged_rows or self.own_tail:
             # a block compares the zap ranges with its own bin indices:
             # no mask the spectrum's size (0.5 GB at 2^29 bins) is made
             self.rfi_bins = rfi.rfi_ranges_to_bins(
@@ -651,8 +731,7 @@ class SegmentProcessor:
     def plan_name(self) -> str:
         """Human-readable plan id: base plan + resolved strategy
         + which fusions are live."""
-        strategy = F.resolve_strategy(self.n, self.cfg.fft_strategy)
-        name = ("staged" if self.staged else "fused") + f":{strategy}"
+        name = ("staged" if self.staged else "fused") + f":{self.strategy}"
         if self.staged_rows:
             name += "+rows"
         if self.fused_tail:
@@ -671,9 +750,13 @@ class SegmentProcessor:
         exp(-2πik/n) — the chirp·twiddle precombination consumed by
         ops.fft.hermitian_rfft_post(premul=...)."""
         m = c_ri.shape[-1]
-        c = jax.lax.complex(c_ri[0], c_ri[1])
-        cw = c * F._iota_phase(m, 2 * m, -1.0)
-        return jnp.stack([jnp.real(cw), jnp.imag(cw)])
+        w = F._iota_phase(m, 2 * m, -1.0)
+        # (c_re + i c_im)(w_re + i w_im) on the stacked planes as they
+        # lie, with no stack of two results: at 2^26 bins the chip's
+        # compiler ends on a check failure there (fusion_emitter.cc,
+        # IsFusibleUnalignedDUS; PERF.md section 6, PR 43)
+        sign = jnp.asarray([[-1.0], [1.0]], c_ri.dtype)
+        return c_ri * jnp.real(w) + (c_ri[::-1] * sign) * jnp.imag(w)
 
     def _tail_epilogue(self, chirp_ri):
         """The elementwise epilogue folded into the forward FFT's final
@@ -690,6 +773,17 @@ class SegmentProcessor:
 
         def epilogue(zf, spec):
             mean_power = rfi.mean_power_packed(zf)
+            if chirp_ri is not None:
+                # the pass that applied the chirp ends in ONE select,
+                # under the chirp's name (a fusion reads as its root's):
+                # the values of the two selects below, bit for bit
+                zap = rfi.s1_zap(
+                    spec, mean_power,
+                    cfg.mitigate_rfi_average_method_threshold,
+                    self.rfi_mask)
+                with jax.named_scope(S.CHIRP):
+                    return jnp.where(zap, jnp.zeros((), spec.dtype),
+                                     spec * self.norm_coeff)
             spec = rfi.mitigate_rfi_s1_given_mean(
                 spec, mean_power,
                 cfg.mitigate_rfi_average_method_threshold,
@@ -736,8 +830,9 @@ class SegmentProcessor:
 
     def _process(self, raw: jnp.ndarray, chirp_ri: jnp.ndarray,
                  chirp_w_ri: jnp.ndarray = None):
-        strategy = self._resolve_rows_impl(
-            F.resolve_strategy(self.n, self.cfg.fft_strategy))
+        if self.own_tail:
+            return self._process_own(raw, chirp_w_ri)
+        strategy = self._resolve_rows_impl(self.strategy)
         epilogue = premul = None
         if self.fused_tail:
             epilogue = self._tail_epilogue(chirp_ri)
@@ -782,6 +877,49 @@ class SegmentProcessor:
             lambda b: chain(U.unpack_stream(
                 b, self.fmt.unpack_variant, self.cfg.baseband_input_bits,
                 self.window)[None, :]), own)
+
+    def _process_own(self, raw: jnp.ndarray, bank: jnp.ndarray):
+        """The segment through the repo's own transform
+        (``ops/fft.own_spectrum``: two kernel passes and the post pass
+        with RFI s1, the manual zap and the chirp in it; ``bank`` is
+        ``chirp_w``), a stream at a time.  A stream's sub-byte samples
+        go to the kernels as the blocked field planes they unpack to,
+        whole bytes as the even/odd pack of the samples in order."""
+        cfg = self.cfg
+        bits = cfg.baseband_input_bits
+        legs = F.own_tail_shape(self.n, _r2c_sample_bits(cfg))[1:]
+
+        def results(planes):                            # [2p, (1,) M]
+            spec = F.own_spectrum(
+                planes, legs, bank,
+                threshold=cfg.mitigate_rfi_average_method_threshold,
+                norm=self.norm_coeff, bins=self.rfi_bins,
+                interpret=self._pallas_interpret)
+            return self._waterfall_detect(spec[None, :])
+
+        def from_bytes(b):
+            planes = U.unpack_subbyte_planes(b, bits)
+            if self.window_planes is not None:
+                with jax.named_scope(S.FFT_R2C):
+                    planes = planes * self.window_planes
+            return results(planes)
+
+        def from_samples(x):                            # x [1, n]
+            z = F.pack_even_odd(x)
+            with jax.named_scope(S.FFT_R2C):
+                planes = jnp.stack([jnp.real(z), jnp.imag(z)])
+            return results(planes)
+        subbyte = _stream_bytes_subbyte(cfg)
+        own = U.stream_bytes(raw, self.fmt.unpack_variant)
+        if len(own) == 1:
+            return (from_bytes(raw) if subbyte
+                    else from_samples(self._unpack(raw)))
+        with jax.named_scope(S.UNPACK):
+            own = jnp.stack(own)                        # [S, bytes]
+        return self._stream_after_stream(
+            from_bytes if subbyte else lambda b: from_samples(
+                U.unpack_stream(b, self.fmt.unpack_variant, bits,
+                                self.window)[None, :]), own)
 
     def _spectrum_to_results(self, spec: jnp.ndarray, chirp_ri):
         """From the R2C's spectrum ``[S, n/2]`` to the program's
@@ -1578,6 +1716,10 @@ class SegmentProcessor:
              # strategy flips monolithic <-> four_step across the
              # threshold) must miss the AOT cache cleanly
              "fused_tail": self.fused_tail,
+             # the repo's own transform with the tail in its post pass:
+             # other programs and another bank than the fused tail's
+             # XLA spelling; a plan without it keeps its signature
+             **({"r2c": "own-v1"} if self.own_tail else {}),
              # resolved front fusion: the staged_ffuse programs have
              # different boundary pytrees (canonical + accumulators)
              # and a blocked stage-(b) spectrum — an AOT cache written

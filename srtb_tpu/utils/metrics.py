@@ -345,6 +345,8 @@ class Metrics:
                             "step's results were not yet fetched",
         "data_streams": "Data streams (polarisations) split from each "
                         "segment on the device",
+        "segment_r2c_own": "Whether the segment R2C is the repo's own "
+                           "transform (1) or XLA's (0)",
         "ring_cold_dispatches": "Ingest-ring cold (full-upload) "
                                 "dispatches",
         "recovered_segments": "Segments rescued by manifest recovery",

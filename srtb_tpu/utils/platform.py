@@ -27,3 +27,13 @@ def on_accelerator() -> bool:
     import jax
 
     return jax.default_backend() == "tpu"
+
+
+def device_bytes_limit() -> int | None:
+    """``bytes_limit`` of the first local device's ``memory_stats()``:
+    what the runtime lets a process allocate on one chip; None where the
+    platform reports none (the CPU backend)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")

@@ -103,6 +103,25 @@ def _grid_program():
     return {"grid_step": _hlo(proc._step, args)}
 
 
+# the served plan with the repo's own transform (ISSUE 43): 2-bit blocked
+# planes through the two kernel passes and the post pass, here in
+# interpret mode with legs of 128 (production legs are 4096 and 8192)
+OWN = dict(fft_strategy="pallas2", baseband_input_bits=2,
+           baseband_input_count=1 << 16,
+           mitigate_rfi_freq_list="1406-1407")
+
+
+def _own_programs(names, **extra):
+    from srtb_tpu.ops import pallas_fft2 as pf2
+    production = pf2.cols_factor
+    pf2.cols_factor = lambda m: (128, m // 128) \
+        if m >= 128 * 128 and not m & (m - 1) else None
+    try:
+        return _served_programs(names, **OWN, **extra)
+    finally:
+        pf2.cols_factor = production
+
+
 FAMILIES = {
     # the quiet cell's plan: monolithic R2C, fused, overlap-save ring
     "ring": (lambda: _served_programs({"ring"}), RING),
@@ -120,6 +139,7 @@ FAMILIES = {
     # two polarisations byte-interleaved in one segment, split on the
     # device (ISSUE 36): the two-stream cell's plan
     "ring_2pol": (lambda: _served_programs({"ring"}, **TWO_POL), RING),
+    "ring_own": (lambda: _own_programs({"ring"}), RING),
 }
 # no reserve, no ring: these hold no ``srtb.ring``
 RINGLESS = {"staged", "grid_step"}
@@ -154,6 +174,45 @@ def test_staged_stages_carry_their_own_scopes():
     assert S.FFT_R2C in locs["stage_b"]
     assert {S.WATERFALL, S.DETECT} <= locs["stage_c"]
     assert S.FFT_R2C not in locs["stage_c"]
+
+
+def test_nothing_of_the_own_transform_reads_as_unscoped():
+    """The plan `auto` picks on a chip (ISSUE 43): the two kernel passes
+    carry ``srtb.fft_r2c``, the post pass, which applies the chirp, ``srtb.chirp``,
+    the mean-power reduction ``srtb.rfi_s1``, the field extraction
+    ``srtb.unpack``; no operation on a plane of the segment's size is
+    without a scope (on the chip a kernel is ONE operation, named by the
+    scope its ``pallas_call`` was traced under)."""
+    _text, located = _own_programs({"ring"})["ring"]
+    # the program's own function: in interpret mode a kernel's loop
+    # bodies are private functions, shared and so without a name stack
+    main = located[located.index("func.func public @main"):]
+    main = main[:main.index("\n  func.func private")]
+    ops, _names = _located_ops(located, within=main)
+    plane = (1 << 16) // 4                      # points of one field plane
+    def points(types):
+        return max([int(np.prod([int(d) for d in dims.split("x") if d]))
+                    for dims in re.findall(r"tensor<((?:\d+x)+)(?:f32|ui8)",
+                                           types)] or [0])
+    big = [(op, types, name) for op, types, name in ops
+           if points(types) >= plane]
+    assert len(big) > 100
+    bare = [(op, types) for op, types, name in big if not _scopes_in(name)]
+    assert not bare, bare[:5]
+    kernels = [name for _op, _t, name in ops if "pallas_call" in name]
+    assert kernels
+    innermost = collections.Counter(
+        re.findall(r"srtb\.[a-z0-9_]+", name)[-1] for name in kernels)
+    assert set(innermost) == {S.FFT_R2C, S.CHIRP}, innermost
+    assert innermost[S.FFT_R2C] >= 2 and innermost[S.CHIRP] >= 1
+    reductions = [name for op, _t, name in ops
+                  if op == "stablehlo.reduce" and S.RFI_S1 in name]
+    assert reductions, "RFI s1's mean power is a reduction of its own"
+    fields = [name for op, types, name in ops
+              if op == "stablehlo.shift_right_logical"]
+    assert fields and all(
+        re.findall(r"srtb\.[a-z0-9_]+", name)[-1] == S.UNPACK
+        for name in fields)
 
 
 def _located_ops(located: str, within: str = "") -> tuple:

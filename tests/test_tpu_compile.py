@@ -15,6 +15,8 @@ cache off (an entry written for a described device cannot be read back
 without one).
 """
 
+import json
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -281,3 +283,137 @@ def test_the_2p30_plan_holds_no_temporary_of_a_whole_plane(one_chip,
         m = compiled[name].memory_analysis()
         assert m.alias_size_in_bytes >= 2 * plane, (name, m)
 
+
+
+# ---- the plan `auto` picks on a chip (ISSUE 43) -----------------------
+
+V5E_BYTES_LIMIT = 16_911_433_728      # ``memory_stats()["bytes_limit"]``
+
+
+def _as_on_a_chip(monkeypatch):
+    """Steer the program onto its TPU branch in the test (it has no
+    option for it): the backend test says "a TPU", the chip reports a
+    v5e's ``bytes_limit``, and the chirp bank, which a compile needs the
+    shape of and nothing else, is zeros (the float64 phase takes 15-30 s
+    of one host thread at these sizes)."""
+    import numpy as np
+
+    from srtb_tpu.ops import dedisperse
+    from srtb_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "on_accelerator", lambda: True)
+    monkeypatch.setattr(platform, "device_bytes_limit",
+                        lambda: V5E_BYTES_LIMIT)
+    monkeypatch.setattr(
+        dedisperse, "chirp_factor_host_ri",
+        lambda n, *a, **k: np.zeros((2, n), np.float32))
+
+
+def _two_in_flight_bytes(compiled) -> int:
+    """One program's temporaries and arguments, and the outputs (the
+    waterfall, the next carry) of two segments."""
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + 2 * m.output_size_in_bytes)
+
+
+def test_j1644_under_auto_is_the_own_transform_and_fits_twice(
+        one_chip, monkeypatch):
+    """The J1644 2^27 served plan as `auto` resolves it on a v5e: the
+    repo's own transform with the tail in its post pass.  Its three
+    programs go through Mosaic (pass 1, pass 2, the post pass: three
+    custom calls each, no XLA FFT left but the waterfall's), and two
+    segments in flight fit the chip."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    _as_on_a_chip(monkeypatch)
+    proc = SegmentProcessor(_j1644(27), donate_input=True)
+    assert proc.plan_name == "fused:pallas2+ftail+ring"
+    assert proc.own_tail and proc._pallas_interpret is False
+    assert proc.chirp_w.shape == (4, (1 << 26) // 128, 128)
+    compiled = _compile_all(proc, one_chip)
+    assert set(compiled) == {"fused", "ring", "ring_cold"}
+    for name, c in compiled.items():
+        text = c.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 3, name
+        assert _two_in_flight_bytes(c) < V5E_BYTES_LIMIT, (
+            name, c.memory_analysis())
+        # what the fit rule of ops/fft.resolve_strategy reckons with
+        assert _two_in_flight_bytes(c) < (24 + 8) * (1 << 27), name
+
+
+def test_j1644_two_streams_under_auto_run_the_kernels_in_the_loop(
+        one_chip, monkeypatch):
+    """Both polarisations: the same three kernels, once, inside the loop
+    over the streams, fed each stream's own blocked field planes."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    _as_on_a_chip(monkeypatch)
+    proc = SegmentProcessor(
+        _j1644(27, baseband_format_type="interleaved_samples_2"),
+        donate_input=True)
+    assert proc.plan_name == "fused:pallas2+ftail+ring" and proc.own_tail
+    c = _compile_all(proc, one_chip, only={"ring"})["ring"]
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    assert _two_in_flight_bytes(c) < V5E_BYTES_LIMIT, c.memory_analysis()
+    assert _two_in_flight_bytes(c) < (24 + 2 * 8) * (1 << 27)
+
+
+@pytest.mark.parametrize("kw,staged", [
+    ({"use_pallas": True, "use_pallas_sk": True}, None),  # chip_smoke's
+    ({"fused_tail": "off"}, None),      # the ladder's Mosaic-free rung
+    ({}, True),                         # the ladder's staged rung
+])
+def test_j1644_plans_auto_never_measured_keep_xlas_transform(
+        monkeypatch, kw, staged):
+    """On a v5e too, a J1644 2^27 plan that would not run the own
+    transform whole is the parent's plan under `auto`: its name, its
+    signature and the gauge say XLA's R2C, and no bank is precombined."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+    from srtb_tpu.utils.metrics import metrics
+
+    from srtb_tpu.utils import platform
+
+    cfg = _j1644(27, **kw)
+    _as_on_a_chip(monkeypatch)
+    proc = SegmentProcessor(cfg, staged=staged)
+    monkeypatch.setattr(platform, "on_accelerator", lambda: False)
+    off_chip = SegmentProcessor(cfg, staged=staged)
+    assert proc.strategy == "monolithic" and not proc.own_tail
+    assert metrics.get("segment_r2c_own") == 0
+    assert "pallas2" not in proc.plan_name and proc._pallas_interpret is False
+    assert proc.plan_name == off_chip.plan_name
+    # ... but for the kernels' interpret mode, which follows the backend
+    sig, sig_off = (json.loads(p.plan_signature()) for p in (proc, off_chip))
+    assert sig.pop("interp") is False and sig_off.pop("interp") is True
+    assert sig == sig_off
+    assert proc.chirp_w is None
+
+
+def test_naoc_1g_under_auto_keeps_xlas_transform_and_fits_twice(
+        one_chip, monkeypatch):
+    """The 1 GSa/s deployment (2^28 samples of 8 bits, 2^15 channels): a
+    packed transform of 2^27 points has no leg the column-native passes
+    hold in VMEM (a leg of 2^14), so `auto` keeps the monolithic plan on
+    the chip too; it compiles and leaves two segments in flight."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    _as_on_a_chip(monkeypatch)
+    cfg = Config(
+        baseband_input_count=1 << 28, baseband_input_bits=8,
+        baseband_format_type="simple", baseband_freq_low=1000.0,
+        baseband_bandwidth=500.0, baseband_sample_rate=1e9, dm=14.2,
+        spectrum_channel_count=1 << 15,
+        mitigate_rfi_average_method_threshold=10.0,
+        mitigate_rfi_spectral_kurtosis_threshold=1.1,
+        signal_detect_signal_noise_threshold=6.0,
+        signal_detect_max_boxcar_length=1024,
+        baseband_reserve_sample=True)
+    proc = SegmentProcessor(cfg, donate_input=True)
+    assert proc.plan_name == "fused:monolithic+ring"
+    assert not proc.own_tail and not proc.fused_tail
+    compiled = _compile_all(proc, one_chip, only={"ring", "ring_cold"})
+    for name, c in compiled.items():
+        assert "tpu_custom_call" not in c.as_text(), name
+        assert _two_in_flight_bytes(c) < V5E_BYTES_LIMIT, (
+            name, c.memory_analysis())
