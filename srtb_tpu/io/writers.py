@@ -212,27 +212,45 @@ def _payload(data: np.ndarray):
     return memoryview(np.ascontiguousarray(data)).cast("B")
 
 
-def _npy_complex64(re: np.ndarray, im: np.ndarray) -> np.ndarray:
-    """``_npy_bytes(re + 1j * im)`` as complex64, byte for byte, made in
-    ONE buffer of the file's size: the two float32 planes are written
-    straight into the body behind the header.  The spelled-out form
-    holds a complex128 sum, its complex64 cast, ``np.save``'s stream and
-    the stream's copy at once: 5.5 times the file, 23 GB for the 4.29 GB
-    waterfall of a 2^30-sample segment, beside whatever else the host
-    holds."""
+# the stacked waterfall crosses to the host this many bytes of rows at
+# a time
+NPY_BLOCK_BYTES = 1 << 26
+
+
+def _npy_complex64_by_blocks(planes) -> list:
+    """``_npy_bytes(re + 1j * im)`` as complex64, byte for byte, of each
+    stream of the stacked waterfall ``planes`` ``[2, S, F, T]`` wherever
+    it lives: ONE buffer of the file's size a stream, the ``.npy``
+    header and then the values, written in place from blocks of rows
+    fetched on their own (``utils/platform.to_host_rows``).  Neither
+    the whole host copy of the planes nor ``re + 1j * im``, its cast
+    and the stream's copy exist: those were 6.5 times the file, 27 GB
+    for the 4.29 GB waterfall of a 2^30-sample segment, beside whatever
+    else the host holds.  A small waterfall is one block."""
     import io as _io
+
+    from srtb_tpu.utils.platform import to_host_rows
+    _two, streams, rows, cols = planes.shape
     fmt = np.lib.format
     head = _io.BytesIO()
     fmt.write_array_header_1_0(head, {
         "descr": fmt.dtype_to_descr(np.dtype(np.complex64)),
-        "fortran_order": False, "shape": tuple(re.shape)})
-    header = head.getvalue()
-    buf = np.empty(len(header) + 8 * re.size, dtype=np.uint8)
-    buf[:len(header)] = np.frombuffer(header, dtype=np.uint8)
-    body = buf[len(header):].view(np.complex64).reshape(re.shape)
-    body.real = re
-    body.imag = im
-    return buf
+        "fortran_order": False, "shape": (rows, cols)})
+    header = np.frombuffer(head.getvalue(), dtype=np.uint8)
+    files = []
+    for _ in range(streams):
+        buf = np.empty(header.size + 8 * rows * cols, dtype=np.uint8)
+        buf[:header.size] = header
+        files.append(buf)
+    bodies = [buf[header.size:].view(np.complex64).reshape(rows, cols)
+              for buf in files]
+    step = max(1, NPY_BLOCK_BYTES // (8 * streams * cols))
+    for lo in range(0, rows, step):
+        block = to_host_rows(planes, lo, min(rows, lo + step))
+        for s, body in enumerate(bodies):
+            body[lo:lo + step].real = block[0, s]
+            body[lo:lo + step].imag = block[1, s]
+    return files
 
 
 @dataclass
@@ -315,6 +333,8 @@ class WriteSignalSink:
         # ``file`` seconds when it began to (take_candidate)
         self._candidate_bytes = 0
         self._file_s_mark = 0.0
+        # ... and the seconds this thread wrote files itself
+        self._own_file_s = 0.0
         # check directory writability up front (ref: write_signal_pipe.hpp:62-75)
         check_path = cfg.baseband_output_file_prefix + ".check"
         with open(check_path, "wb"):
@@ -351,14 +371,18 @@ class WriteSignalSink:
         them over, summed over the threads (concurrent with the
         segment's stages, like ``device_ms``; complete where something
         drained the pool before the record was taken, else what has
-        landed so far).  {} after a quiet push."""
+        landed so far), and the seconds this thread wrote in the pool's
+        stead (a payload over the pool's bound: ``_write_bytes``).  {}
+        after a quiet push."""
         if not self._candidate_bytes:
             return {}
         out = {"candidate_bytes": self._candidate_bytes}
         self._candidate_bytes = 0
+        own, self._own_file_s = self._own_file_s, 0.0
         if self.pool is not None:
             out["writer_file_ms"] = 1e3 * (
-                self.pool.stats()["file_seconds"] - self._file_s_mark)
+                self.pool.stats()["file_seconds"] - self._file_s_mark
+                + own)
         return out
 
     # ------------------------------------------------------------------
@@ -474,7 +498,12 @@ class WriteSignalSink:
                 # quiet on this sanctioned sync
                 from srtb_tpu.utils.platform import to_host
                 with self._span("d2h"):
-                    wf = to_host(work.waterfall)
+                    # the stacked (re, im) boundary representation
+                    # [2, S, F, T] arrives as each stream's file, made
+                    # as its blocks of rows are fetched
+                    wf = _npy_complex64_by_blocks(work.waterfall) \
+                        if len(work.waterfall.shape) == 4 \
+                        else to_host(work.waterfall)
             with self._span("write"):
                 self._write_artifacts(work, base, wf)
             with self._span("publish"):
@@ -489,9 +518,10 @@ class WriteSignalSink:
         log.info(f"[write_signal] finished writing, file_counter = {counter}")
 
     def _write_artifacts(self, work: SegmentResultWork, base: str,
-                         wf: np.ndarray | None) -> None:
-        """``wf``: the segment's waterfall already on the host, or
-        None where the segment has none."""
+                         wf: "np.ndarray | list | None") -> None:
+        """``wf``: the segment's waterfall already on the host (a list:
+        each stream's ``.npy`` file as ``_npy_complex64_by_blocks`` made
+        it), or None where the segment has none."""
         bin_path = base + ".bin"
         with self._span("format"):
             payload = np.ascontiguousarray(work.segment.data)
@@ -499,14 +529,10 @@ class WriteSignalSink:
 
         npy_paths = []
         if wf is not None:
-            # stacked (re, im) boundary representation [2, S, F, T]: each
-            # stream's file is made from its two planes (_npy_complex64)
-            planes = wf if wf.ndim == 4 else None
-            if planes is not None:
-                wf = planes[0]
-            if wf.ndim == 2:
+            files = isinstance(wf, list)
+            if not files and wf.ndim == 2:
                 wf = wf[None]
-            for i in range(wf.shape[0]):
+            for i in range(len(wf)):
                 path = self._inflight_npy.get(i)
                 if path is None:
                     # pick first non-existing index (ref: 230-235);
@@ -523,8 +549,7 @@ class WriteSignalSink:
                     path = f"{base}.{j}.npy"
                     self._inflight_npy[i] = path
                 with self._span("format"):
-                    payload = _npy_complex64(planes[0, i], planes[1, i]) \
-                        if planes is not None \
+                    payload = wf[i] if files \
                         else _npy_bytes(wf[i].astype(np.complex64))
                 self._write_bytes(path, payload)
                 npy_paths.append(path)
@@ -619,26 +644,37 @@ class WriteSignalSink:
                 tmp = stage_write(path, _payload(data), fsync=fsync)
             self._tx_staged.append((path, tmp, fsync, commit))
             return
-        if self.pool is not None:
+        pool = self.pool
+        if pool is not None and 0 < pool.max_queued_bytes < data.nbytes:
+            # a payload over the pool's whole bound on queued copies
+            # (the 4.29 GB waterfall of a 2^30-sample segment, where the
+            # bound is 1 GiB) is not queued: the pool's copy would be a
+            # second 4.29 GB beside the file's bytes and the fetched
+            # planes, which with a 1 GiB ``.bin`` and the float64
+            # reference beside them ended a run on a 40 GiB host.  This
+            # thread writes it, as without a pool
+            pool = None
+        if pool is not None:
             if path in self._assigned_paths:
                 # same target queued again (e.g. a piggybacked segment
                 # sharing a packet counter): flush first so the later
                 # write deterministically wins instead of racing
                 with self._span("drain"):
-                    self.pool.drain()
+                    pool.drain()
                 self._assigned_paths.clear()
             self._assigned_paths.add(path)
             with self._span("submit"):
-                self.pool.submit(path, data, fsync=fsync, on_done=commit,
-                                 pre_publish=barrier,
-                                 timer=self.stage_timer,
-                                 trace_id=self._span_tid)
+                pool.submit(path, data, fsync=fsync, on_done=commit,
+                            pre_publish=barrier, timer=self.stage_timer,
+                            trace_id=self._span_tid)
             return
         # crash-consistent: a crash mid-write leaves an orphan temp
         # (swept at startup), never a torn candidate file
+        t0 = time.perf_counter()
         with self._span("file"):
             atomic_write(path, _payload(data), fsync=fsync,
                          pre_rename=barrier)
+        self._own_file_s += time.perf_counter() - t0
         if commit is not None:
             commit()
 

@@ -481,6 +481,13 @@ for _fam in (
                {"fft_strategy": "four_step", "fused_tail": "on",
                 **_RING_CFG},
                donate=True, staged=True),
+    PlanFamily("staged_unfused_ring", "the plain staged plan + ring "
+               "(what a 2^30 segment with a reserve resolves to): stage "
+               "(a) takes the carry and the new bytes as rows of its "
+               "view and joins them a strip at a time",
+               {"fft_strategy": "four_step", "fused_tail": "off",
+                **_RING_CFG},
+               donate=True, staged=True),
     # ---- front-fused staged megakernel (staged_ffuse): unpack +
     # window + even/odd pack + FFT pass 1 fold into the pallas2 pass-1
     # kernel (raw bytes in, blocked intermediate out) and the whole

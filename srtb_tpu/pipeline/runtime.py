@@ -261,6 +261,17 @@ class _DeadlineArray:
     def __getitem__(self, idx):
         return self.__array__()[idx]
 
+    def rows(self, lo: int, hi: int):
+        """``[..., lo:hi, :]`` on the host, fetched on its own under the
+        deadline and not kept (utils/platform.to_host_rows): a sink that
+        takes the waterfall a block at a time never makes the whole
+        host copy."""
+        if self._fetched:
+            return self._arr[..., lo:hi, :]
+        from srtb_tpu.utils.platform import to_host_rows
+        dev = self._arr
+        return self._sync(lambda: to_host_rows(dev, lo, hi))
+
     def __array__(self, dtype=None, copy=None):
         if not self._fetched:
             dev = self._arr

@@ -472,6 +472,9 @@ class SegmentProcessor:
             * self.fmt.data_stream_count)
         self.stride_bytes = self._segment_bytes - self.reserved_bytes
         self.ring = self._resolve_ring()
+        # the staged ring hands stage (a) the bytes as whole rows of its
+        # ``[T, bytes a row]`` view (0: flat bytes, joined in the program)
+        self.ring_row_bytes = self._resolve_ring_rows()
 
         # Pallas kernels need interpret mode off-TPU (CPU CI)
         from srtb_tpu.utils.platform import on_accelerator
@@ -541,8 +544,13 @@ class SegmentProcessor:
         if self.ring:
             ring_donate = (0,) + ((1,) if self._donate_input else ())
             if self.staged:
+                # the carry alone: stage (a) returns the boundary
+                # (float32, four times the segment's bytes) and the
+                # next carry, so the stride's bytes can alias nothing
+                # and their donation was dropped at every compile
+                # ("Some donated buffers were not usable")
                 self._jit_stage_a_ring = jax.jit(
-                    self._stage_a_ring, donate_argnums=ring_donate)
+                    self._stage_a_ring, donate_argnums=(0,))
                 self._jit_stage_a_cold = jax.jit(
                     self._stage_a_cold, donate_argnums=in_donate)
             else:
@@ -688,16 +696,66 @@ class SegmentProcessor:
         return (self._process(raw, chirp_ri, chirp_w_ri),
                 self._next_carry(raw))
 
-    def _stage_a_with_carry(self, raw: jnp.ndarray):
+    def _resolve_ring_rows(self) -> int:
+        """Bytes in a row of stage (a)'s ``[T, bytes a row]`` view of a
+        segment where the staged ring assembles by strips, else 0.  The
+        plain staged plan's stage (a) makes a block of boundary rows
+        from a strip of columns of that view (``_stage_a_rows``), and
+        the reserve is whole rows of it (``ops/dedisperse.nsamps_
+        reserved`` keeps the stride a multiple of ``2 * channels``
+        samples: 3994 rows of carry and 12390 of new bytes, 65536 bytes
+        each, at 2^30 / 2^15 / DM 56.77).  So the carry and the new
+        bytes cross to the device as ``[rows, bytes a row]`` and a block
+        reads its strip from each: no ``u8[segment_bytes]`` join and no
+        relayout of a whole segment's bytes (2 x 1.07 GB at 2^30 8-bit
+        samples).  0 where stage (a) walks no blocks (several streams in
+        one byte stream, the fused-tail and Pallas variants): those
+        rings keep flat bytes and the whole-segment join."""
+        if not (self.ring and self.staged and self._stage_a_block_rows()):
+            return 0
+        row = self.channel_count * 2 * abs(
+            int(self.cfg.baseband_input_bits)) // 8
+        if self.reserved_bytes % row:
+            raise ValueError(
+                f"the staged ring's reserve of {self.reserved_bytes} "
+                f"bytes is no whole number of {row}-byte rows")
+        return row
+
+    def _ring_shape(self, nbytes: int) -> tuple:
+        """``nbytes`` of a segment as the staged ring's programs take
+        them: whole rows by strips, else flat."""
+        row = self.ring_row_bytes
+        return (nbytes // row, row) if row else (nbytes,)
+
+    def _ring_aval(self, nbytes: int) -> jax.ShapeDtypeStruct:
+        return jax.ShapeDtypeStruct(self._ring_shape(nbytes), jnp.uint8)
+
+    def _ring_rows(self, raw):
+        """``raw`` in :meth:`_ring_shape` (host bytes are viewed; a
+        device array already in that shape is itself)."""
+        return raw.reshape(self._ring_shape(raw.size))
+
+    def _stage_a_with_carry(self, *parts: jnp.ndarray):
         """Shared body of the staged ring variants: stage (a) — in
-        whichever spelling the plan resolved, classic or front-fused —
-        plus the next carry sliced from the same assembled raw view.
-        One home, so the warm/cold twins (and any future variant)
-        cannot drift apart."""
+        whichever spelling the plan resolved — plus the next carry,
+        from the segment's bytes in ``parts``: the carry and the new
+        bytes (warm) or the whole upload (cold).  One home, so the
+        warm/cold twins (and any future variant) cannot drift apart.
+        By strips (``ring_row_bytes``) the parts are rows of stage
+        (a)'s view, joined a strip at a time inside its loop, and the
+        next carry is the last rows of the last part (the reserve is
+        under half a segment: ``refuse_overlong_reserve``); else they
+        are flat bytes, joined whole."""
+        if self.ring_row_bytes:
+            with jax.named_scope(S.RING):
+                carry = parts[-1][-(self.reserved_bytes
+                                    // self.ring_row_bytes):]
+            return self._stage_a_rows(parts), carry
+        raw = parts[0] if len(parts) == 1 else self._assemble(*parts)
         return self._stage_a(raw), self._next_carry(raw)
 
     def _stage_a_ring(self, carry: jnp.ndarray, new: jnp.ndarray):
-        return self._stage_a_with_carry(self._assemble(carry, new))
+        return self._stage_a_with_carry(carry, new)
 
     def _stage_a_cold(self, raw: jnp.ndarray):
         return self._stage_a_with_carry(raw)
@@ -1053,7 +1111,7 @@ class SegmentProcessor:
         if self.front_fuse:
             return self._stage_a_front(raw)
         if self.staged_rows:
-            return self._stage_a_rows(raw)
+            return self._stage_a_rows((raw.reshape(self.watfft_len, -1),))
         return self._boundary_canon(self._stage_a_nat(raw))
 
     def _stage_b(self, a_ri, aux=None):
@@ -1268,19 +1326,23 @@ class SegmentProcessor:
         return rows if self.channel_count % rows == 0 else 0
 
     @S.scoped(S.FFT_R2C)
-    def _stage_a_rows(self, raw: jnp.ndarray):
+    def _stage_a_rows(self, parts: tuple):
         """unpack + even/odd pack + segment-FFT first half over blocks of
         the boundary's rows.  Row ``j2`` of ``A[j2, k1]`` transforms the
         points ``x[j1*F + j2]``: a block of rows reads one strip of
         columns of the bytes' ``[T, bytes a row]`` view, unpacks and
         packs it, transforms it and writes its rows, so the unpacked
         float32 samples (4 GB at 2^30) and the packed plane exist a
-        block at a time."""
+        block at a time.  ``parts`` are that view's rows: all of them in
+        one array or, from the staged ring, the carry's and then the
+        new bytes'; a strip is read from each part and the strips are
+        joined under ``srtb.ring``, so the ring never makes the view."""
         cfg = self.cfg
         n2, n1 = self.channel_count, self.watfft_len
         jw = self._stage_a_block_rows()
         if not jw:
-            z = self._staged_pack(raw)                        # [S, n/2]
+            (raw,) = parts          # no ring by strips without blocks
+            z = self._staged_pack(raw.reshape(-1))            # [S, n/2]
             # the rows are a plane here: XLA's own cap, not a block's
             a = F.four_step_stage1_cols(
                 z.reshape(z.shape[0], n1, n2),
@@ -1288,12 +1350,17 @@ class SegmentProcessor:
             return jnp.stack([jnp.real(a), jnp.imag(a)])
         bits = abs(int(cfg.baseband_input_bits))
         wb = jw * 2 * bits // 8              # a block's bytes in a row
-        raw2 = raw.reshape(n1, n2 * 2 * bits // 8)
         win2 = None if self.window is None \
             else self.window.reshape(n1, 2 * n2)
 
         def body(b, out):
-            x = jax.lax.dynamic_slice_in_dim(raw2, b * wb, wb, 1)
+            strips = [jax.lax.dynamic_slice_in_dim(p, b * wb, wb, 1)
+                      for p in parts]
+            if len(strips) == 1:
+                x = strips[0]
+            else:
+                with jax.named_scope(S.RING):
+                    x = jnp.concatenate(strips)               # [n1, wb]
             win = None if win2 is None else jax.lax.dynamic_slice_in_dim(
                 win2, b * 2 * jw, 2 * jw, 1).reshape(-1)
             x = unpack_streams(x.reshape(-1), self.fmt.unpack_variant,
@@ -1781,17 +1848,19 @@ class SegmentProcessor:
                  (b_out,), (0,)),
             ]
             if self.ring:
+                # the bytes as the staged ring takes them (rows where it
+                # assembles by strips), the carry alone donated
                 progs += [
                     ("stage_a_ring",
                      # srtb-lint: disable=recompile-hazard
-                     jax.jit(self._stage_a_ring,
-                             donate_argnums=ring_donate),
-                     (carry_s, new_s), ring_donate),
+                     jax.jit(self._stage_a_ring, donate_argnums=(0,)),
+                     (self._ring_aval(self.reserved_bytes),
+                      self._ring_aval(self.stride_bytes)), (0,)),
                     ("stage_a_cold",
                      # srtb-lint: disable=recompile-hazard
                      jax.jit(self._stage_a_cold,
                              donate_argnums=in_donate),
-                     (raw_s,), in_donate),
+                     (self._ring_aval(expected),), in_donate),
                 ]
             return progs
 
@@ -1882,9 +1951,11 @@ class SegmentProcessor:
             if self.ring:
                 self._jit_stage_a_ring = cache.get_or_compile(
                     "stage_a_ring", sig, self._jit_stage_a_ring,
-                    carry_s, new_s)
+                    self._ring_aval(self.reserved_bytes),
+                    self._ring_aval(self.stride_bytes))
                 self._jit_stage_a_cold = cache.get_or_compile(
-                    "stage_a_cold", sig, self._jit_stage_a_cold, raw_s)
+                    "stage_a_cold", sig, self._jit_stage_a_cold,
+                    self._ring_aval(expected))
         self.aot_active = True
         return True
 
@@ -2010,7 +2081,9 @@ class SegmentProcessor:
             # segment_bytes + warm_count * stride_bytes
             metrics.add("ring_cold_dispatches")
         self._count_h2d(staged.nbytes)
-        return jax.device_put(staged)
+        # by strips the bytes land on the device as the rows stage (a)
+        # reads (a view on the host: the same bytes in the same order)
+        return jax.device_put(self._ring_rows(staged))
 
     def _batch_jit(self):
         """The lazily-built micro-batch program: the fused plan vmapped
@@ -2178,7 +2251,8 @@ class SegmentProcessor:
                 # whole chain under one timer (see run_device): the
                 # b/c stages compile on first dispatch too
                 a, nc = self._enqueue_stage(
-                    "a", self._jit_stage_a_ring, carry, new)
+                    "a", self._jit_stage_a_ring, self._ring_rows(carry),
+                    self._ring_rows(new))
                 if not self._sanitize:
                     return self._enqueue_stage(
                         "c", self._jit_stage_c,
@@ -2218,7 +2292,7 @@ class SegmentProcessor:
             def _run_cold():
                 # whole chain under one timer (see run_device)
                 a, nc = self._enqueue_stage(
-                    "a", self._jit_stage_a_cold, raw)
+                    "a", self._jit_stage_a_cold, self._ring_rows(raw))
                 if not self._sanitize:
                     return self._enqueue_stage(
                         "c", self._jit_stage_c,

@@ -6,6 +6,8 @@ nothing here overrides it.
 
 from __future__ import annotations
 
+import functools
+
 
 def to_host(x):
     """Explicit device->host fetch for possibly-device arrays — the
@@ -19,6 +21,42 @@ def to_host(x):
     if isinstance(x, jax.Array):
         return jax.device_get(x)
     return np.asarray(x)
+
+
+def to_host_rows(x, lo: int, hi: int):
+    """Rows ``[..., lo:hi, :]`` of a possibly-device array on the host,
+    fetched on their own: a consumer that takes a multi-GB array a block
+    at a time (the candidate writer, a 2^30-sample segment's 4.29 GB
+    waterfall) never holds the whole host copy that :func:`to_host`
+    makes and the array memoizes.  A lazy handle with a ``rows`` method
+    (``pipeline/runtime._DeadlineArray``) fetches under its own
+    deadline."""
+    import jax
+    import numpy as np
+
+    if hasattr(x, "rows"):
+        return x.rows(lo, hi)
+    if isinstance(x, jax.Array):
+        return jax.device_get(_row_block(x, lo, hi - lo))
+    return np.asarray(x)[..., lo:hi, :]
+
+
+@functools.lru_cache(maxsize=None)
+def _row_block_program():
+    """The one jitted slicer (made once: ``jax`` is imported late in
+    this module)."""
+    import jax
+
+    def rows(a, start, count):
+        return jax.lax.dynamic_slice_in_dim(a, start, count, a.ndim - 2)
+
+    return jax.jit(rows, static_argnums=2)
+
+
+def _row_block(x, lo: int, count: int):
+    """``count`` rows from ``lo`` of the device array ``x``, sliced on
+    the device by one program whatever ``lo`` is."""
+    return _row_block_program()(x, lo, count)
 
 
 def on_accelerator() -> bool:

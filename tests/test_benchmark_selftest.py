@@ -6,8 +6,8 @@ file imports them under their own names and adds one guard of its own.
 
 The selftest directory goes on ``sys.path`` and its modules are imported
 by their top-level names, as ``pytest benchmark/selftest`` imports them:
-``test_naoc_cell``, ``test_2pol_cell`` and ``test_2p30_cell`` do ``import
-test_scopes`` (its hand-built profile messages; since PR 39 a tiny cell
+``test_naoc_cell``, ``test_2pol_cell``, ``test_2p30_cell`` and
+``test_crab_cell`` (PR 44) do ``import test_scopes`` (its hand-built profile messages; since PR 39 a tiny cell
 names the cell it stands for in its own file and none registers in that
 module any more), so all must see one module object.  The two
 grid cases want four devices where ``tests/conftest.py`` forces eight;
@@ -28,7 +28,9 @@ way and asserts what the selftest asserts but for that line, until a
 metrics against another cell's (``METRIC_SET_CASES``; the third against
 its tiny relative's, whose ``BENCHMARK.json`` a PR that changes the
 program may not edit): they hold as written over the metrics they were
-written for, and this file says what PR 42's eight add to each cell.
+written for, and this file says what PR 42's eight add to each cell;
+PR 42's own case holds its eight to the end of ``per_layer`` and reads
+the file without the ninth that PR 44 appended behind them.
 """
 
 import itertools
@@ -46,6 +48,7 @@ sys.path[:0] = [ROOT, SELFTEST]
 
 import test_2p30_cell  # noqa: E402
 import test_2pol_cell  # noqa: E402
+import test_crab_cell  # noqa: E402
 import test_gen  # noqa: E402
 import test_naoc_cell  # noqa: E402
 import test_reference  # noqa: E402
@@ -67,6 +70,8 @@ FOUR_DEVICES = ("test_grid_rehearsal_reports_its_five_stages",
 DRAIN_CASE = "test_a_record_is_stamped_when_it_arrives_not_at_the_next_pull"
 GRID_CELL_CASE = "test_grid_cell_on_four_virtual_devices"
 
+LISTED_CASE = "test_every_new_metric_is_listed_where_the_issue_lists_it"
+
 # the cases that pin one cell's set of per-layer metrics to another's
 METRIC_SET_CASES = {
     "test_the_two_stream_cells_files_load_through_spec": test_2pol_cell,
@@ -75,11 +80,11 @@ METRIC_SET_CASES = {
 }
 
 for _mod in (test_gen, test_reference, test_trace, test_scopes,
-             test_naoc_cell, test_2pol_cell, test_2p30_cell, test_run,
-             test_setup_spans):
+             test_naoc_cell, test_2pol_cell, test_2p30_cell, test_crab_cell,
+             test_run, test_setup_spans):
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_") and callable(_obj) \
-                and _name not in FOUR_DEVICES + (DRAIN_CASE,
+                and _name not in FOUR_DEVICES + (DRAIN_CASE, LISTED_CASE,
                                                  GRID_CELL_CASE) \
                 and _name not in METRIC_SET_CASES:
             assert _name not in globals(), _name
@@ -118,12 +123,23 @@ def test_a_cells_metrics_against_another_cells(case, monkeypatch):
     staged root lists what the 2^30 cell listed then), then what PR 42
     appended: the two-stream cell gets what the one-stream cell
     gets, the 2^30 cell everything the flagship gets but
-    ``plan.chirp_bank_s``, since its plan holds no bank."""
+    ``plan.chirp_bank_s``, since its plan holds no bank.  PR 44's cell
+    and its one metric are left out the same way (``test_crab_cell``
+    holds that cell's own set)."""
     from benchmark import spec as spec_mod
 
     new = test_setup_spans.NEW
     metrics = spec_mod.Spec.metrics
+    init = spec_mod.Spec.__init__
+
+    def before_pr44(self, root, workload):
+        # PR 44's cell joined ``ops.stage_b_`` / ``_c_ms_per_seg``, which
+        # the 2^30 case holds to its own cell, and brought one metric
+        init(self, root, workload)
+        self.bench = _before_pr44(self.bench)
+
     with monkeypatch.context() as patch:
+        patch.setattr(spec_mod.Spec, "__init__", before_pr44)
         patch.setattr(
             spec_mod.Spec, "metrics",
             lambda self, kind: [(m, r) for m, r in metrics(self, kind)
@@ -134,6 +150,34 @@ def test_a_cells_metrics_against_another_cells(case, monkeypatch):
         "j1644_2p30.replay_quiet"))
     assert len(flagship) == 7 and two_pol == flagship
     assert big == flagship - {"plan.chirp_bank_s"}
+
+
+def _before_pr44(bench: dict) -> dict:
+    """A ``BENCHMARK.json`` without what PR 44 appended to ``per_layer``:
+    its one metric, and its cell's name at the end of the lists it
+    joined."""
+    if "per_layer" not in bench:
+        return bench
+    return dict(bench, per_layer=[
+        dict(m, workloads=[w for w in m["workloads"]
+                           if w != test_crab_cell.CELL])
+        if "workloads" in m else m
+        for m in bench["per_layer"] if m["name"] != test_crab_cell.NEW])
+
+
+def test_every_new_metric_is_listed_where_the_issue_lists_it(monkeypatch):
+    """PR 42's case holds its eight metrics to the END of ``per_layer``,
+    where PR 44 appended a ninth behind them: it reads the file as PR 42
+    left it (``test_crab_cell`` holds the ninth to the end)."""
+    class Json:
+        dumps, loads = json.dumps, json.loads
+
+        @staticmethod
+        def load(f):
+            return _before_pr44(json.load(f))
+
+    monkeypatch.setattr(test_setup_spans, "json", Json)
+    getattr(test_setup_spans, LISTED_CASE)()
 
 
 def _four_device_env() -> dict:

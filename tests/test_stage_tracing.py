@@ -122,12 +122,30 @@ def _own_programs(names, **extra):
         pf2.cols_factor = production
 
 
+def _strip_ring_programs(names, **extra):
+    """The plan a 2^30-sample segment with a reserve resolves to by
+    itself (ISSUE 44): the plain staged plan's ring, stage (a) taking
+    the carry and the new bytes as rows and joining them a strip at a
+    time.  The bankless rule is patched down in the test, as
+    ``tests/test_staged_rows.py`` patches it: no option chooses the
+    plan."""
+    from srtb_tpu.pipeline import segment
+    rule = segment.FUSED_TAIL_DF64_MAX_SPECTRUM
+    segment.FUSED_TAIL_DF64_MAX_SPECTRUM = N >> 4
+    try:
+        return _served_programs(names, staged=True, **extra)
+    finally:
+        segment.FUSED_TAIL_DF64_MAX_SPECTRUM = rule
+
+
 FAMILIES = {
     # the quiet cell's plan: monolithic R2C, fused, overlap-save ring
     "ring": (lambda: _served_programs({"ring"}), RING),
     "ring_cold": (lambda: _served_programs({"ring_cold"}), RING),
     "staged_ring": (lambda: _served_programs(
         {"stage_a_ring", "stage_a_cold"}, staged=True), {S.RING}),
+    "staged_ring_strips": (lambda: _strip_ring_programs(
+        {"stage_a_ring", "stage_a_cold"}), {S.RING, S.FFT_R2C}),
     "batch_ring": (lambda: _served_programs(
         {"batch_ring", "batch_cold"}, micro_batch_segments=2), RING),
     "staged": (lambda: _served_programs(
@@ -226,6 +244,50 @@ def _located_ops(located: str, within: str = "") -> tuple:
                      flags=re.M)
     return [(op, types, names.get(loc, "")) for op, types, loc in ops], \
         names
+
+
+@pytest.mark.parametrize("program", ["stage_a_ring", "stage_a_cold"])
+def test_the_staged_rings_byte_operations_are_the_rings_or_stage_as(
+        program):
+    """The staged ring by strips (ISSUE 44): every operation on bytes in
+    its warm and cold stage (a) carries ``srtb.ring`` (the join of a
+    strip of the carry's rows with a strip of the new bytes', the slice
+    that leaves the next carry), ``srtb.unpack`` or ``srtb.fft_r2c``
+    (a block's strip read from the rows, its cast), and none makes an
+    array of a whole segment's bytes: neither the flat join nor the
+    ``[T, bytes a row]`` view of it."""
+    _text, located = _strip_ring_programs({program})[program]
+    ops, _names = _located_ops(located)
+    byte_ops = [(op, types, name) for op, types, name in ops
+                if "ui8>" in types and op != "stablehlo.constant"]
+    assert byte_ops
+    # the loop's body is printed as a function of its own, its names
+    # relative to the loop's: ``srtb.fft_r2c/while``, stage (a)'s
+    assert re.search(r"srtb\.fft_r2c/while", located)
+    innermost = collections.Counter(
+        (re.findall(r"srtb\.[a-z0-9_]+", name) or [S.FFT_R2C])[-1]
+        for _op, _t, name in byte_ops)
+    assert set(innermost) <= {S.RING, S.UNPACK, S.FFT_R2C}, innermost
+    assert {op for op, _t, name in byte_ops if not _scopes_in(name)} \
+        <= {"stablehlo.dynamic_slice", "stablehlo.reshape"}
+    ring = sorted(op for op, _t, name in byte_ops if S.RING in name)
+    row, rows = 2 * 64, N // (2 * 64)            # 8-bit samples, 64 channels
+    whole = (f"tensor<{N}xui8>", f"tensor<{rows}x{row}xui8>")
+    made = [types for _op, types, _n in byte_ops
+            if types.split("->")[-1].strip() in whole]
+    assert not made, made
+    if program == "stage_a_ring":
+        # a strip of each part joined inside the loop, the carry sliced
+        assert ring == ["stablehlo.concatenate", "stablehlo.slice"]
+        joined = [types for op, types, name in byte_ops
+                  if op == "stablehlo.concatenate"]
+        strip = re.fullmatch(
+            rf"\(tensor<(\d+)x(\d+)xui8>, tensor<(\d+)x\2xui8>\) -> "
+            rf"tensor<{rows}x\2xui8>", joined[0])
+        assert strip and int(strip.group(2)) < row
+        assert int(strip.group(1)) + int(strip.group(3)) == rows
+    else:
+        assert ring == ["stablehlo.slice"]
 
 
 def test_the_split_is_the_unpacks_and_no_sample_stack_is_traced():
@@ -727,6 +789,45 @@ def test_the_candidates_write_by_child(tmp_path, monkeypatch, how):
     metrics.reset()
 
 
+def test_a_payload_over_the_pools_bound_is_written_by_the_sink(
+        tmp_path, monkeypatch):
+    """ISSUE 44: the pool bounds the bytes of queued copies (1 GiB); a
+    payload over the whole bound (a 2^30-sample segment's 4.29 GB
+    waterfall) would be held a second time for nothing, so the sink's
+    thread writes it itself under ``file``, and the smaller files still
+    go through the pool.  The record's ``writer_file_ms`` covers both."""
+    from srtb_tpu.io import native_writer
+
+    # the .bin (N bytes) fits the bound, the waterfall (4 N + header) not
+    monkeypatch.setattr(native_writer.AsyncWriterPool,
+                        "DEFAULT_MAX_QUEUED_BYTES", 2 * N)
+    metrics.reset()
+    cfg = _pulsed_cfg(tmp_path, writer_thread_count=2)
+    with Pipeline(cfg) as pipe:
+        pool = pipe._owned_writer_pool
+        assert pool.max_queued_bytes == 2 * N
+        pipe.sinks.append(_Drainer(pipe))
+        pipe.run()
+        written = list(pipe.sinks[0].written)
+        jobs = pool.stats()["jobs_done"]
+    files = [written[0].bin_path, *written[0].npy_paths,
+             *written[0].tim_paths]
+    assert len(written) == 1 and len(written[0].npy_paths) == 1
+    assert os.path.getsize(written[0].npy_paths[0]) > 2 * N \
+        > os.path.getsize(written[0].bin_path)
+    assert jobs == len(files) - 1
+    wf = np.load(written[0].npy_paths[0])
+    assert wf.shape == (64, N // 2 // 64) and wf.dtype == np.complex64
+    rec, = [r for r in TR.load(cfg.telemetry_journal_path) if r["dump"]]
+    ms = rec["stages_ms"]
+    assert {"format", "submit", "drain", "file"} <= set(ms)
+    assert sum(ms[k] for k in ("format", "submit", "drain", "file")) \
+        <= ms["write"] + 1e-3
+    assert rec["writer_file_ms"] >= ms["file"] - 0.01
+    assert rec["candidate_bytes"] == sum(os.path.getsize(p) for p in files)
+    metrics.reset()
+
+
 def test_report_tolerates_records_without_the_candidate_fields(tmp_path):
     """A v12 journal (no ``candidate_bytes``) has no candidates section,
     and a mixed one counts the v13 records only."""
@@ -832,6 +933,42 @@ def test_first_dispatches_by_program_add_up_served(tmp_path, logged):
         r"(\w+) ([0-9.]+) s(?:,|$)", line[0].split("dispatches: ")[1])}
     assert list(said) == ["ring_cold", "ring"]
     assert sum(said.values()) == pytest.approx(total, abs=0.011)
+    metrics.reset()
+
+
+def test_the_staged_ring_names_its_plan_dispatches_and_carry(
+        tmp_path, logged, monkeypatch):
+    """ISSUE 44: every record of the staged ring says the plan, the
+    ``[setup]`` line names its two first dispatches, and the carry the
+    device kept reads the reserve's bytes on a warm dispatch and nothing
+    on the cold one."""
+    from srtb_tpu.pipeline import segment
+
+    monkeypatch.setattr(segment, "STAGED_MIN_N", N)
+    monkeypatch.setattr(segment, "FUSED_TAIL_DF64_MAX_SPECTRUM", N >> 4)
+    metrics.reset()
+    cfg = _pulsed_cfg(tmp_path, segments=4)
+    with Pipeline(cfg) as pipe:
+        proc = pipe.processor
+        assert proc.ring_row_bytes == 2 * 64
+        pipe.run()
+        first = dict(proc.first_dispatch_s)
+    assert list(first) == ["staged_ring_cold", "staged_ring"]
+    assert _by_program("compile_seconds") == first
+    recs = TR.load(cfg.telemetry_journal_path)
+    assert len(recs) >= 4
+    assert {r["active_plan"] for r in recs} == {"staged:monolithic+rows+ring"}
+    # the counters are the process's, read when a record is written:
+    # by the last record every dispatch is counted
+    warm = len(recs) - 1
+    assert recs[-1]["ring_cold_dispatches"] == 1
+    assert recs[-1]["ring_carry_bytes"] == warm * proc.reserved_bytes
+    assert recs[-1]["h2d_bytes"] == N + warm * proc.stride_bytes
+    line = [ln for ln in logged().splitlines() if "[setup] construct" in ln]
+    assert len(line) == 1 and "no chirp_bank" in line[0]
+    said = re.findall(r"(\w+) [0-9.]+ s(?:,|$)",
+                      line[0].split("dispatches: ")[1])
+    assert said == ["staged_ring_cold", "staged_ring"]
     metrics.reset()
 
 

@@ -16,6 +16,7 @@ without one).
 """
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,21 @@ def _j1644(log2n: int, **over) -> Config:
     )
     kw.update(over)
     return Config(**kw)
+
+
+def _naoc(log2n: int, dm: float) -> Config:
+    """The upstream defaults (8-bit 1 GSa/s, 2^15 channels, reserve on:
+    ``benchmark/configs/naoc_1g_dm14.json`` / ``naoc_crab_2p30.json``)."""
+    return Config(
+        baseband_input_count=1 << log2n, baseband_input_bits=8,
+        baseband_format_type="simple", baseband_freq_low=1000.0,
+        baseband_bandwidth=500.0, baseband_sample_rate=1e9, dm=dm,
+        spectrum_channel_count=1 << 15,
+        mitigate_rfi_average_method_threshold=10.0,
+        mitigate_rfi_spectral_kurtosis_threshold=1.1,
+        signal_detect_signal_noise_threshold=6.0,
+        signal_detect_max_boxcar_length=1024,
+        baseband_reserve_sample=True)
 
 
 def _compile_all(proc, one_chip, only=None) -> dict:
@@ -399,17 +415,7 @@ def test_naoc_1g_under_auto_keeps_xlas_transform_and_fits_twice(
     from srtb_tpu.pipeline.segment import SegmentProcessor
 
     _as_on_a_chip(monkeypatch)
-    cfg = Config(
-        baseband_input_count=1 << 28, baseband_input_bits=8,
-        baseband_format_type="simple", baseband_freq_low=1000.0,
-        baseband_bandwidth=500.0, baseband_sample_rate=1e9, dm=14.2,
-        spectrum_channel_count=1 << 15,
-        mitigate_rfi_average_method_threshold=10.0,
-        mitigate_rfi_spectral_kurtosis_threshold=1.1,
-        signal_detect_signal_noise_threshold=6.0,
-        signal_detect_max_boxcar_length=1024,
-        baseband_reserve_sample=True)
-    proc = SegmentProcessor(cfg, donate_input=True)
+    proc = SegmentProcessor(_naoc(28, 14.2), donate_input=True)
     assert proc.plan_name == "fused:monolithic+ring"
     assert not proc.own_tail and not proc.fused_tail
     compiled = _compile_all(proc, one_chip, only={"ring", "ring_cold"})
@@ -417,3 +423,51 @@ def test_naoc_1g_under_auto_keeps_xlas_transform_and_fits_twice(
         assert "tpu_custom_call" not in c.as_text(), name
         assert _two_in_flight_bytes(c) < V5E_BYTES_LIMIT, (
             name, c.memory_analysis())
+
+
+# ---- the upstream defaults at the Crab's DM (ISSUE 44) -----------------
+
+def test_naoc_crab_2p30_ring_assembles_by_strips_and_fits_twice(one_chip):
+    """The deployment of ``benchmark/configs/naoc_crab_2p30.json`` at its
+    own size: 2^30 samples of 8 bits, 2^15 channels, DM 56.77, 24.38 % of
+    every segment overlapped.  The program picks the staged plan's ring
+    by itself; its warm and cold stage (a) take the bytes as rows of
+    65536 (3994 of carry, 12390 new) and hold no ``u8`` temporary of a
+    whole segment, neither the join of carry and new bytes nor a
+    relayout of them (the parent held both, 1.14 GB of temporaries where
+    these hold 0.10 / 0.07), the carry aliases its donated twin, and by
+    section 4's reckoning (three boundaries, two uploads, two carries,
+    the largest program's temporaries) two segments in flight stay under
+    16.0 GB with a cold pass among them."""
+    from srtb_tpu.pipeline.segment import SegmentProcessor
+
+    proc = SegmentProcessor(_naoc(30, 56.77), donate_input=True)
+    assert proc.plan_name == "staged:four_step+rows+ring"
+    assert (proc.reserved_bytes, proc.stride_bytes) \
+        == (261_750_784, 811_991_040)
+    assert proc.time_reserved_count == 7988 and proc.watfft_len == 16384
+    assert proc.ring_row_bytes == 65536
+    programs = {"stage_a_ring", "stage_a_cold", "stage_b", "stage_c"}
+    compiled = _compile_all(proc, one_chip, only=programs)
+    assert set(compiled) == programs
+    memory = {name: c.memory_analysis() for name, c in compiled.items()}
+    boundary = 2 * (1 << 29) * 4
+    for name in ("stage_a_ring", "stage_a_cold"):
+        text = compiled[name].as_text()
+        # what makes an array of a whole segment's bytes: nothing, but
+        # the cold program's own argument, read in place by its loop
+        made = set(re.findall(
+            r"= u8\[(?:1073741824|16384,65536)\]\S* ([a-z-]+)\(", text))
+        assert made <= {"parameter", "get-tuple-element"}, made
+        assert memory[name].temp_size_in_bytes < 0.5e9, memory[name]
+        # the boundary and the next carry (3994 rows tiled as 4000)
+        assert 0 <= memory[name].output_size_in_bytes \
+            - boundary - proc.reserved_bytes < 1 << 20
+    assert 0 <= memory["stage_a_ring"].alias_size_in_bytes \
+        - proc.reserved_bytes < 1 << 20
+    for name in ("stage_b", "stage_c"):
+        assert memory[name].alias_size_in_bytes >= boundary, memory[name]
+    in_flight = (3 * boundary + (1 << 30) + proc.stride_bytes
+                 + 2 * proc.reserved_bytes
+                 + max(m.temp_size_in_bytes for m in memory.values()))
+    assert in_flight < 16.0e9 < V5E_BYTES_LIMIT, in_flight

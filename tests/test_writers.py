@@ -227,3 +227,69 @@ def test_piggybank_other_polarization_capture(tmp_path):
 
     counters = [os.path.basename(w.bin_path) for w in sink.written]
     assert counters == ["cand_100.bin", "cand_101.bin"]
+
+
+# ----------------------------------------------------------------------
+# The stacked waterfall is made into each stream's file from the device
+# a block of rows at a time, and a file over the writer pool's bound
+# (the 4.29 GB of a 2^30-sample segment) is written by the sink's own
+# thread (ISSUE 44): the same bytes, without the whole host copy of the
+# planes and without the pool's copy.
+# ----------------------------------------------------------------------
+
+def _planes_work(streams=2):
+    work = _mk_work()
+    rng = np.random.default_rng(7)
+    work.waterfall = rng.normal(size=(2, streams, 16, 32)) \
+        .astype(np.float32)
+    work.detect = None
+    return work
+
+
+def _npy_files(planes):
+    """Each stream's file, spelled out."""
+    from srtb_tpu.io import writers
+    return [np.frombuffer(writers._npy_bytes(
+        (planes[0, s] + 1j * planes[1, s]).astype(np.complex64)), np.uint8)
+        for s in range(planes.shape[1])]
+
+
+@pytest.mark.parametrize("where", ["host", "device", "lazy"])
+def test_npy_by_blocks_is_the_whole_planes_file(where, monkeypatch):
+    import jax
+
+    from srtb_tpu.io import writers
+    from srtb_tpu.pipeline.runtime import _DeadlineArray
+
+    planes = _planes_work().waterfall
+    want = _npy_files(planes)
+    # blocks of 3 rows: the last one is short
+    monkeypatch.setattr(writers, "NPY_BLOCK_BYTES", 3 * 8 * 2 * 32)
+    src = planes if where == "host" else jax.device_put(planes)
+    if where == "lazy":
+        src = _DeadlineArray(src, lambda fn: fn())
+    got = writers._npy_complex64_by_blocks(src)
+    assert len(got) == 2
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    if where == "lazy":
+        assert not src._fetched          # the whole was never fetched
+
+
+def test_an_oversized_waterfall_is_written_by_the_sink(tmp_path,
+                                                       monkeypatch):
+    from srtb_tpu.io import writers
+
+    work = _planes_work()
+    want = _npy_files(work.waterfall)
+    monkeypatch.setattr(writers, "NPY_BLOCK_BYTES", 4096)
+    with AsyncWriterPool(n_threads=2, max_queued_bytes=2048) as pool:
+        sink = WriteSignalSink(_mk_cfg(tmp_path, "blocks"),
+                               fdatasync=False, writer_pool=pool)
+        sink.push(work, has_signal=True)
+        sink.drain()
+        assert pool.stats()["jobs_done"] == 1        # the .bin alone
+    written = sink.written[0]
+    assert len(written.npy_paths) == 2
+    for path, w in zip(written.npy_paths, want):
+        np.testing.assert_array_equal(np.fromfile(path, np.uint8), w)
