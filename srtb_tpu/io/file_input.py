@@ -40,6 +40,10 @@ from srtb_tpu.utils.metrics import metrics
 # process-wide segment-buffer pool (ref: srtb::host_allocator singleton,
 # global_variables.hpp:49-61)
 host_buffer_pool = BufferPool("segments")
+# ... and the readers' retained overlap tails: a reader gives its tail
+# back when it closes, and the next reader opened in the process (a
+# replay's next pass, the next file of an archive) takes the same pages
+host_tail_pool = BufferPool("overlap_tails")
 
 
 class BasebandFileReader:
@@ -73,7 +77,8 @@ class BasebandFileReader:
         # seek-back segments overlap too, so seq is always stamped —
         # only the tail retention is gated on the skip-read path
         from srtb_tpu.io.overlap import OverlapTailCarry
-        self._carry = OverlapTailCarry(self.reserved_bytes)
+        self._carry = OverlapTailCarry(self.reserved_bytes,
+                                       pool=host_tail_pool)
 
     def __iter__(self):
         return self
@@ -158,6 +163,7 @@ class BasebandFileReader:
 
     def close(self):
         self._file.close()
+        self._carry.release()
 
 
 # fixed epoch the deterministic stamps count from (an arbitrary 2023

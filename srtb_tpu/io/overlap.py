@@ -11,7 +11,11 @@ invariants in one place:
 - **tail retention**: the reserved tail of the last emitted segment is
   kept in ONE persistent host buffer (``np.copyto``, never a fresh
   allocation per segment — at high DM the tail is a large fraction of
-  the segment) and memcpy'd into the next segment's head;
+  the segment) and memcpy'd into the next segment's head; a source
+  that is opened again and again (a replayed file) hands in a pool, and
+  ``release()`` gives the buffer back when the source closes: fresh
+  pages cost the chip machine's host 3.3 us each to fault, 0.2 s for the
+  262 MB tail of a 2^30-sample segment at the Crab's DM;
 - **seq stamping**: a per-source monotonically increasing emission
   counter, or ``-1`` (never warm-assembled) when the source cannot
   guarantee the overlap — the misaligned-UDP fallback, hand-built
@@ -29,9 +33,11 @@ class OverlapTailCarry:
     """Retained reserved-tail + emission-seq bookkeeping for one
     segment source (one instance per receiver/reader)."""
 
-    def __init__(self, reserved_bytes: int, stamp_seq: bool = True):
+    def __init__(self, reserved_bytes: int, stamp_seq: bool = True,
+                 pool=None):
         self.reserved_bytes = int(reserved_bytes)
         self._stamp_seq = bool(stamp_seq)
+        self._pool = pool
         self._tail: np.ndarray | None = None
         self._seq = 0
 
@@ -53,8 +59,19 @@ class OverlapTailCarry:
         """Retain ``buf``'s reserved tail for the next segment's head
         (persistent buffer; no per-segment allocation)."""
         if self._tail is None:
-            self._tail = np.empty(self.reserved_bytes, np.uint8)
+            self._tail = (np.empty(self.reserved_bytes, np.uint8)
+                          if self._pool is None else
+                          self._pool.acquire(self.reserved_bytes,
+                                             zero=False))
         np.copyto(self._tail, buf[buf.shape[0] - self.reserved_bytes:])
+
+    def release(self) -> None:
+        """The source closes: the retained tail goes back to the pool
+        it came from (the next source opened on it takes pages that are
+        already mapped), and the carry is cold."""
+        if self._tail is not None and self._pool is not None:
+            self._pool.release(self._tail)
+        self._tail = None
 
     def next_seq(self) -> int:
         """The emitted segment's ``SegmentWork.seq``: adjacent stamps

@@ -11,6 +11,11 @@ segment engine** in :meth:`Pipeline.run`:
   ingest, sub-byte unpack, and H2D staging run while the device
   computes segment k (the double-buffer AstroAccelerate builds with
   CUDA streams, arXiv:2101.00941);
+- where the source's pull is long enough to keep the device waiting,
+  a reader stage pulls exactly one segment ahead on a thread of its
+  own and the loop takes the ready segment (``Pipeline``'s docstring:
+  who pulls, when, and what ``ingest`` / ``ingest_wait`` /
+  ``ingest_ahead`` mean in a journal);
 - fetch is non-blocking where possible: the drain loop polls device
   readiness (``jax.Array.is_ready``) and drains completed segments in
   order, blocking only when the window is full or the source is done;
@@ -64,6 +69,7 @@ import collections
 import functools
 import itertools
 import os
+import statistics
 import threading
 import time
 from dataclasses import dataclass, field
@@ -84,6 +90,18 @@ from srtb_tpu.utils import events, slo, telemetry
 from srtb_tpu.utils.logging import log
 from srtb_tpu.utils.metrics import metrics
 from srtb_tpu.utils.tracing import StageTimer, span as stage_span
+
+
+# the served loop's reader pulls one segment ahead, on a thread of its
+# own, while the source's pull takes more than this share of the period
+# between the loop's takes (``Pipeline._run_engine``).  Read on the
+# chip (PERF.md section 6, PR 45): the cells whose chip waited for the
+# pull stand at 0.59-0.96, the cells paced by the chip at 0.19-0.37,
+# where pulling a step early would only add a step to every segment's
+# latency.  Both are medians over the last ``_PACE_SAMPLES`` segments:
+# a replay's cold pass is one pull in six or eight.
+_PULL_AHEAD_SHARE = 0.5
+_PACE_SAMPLES = 8
 
 
 def _metrics_stage_timer() -> StageTimer:
@@ -290,7 +308,36 @@ class _DeadlineArray:
 
 
 class Pipeline:
-    """File (or any SegmentWork iterator) to sinks."""
+    """File (or any SegmentWork iterator) to sinks.
+
+    One loop (:meth:`_run_engine`) between two stages on threads of
+    their own, both built from ``pipeline/framework`` parts.  The loop
+    takes a segment, stages its bytes and enqueues its program(s)
+    (``dispatch``: ``h2d``, ``enqueue``), and with ``inflight_segments``
+    in flight blocks on the oldest result (``fetch``); the sink pipe
+    behind it gates detections, writes candidates, journals and
+    checkpoints (``sink``).  Who pulls: the loop itself, on its own
+    thread, as long as the chip paces it; where the source's pull takes
+    over half the period between the loop's takes (a pull that long
+    keeps the chip waiting for pull + dispatch + upload) the loop asks a
+    reader stage for segment k+1 the moment it has taken segment k, and
+    the pull runs under the loop's dispatch and fetch.  Exactly one
+    segment ahead (one more pooled block out), never more; dispatch
+    stays gated by the window; ``max_segments`` bounds pulls; the reader
+    starts with the first pull it is asked for and ends with the run.
+    Every run starts with the loop pulling (nothing is measured yet).
+
+    In a journal record ``stages_ms.ingest`` is the pull's own seconds
+    wherever it ran: where the reader ran ahead it is concurrent with
+    the loop's stages of the segments before.  ``stages_ms.ingest_wait``
+    is what the loop then waited for the reader, the tail of that same
+    pull (0 where the loop pulled by itself or the segment lay ready),
+    and the cumulative ``ingest_ahead`` counts the segments taken from
+    the reader ahead.  The pull's seconds, the source's offset after it
+    (what a checkpoint records), the trace id and the ``("ingest",
+    index)`` fault and retry site are taken where the pull runs
+    (:meth:`_pull`) and travel with the segment.
+    """
 
     @_under_construct_span
     def __init__(self, cfg: Config, source=None, sinks=None,
@@ -565,6 +612,20 @@ class Pipeline:
                 self.events.emit("stage.ingest", trace=tid,
                                  stream=self.stream, seg=index, dur=dt)
         return seg
+
+    def _pull(self, it, index: int):
+        """One pull with what belongs to its segment, taken where the
+        pull runs (the loop's thread, or the reader's when it runs one
+        segment ahead): ``(seg, ingest_seconds,
+        offset_after_this_segment)``, or None at the source's end.  Read
+        later, on another thread, the timer's ``last`` and the source's
+        ``logical_offset`` are the NEXT segment's, and a checkpoint
+        written from that offset skips a segment on resume."""
+        seg = self._timed_ingest(it, index)
+        if seg is None:
+            return None
+        return (seg, self.stage_timer.last["ingest"],
+                getattr(self.source, "logical_offset", 0))
 
     def _record_segment(self, index: int, seg, det_res, positive: bool,
                         span: dict, queue_depth: int,
@@ -1022,13 +1083,16 @@ class Pipeline:
 
     def _dispatch_segment(self, seg, ingest_s: float,
                           offset_after: int, index: int = 0,
-                          requeue: bool = False) -> tuple:
+                          requeue: bool = False,
+                          ingest_wait_s: float = 0.0) -> tuple:
         """Stage one segment's bytes to the device (async H2D) and
         enqueue its program; both run under the "dispatch" stage, and
         under the "h2d" / "dispatch" fault sites respectively.
         ``offset_after`` is the source's logical offset captured right
         after THIS segment's ingest (not at dispatch time — with
         batching, later ingests have already advanced the source).
+        ``ingest_wait_s`` is what the loop waited for a reader that
+        pulled this segment ahead (0 where the caller pulled it itself).
         Returns the in-flight record (the trailing ``index`` is the
         dispatch-order segment index, which the watchdog uses to bound
         requeues and the fault injector to schedule)."""
@@ -1037,7 +1101,7 @@ class Pipeline:
             events.set_current(tid, self.stream)
         self._canary_prepare(seg, index)
         data = self._device_bytes(seg)
-        span = {"ingest": ingest_s}
+        span = {"ingest": ingest_s, "ingest_wait": ingest_wait_s}
         with stage_span("dispatch", self.stage_timer, tid) as sp:
             stage_in = getattr(self.processor, "stage_input", None)
             if self._ring_live:
@@ -1074,7 +1138,8 @@ class Pipeline:
                 time.perf_counter(), index)
 
     def _dispatch_micro_batch(self, segs: list, ingests: list,
-                              offsets: list, first_index: int = 0) \
+                              offsets: list, first_index: int = 0,
+                              ingest_waits: list | None = None) \
             -> list:
         """Stack B ingested segments into ONE vmapped jit call; each
         segment's results are lazy device slices of the batch outputs.
@@ -1110,7 +1175,10 @@ class Pipeline:
             self.stage_timer.record("dispatch", per_seg)
             det_i = jax.tree_util.tree_map(
                 lambda x, j=i: x[j], det_b)
-            span = {"ingest": ingests[i], "dispatch": per_seg}
+            span = {"ingest": ingests[i],
+                    "ingest_wait": ingest_waits[i] if ingest_waits
+                    else 0.0,
+                    "dispatch": per_seg}
             if self.events is not None:
                 self.events.emit("stage.dispatch",
                                  trace=getattr(seg, "trace_id", 0),
@@ -1586,15 +1654,86 @@ class Pipeline:
                     and (max_segments is None
                          or dispatched[0] < max_segments))
 
+        # ---- the reader stage: the sink pipe's mirror at the source's
+        # end.  Where the pull holds the chip (below) segment k+1 is
+        # pulled on a thread of its own while the loop dispatches and
+        # fetches, and the loop takes the ready segment.  Exactly one
+        # ahead: the loop asks for pull k+1 when it has taken segment k,
+        # so one more pooled block is out and never two; dispatch stays
+        # gated by the window.  The thread starts where the rule first
+        # engages and ends with the run.
+        q_pull = fw.WorkQueue(capacity=1)
+        q_ready = fw.WorkQueue(capacity=1)
+        reader = [None]
+        reader_stop = fw.StopToken()
+        asked = [None]     # index of the pull the reader holds
+        fetches = [0]      # results fetched by this loop, this run
+        pull_s: collections.deque = collections.deque(
+            maxlen=_PACE_SAMPLES)
+        take_gap_s: collections.deque = collections.deque(
+            maxlen=_PACE_SAMPLES)
+        last_take = [None]  # (clock, fetches[0]) at the previous take
+
+        def reader_f(_stop, index):
+            # a pull that raises ends the pipe, which hands the loop
+            # the sentinel; (None,) is the source's end
+            return self._pull(it, index) or (None,)
+
+        def pull_ahead_pays(index: int) -> bool:
+            """Whether the reader should pull segment ``index + 1``
+            now.  It pays where pull + dispatch + upload outlast the
+            device's step, and costs a step of latency where they do
+            not; the loop sees the pull's seconds and the period
+            between its own takes, both the same whether it pulls ahead
+            or not, so the rule does not flap: ahead while the pull is
+            over ``_PULL_AHEAD_SHARE`` of the period (medians of the
+            last few; a period is counted only across a fetch, so the
+            takes that fill the window at a run's start say nothing
+            and every run starts as the serial loop).  ``max_segments``
+            bounds pulls, not only dispatches."""
+            if max_segments is not None and index + 1 >= max_segments:
+                return False
+            return bool(take_gap_s) and statistics.median(pull_s) \
+                > _PULL_AHEAD_SHARE * statistics.median(take_gap_s)
+
         def ingest_one(index: int):
-            """One source read; returns (seg, ingest_seconds,
-            offset_after_this_segment) or None when exhausted."""
-            seg = self._timed_ingest(it, index)
-            if seg is None:
+            """The next segment, pulled here or taken from the reader
+            that pulled it ahead; returns (seg, ingest_seconds,
+            offset_after_this_segment, seconds_waited_for_the_reader)
+            or None when exhausted."""
+            wait_s = 0.0
+            if asked[0] is None:
+                one = self._pull(it, index)
+            else:
+                asked[0] = None
+                with stage_span("ingest_wait", self.stage_timer) as sp:
+                    one = q_ready.pop()
+                    if one is fw.SENTINEL or one[0] is None:
+                        sp.cancel()
+                if one is fw.SENTINEL:
+                    # the pull raised on the reader's thread: out of
+                    # run() as if this thread had made it
+                    raise reader[0].exception
+                if one[0] is None:
+                    one = None
+                else:
+                    wait_s = sp.seconds
+                    metrics.add("ingest_ahead")
+            if one is None:
                 exhausted[0] = True
                 return None
-            return (seg, self.stage_timer.last["ingest"],
-                    getattr(self.source, "logical_offset", 0))
+            now = time.perf_counter()
+            pull_s.append(one[1])
+            if last_take[0] is not None and fetches[0] > last_take[0][1]:
+                take_gap_s.append(now - last_take[0][0])
+            last_take[0] = (now, fetches[0])
+            if pull_ahead_pays(index):
+                if reader[0] is None:
+                    reader[0] = fw.start_pipe(reader_f, q_pull, q_ready,
+                                              reader_stop, "reader")
+                asked[0] = index + 1
+                q_pull.push(index + 1)
+            return one + (wait_s,)
 
         # dispatch granularity: a micro-batch lands B segments at once,
         # so admission is gated on the whole unit fitting the window —
@@ -1639,11 +1778,7 @@ class Pipeline:
                             f"({e!r}); proceeding with the rebuild")
             self._swap_processor(newp)
             for i in range(len(pending)):
-                seg, _wf, _det, offset_after, span, _t0, idx = \
-                    pending[i]
-                pending[i] = dispatch_one(seg, span["ingest"],
-                                          offset_after, idx,
-                                          requeue=True)
+                pending[i] = redispatch(pending[i])
             return True
 
         def heal(exc) -> bool:
@@ -1686,7 +1821,7 @@ class Pipeline:
             return True
 
         def dispatch_one(seg, ingest_s, offset_after, index,
-                         requeue=False):
+                         requeue=False, ingest_wait_s=0.0):
             """One segment dispatch with self-healing: a device-
             classified failure demotes/reinits and re-dispatches the
             SAME segment from its retained host buffer; anything else
@@ -1695,13 +1830,23 @@ class Pipeline:
             re-dispatched segment must never warm-assemble."""
             while True:
                 try:
-                    return self._dispatch_segment(seg, ingest_s,
-                                                  offset_after, index,
-                                                  requeue=requeue)
+                    return self._dispatch_segment(
+                        seg, ingest_s, offset_after, index,
+                        requeue=requeue, ingest_wait_s=ingest_wait_s)
                 except BaseException as e:  # noqa: BLE001 — classified
                     if not heal(e):
                         raise
                     requeue = True
+
+        def redispatch(item):
+            """An in-flight record dispatched again from its retained
+            host buffer, cold and carry-isolated; what its pull took
+            stays on its span."""
+            seg, _wf, _det, offset_after, span, _t0, index = item
+            return dispatch_one(seg, span["ingest"], offset_after,
+                                index, requeue=True,
+                                ingest_wait_s=span.get("ingest_wait",
+                                                       0.0))
 
         def maybe_promote() -> None:
             """Promotion probe: after promote_after_segments healthy
@@ -1739,11 +1884,12 @@ class Pipeline:
                         got.append(one)
                     if not got:
                         return
-                    segs, ingests, offsets = map(list, zip(*got))
+                    segs, ingests, offsets, waits = map(list, zip(*got))
                     if len(segs) == b:
                         try:
                             items = self._dispatch_micro_batch(
-                                segs, ingests, offsets, dispatched[0])
+                                segs, ingests, offsets, dispatched[0],
+                                ingest_waits=waits)
                         except BaseException as e:  # noqa: BLE001
                             if not heal(e):
                                 raise
@@ -1754,13 +1900,16 @@ class Pipeline:
                             # result-compatible)
                             items = [dispatch_one(s, dt, off,
                                                   dispatched[0] + i,
-                                                  requeue=True)
-                                     for i, (s, dt, off)
+                                                  requeue=True,
+                                                  ingest_wait_s=w)
+                                     for i, (s, dt, off, w)
                                      in enumerate(got)]
                     else:  # tail shorter than B: single-segment plan
                         items = [dispatch_one(s, dt, off,
-                                              dispatched[0] + i)
-                                 for i, (s, dt, off) in enumerate(got)]
+                                              dispatched[0] + i,
+                                              ingest_wait_s=w)
+                                 for i, (s, dt, off, w)
+                                 in enumerate(got)]
                     pending.extend(items)
                     live_add(len(segs))
                     dispatched[0] += len(segs)
@@ -1770,9 +1919,10 @@ class Pipeline:
                     one = ingest_one(dispatched[0])
                     if one is None:
                         return
-                    seg, dt, off = one
+                    seg, dt, off, wait_s = one
                     pending.append(
-                        dispatch_one(seg, dt, off, dispatched[0]))
+                        dispatch_one(seg, dt, off, dispatched[0],
+                                     ingest_wait_s=wait_s))
                     live_add(1)
                     dispatched[0] += 1
                     self.stats.segments += 1
@@ -1845,7 +1995,6 @@ class Pipeline:
                         f"{deadline_s:g}s (fetch never ready): "
                         f"cancelling and re-dispatching "
                         f"({used + 1}/{watchdog_max})")
-                    seg, _wf, _det, offset_after, span, _t0, _i = item
                     # ring: the wedged device may never materialize the
                     # in-flight carry chain — invalidate so the next
                     # FRESH dispatch goes cold too, and re-dispatch
@@ -1856,9 +2005,7 @@ class Pipeline:
                     # (the wedge WAS an OOM in disguise, or the probe
                     # plan broke) demotes and retries instead of
                     # re-wedging through the whole requeue budget
-                    item = dispatch_one(seg, span["ingest"],
-                                        offset_after, index,
-                                        requeue=True)
+                    item = redispatch(item)
                     pending[0] = item
                     waited_since = time.perf_counter()
                 else:
@@ -1891,10 +2038,8 @@ class Pipeline:
                     # the fault: re-dispatch it cold from the retained
                     # host buffer under the (possibly demoted /
                     # reinitialized) plan, then fetch again
-                    seg, _wf, _det, offset_after, span, _t0, idx = item
-                    item = dispatch_one(seg, span["ingest"],
-                                        offset_after, idx,
-                                        requeue=True)
+                    item = redispatch(item)
+            fetches[0] += 1
             h = self.healer
             if h is not None:
                 h.note_healthy()
@@ -1981,12 +2126,34 @@ class Pipeline:
                 if not drain_oldest():
                     break
         finally:
+            join_s = float(getattr(cfg, "shutdown_join_timeout_s", 0)
+                           or 0)
+            if reader[0] is not None:
+                # the reader ends with the run.  A run that returns has
+                # taken every pull it asked for (an asked pull keeps
+                # want_more() true), so only a run that raises can leave
+                # one behind: its block goes back to the pool.  The join
+                # is bounded like the sink's: a real-time source may
+                # block in its pull until its stream ends.
+                reader_stop.request_stop()
+                q_pull.push_lossy(fw.SENTINEL)   # wakes an idle reader
+                if not reader[0].join(join_s if join_s > 0 else None):
+                    from srtb_tpu.utils import termination
+                    termination.report_wedged(
+                        [reader[0].thread],
+                        f"pipeline shutdown ({join_s:g}s join timeout)")
+                left = q_ready.try_pop()
+                if left is not None and left is not fw.SENTINEL \
+                        and left[0] is not None:
+                    log.warning("[pipeline] the run ended with a segment "
+                                "pulled ahead and never dispatched")
+                    pool = getattr(self.source, "pool", None)
+                    if pool is not None and cfg.input_file_path:
+                        pool.release(left[0].data)
             if sink_pipe is not None:
                 # bounded sentinel push: a sink wedged with a full
                 # queue can never accept the sentinel — give up after
                 # the join budget instead of hanging shutdown on it
-                join_s = float(getattr(cfg, "shutdown_join_timeout_s",
-                                       0) or 0)
                 t_sent = time.perf_counter()
                 while not q_sink.push_lossy(fw.SENTINEL):
                     if not sink_alive() or stop.stop_requested:
@@ -2585,19 +2752,21 @@ class ThreadedPipeline(Pipeline):
         def source_f(stop_token, _):
             if max_segments is not None and count[0] >= max_segments:
                 raise StopIteration
-            seg = self._timed_ingest(it, count[0])
-            if seg is None:
+            one = self._pull(it, count[0])
+            if one is None:
                 raise StopIteration
             count[0] += 1
-            # carry the ingest time AND the ingest-order index with
-            # the work item: the span is assembled across three
-            # threads, and every fault/retry site downstream must
-            # address this segment by the same index ingest used
-            return (seg, self.stage_timer.last["ingest"], count[0] - 1)
+            # carry the ingest time, the source's offset after THIS
+            # segment AND the ingest-order index with the work item:
+            # the span is assembled across three threads, this one may
+            # be any number of segments on when the others read, and
+            # every fault/retry site downstream must address this
+            # segment by the same index ingest used
+            return one + (count[0] - 1,)
 
         def device_f(stop_token, item):
             from srtb_tpu.resilience.errors import LadderExhausted
-            seg, ingest_dt, index = item
+            seg, ingest_dt, offset_after, index = item
             h = self.healer
             if h is not None and h.promote_due():
                 # promotion probe, same pacing as the async engine
@@ -2645,9 +2814,7 @@ class ThreadedPipeline(Pipeline):
                                  dur=span["dispatch"])
             self.stats.segments += 1
             self.stats.samples += cfg.baseband_input_count
-            return (seg, wf, det_res,
-                    getattr(self.source, "logical_offset", 0), span,
-                    index)
+            return (seg, wf, det_res, offset_after, span, index)
 
         drain_busy = [False]
 

@@ -343,6 +343,8 @@ class Metrics:
                             "(0 = generated in every step)",
         "grid_steps_ahead": "DM-grid steps enqueued while an earlier "
                             "step's results were not yet fetched",
+        "ingest_ahead": "Segments the served loop took from its reader "
+                        "pulled one ahead",
         "data_streams": "Data streams (polarisations) split from each "
                         "segment on the device",
         "segment_r2c_own": "Whether the segment R2C is the repo's own "

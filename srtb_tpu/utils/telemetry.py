@@ -121,12 +121,24 @@ from srtb_tpu.utils.metrics import metrics
 # segment that dumps nothing.  ``compile_ms`` is unchanged; which
 # program paid it is in the registry (``compile_seconds{program=...}``)
 # and in the run's ``[setup]`` log line, not in a record.
+# Still v13 (additions every reader already tolerates): the served
+# loop's reader may pull one segment ahead on a thread of its own
+# (pipeline/runtime.py).  ``stages_ms.ingest`` stays the pull's own
+# seconds wherever it ran, so where it ran ahead it is concurrent with
+# the loop's stages of the segments before; ``stages_ms.ingest_wait`` is
+# what the loop then waited for the reader, the tail of that same pull
+# (0 where the loop pulled by itself), a child of ``ingest`` below so
+# that ``segment_wall`` counts the pull once; and the cumulative
+# ``ingest_ahead`` counts the segments the loop took from the reader
+# ahead (its delta between consecutive records is 1 where the reader
+# ran ahead and 0 where it did not).
 # Readers must tolerate mixed v1-v13 journals: rotation can leave an
 # older-schema tail in the previous generation after an upgrade.
 SPAN_SCHEMA_VERSION = 13
 
 # child stage -> the stage it is timed inside (see the note above)
-CHILD_STAGES = {"h2d": "dispatch", "enqueue": "dispatch",
+CHILD_STAGES = {"ingest_wait": "ingest",
+                "h2d": "dispatch", "enqueue": "dispatch",
                 "enqueue_a": "enqueue", "enqueue_b": "enqueue",
                 "enqueue_c": "enqueue",
                 "d2h": "sink", "write": "sink", "publish": "sink",
@@ -394,6 +406,8 @@ def segment_span(segment: int, stages_s: dict, queue_depth: int,
         # them again: reserved_bytes a warm dispatch, 0 a cold one
         "ring_carry_bytes": int(metrics.get("ring_carry_bytes")),
         "ring_cold_dispatches": int(metrics.get("ring_cold_dispatches")),
+        # segments the loop took from a reader that pulled them ahead
+        "ingest_ahead": int(metrics.get("ingest_ahead")),
         # v4 self-healing compute fields (cumulative counters + the
         # ladder position gauge at drain)
         "plan_demotions": int(metrics.get("plan_demotions")),

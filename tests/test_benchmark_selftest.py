@@ -30,7 +30,13 @@ its tiny relative's, whose ``BENCHMARK.json`` a PR that changes the
 program may not edit): they hold as written over the metrics they were
 written for, and this file says what PR 42's eight add to each cell;
 PR 42's own case holds its eight to the end of ``per_layer`` and reads
-the file without the ninth that PR 44 appended behind them.
+the file without the ninth that PR 44 appended behind them.  PR 45
+appended two more (``PR45``: the served loop's reader, listed for the five
+served cells): every one of those cases, and ``test_crab_cell``'s two that
+hold PR 44's metric to the end of the list and its cell's set to its tiny
+relative's, read the file without them (``_before_pr45``); what the two
+read is held by ``tests/test_pipeline.py`` and by this file's
+``test_the_slice_is_found_with_the_source_ticked_on_the_reader_thread``.
 """
 
 import itertools
@@ -78,6 +84,12 @@ METRIC_SET_CASES = {
     "test_the_2p30_cells_files_load_through_spec": test_2p30_cell,
     "test_the_tiny_relative_stands_for_the_cell": test_2p30_cell,
 }
+# PR 44's two that hold its metric to the end of ``per_layer`` and its
+# cell's set to the tiny relative's
+CRAB_SET_CASES = ("test_the_crab_cells_files_load_through_spec",
+                  "test_the_tiny_relative_stands_for_the_crab_cell")
+# what PR 45 appended to ``per_layer``
+PR45 = ("runtime.ingest_wait_ms", "io.ingest_ahead_per_seg")
 
 for _mod in (test_gen, test_reference, test_trace, test_scopes,
              test_naoc_cell, test_2pol_cell, test_2p30_cell, test_crab_cell,
@@ -86,7 +98,8 @@ for _mod in (test_gen, test_reference, test_trace, test_scopes,
         if _name.startswith("test_") and callable(_obj) \
                 and _name not in FOUR_DEVICES + (DRAIN_CASE, LISTED_CASE,
                                                  GRID_CELL_CASE) \
-                and _name not in METRIC_SET_CASES:
+                and _name not in METRIC_SET_CASES \
+                and _name not in CRAB_SET_CASES:
             assert _name not in globals(), _name
             globals()[_name] = _obj
 
@@ -152,10 +165,41 @@ def test_a_cells_metrics_against_another_cells(case, monkeypatch):
     assert big == flagship - {"plan.chirp_bank_s"}
 
 
+def _before_pr45(bench: dict) -> dict:
+    """A ``BENCHMARK.json`` without the two metrics PR 45 appended."""
+    if "per_layer" not in bench:
+        return bench
+    return dict(bench, per_layer=[m for m in bench["per_layer"]
+                                  if m["name"] not in PR45])
+
+
+@pytest.mark.parametrize("case", CRAB_SET_CASES)
+def test_the_crab_cells_sets_as_pr44_wrote_them(case, monkeypatch):
+    from benchmark import spec as spec_mod
+
+    init = spec_mod.Spec.__init__
+
+    def before_pr45(self, root, workload):
+        init(self, root, workload)
+        self.bench = _before_pr45(self.bench)
+
+    monkeypatch.setattr(spec_mod.Spec, "__init__", before_pr45)
+    getattr(test_crab_cell, case)()
+    # ... and what PR 45 appended stands at the end, for the served cells
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert tuple(m["name"] for m in bench["per_layer"][-2:]) == PR45
+    served = [w["name"] for w in bench["workloads"] if w["chips"] == 1]
+    for m in bench["per_layer"][-2:]:
+        assert sorted(m["workloads"]) == sorted(served)
+        assert m["moves"] == "rt_factor"
+
+
 def _before_pr44(bench: dict) -> dict:
     """A ``BENCHMARK.json`` without what PR 44 appended to ``per_layer``:
     its one metric, and its cell's name at the end of the lists it
-    joined."""
+    joined (and without PR 45's two behind it)."""
+    bench = _before_pr45(bench)
     if "per_layer" not in bench:
         return bench
     return dict(bench, per_layer=[
@@ -281,3 +325,60 @@ def test_every_span_key_the_benchmark_reads_is_journalled(capsys):
         assert not {"roofline_frac", "achieved_msamps"} & set(s), s
     journalled = set().union(*(s["stages_ms"] for s in spans))
     assert stages <= journalled, stages - journalled
+
+
+def test_the_slice_is_found_with_the_source_ticked_on_the_reader_thread(
+        capsys, tmp_path, monkeypatch):
+    """With the served loop's reader ahead, the benchmark's source is
+    pulled, and its tracer ticked (``start_trace``, the ``bench:slice``
+    annotation), on the reader's thread.  The tiny relative of the Crab
+    cell, traced, with the rule held on (a tiny pull is microseconds): the
+    slice is found, the spans are read, and PR 45's two metrics read the
+    reader: every segment of the window taken from it, the loop's wait
+    journalled."""
+    from benchmark import run
+    from srtb_tpu.pipeline import runtime
+    from srtb_tpu.utils import logging as program_logging
+
+    root = str(tmp_path / "tiny_staged_ring")
+    shutil.copytree(test_crab_cell.TINY_ROOT, root)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        new = [m for m in json.load(f)["per_layer"] if m["name"] in PR45]
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["per_layer"] += [dict(m, workloads=[test_crab_cell.TINY_CELL])
+                           for m in new]
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    pulled_on = set()
+    timed_ingest = runtime.Pipeline._timed_ingest
+
+    def spying(self, it, index=0):
+        import threading
+        pulled_on.add(threading.current_thread().name)
+        return timed_ingest(self, it, index)
+
+    monkeypatch.setattr(runtime.Pipeline, "_timed_ingest", spying)
+    monkeypatch.setattr(runtime, "_PULL_AHEAD_SHARE", 0.0)
+    program_logging.log.stream = sys.stderr
+    work = os.path.join(ROOT, ".bench_work", test_crab_cell.TINY_CELL)
+    try:
+        rc = run.main(["--root", root, "--workload",
+                       test_crab_cell.TINY_CELL, "--seed", "2147496019",
+                       "--seconds", "1", "--trace", "1", "--allow-cpu"])
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    lines = capsys.readouterr().out.strip().splitlines()
+    out = json.loads(lines[-1])
+    assert rc == 0 and out["correct"] and out["failed"] == 0
+    assert pulled_on == {"MainThread", "reader"}
+    assert any("trace: slice of" in ln for ln in lines)
+    assert out["device"]["window_s"] > 0
+    m = {k: v["value"] for k, v in out["metrics"].items()}
+    assert m["io.ingest_ahead_per_seg"] == 1
+    assert 0 <= m["runtime.ingest_wait_ms"] < 50
+    assert {"io.ingest_ms", "runtime.fetch_ms", "io.h2d_ms_per_seg",
+            "runtime.enqueue_ms_per_seg"} <= set(m)
+    # the program's spans are read beside the slice (the CPU's profile
+    # holds no device plane: the one gap goes to the longest of them)
+    assert out["breakdown"]["idle_gaps"][0][0].startswith("srtb:")

@@ -10,6 +10,8 @@ from srtb_tpu.config import Config
 from srtb_tpu.pipeline.checkpoint import StreamCheckpoint
 from srtb_tpu.pipeline.runtime import Pipeline
 
+from slow_source import SlowFile
+
 
 def _cfg(tmp_path, n=1 << 12):
     rng = np.random.default_rng(0)
@@ -57,3 +59,45 @@ def test_pipeline_resume(tmp_path):
     ck = StreamCheckpoint(cfg.checkpoint_path)
     assert ck.segments_done == 4
     assert ck.file_offset_bytes == 4 * cfg.baseband_input_count
+
+
+class _Bytes:
+    def __init__(self):
+        self.seen = []
+
+    def push(self, work, positive):
+        self.seen.append(bytes(work.segment.data))
+
+
+def test_checkpoint_offset_is_the_segments_own_with_the_reader_ahead(
+        tmp_path):
+    """The offset recorded after segment k is where the source stood
+    after segment k, not where the reader, one segment on, stands when
+    the sinks return: a resume re-reads nothing and skips nothing."""
+    cfg = _cfg(tmp_path)
+    n = cfg.baseband_input_count
+    rng = np.random.default_rng(1)
+    rng.integers(0, 256, size=10 * n, dtype=np.uint8).tofile(
+        cfg.input_file_path)
+    sink = _Bytes()
+    source = SlowFile(cfg)
+    pipe1 = Pipeline(cfg, source=source, sinks=[sink])
+    seen = []
+    update = pipe1.checkpoint.update
+    pipe1.checkpoint.update = lambda done, offset: (
+        seen.append((done, offset)), update(done, offset))
+    assert pipe1.run(max_segments=7).segments == 7
+    assert "reader" in source.threads[3:]      # it did run ahead
+    assert seen == [(k + 1, (k + 1) * n) for k in range(7)]
+    ck = StreamCheckpoint(cfg.checkpoint_path)
+    assert (ck.segments_done, ck.file_offset_bytes) == (7, 7 * n)
+
+    # resume from the checkpoint, the reader ahead again: the rest, once
+    source2 = SlowFile(cfg, start=ck.file_offset_bytes)
+    pipe2 = Pipeline(cfg, source=source2, sinks=[sink])
+    assert pipe2.run().segments == 3
+    data = np.fromfile(cfg.input_file_path, dtype=np.uint8)
+    assert sink.seen == [data[k * n:(k + 1) * n].tobytes()
+                         for k in range(10)]
+    ck = StreamCheckpoint(cfg.checkpoint_path)
+    assert (ck.segments_done, ck.file_offset_bytes) == (10, 10 * n)

@@ -323,3 +323,32 @@ def test_zero_fill_counter(tmp_path, ring, bits):
         else len(data) + (len(want) - 1) * reserved)
     reader.close()
     metrics.reset()
+
+
+def test_a_closed_readers_tail_serves_the_next_reader(tmp_path):
+    """A file opened again and again (a replay's passes, an archive's
+    files): the reader's retained overlap tail goes back to its pool at
+    ``close()`` and the next reader's tail is the same pages, so a pass
+    does not fault a fresh tail; the streams are the same bytes."""
+    from srtb_tpu.io import file_input
+
+    cfg, data, seg, reserved = _overlapped(
+        tmp_path, 8, "auto", lambda seg, stride: seg + 3 * stride)
+    want = _sliced(data, seg, reserved)
+    first = BasebandFileReader(cfg)
+    got = [next(first).data.copy() for _ in range(2)]
+    tail = first._carry._tail
+    assert tail is not None and tail.nbytes == reserved
+    before = file_input.host_tail_pool.stats()
+    first.close()
+    assert first._carry._tail is None and not first._carry.warm
+    first.close()                       # closing twice releases once
+    after = file_input.host_tail_pool.stats()
+    assert after["in_use"] == before["in_use"] - 1
+    second = BasebandFileReader(cfg)
+    got = _drain(second)
+    assert second._carry._tail is tail
+    assert file_input.host_tail_pool.stats()["new_blocks"] \
+        == before["new_blocks"]
+    _assert_stream(got, want)
+    second.close()

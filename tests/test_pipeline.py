@@ -18,6 +18,8 @@ from srtb_tpu.io.synth import make_dispersed_baseband
 from srtb_tpu.pipeline.runtime import Pipeline, has_signal
 from srtb_tpu.pipeline.segment import SegmentProcessor
 
+from slow_source import SlowDevice, SlowFile
+
 
 @pytest.fixture(scope="module")
 def synthetic_cfg(tmp_path_factory):
@@ -555,3 +557,211 @@ def test_staged_pallas2_blocked_2bit_production_format(monkeypatch):
     assert proc._staged_impl() == "pallas2_interpret"
     got = waterfall_to_numpy(proc.process(raw)[0])
     np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-4)
+
+
+# ------------------------------------------- the served loop's reader
+# pulls one segment ahead, on a thread of its own, where the pull holds
+# the device (pipeline/runtime.py, `_run_engine`)
+
+
+class _BytesSink:
+    def __init__(self):
+        self.seen = []
+
+    def push(self, work, positive):
+        det = work.detect
+        self.seen.append((work.segment.timestamp,
+                          np.array(work.segment.data, copy=True),
+                          np.asarray(det.time_series).copy(),
+                          bool(positive)))
+
+
+def _ahead_cfg(tmp_path, tag, segments=8, **extra):
+    n = 1 << 12
+    path = str(tmp_path / "ahead.bin")
+    if not os.path.exists(path):
+        np.random.default_rng(7).integers(
+            0, 256, size=segments * n, dtype=np.uint8).tofile(path)
+    return Config(
+        baseband_input_count=n, baseband_input_bits=8,
+        input_file_path=path,
+        baseband_output_file_prefix=str(tmp_path / f"{tag}_"),
+        spectrum_channel_count=1 << 4,
+        signal_detect_max_boxcar_length=8,
+        signal_detect_signal_noise_threshold=99.0,  # never trigger
+        baseband_reserve_sample=False, writer_thread_count=0,
+        deterministic_timestamps=True,
+        telemetry_journal_path=str(tmp_path / f"{tag}.jsonl"), **extra)
+
+
+def _no_reader_thread():
+    import threading
+
+    return not [t for t in threading.enumerate() if t.name == "reader"]
+
+
+def _journal(cfg):
+    from srtb_tpu.tools import telemetry_report as TR
+
+    return TR.load(cfg.telemetry_journal_path)
+
+
+def test_reader_ahead_matches_the_loop_that_pulls_by_itself(
+        tmp_path, monkeypatch):
+    """A source slower than the device: the reader engages, and the
+    segments, their order, the journal and the sinks' bytes are those
+    of the loop that pulls every segment itself."""
+    from srtb_tpu.pipeline import runtime
+    from srtb_tpu.utils import telemetry
+    from srtb_tpu.utils.metrics import metrics
+
+    out = {}
+    for tag, share in (("self", float("inf")), ("ahead", None)):
+        metrics.reset()
+        if share is not None:
+            monkeypatch.setattr(runtime, "_PULL_AHEAD_SHARE", share)
+        else:
+            monkeypatch.undo()
+        cfg = _ahead_cfg(tmp_path, tag)
+        source, sink = SlowFile(cfg), _BytesSink()
+        with Pipeline(cfg, source=source, sinks=[sink]) as pipe:
+            stats = pipe.run()
+        assert stats.segments == 8 and _no_reader_thread()
+        out[tag] = (source, sink, _journal(cfg))
+    metrics.reset()
+    (src_a, sink_a, recs_a), (src_b, sink_b, recs_b) = \
+        out["self"], out["ahead"]
+    assert set(src_a.threads) == {"MainThread"}
+    # the first takes of a run fill the window and say nothing of the
+    # period: the run starts as the loop that pulls by itself
+    assert src_b.threads[:3] == ["MainThread"] * 3
+    assert set(src_b.threads[3:]) == {"reader"}
+    assert [r["ingest_ahead"] for r in recs_a] == [0] * 8
+    # cumulative when the record is written, as every counter there
+    ahead = [r["ingest_ahead"] for r in recs_b]
+    assert ahead == sorted(ahead) and ahead[0] == 0 and ahead[-1] == 5
+    for key in ("segment", "timestamp_ns", "samples", "detections"):
+        assert [r[key] for r in recs_a] == [r[key] for r in recs_b]
+    assert len(sink_a.seen) == len(sink_b.seen) == 8
+    for (ts_a, data_a, series_a, pos_a), (ts_b, data_b, series_b, pos_b) \
+            in zip(sink_a.seen, sink_b.seen):
+        assert ts_a == ts_b and pos_a == pos_b
+        np.testing.assert_array_equal(data_a, data_b)
+        np.testing.assert_array_equal(series_a, series_b)
+    for rec in recs_a + recs_b:
+        ms = rec["stages_ms"]
+        assert ms["ingest_wait"] >= 0 and ms["ingest"] >= 45
+        # the wait is the tail of the segment's own pull: the pull is
+        # counted once
+        assert telemetry.segment_wall(ms) == pytest.approx(
+            ms["ingest"] + ms["dispatch"] + ms["fetch"] + ms["sink"])
+    assert all(r["stages_ms"]["ingest_wait"] == 0 for r in recs_a)
+    # pulled ahead, under the loop's dispatch and fetch: the loop waited
+    # for the rest of the pull at most (and the hand-over)
+    assert all(r["stages_ms"]["ingest_wait"] < r["stages_ms"]["ingest"] + 5
+               for r in recs_b[3:])
+
+
+def test_max_segments_bounds_the_pulls_of_a_reader_ahead(tmp_path):
+    from srtb_tpu.utils.bufferpool import BufferPool
+    from srtb_tpu.utils.metrics import metrics
+
+    metrics.reset()
+    cfg = _ahead_cfg(tmp_path, "bound")
+    pool = BufferPool("test_ahead")
+    source, sink = SlowFile(cfg, pool=pool), _BytesSink()
+    with Pipeline(cfg, source=source, sinks=[sink]) as pipe:
+        stats = pipe.run(max_segments=6)
+    assert stats.segments == 6 == len(sink.seen)
+    assert metrics.get("ingest_ahead") == 3
+    # exactly six pulls: the file stands behind the sixth segment and
+    # every block is back in the pool
+    assert source.pulled == 6
+    assert source.logical_offset == 6 * cfg.baseband_input_count
+    assert source.reader._file.tell() == 6 * cfg.baseband_input_count
+    assert pool.stats()["in_use"] == 0
+    # one ahead, not two: the window's two blocks and the reader's
+    assert pool.stats()["new_blocks"] <= 3
+    metrics.reset()
+
+
+@pytest.mark.parametrize("sanitize", [False, True])
+def test_two_runs_on_one_pipeline_leave_no_reader_thread(tmp_path,
+                                                         sanitize):
+    """The benchmark's shape: warm-up and window are two ``run()`` calls
+    on one ``Pipeline``.  The reader ends with each run (the sanitizer's
+    leaked-thread check is armed in the second case), and each run
+    starts as the loop that pulls by itself."""
+    from srtb_tpu.utils.metrics import metrics
+
+    metrics.reset()
+    cfg = _ahead_cfg(tmp_path, f"twice{int(sanitize)}", segments=20,
+                     sanitize=sanitize)
+    source, sink = SlowFile(cfg), _BytesSink()
+    with Pipeline(cfg, source=source, sinks=[sink]) as pipe:
+        # (the sanitizer's first checks compile inside the first period
+        # the loop reads: the median needs a few more)
+        assert pipe.run(max_segments=10).segments == 10
+        first = metrics.get("ingest_ahead")
+        assert _no_reader_thread() and first > 0
+        assert pipe.run(max_segments=6).segments == 16
+        assert _no_reader_thread()
+        assert metrics.get("ingest_ahead") == first + 3
+    for run in (source.threads[:10], source.threads[10:]):
+        assert run[:3] == ["MainThread"] * 3 and run[-1] == "reader"
+    assert source.threads[10:] == ["MainThread"] * 3 + ["reader"] * 3
+    assert [s[0] for s in sink.seen] == sorted(s[0] for s in sink.seen)
+    assert len(sink.seen) == 16
+    metrics.reset()
+
+
+def test_a_pull_that_raises_on_the_reader_raises_out_of_run(tmp_path):
+    cfg = _ahead_cfg(tmp_path, "raises")
+    source, sink = SlowFile(cfg, raise_at=5), _BytesSink()
+    with Pipeline(cfg, source=source, sinks=[sink]) as pipe:
+        with pytest.raises(RuntimeError, match="disk gone"):
+            pipe.run()
+    assert source.threads[-1] == "reader" and _no_reader_thread()
+    assert source.pulled == 5 and len(sink.seen) <= 5
+
+
+def test_a_source_faster_than_the_device_never_engages_the_reader(
+        tmp_path):
+    """A pull of microseconds beside a device step of milliseconds (the
+    cells the chip paces): no reader, no thread, ``ingest_ahead`` 0."""
+    from srtb_tpu.utils.metrics import metrics
+
+    metrics.reset()
+    cfg = _ahead_cfg(tmp_path, "fast")
+    source, sink = SlowFile(cfg, pull_s=0.0), _BytesSink()
+    with Pipeline(cfg, source=source, sinks=[sink],
+                  processor=SlowDevice(SegmentProcessor(cfg),
+                                       0.02)) as pipe:
+        assert pipe.run().segments == 8
+    assert set(source.threads) == {"MainThread"}
+    recs = _journal(cfg)
+    assert [r["ingest_ahead"] for r in recs] == [0] * 8
+    assert all(r["stages_ms"]["ingest_wait"] == 0 for r in recs)
+    metrics.reset()
+
+
+def test_threaded_pipeline_checkpoints_each_segments_own_offset(tmp_path):
+    """``ThreadedPipeline``'s source thread runs ahead of its device
+    thread: the offset a checkpoint records after segment k is the one
+    the source stood at after segment k, carried with the work item."""
+
+    from srtb_tpu.pipeline.runtime import ThreadedPipeline
+
+    cfg = _ahead_cfg(tmp_path, "threaded",
+                     checkpoint_path=str(tmp_path / "threaded.json"))
+    pipe = ThreadedPipeline(
+        cfg, sinks=[_BytesSink()],
+        processor=SlowDevice(SegmentProcessor(cfg), 0.03))
+    seen = []
+    update = pipe.checkpoint.update
+    pipe.checkpoint.update = lambda done, offset: (
+        seen.append((done, offset)), update(done, offset))
+    with pipe:
+        assert pipe.run().segments == 8
+    n = cfg.baseband_input_count
+    assert seen == [(k + 1, (k + 1) * n) for k in range(8)]

@@ -1402,3 +1402,59 @@ def test_telemetry_report_tolerates_mixed_v2_v3_v4(tmp_path):
 # test_lint.py::test_repo_lints_clean_against_baseline, which runs
 # EVERY rule — including the new one — against the checked-in
 # baseline; no duplicate whole-repo lint pass here)
+
+
+# ----------------------------- the ingest site with the reader ahead
+
+
+class _SlowCountingSource(_CountingSource):
+    """A pull slower than the stub device's step: the served loop asks
+    its reader for segment k+1 while it works on k."""
+
+    def __next__(self) -> SegmentWork:
+        time.sleep(0.02)
+        return super().__next__()
+
+
+def test_ingest_fault_addresses_the_same_segment_with_the_reader_ahead(
+        tmp_path):
+    """``("ingest", index)`` is the site of the pull that makes segment
+    ``index``, wherever that pull runs: the fault fires on the reader's
+    thread with ``index`` segments handed out, the retry re-runs that
+    pull, and the sinks see every segment once, in order."""
+    from srtb_tpu.tools import telemetry_report as TR
+
+    metrics.reset()
+    cfg = _watchdog_cfg(tmp_path, "ahead_fault", inflight_segments=2,
+                        fault_plan="ingest:raise@5")
+    source = _SlowCountingSource(8)
+    sink = _CrashingSink(crashes=0)
+    fired = []
+    fire = FaultInjector.fire
+
+    def spying(self, site, index):
+        pending = not self._by_site[site][index].fired \
+            if index in self._by_site.get(site, {}) else False
+        if pending:
+            fired.append((site, index, source._i,
+                          threading.current_thread().name))
+        return fire(self, site, index)
+
+    FaultInjector.fire = spying
+    try:
+        with Pipeline(cfg, source=source, sinks=[sink],
+                      processor=_InstantProcessor()) as pipe:
+            stats = pipe.run()
+            assert pipe.faults.unfired() == []
+    finally:
+        FaultInjector.fire = fire
+    assert stats.segments == 8
+    assert fired == [("ingest", 5, 5, "reader")]
+    assert sink.pushed == list(range(1, 9))
+    assert metrics.get("retries_ingest") == 1
+    assert metrics.get("segments_dropped") == 0
+    assert metrics.get("ingest_ahead") >= 4
+    recs = TR.load(cfg.telemetry_journal_path)
+    assert [r["segment"] for r in recs] == list(range(8))
+    assert [r["timestamp_ns"] for r in recs] == list(range(1, 9))
+    metrics.reset()

@@ -559,9 +559,14 @@ def test_pipeline_writes_segment_spans(tmp_path):
     recs = TR.load(journal)
     assert len(recs) == 2
     for rec in recs:
-        # h2d and enqueue are child stages, timed inside dispatch
-        assert set(rec["stages_ms"]) == {"ingest", "dispatch", "h2d",
-                                         "enqueue", "fetch", "sink"}
+        # h2d and enqueue are child stages, timed inside dispatch;
+        # ingest_wait is the loop's wait for a reader ahead, inside
+        # ingest (0 here: the loop pulled by itself)
+        assert set(rec["stages_ms"]) == {"ingest", "ingest_wait",
+                                         "dispatch", "h2d", "enqueue",
+                                         "fetch", "sink"}
+        assert rec["stages_ms"]["ingest_wait"] == 0
+        assert rec["ingest_ahead"] == 0
         assert all(v >= 0 for v in rec["stages_ms"].values())
         assert rec["samples"] == n
     assert [r["segment"] for r in recs] == [0, 1]
