@@ -421,13 +421,18 @@ def hermitian_rfft_post(zf: jnp.ndarray,
     return out
 
 
+def window_planes(window: np.ndarray, count: int) -> np.ndarray:
+    """A sample-order window [n] dealt out to ``count`` planes [count,
+    n / count], plane j = coefficients j, j + count, ... (host-side
+    numpy: the strided reshape would be a pathological layout on
+    device)."""
+    return np.ascontiguousarray(np.asarray(window).reshape(-1, count).T)
+
+
 def subbyte_window_planes(window: np.ndarray, nbits: int) -> np.ndarray:
-    """Reorder a sample-order window [n] into blocked field planes
-    [count, M] matching `unpack_subbyte_planes` (host-side numpy: the
-    strided reshape would be a pathological layout on device)."""
-    count = 8 // nbits
-    return np.ascontiguousarray(
-        np.asarray(window).reshape(-1, count).T)
+    """:func:`window_planes` matching the blocked field planes of
+    `unpack_subbyte_planes`."""
+    return window_planes(window, 8 // nbits)
 
 
 @S.scoped(S.FFT_R2C)
@@ -496,20 +501,55 @@ def own_tail_shape(n: int, nbits: int):
     """(p, n1, n2) where the repo's own transform takes a segment of n
     samples whole, Hermitian post included (ops/pallas_fft2: the
     column-native passes and :func:`~srtb_tpu.ops.pallas_fft2.post_spectrum`),
-    else None.  Sub-byte samples go as p = 4/nbits pairs of blocked
-    field planes of n*nbits/8 points (``unpack_subbyte_planes``), whole
-    bytes as one packed transform of n/2 (:func:`pack_even_odd`)."""
+    else None: p pairs of planes of n / (2p) = n1 * n2 points each, the
+    post pass joining the pairs.  Sub-byte samples go as the p = 4/nbits
+    pairs of blocked field planes they unpack to (``unpack_subbyte_planes``).
+    Whole bytes go as one packed transform of n/2 points where that
+    length has a leg pair (2^25 to 2^27 samples), else as two pairs of
+    n/4 (:func:`deal_planes`: every fourth sample a plane; 2^28 samples,
+    legs 8192 x 8192, where one transform would need a leg of 2^14)."""
     from srtb_tpu.ops import pallas_fft2 as pf2
     nbits = abs(nbits)
     if nbits not in (2, 4, 8):
         return None
-    p = 4 // nbits if nbits < 8 else 1
-    if n % (2 * p):
-        return None
-    fac = pf2.cols_factor(n // (2 * p))
-    if fac is None or not pf2.post_supported(p, *fac):
-        return None
-    return (p, *fac)
+    for p in (4 // nbits,) if nbits < 8 else (1, 2):
+        if n % (2 * p):
+            continue
+        fac = pf2.cols_factor(n // (2 * p))
+        if fac is not None and pf2.post_supported(p, *fac):
+            return (p, *fac)
+    return None
+
+
+# Lanes of a row of the deal-out (:func:`deal_planes`)
+_DEAL_LANES = 128
+
+
+@S.scoped(S.FFT_R2C)
+def deal_planes(x: jnp.ndarray, count: int) -> tuple:
+    """Samples in order ``[..., n]`` (the own plan hands in the bytes
+    of one-byte samples and casts the planes) dealt out to ``count``
+    planes ``[..., n / count]``, plane j = samples j, j + count, ...:
+    what the blocked field planes of a sub-byte unpack are, for whole
+    bytes.  With count 2 the planes are the real and imaginary part of
+    :func:`pack_even_odd`; with count 4 they are two such pairs, z[2b +
+    p'] = x[4b + 2p'] + i x[4b + 2p' + 1], which the post pass joins
+    (``own_tail_shape``).  Lane-dense as there: every ``count``-th lane
+    of rows ``count`` x 128 wide, never ``reshape(-1, count)`` (a minor
+    dimension of 4 pads to 128 lanes on the chip).  ``rows[..., j::count]``
+    lowers to a gather, as in ``ops/unpack.stream_bytes``: on a v5e, 2^28
+    bytes to four float planes with the cast read 10.5 ms so and 65.6 ms
+    as a strided ``lax.slice`` (the 1 GSa/s ring program whole 93.5
+    against 148.3 ms), the same on the cast floats 27.4 and 73.2,
+    which is why samples wider than a byte stay with XLA's plan
+    (PERF.md section 6, PR 48)."""
+    n = x.shape[-1]
+    row = count * _DEAL_LANES
+    if n % row:
+        raise ValueError(f"{n} samples do not deal out in rows of {row}")
+    rows = x.reshape(*x.shape[:-1], n // row, row)
+    return tuple(rows[..., j::count].reshape(*x.shape[:-1], n // count)
+                 for j in range(count))
 
 
 @S.scoped(S.FFT_R2C)
@@ -541,7 +581,9 @@ def _pallas2_or_fallback(z: jnp.ndarray, strategy: str,
     and the four-step-with-Pallas-legs form below 2^24 (tiny test
     configs).  The lengths between, 2^27 to 2^29, have only the first
     spelling of the passes (``fft2_c2c``), which Mosaic refuses for a
-    v5e: in interpret mode it runs, on a chip it is an error here."""
+    v5e: in interpret mode it runs, on a chip it is an error here.  (A
+    served plan never asks for them: its own transform takes 2^28
+    samples as two transforms of 2^26 points, ``own_tail_shape``.)"""
     from srtb_tpu.ops import pallas_fft2 as pf2
     interp = strategy.endswith("interpret")
     length = z.shape[-1]
@@ -556,7 +598,8 @@ def _pallas2_or_fallback(z: jnp.ndarray, strategy: str,
                 f"to 2^26 points, and Mosaic refuses the first spelling "
                 f"of the passes at these sizes (96.14 MB of scoped VMEM "
                 f"against 80 at 2^26 points on a v5e; PERF.md section "
-                f"6, PR 43)")
+                f"6, PR 43).  A served plan splits such a segment into "
+                f"plane pairs of 2^26 points (ops/fft.own_tail_shape)")
         return pf2.fft2_c2c(z, inverse=False, interpret=interp)
     # loud when an explicit SRTB_PALLAS2_N1 pin is why we're falling
     # back — the A/B knob must not silently measure the wrong path
@@ -606,27 +649,35 @@ def finish_rfft_subbyte(a: jnp.ndarray,
 # segment does not compile for a v5e (PERF.md section 4).
 LARGE_FFT_THRESHOLD = 1 << 28
 
-# What the plan with the repo's own transform holds on the chip, in
-# bytes a sample: the chirp bank and the post pass's (chirp, chirp *
-# twiddle) bank, 4 + 8, and one stream's temporaries, 12 (the four
-# planes between the passes, the unpacked planes: 1.16 GB at 2^27
-# samples compiled for a described v5e), whatever the streams; and a
-# stream's waterfall of each of two segments in flight, 2 x 4.  A floor:
-# the chip's peak read 4.51 GB at 2^27 samples in one stream, 7.20 in two
-# (PERF.md section 5, PR 43).  ``auto`` keeps XLA's transform where the
-# chip's ``bytes_limit`` is under the sum.
-OWN_R2C_BYTES_PER_SAMPLE = 24
-OWN_R2C_BYTES_PER_STREAM_SAMPLE = 8
-
-# The shapes (:func:`own_tail_shape`) at which a v5e has read the repo's
-# own transform faster than XLA's, parent beside change: 2-bit samples,
-# two pairs of blocked planes through legs 4096 x 8192 (2^27 samples a
-# stream; one stream 65.28 -> 33.93 ms busy a segment, two 134.88 ->
-# 74.76: PERF.md section 5, PR 43).  The other shapes the kernels take
-# (whole bytes and 4-bit samples through legs 8192 x 8192 with one plane
-# pair, 2^25 and 2^26 samples) compile for a described v5e and no chip
-# has run them: ``auto`` leaves them to XLA until one has.
-OWN_R2C_READ_FASTER = frozenset({(2, 4096, 8192)})
+# Where a v5e has read the repo's own transform faster than XLA's,
+# parent beside change, in a cell, and what the chip held at its peak
+# there (``device.peak_hbm_gb``: the banks, two segments in flight,
+# the largest program's temporaries).  The key is what was read and no
+# more: the samples' bits, the streams, the shape
+# (:func:`own_tail_shape`).  ``auto`` gives the own transform at these
+# keys alone, and only on a chip whose ``bytes_limit`` holds the
+# reading; everywhere else XLA's plan, which holds less (9.76 GB where
+# the last row reads 12.02).
+# - 2-bit samples, 2^27 a stream, two pairs of blocked planes through
+#   legs 4096 x 8192, 2^11 channels: one stream busy 65.28 -> 33.93 ms
+#   a segment, two 134.88 -> 74.76 (PERF.md section 5, PR 43);
+# - 8-bit samples, 2^28 of them dealt out to two plane pairs
+#   (:func:`deal_planes` on the bytes) through legs 8192 x 8192, 2^15
+#   channels, the 1 GSa/s segment: busy 157.51 -> 89.27 ms,
+#   ``rt_factor`` 1.238 -> 1.852 (PERF.md section 5, PR 48).  The
+#   programs hold 9.53 GB of it with two in flight and the chirp bank
+#   1.07 (``tests/test_tpu_compile.py``).
+# The other shapes the kernels take (whole bytes and 4-bit samples
+# through one plane pair, 2^25 to 2^27 samples; 2-bit 2^25, 2^26 and
+# 2^28; 8-bit 2^28 in two streams) compile for a described v5e or run
+# the same kernels and no chip has run them: ``auto`` leaves them to
+# XLA until one has.
+OWN_R2C_READ_FASTER = {
+    # (bits, streams, p, n1, n2): bytes on the chip at the peak
+    (2, 1, 2, 4096, 8192): 4_510_000_000,     # ledger, PR 45: 4.5099
+    (2, 2, 2, 4096, 8192): 7_196_000_000,     # ledger, PR 45: 7.1957
+    (8, 1, 2, 8192, 8192): 12_017_000_000,    # my chip runs, PR 48
+}
 
 
 def resolve_strategy(n: int, strategy: str, bits: int = 8,
@@ -640,22 +691,22 @@ def resolve_strategy(n: int, strategy: str, bits: int = 8,
     it the repo's own transform, "pallas2" with the tail in its post
     pass, where all of this holds: the backend is a TPU (``on_tpu``),
     the caller's plan runs that transform whole when it is given
-    "pallas2" (``own_plan``: ``pipeline/segment.own_r2c_hostable``), the
-    shape is one a chip has read faster (``OWN_R2C_READ_FASTER``) and
-    the chip holds the plan twice (``bytes_limit`` of ``memory_stats``;
-    None where the platform reports none).  Else "monolithic", XLA's own
-    R2C: off the chip, at 2^28 samples of whole bytes, and wherever the
-    kernel has not been read faster (PERF.md section 6, PR 43, has the
-    table)."""
+    "pallas2" (``own_plan``: ``pipeline/segment.own_r2c_hostable``), a
+    chip has read these bits, streams and shape faster
+    (``OWN_R2C_READ_FASTER``: 2^27 2-bit samples a stream in one or two
+    streams, and 2^28 samples of 8 bits in one, the 1 GSa/s segment)
+    and the chip holds what that chip held at its peak (``bytes_limit``
+    of ``memory_stats``; None where the platform reports none).  Else
+    "monolithic", XLA's own R2C: off the chip, on a chip too small, and
+    wherever the kernel has not been read faster (PERF.md section 6,
+    PRs 43 and 48, has the tables)."""
     if strategy != "auto":
         return strategy
     if n // 2 > LARGE_FFT_THRESHOLD:
         return "four_step"
-    if (on_tpu and own_plan
-            and own_tail_shape(n, bits) in OWN_R2C_READ_FASTER
-            and (not bytes_limit or n * (
-                OWN_R2C_BYTES_PER_SAMPLE
-                + streams * OWN_R2C_BYTES_PER_STREAM_SAMPLE) <= bytes_limit)):
+    shape = own_tail_shape(n, bits) if on_tpu and own_plan else None
+    held = shape and OWN_R2C_READ_FASTER.get((abs(bits), streams, *shape))
+    if held and (not bytes_limit or held <= bytes_limit):
         return "pallas2"
     return "monolithic"
 

@@ -187,6 +187,23 @@ def unpack_stream(data: jnp.ndarray, variant: str, nbits: int,
     return unpack(data, nbits, window)
 
 
+def one_byte_cast(variant: str, nbits: int):
+    """Where a sample of this format is one byte, the cast of a stream's
+    own bytes to its float32 samples, as :func:`unpack_stream` makes
+    them; None for any other width.  Under no scope of its own: the
+    caller names the work (the own transform deals the bytes out to
+    planes first and casts plane by plane inside the R2C,
+    ``pipeline/segment.SegmentProcessor._process_own``)."""
+    if variant == "gznupsr_a1":
+        return lambda b: jnp.bitwise_xor(b, jnp.uint8(0x80)).view(
+            jnp.int8).astype(jnp.float32)
+    if variant == "gznupsr_a1_v2_1" or nbits == -8:
+        return lambda b: b.view(jnp.int8).astype(jnp.float32)
+    if nbits == 8:
+        return lambda b: b.astype(jnp.float32)
+    return None
+
+
 def _unpack_streams(data, variant: str, nbits: int, window) -> tuple:
     return tuple(unpack_stream(own, variant, nbits, window)
                  for own in stream_bytes(data, variant))

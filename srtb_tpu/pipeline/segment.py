@@ -144,9 +144,17 @@ def _stream_bytes_subbyte(cfg) -> bool:
 
 def _r2c_sample_bits(cfg) -> int:
     """The bits ``ops/fft.own_tail_shape`` is asked with: the samples'
-    own where a stream's bytes go to the R2C as blocked field planes,
-    8 where it takes samples in order."""
-    return cfg.baseband_input_bits if _stream_bytes_subbyte(cfg) else 8
+    own where a stream's bytes go to the own transform as they lie
+    (sub-byte samples MSB first as blocked field planes, a byte a
+    sample dealt out as bytes), and 0, which it refuses, for anything
+    else: wider samples, and sub-byte samples of a word-interleaved
+    format, would be dealt out as floats, which a v5e read 2.6x dearer
+    than the bytes and no plan runs (PERF.md section 6, PR 48)."""
+    if _stream_bytes_subbyte(cfg):
+        return cfg.baseband_input_bits
+    fmt = formats.resolve(cfg.baseband_format_type)
+    one_byte = U.one_byte_cast(fmt.unpack_variant, cfg.baseband_input_bits)
+    return 8 if one_byte is not None else 0
 
 
 def own_r2c_hostable(cfg, staged: bool) -> bool:
@@ -156,8 +164,9 @@ def own_r2c_hostable(cfg, staged: bool) -> bool:
     a fused plan and not the staged one, a tail that may fold in
     (``fused_tail`` not off), a chirp bank to fold (``use_pallas`` plans
     keep none), one segment a program (the micro-batch and the fleet
-    ``vmap`` it, which no chip run has measured), and a shape the
-    kernels take (``ops/fft.own_tail_shape``).  Any other plan given
+    ``vmap`` it, which no chip run has measured), samples of a byte or
+    less (``_r2c_sample_bits``) and a shape the kernels take
+    (``ops/fft.own_tail_shape``).  Any other plan given
     "pallas2" runs the two passes with XLA's Hermitian post and tail,
     which a v5e read slower than XLA's R2C (PERF.md section 6, PR 43)."""
     batched = (int(getattr(cfg, "micro_batch_segments", 1) or 1) > 1
@@ -373,12 +382,12 @@ class SegmentProcessor:
         self.own_tail = (self.strategy == "pallas2"
                          and own_r2c_hostable(cfg, self.staged))
         assert self.fused_tail or not self.own_tail
-        if (self.own_tail and win is not None and self.window_planes is None
-                and _stream_bytes_subbyte(cfg)):
-            # each of several streams of sub-byte samples goes to the
-            # own transform as blocked planes too
-            self.window_planes = jnp.asarray(F.subbyte_window_planes(
-                win, cfg.baseband_input_bits))
+        if self.own_tail and win is not None and self.window_planes is None:
+            # every stream goes to the own transform as 2p planes (a
+            # sub-byte stream's blocked field planes, whole bytes dealt
+            # out), and the window with them
+            self.window_planes = jnp.asarray(F.window_planes(
+                win, 2 * F.own_tail_shape(self.n, _r2c_sample_bits(cfg))[0]))
         from srtb_tpu.utils.metrics import metrics
         metrics.set("segment_r2c_own", int(self.own_tail))
         # front-fused staged megakernel (Config.front_fuse, the
@@ -942,42 +951,45 @@ class SegmentProcessor:
         with RFI s1, the manual zap and the chirp in it; ``bank`` is
         ``chirp_w``), a stream at a time.  A stream's sub-byte samples
         go to the kernels as the blocked field planes they unpack to,
-        whole bytes as the even/odd pack of the samples in order."""
+        a byte a sample dealt out as bytes to 2p planes and cast plane
+        by plane (``ops/fft.deal_planes``): the even/odd pack at p = 1,
+        every fourth sample a plane at p = 2 (2^28 samples: the 1 GSa/s
+        segment).  A window goes in as planes of the same deal
+        (``window_planes``).  Wider samples never come here
+        (``_r2c_sample_bits``)."""
         cfg = self.cfg
         bits = cfg.baseband_input_bits
-        legs = F.own_tail_shape(self.n, _r2c_sample_bits(cfg))[1:]
+        p, n1, n2 = F.own_tail_shape(self.n, _r2c_sample_bits(cfg))
 
-        def results(planes):                            # [2p, (1,) M]
+        def results(planes):                            # [2p, M]
             spec = F.own_spectrum(
-                planes, legs, bank,
+                planes, (n1, n2), bank,
                 threshold=cfg.mitigate_rfi_average_method_threshold,
                 norm=self.norm_coeff, bins=self.rfi_bins,
                 interpret=self._pallas_interpret)
             return self._waterfall_detect(spec[None, :])
 
         def from_bytes(b):
-            planes = U.unpack_subbyte_planes(b, bits)
+            if _stream_bytes_subbyte(cfg):
+                planes = U.unpack_subbyte_planes(b, bits)
+            else:
+                # a byte a sample: dealt out as bytes and cast plane by
+                # plane, a quarter of the bytes the floats would move;
+                # the cast under the R2C's name, where XLA's plan has it
+                cast = U.one_byte_cast(self.fmt.unpack_variant, bits)
+                with jax.named_scope(S.FFT_R2C):
+                    planes = jnp.stack(
+                        [cast(q) for q in F.deal_planes(b, 2 * p)])
             if self.window_planes is not None:
                 with jax.named_scope(S.FFT_R2C):
                     planes = planes * self.window_planes
             return results(planes)
-
-        def from_samples(x):                            # x [1, n]
-            z = F.pack_even_odd(x)
-            with jax.named_scope(S.FFT_R2C):
-                planes = jnp.stack([jnp.real(z), jnp.imag(z)])
-            return results(planes)
-        subbyte = _stream_bytes_subbyte(cfg)
         own = U.stream_bytes(raw, self.fmt.unpack_variant)
         if len(own) == 1:
-            return (from_bytes(raw) if subbyte
-                    else from_samples(self._unpack(raw)))
+            return from_bytes(raw)
         with jax.named_scope(S.UNPACK):
             own = jnp.stack(own)                        # [S, bytes]
-        return self._stream_after_stream(
-            from_bytes if subbyte else lambda b: from_samples(
-                U.unpack_stream(b, self.fmt.unpack_variant, bits,
-                                self.window)[None, :]), own)
+        return self._stream_after_stream(from_bytes, own)
 
     def _spectrum_to_results(self, spec: jnp.ndarray, chirp_ri):
         """From the R2C's spectrum ``[S, n/2]`` to the program's

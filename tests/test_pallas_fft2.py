@@ -188,7 +188,14 @@ def test_cols_production_shapes():
     # two streams in sample order, and 4-bit: one transform of 2^26
     assert F.own_tail_shape(1 << 27, 8) == (1, 8192, 8192)
     assert F.own_tail_shape(1 << 27, 4) == (1, 8192, 8192)
-    assert F.own_tail_shape(1 << 28, 8) is None
+    assert F.own_tail_shape(1 << 26, 8) == (1, 4096, 8192)
+    assert F.own_tail_shape(1 << 25, 8) == (1, 4096, 4096)
+    # 1 GSa/s: whole bytes where one packed transform has no leg pair go
+    # as two plane pairs of n/4 points, every fourth sample a plane
+    assert F.own_tail_shape(1 << 28, 8) == (2, 8192, 8192)
+    assert F.own_tail_shape(1 << 28, -8) == (2, 8192, 8192)
+    assert F.own_tail_shape(1 << 28, 2) == (2, 8192, 8192)
+    assert F.own_tail_shape(1 << 29, 8) is None
     assert F.own_tail_shape(1 << 27, 1) is None  # four pairs: no post
 
 
@@ -255,7 +262,19 @@ V5E = 16_911_433_728     # ``bytes_limit`` of one v5e (PERF.md section 4)
     (1 << 27, 4, 1, True, V5E, True, "monolithic"),
     (1 << 26, 2, 1, True, V5E, True, "monolithic"),
     (1 << 25, 8, 1, True, V5E, True, "monolithic"),
-    (1 << 28, 8, 1, True, V5E, True, "monolithic"),     # 1 GSa/s: no leg
+    # 1 GSa/s: two plane pairs of 2^26 points, read faster in PR 48
+    (1 << 28, 8, 1, True, V5E, True, "pallas2"),
+    (1 << 28, 8, 1, False, V5E, True, "monolithic"),    # not on a TPU
+    (1 << 28, 8, 1, True, 8_000_000_000, True, "monolithic"),   # no room
+    # a chip that holds XLA's plan (9.76 GB) and not what the own
+    # transform's plan read at its peak (12.02 GB) keeps XLA's
+    (1 << 28, 8, 1, True, 11_000_000_000, True, "monolithic"),
+    (1 << 28, 8, 1, True, 12_500_000_000, True, "pallas2"),
+    (1 << 28, 8, 1, True, V5E, False, "monolithic"),    # not the own plan
+    # the same shape with other bits or streams: no chip has run it
+    (1 << 28, 2, 1, True, V5E, True, "monolithic"),
+    (1 << 28, 8, 2, True, V5E, True, "monolithic"),
+    (1 << 28, 0, 1, True, V5E, True, "monolithic"),     # wider than a byte
     (1 << 27, 1, 1, True, V5E, True, "monolithic"),     # four plane pairs
     (1 << 27, 2, 1, True, 4_000_000_000, True, "monolithic"),   # no room
     (1 << 27, 2, 2, True, 5_000_000_000, True, "monolithic"),
@@ -302,6 +321,17 @@ def _j1644(**kw):
     ({"fleet_batch_max": 4}, False, "monolithic"),
     ({"baseband_input_bits": 8}, False, "monolithic"),
     ({"baseband_input_bits": 4}, False, "monolithic"),
+    # 2^28 samples: the 1 GSa/s segment of one-byte samples and nothing
+    # else (2 bits, two streams and 16 bits were never read)
+    ({"baseband_input_count": 1 << 28, "baseband_input_bits": 8}, False,
+     "pallas2"),
+    ({"baseband_input_count": 1 << 28, "baseband_input_bits": -8}, False,
+     "pallas2"),
+    ({"baseband_input_count": 1 << 28}, False, "monolithic"),
+    ({"baseband_input_count": 1 << 28, "baseband_input_bits": 8,
+      "baseband_format_type": "interleaved_samples_2"}, False, "monolithic"),
+    ({"baseband_input_count": 1 << 28, "baseband_input_bits": 16}, False,
+     "monolithic"),
     # by name it is the caller's to choose, whatever the plan
     ({"fft_strategy": "pallas2", "use_pallas": True}, False, "pallas2"),
     ({"fft_strategy": "monolithic"}, False, "monolithic"),
@@ -355,13 +385,20 @@ def test_first_spelling_is_an_error_on_a_chip(monkeypatch, log2m, on_tpu,
         assert trace().shape == z.shape
 
 
+_SMALL_LEGS = (128, 256)
+
+
 @pytest.fixture
 def small_legs(monkeypatch):
-    """The column-native passes at CPU sizes: legs of 128 and up."""
-    monkeypatch.setattr(
-        PF2, "cols_factor",
-        lambda m: (128, m // 128)
-        if m >= 128 * 128 and not m & (m - 1) else None)
+    """The column-native passes at CPU sizes: a table of two legs as in
+    production, 128 and 256, so 2^14 to 2^16 points have a leg pair and
+    2^17 (whole bytes at 2^18 samples) has none."""
+    def factor(m):
+        for n1 in _SMALL_LEGS:
+            if m % n1 == 0 and m // n1 in _SMALL_LEGS:
+                return n1, m // n1
+        return None
+    monkeypatch.setattr(PF2, "cols_factor", factor)
 
 
 def _plan(n, bits, fmt, strategy, window="rectangle"):
@@ -393,6 +430,11 @@ def _plan(n, bits, fmt, strategy, window="rectangle"):
     (1 << 16, 2, "simple", "hamming"),
     (1 << 16, 2, "interleaved_samples_2", "hamming"),
     (1 << 15, 8, "interleaved_samples_2", "hamming"),
+    # whole bytes whose packed length has no leg pair (2^17 points here,
+    # 2^27 on a chip): p = 2, every fourth sample a plane
+    (1 << 18, 8, "simple", "rectangle"),
+    (1 << 18, 8, "simple", "hamming"),
+    (1 << 18, 8, "interleaved_samples_2", "rectangle"),
 ])
 def test_own_tail_plan_equals_the_monolithic_chain(small_legs, n, bits,
                                                    fmt, window):
@@ -402,6 +444,8 @@ def test_own_tail_plan_equals_the_monolithic_chain(small_legs, n, bits,
     from srtb_tpu.utils.metrics import metrics
     own, wf1, r1 = _plan(n, bits, fmt, "pallas2", window)
     assert own.own_tail and own.plan_name == "fused:pallas2+ftail+ring"
+    assert F.own_tail_shape(n, bits)[0] == (
+        1 if n == 1 << 15 else 2)
     assert metrics.get("segment_r2c_own") == 1
     mono, wf0, r0 = _plan(n, bits, fmt, "monolithic", window)
     assert not mono.own_tail and mono.plan_name == "fused:monolithic+ring"
@@ -417,6 +461,65 @@ def test_own_tail_plan_equals_the_monolithic_chain(small_legs, n, bits,
         if isinstance(f1, jax.Array) and f1.dtype == jnp.float32:
             f1, f0 = np.asarray(f1), np.asarray(f0)
             assert np.abs(f1 - f0).max() <= 1e-5 * max(np.abs(f0).max(), 1)
+
+
+def test_four_planes_of_a_byte_sequence_give_numpys_rfft():
+    """Whole bytes at p = 2: every fourth sample a plane
+    (`deal_planes` on the bytes, the cast plane by plane), two passes a
+    plane pair and the post pass's join, against NumPy's float64
+    `rfft` of the samples in order; a unit chirp, nothing zapped."""
+    n1 = n2 = 256
+    n = 4 * n1 * n2
+    m = n // 2
+    raw = np.random.default_rng(23).integers(0, 256, n, dtype=np.uint8)
+    planes = jnp.stack([q.view(jnp.int8).astype(jnp.float32)
+                        for q in F.deal_planes(jnp.asarray(raw), 4)])
+    assert planes.shape == (4, n // 4)
+    x = raw.view(np.int8).astype(np.float64)
+    assert (np.asarray(planes) == x.reshape(-1, 4).T).all()
+    c_ri = jnp.stack([jnp.ones(m), jnp.zeros(m)])
+    w = F._iota_phase(m, 2 * m, -1.0)
+    got = np.asarray(F.own_spectrum(
+        planes, (n1, n2), PF2.post_bank(
+            c_ri, jnp.stack([jnp.real(w), jnp.imag(w)])),
+        threshold=1e20, norm=1.0, bins=(), interpret=True))
+    want = np.fft.rfft(x)[:-1]
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() / np.abs(want).max() < 2e-6
+
+
+@pytest.mark.parametrize("fmt,bits,want", [
+    ("simple", 8, 8), ("simple", -8, 8), ("interleaved_samples_2", 8, 8),
+    ("naocpsr_snap1", -8, 8), ("gznupsr_a1", 8, 8),
+    ("gznupsr_a1_v1", 8, 8),
+    ("simple", 2, 2), ("interleaved_samples_2", 4, 4),
+    # dealt out as floats or not at all: not the own plan's
+    ("simple", 16, 0), ("simple", -16, 0), ("simple", 32, 0),
+    ("naocpsr_snap1", 2, 0),
+])
+def test_own_plan_takes_samples_of_a_byte_or_less(small_legs, fmt, bits,
+                                                  want):
+    """The own transform takes a stream's bytes as they lie: sub-byte
+    fields MSB first, or a byte a sample, whose cast (`one_byte_cast`,
+    unscoped, so that it runs under the R2C's name) is the one
+    `unpack_stream` makes.  Anything else is refused, by name too."""
+    from srtb_tpu.io import formats
+    from srtb_tpu.ops import unpack as U
+    from srtb_tpu.pipeline import segment as SG
+    cfg = _j1644(baseband_input_count=1 << 16, baseband_input_bits=bits,
+                 baseband_format_type=fmt, fft_strategy="pallas2")
+    assert SG._r2c_sample_bits(cfg) == want
+    assert SG.own_r2c_hostable(cfg, False) == bool(want)
+    variant = formats.resolve(fmt).unpack_variant
+    cast = U.one_byte_cast(variant, bits)
+    assert (cast is not None) == (want == 8)
+    if cast is not None:
+        raw = jnp.asarray(np.random.default_rng(5).integers(
+            0, 256, 4096, dtype=np.uint8))
+        got = cast(raw)
+        assert got.dtype == jnp.float32
+        assert (np.asarray(got) == np.asarray(
+            U.unpack_stream(raw, variant, bits))).all()
 
 
 def test_fused_tail_through_premul_and_epilogue_equals_unfused():

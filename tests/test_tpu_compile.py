@@ -406,23 +406,52 @@ def test_j1644_plans_auto_never_measured_keep_xlas_transform(
     assert proc.chirp_w is None
 
 
-def test_naoc_1g_under_auto_keeps_xlas_transform_and_fits_twice(
+def test_naoc_1g_under_auto_is_the_own_transform_and_fits_twice(
         one_chip, monkeypatch):
-    """The 1 GSa/s deployment (2^28 samples of 8 bits, 2^15 channels): a
-    packed transform of 2^27 points has no leg the column-native passes
-    hold in VMEM (a leg of 2^14), so `auto` keeps the monolithic plan on
-    the chip too; it compiles and leaves two segments in flight."""
+    """The 1 GSa/s deployment (2^28 samples of 8 bits, 2^15 channels) as
+    `auto` resolves it on a v5e since PR 48: a packed transform of 2^27
+    points has no leg the column-native passes hold in VMEM (a leg of
+    2^14), so the bytes go as TWO plane pairs of 2^26 points, every
+    fourth sample a plane, through legs 8192 x 8192, and the post pass
+    joins them.  The programs go through Mosaic (three custom calls) and
+    the deal-out is lane-dense: no array of weight has a minor dimension
+    of 2 or 4, which the chip pads to 128 lanes (every fourth byte of
+    rows of 512 as a gather, as the two-stream split takes every other
+    one, and the cast in the fusion that writes the four planes, under
+    the R2C's name).  Two segments in flight, with the chirp bank the
+    plan keeps beside them, hold under what `resolve_strategy` asks of a
+    chip's `bytes_limit` at this key (the chip's own peak, 12.02 GB):
+    the programs read 9.53 GB, because at 2^15 channels the waterfall's
+    C2C holds 18 B a sample of temporaries (4.83 GB, in XLA's plan too)
+    where the J1644 shape holds 8.6."""
+    from srtb_tpu.ops import fft as F
     from srtb_tpu.pipeline.segment import SegmentProcessor
 
     _as_on_a_chip(monkeypatch)
     proc = SegmentProcessor(_naoc(28, 14.2), donate_input=True)
-    assert proc.plan_name == "fused:monolithic+ring"
-    assert not proc.own_tail and not proc.fused_tail
+    assert proc.plan_name == "fused:pallas2+ftail+ring"
+    assert proc.own_tail and proc.fused_tail
+    assert proc._pallas_interpret is False
+    assert proc.chirp_w.shape == (4, (1 << 27) // 128, 128)
     compiled = _compile_all(proc, one_chip, only={"ring", "ring_cold"})
+    assert set(compiled) == {"ring", "ring_cold"}
     for name, c in compiled.items():
-        assert "tpu_custom_call" not in c.as_text(), name
-        assert _two_in_flight_bytes(c) < V5E_BYTES_LIMIT, (
+        text = c.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 3, name
+        # the chirp bank stays on the chip though the programs read the
+        # post pass's bank alone
+        held = _two_in_flight_bytes(c) + proc.chirp.nbytes
+        rule = F.OWN_R2C_READ_FASTER[(8, 1, 2, 8192, 8192)]
+        assert 0.85 * rule < held <= rule < V5E_BYTES_LIMIT, (
             name, c.memory_analysis())
+        assert c.memory_analysis().temp_size_in_bytes < 18.1 * (1 << 28)
+        assert not re.search(r"(f32|u8|s8)\[\d{5,},[24]\]", text), name
+        front = [ln for ln in text.splitlines()
+                 if re.match(r"\s+%\S+ = f32\[4,8192,8192\]", ln)]
+        assert len(front) == 1 and " fusion(" in front[0] \
+            and "srtb.fft_r2c" in front[0], [ln[:200] for ln in front]
+        # nothing runs under a name this cell's metrics do not read
+        assert "srtb.unpack" not in text, name
 
 
 # ---- the upstream defaults at the Crab's DM (ISSUE 44) -----------------
