@@ -99,37 +99,6 @@ scale = np.abs(a).max()
 np.testing.assert_allclose(b, a, atol=1e-3 * scale, rtol=0)
 print(f"fused-plan parity OK: plan {fused.plan_name} "
       "matches the legacy unfused chain, detections bit-identical")
-
-# ---- front-fused staged megakernel parity (ISSUE 15): staged_ffuse
-# (raw bytes -> blocked intermediate -> dedispersed spectrum) vs the
-# staged+skzap plan it demotes onto —
-# decisions bit-identical under Pallas interpret.
-import os
-os.environ["SRTB_STAGED_ROWS_IMPL"] = "pallas2"
-from srtb_tpu.io.synth import make_dispersed_baseband as _synth
-raw2 = _synth(n, 1405.0, 64.0, 30.0, pulse_positions=n // 2,
-              pulse_amp=8.0, nbits=2)
-fbase = dict(base, fused_tail="on", use_pallas=True,
-             use_pallas_sk=True,
-             mitigate_rfi_spectral_kurtosis_threshold=5.0)
-ffuse = SegmentProcessor(Config(front_fuse="on", **fbase), staged=True)
-staged = SegmentProcessor(Config(front_fuse="off", **fbase),
-                          staged=True)
-assert ffuse.front_fuse and "+ffuse" in ffuse.plan_name
-assert not staged.front_fuse and staged._skzap
-assert ffuse.plan_signature() != staged.plan_signature()
-wf_ff, res_ff = ffuse.process(raw2)
-wf_st, res_st = staged.process(raw2)
-np.testing.assert_array_equal(np.asarray(res_ff.signal_counts),
-                              np.asarray(res_st.signal_counts))
-np.testing.assert_array_equal(np.asarray(res_ff.zero_count),
-                              np.asarray(res_st.zero_count))
-a2, b2 = waterfall_to_numpy(wf_st), waterfall_to_numpy(wf_ff)
-scale2 = np.abs(a2).max()
-assert scale2 > 0
-np.testing.assert_allclose(b2, a2, atol=1e-3 * scale2, rtol=0)
-print(f"ffuse parity OK: plan {ffuse.plan_name} vs "
-      f"{staged.plan_name}, decisions bit-identical")
 EOF
 
 echo "== [8/21] ring parity smoke (incremental H2D ring on vs off, Pallas interpret) =="

@@ -485,19 +485,6 @@ LADDER_AUDIT_CFG = {
     "baseband_reserve_sample": True, "dm": 0.1,
 }
 
-# second ladder walk: the front-fused staged megakernel's demotion
-# chain.  staged_ffuse is structurally disjoint from the fused-plan
-# base above (staged forbids micro-batch, front fusion requires the
-# pallas2 staged rows), so its rungs — front_fuse -> today's staged
-# plan, then the shared back half — are only exercised by walking
-# from an ffuse-featured base of their own.
-FFUSE_LADDER_AUDIT_CFG = {
-    "fft_strategy": "four_step", "fused_tail": "on",
-    "front_fuse": "on", "baseband_reserve_sample": True, "dm": 0.1,
-}
-FFUSE_LADDER_AUDIT_ENV = {"SRTB_STAGED_ROWS_IMPL": "pallas2"}
-
-
 def _plan_fingerprint(plan_name: str, ingest: str, staged: bool,
                       micro_batch: bool) -> tuple:
     return (str(plan_name), str(ingest), bool(staged),
@@ -543,23 +530,6 @@ def audit_ladder(baseline: "CardBaseline",
         return ["ladder: no demotion rungs resolved from the "
                 "fully-featured audit config (ladder dead?)"]
     fps = _card_fingerprints(baseline)
-    _check_rungs(rungs, fps, failures)
-    # the front-fused staged chain (its base is a different plan
-    # topology — see FFUSE_LADDER_AUDIT_CFG)
-    ffcfg = _audit_config(log2n, channels, dict(FFUSE_LADDER_AUDIT_CFG))
-    with _env(dict(FFUSE_LADDER_AUDIT_ENV)):
-        ffrungs = ladder_rungs(ffcfg, base_staged=True)
-        if not any(r.step == "front_fuse" for r in ffrungs):
-            failures.append(
-                "ladder: the front_fuse rung never resolved from the "
-                "ffuse-featured audit config (rung dead?)")
-        _check_rungs(ffrungs, fps, failures)
-    return failures
-
-
-def _check_rungs(rungs, fps, failures) -> None:
-    """Shared per-rung carded/registered/eligible checks of
-    :func:`audit_ladder` (one body for both ladder walks)."""
     for rung in rungs:
         proc = registry.build_processor(rung.cfg, staged=rung.staged,
                                         donate_input=True)
@@ -590,6 +560,7 @@ def _check_rungs(rungs, fps, failures) -> None:
                 f"{'/'.join(keys)}, registered ladder-INELIGIBLE "
                 "(PlanFamily.ladder=False) — the ladder may shed such "
                 "a family but never demote into it")
+    return failures
 
 
 # ------------------------------------------------------------------
@@ -678,48 +649,6 @@ def selftest(log2n: int = DEFAULT_LOG2N,
             "carry-donation-disabled injection not caught: the "
             f"non-donating assemble still audits aliased: "
             f"{lost['donation']}")
-
-    # front-fuse: an UN-fused unpack front — the sample-order unpack +
-    # even/odd pack materialized as its own spectrum-sized pass before
-    # pass 1 consumes it — must move the ffuse stage_a's pinned count
-    # by at least a read + a write.  As with the extra-pass injection
-    # above, the materialization is anchored by a cumulative sum (its
-    # exact inverse follows, so the values are the same z): a plain
-    # unpack->pack chain re-fuses into pass 1's operands at the tiny
-    # audit shape and the z traffic goes entry-invisible.
-    import jax.numpy as jnp
-    from srtb_tpu.ops import pallas_fft2 as pf2
-
-    fspec = registry.family("staged_ffuse")
-    fproc = build_plan(fspec, log2n=log2n, channels=channels)
-    fbytes = 8 * fproc.n_spectrum
-    (_, afn, aargs, adon), = [p for p in fproc.lowerables()
-                              if p[0] == "stage_a"]
-    fclean = audit_program(afn, aargs, adon, fbytes)
-    fn1, fn2 = fproc._ffuse_fac
-
-    def unfused_front(raw):
-        z = fproc._staged_pack(raw)   # sample-order unpack + pack
-        zri = jnp.stack([jnp.real(z), jnp.imag(z)])  # [2, S, m]
-        zri = jnp.cumsum(zri, axis=-1)               # materialize ...
-        zri = zri - jnp.concatenate(                 # ... then undo
-            [jnp.zeros_like(zri[..., :1]), zri[..., :-1]], axis=-1)
-        outs = [pf2.pass1_2d(zri[0, s].reshape(fn1, fn2),
-                             zri[1, s].reshape(fn1, fn2),
-                             interpret=True)
-                for s in range(z.shape[0])]
-        a_ri = jnp.stack([jnp.stack([o[0] for o in outs]),
-                          jnp.stack([o[1] for o in outs])])
-        aux = jnp.zeros((z.shape[0], 3, 128), jnp.float32)
-        return fproc._boundary_canon(a_ri), aux
-
-    funfused = audit_program(jax.jit(unfused_front), aargs, (), fbytes)
-    fgained = funfused["spectrum_passes"] - fclean["spectrum_passes"]
-    if fgained < 2:
-        failures.append(
-            "un-fused-unpack injection not caught: audited passes "
-            f"moved by {fgained} (expected >= 2: the materialized "
-            "sample-order z write + read the front fusion eliminates)")
 
     # demotion-ladder gate: every rung must match the checked-in
     # baseline, and the gate must visibly fail against a baseline

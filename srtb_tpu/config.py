@@ -209,25 +209,6 @@ class Config:
     # custom call; "on" forces it (errors on monolithic); "off"
     # restores the legacy unfused chain.
     fused_tail: str = "auto"
-    # front-fused staged megakernel ("auto" | "on" | "off"): fold the
-    # sub-byte unpack + window + even/odd pack + forward-FFT pass 1
-    # into the pallas2 row-FFT kernel (raw bytes in, blocked
-    # intermediate out) and the whole spectrum tail — Hermitian
-    # post-process, RFI s1, dedispersion chirp — into pass 2's
-    # epilogue, so a staged segment's front half completes in 2 HBM
-    # sweeps (the staged_ffuse plan family, ops/pallas_fft2).  Requires
-    # the staged plan with SRTB_STAGED_ROWS_IMPL=pallas2, a fusable
-    # tail, and an unpack variant the kernel can spell in-register
-    # (simple 1/2/4/8-bit or 2-pol byte-interleaved).  "auto" = on when all of that holds AND
-    # the kernels are trusted (the FFUSE_MOSAIC_OK probe flag or
-    # SRTB_PALLAS_FFUSE=1 — never implicitly, so existing pallas2
-    # configs keep their plan); "on" forces (errors when structurally
-    # impossible — how the staged_ffuse family, tests and the
-    # hardware-probe legs select it); "off" restores the classic
-    # staged front.  The
-    # demotion ladder's front_fuse rung drops exactly this knob, so a
-    # Mosaic rejection heals onto today's audited staged plan.
-    front_fuse: str = "auto"
     # escape hatch: force the exact per-element df64 chirp evaluation
     # (~3 df64 divisions/channel) instead of the anchored-Taylor fast
     # path that is the default everywhere (segment plans, Pallas
@@ -322,8 +303,7 @@ class Config:
     degrade_hold_segments: int = 3
     # ---- self-healing compute (resilience/demote.py) ----
     # plan-demotion ladder for device OOM / compile faults: "auto"
-    # walks search_mode -> micro_batch -> front_fuse -> ring -> skzap
-    # -> fused_tail
+    # walks search_mode -> micro_batch -> ring -> skzap -> fused_tail
     # -> staged -> monolithic (the registry's canonical order,
     # cumulatively, skipping rungs the active config
     # doesn't use); an explicit comma list selects a subset in that

@@ -439,7 +439,6 @@ def subbyte_window_planes(window: np.ndarray, nbits: int) -> np.ndarray:
 def rfft_subbyte(data: jnp.ndarray, nbits: int, strategy: str = "four_step",
                  window_planes: jnp.ndarray | None = None,
                  drop_nyquist: bool = True,
-                 planes: jnp.ndarray | None = None,
                  len_cap: int | None = None,
                  epilogue=None, premul=None) -> jnp.ndarray:
     """Fused unpack + even/odd pack + R2C for 1/2/4-bit baseband bytes,
@@ -467,19 +466,14 @@ def rfft_subbyte(data: jnp.ndarray, nbits: int, strategy: str = "four_step",
     ``window_planes``: optional [count, M] from `subbyte_window_planes`.
     ``strategy``: "four_step" (XLA batched FFTs) or "mxu" (DFT-matmul
     stages) for the M-point plane FFTs.
-    ``planes``: optional precomputed (and already-windowed) blocked field
-    planes [..., count, M] — e.g. from the fused Pallas
-    unpack_subbyte_planes_window; when given, ``data``/``nbits`` unpack
-    and ``window_planes`` are skipped entirely.
     """
     from srtb_tpu.ops import unpack as _U
     count = 8 // nbits
     if count < 2:
         raise ValueError("rfft_subbyte requires 1/2/4-bit input")
-    if planes is None:
-        planes = _U.unpack_subbyte_planes(data, nbits)    # [..., count, M]
-        if window_planes is not None:
-            planes = planes * window_planes
+    planes = _U.unpack_subbyte_planes(data, nbits)        # [..., count, M]
+    if window_planes is not None:
+        planes = planes * window_planes
     z = subbyte_planes_to_packed(planes)
     if strategy == "mxu":
         from srtb_tpu.ops.mxu_fft import mxu_fft
@@ -574,36 +568,31 @@ def own_spectrum(planes: jnp.ndarray, legs: tuple, bank: jnp.ndarray, *,
     return jax.lax.complex(s_re, s_im)
 
 
+# Points from which "pallas2" is the column-native passes or nothing
+_PALLAS2_MIN_POINTS = 1 << 24
+
+
 def _pallas2_or_fallback(z: jnp.ndarray, strategy: str,
                          len_cap: int | None = None) -> jnp.ndarray:
     """The two-pass Pallas C2C (ops/pallas_fft2) on [..., L] complex z:
     the column-native passes at the lengths they take (2^24 to 2^26),
     and the four-step-with-Pallas-legs form below 2^24 (tiny test
-    configs).  The lengths between, 2^27 to 2^29, have only the first
-    spelling of the passes (``fft2_c2c``), which Mosaic refuses for a
-    v5e: in interpret mode it runs, on a chip it is an error here.  (A
-    served plan never asks for them: its own transform takes 2^28
-    samples as two transforms of 2^26 points, ``own_tail_shape``.)"""
+    configs).  Longer transforms are an error on every backend: no
+    kernel here factors them (Mosaic refused the one that did for a
+    v5e; PERF.md section 6, PRs 43 and 50).  A served plan never asks
+    for them: its own transform takes 2^28 samples as two transforms of
+    2^26 points, ``own_tail_shape``."""
     from srtb_tpu.ops import pallas_fft2 as pf2
     interp = strategy.endswith("interpret")
     length = z.shape[-1]
     if pf2.cols_factor(length) is not None:
         return pf2.fft2_cols(z, inverse=False, interpret=interp)
-    if pf2.supported(length):
-        from srtb_tpu.utils.platform import on_accelerator
-        if not interp and on_accelerator():
-            raise ValueError(
-                f"fft_strategy pallas2 has no transform of {length} "
-                f"points on a chip: the column-native passes take 2^24 "
-                f"to 2^26 points, and Mosaic refuses the first spelling "
-                f"of the passes at these sizes (96.14 MB of scoped VMEM "
-                f"against 80 at 2^26 points on a v5e; PERF.md section "
-                f"6, PR 43).  A served plan splits such a segment into "
-                f"plane pairs of 2^26 points (ops/fft.own_tail_shape)")
-        return pf2.fft2_c2c(z, inverse=False, interpret=interp)
-    # loud when an explicit SRTB_PALLAS2_N1 pin is why we're falling
-    # back — the A/B knob must not silently measure the wrong path
-    pf2.require_pin_fit(length)
+    if length >= _PALLAS2_MIN_POINTS:
+        raise ValueError(
+            f"fft_strategy pallas2 has no transform of {length} points: "
+            f"the column-native passes take 2^24 to 2^26 points.  A "
+            f"served plan splits a longer segment into plane pairs of "
+            f"2^26 points (ops/fft.own_tail_shape)")
     return _fft_minor(z, inverse=False,
                       rows_impl="pallas_interpret" if interp else "pallas",
                       len_cap=len_cap)

@@ -91,8 +91,7 @@ def vmem_fft_rows(xr, xi, war, wai, wbr, wbi, twr, twi, *, la, lb, rows):
     ``[r, ka, kb]`` is bin ``k = ka*lb + kb``) — kernels store it to a
     matching 3D ref and callers flatten OUTSIDE the pallas_call, where
     the contiguous reshape is free metadata.  Pure function of
-    VMEM-resident values — shared by the kernels here and by the fused
-    two-pass four-step in ops/pallas_fft2.
+    VMEM-resident values, shared by the kernels here.
 
     This is the one spelling real Mosaic accepted (round-5 acceptance
     probes; not re-measured on this JAX): in-kernel lane-dim reshapes
@@ -316,9 +315,9 @@ def _vmem_mb() -> int | None:
 
 
 def _rows_budget_padded(length: int, budget_bytes: int) -> int:
-    """Largest rows whose PADDED footprint fits the budget, using the
-    ops/pallas_fft2 accounting discipline: 2x-pipelined in/out block
-    refs at rows*length f32 each (the 3D output block's minor dim lb
+    """Largest rows whose PADDED footprint fits the budget:
+    2x-pipelined in/out block refs at rows*length f32 each (the 3D
+    output block's minor dim lb
     lane-pads to 128, up to 4x on the small-length end — which a flat
     per-plane divisor would undercount exactly where it hurts), plus
     the helper's live stages ([rows, la, lb] intermediates, lb
@@ -395,9 +394,8 @@ def _dft_matrix_np(r: int, inverse: bool):
 def leg_consts(length: int, inverse: bool):
     """(la, lb, const arrays) for a two-level in-VMEM row FFT of this
     length — the DFT matrices and inner twiddle every kernel using
-    :func:`vmem_fft_rows` must pass in.  Single home (with
-    :func:`leg_const_specs`) so _Launch and ops/pallas_fft2 can never
-    drift apart on split bounds, precision, or twiddle discipline."""
+    :func:`vmem_fft_rows` must pass in (``_Launch`` with
+    :func:`leg_const_specs`)."""
     split = _split_la_lb(length)
     if split is None:
         raise ValueError(f"row-FFT length {length} unsupported")

@@ -1,24 +1,24 @@
 """Pallas TPU kernels for the hot elementwise ops.
 
-Two kernels where explicit VMEM control beats relying on XLA fusion:
+Kernels where explicit VMEM control beats relying on XLA fusion:
 
-1. ``dedisperse_df64``: chirp multiply with the phase computed **on the
-   fly** inside the kernel using df64 two-float arithmetic.  The baseline
-   path streams a precomputed chirp bank from HBM (8 bytes/channel/trial);
-   computing the phase in-register turns the op from memory-bound (3
-   arrays in, 2 out) into 2-in/2-out — and for DM search it removes the
-   [n_dm, 2, n] chirp bank from HBM entirely.  (Same math as
-   ops.dedisperse.chirp_factor_df64 / ref: coherent_dedispersion.hpp
-   phase_factor_v3 with dsmath df64.)
+1. ``dedisperse_df64`` (and ``rfi_s1_dedisperse_df64``, the same pass
+   with RFI s1's zap in it): chirp multiply with the phase computed **on
+   the fly** inside the kernel using df64 two-float arithmetic.  The
+   baseline path streams a precomputed chirp bank from HBM (8
+   bytes/channel/trial); computing the phase in-register turns the op
+   from memory-bound (3 arrays in, 2 out) into 2-in/2-out — and for DM
+   search it removes the [n_dm, 2, n] chirp bank from HBM entirely.
+   (Same math as ops.dedisperse.chirp_factor_df64 / ref:
+   coherent_dedispersion.hpp phase_factor_v3 with dsmath df64.)
 
-2. ``unpack_2bit_window``: sub-byte unpack fused with the FFT-window
-   multiply (ref: unpack.hpp:102-121 handwritten 2-bit kernel + fused
-   transform) — one byte load produces four windowed f32 samples without
-   an intermediate HBM round trip.
+2. ``sk_zap_timeseries``: the spectral-kurtosis statistics, the zap and
+   the time series over the waterfall in two kernel passes.
 
-Both fall back transparently to the jnp implementations when Pallas is
-unavailable (pure-CPU CI), and are validated against them in tests via
-``interpret=True``.
+They are validated against the jnp implementations in tests via
+``interpret=True``.  (The sub-byte unpack is XLA's: a kernel for it
+needs a lane interleave Mosaic does not lower, and XLA fuses the
+shift/mask chain into the FFT's input anyway, ops/unpack.py.)
 """
 
 from __future__ import annotations
@@ -620,188 +620,3 @@ def sk_apply_timeseries(wf_ri: jnp.ndarray, zap: jnp.ndarray,
     )(re, im, keep)
 
     return jnp.stack([out_re, out_im]), ts2d.reshape(ntime)
-
-
-# Sub-byte unpack needs a lane interleave (out[4c+j] = field_j(byte[c])),
-# which Mosaic cannot lower today: every legal spelling (stack+reshape,
-# repeat, per-field slice-assign then flatten) either raises
-# "infer-vector-layout: unsupported shape cast" on a real chip or lands
-# the fields in blocked, not sample, order.  The kernel stays for
-# interpret-mode CI parity and as the reference spelling; real-TPU
-# segments take the XLA unpack (ops/unpack.py), whose shift/mask chain
-# XLA fuses into the FFT input anyway — unpack is a few percent of an
-# FFT-dominated pipeline, so nothing measurable is lost.
-UNPACK_MOSAIC_OK = False
-
-
-def _unpack_subbyte_kernel(byte_ref, win_ref, out_ref, *, nbits,
-                           apply_window):
-    b = byte_ref[:].astype(jnp.int32)
-    per_byte = 8 // nbits
-    mask = (1 << nbits) - 1
-    # MSB-first fields (ref: unpack.hpp:43-140 generic + handwritten
-    # 1/2/4-bit kernels share this bit order)
-    fields = [((b >> (8 - nbits * (j + 1))) & mask).astype(jnp.float32)
-              for j in range(per_byte)]
-    # interleave along lanes: [R, C] x per_byte -> [R, per_byte*C]
-    out = jnp.stack(fields, axis=-1).reshape(
-        b.shape[0], per_byte * b.shape[1])
-    if apply_window:
-        out = out * win_ref[:]
-    out_ref[:] = out
-
-
-@S.scoped(S.UNPACK)
-def unpack_subbyte_window(data: jnp.ndarray, nbits: int,
-                          window: jnp.ndarray | None = None,
-                          interpret: bool = False) -> jnp.ndarray:
-    """uint8 [m] -> f32 [(8/nbits)*m] for nbits in {1, 2, 4}: MSB-first
-    sub-byte unpack fused with an optional window multiply, one HBM pass
-    (ref: unpack.hpp handwritten 1/2/4-bit kernels + fused transform)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if nbits not in (1, 2, 4):
-        raise ValueError(f"sub-byte unpack needs nbits in 1/2/4, got {nbits}")
-    per_byte = 8 // nbits
-    m = data.shape[-1]
-    if m % _LANES:
-        raise ValueError(f"byte count must be a multiple of {_LANES}")
-    rows_total = m // _LANES
-    rows = min(_ROWS, rows_total)
-    if rows_total % rows:
-        raise ValueError(f"{rows_total} rows not divisible by block {rows}")
-    grid = (rows_total // rows,)
-
-    bytes2d = data.reshape(rows_total, _LANES)
-    apply_window = window is not None
-    if window is None:
-        window = jnp.ones((rows_total, per_byte * _LANES),
-                          dtype=jnp.float32)
-    else:
-        window = window.reshape(rows_total, per_byte * _LANES)
-
-    kernel = functools.partial(_unpack_subbyte_kernel, nbits=nbits,
-                               apply_window=apply_window)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((rows, per_byte * _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((rows, per_byte * _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows_total, per_byte * _LANES),
-                                       jnp.float32),
-        interpret=interpret,
-    )(bytes2d, window)
-    return out.reshape(per_byte * m)
-
-
-@S.scoped(S.UNPACK)
-def unpack_2bit_window(data: jnp.ndarray,
-                       window: jnp.ndarray | None = None,
-                       interpret: bool = False) -> jnp.ndarray:
-    """uint8 [m] -> f32 [4m]; see :func:`unpack_subbyte_window`."""
-    return unpack_subbyte_window(data, 2, window, interpret)
-
-
-# ----------------------------------------------------------------
-# blocked-plane sub-byte unpack (the Mosaic-lowerable spelling)
-# ----------------------------------------------------------------
-
-def _unpack_planes_kernel(byte_ref, win_ref, out_ref, *, nbits,
-                          apply_window):
-    b = byte_ref[:].astype(jnp.int32)            # [rows, LANES]
-    count = 8 // nbits
-    mask = (1 << nbits) - 1
-    for j in range(count):
-        # MSB-first field j of every byte (ref: unpack.hpp:43-140)
-        f = ((b >> (8 - nbits * (j + 1))) & mask).astype(jnp.float32)
-        if apply_window:
-            f = f * win_ref[j]
-        out_ref[j] = f
-
-
-@S.scoped(S.UNPACK)
-def unpack_subbyte_planes_window(data: jnp.ndarray, nbits: int,
-                                 window_planes: jnp.ndarray | None = None,
-                                 interpret: bool = False) -> jnp.ndarray:
-    """uint8 [m] -> blocked field planes [count, m] f32 (count = 8/nbits,
-    plane k = field k of every byte), fused with the blocked window
-    multiply — ONE HBM pass for unpack + window.
-
-    This is the Mosaic-LOWERABLE sub-byte unpack: the sample-order kernel
-    (:func:`unpack_subbyte_window`) needs a lane interleave
-    (out[4c+j] = field_j(byte[c])) that Mosaic cannot lower (see
-    UNPACK_MOSAIC_OK), but blocked planes put each field on a new MAJOR
-    axis — per-plane [rows, 128] writes, no lane shuffle anywhere.  The
-    blocked layout is exactly what ops.fft.rfft_subbyte consumes (its
-    FFT decimation absorbs the blocked->natural permutation), so nothing
-    downstream ever wants sample order.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if nbits not in (1, 2, 4):
-        raise ValueError(f"sub-byte unpack needs nbits in 1/2/4, got {nbits}")
-    count = 8 // nbits
-    m = data.shape[-1]
-    if m % _LANES:
-        raise ValueError(f"byte count {m} not a multiple of {_LANES}")
-    rows_total = m // _LANES
-    rows = min(_ROWS, rows_total)
-    if rows_total % rows:
-        raise ValueError(f"{rows_total} rows not divisible by block {rows}")
-    grid = (rows_total // rows,)
-
-    bytes2d = data.reshape(rows_total, _LANES)
-    apply_window = window_planes is not None
-    if window_planes is None:
-        win3d = jnp.ones((count, 1, _LANES), dtype=jnp.float32)
-        win_block = pl.BlockSpec((count, 1, _LANES), lambda i: (0, 0, 0),
-                                 memory_space=pltpu.VMEM)
-    else:
-        win3d = window_planes.reshape(count, rows_total, _LANES)
-        win_block = pl.BlockSpec((count, rows, _LANES), lambda i: (0, i, 0),
-                                 memory_space=pltpu.VMEM)
-
-    kernel = functools.partial(_unpack_planes_kernel, nbits=nbits,
-                               apply_window=apply_window)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-                  win_block],
-        out_specs=pl.BlockSpec((count, rows, _LANES), lambda i: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((count, rows_total, _LANES),
-                                       jnp.float32),
-        interpret=interpret,
-    )(bytes2d, win3d)
-    return out.reshape(count, m)
-
-
-# Pending on-chip Mosaic validation (then flip to True): the spelling avoids every
-# construct the sample-order kernel died on, but Mosaic acceptance is
-# only provable by compiling on a real chip.  SRTB_PALLAS_PLANES_UNPACK=1
-# opts in before that.
-PLANES_UNPACK_MOSAIC_OK = False
-
-
-def planes_unpack_enabled(interpret: bool) -> bool:
-    import os
-    return interpret or PLANES_UNPACK_MOSAIC_OK or \
-        os.environ.get("SRTB_PALLAS_PLANES_UNPACK", "") == "1"
-
-
-def planes_tiling_ok(m: int) -> bool:
-    """Whether a byte count fits the planes-unpack launch geometry
-    (same pre-flight role as sk_tiling_ok: callers fall back to the XLA
-    unpack instead of crashing at trace)."""
-    if m % _LANES:
-        return False
-    rows_total = m // _LANES
-    return rows_total % min(_ROWS, rows_total) == 0

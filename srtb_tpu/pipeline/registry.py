@@ -267,31 +267,6 @@ def _apply_micro_batch(cfg, staged):
     return cfg.replace(micro_batch_segments=1), staged
 
 
-def _apply_front_fuse(cfg, staged):
-    from srtb_tpu.pipeline.segment import (_front_fuse_structural,
-                                           front_fuse_resolves)
-    resolved = _resolved_staged(cfg, staged)
-    # structural precheck FIRST: a forced front_fuse="on" evaluated
-    # under a stagedness where the fusion is impossible (e.g. the
-    # healer's pre-bind rung scan on a small segment) must read as
-    # "nothing to drop", not trip the knob's loud constructor check
-    if not _front_fuse_structural(cfg, resolved):
-        return None
-    if not front_fuse_resolves(cfg, resolved):
-        return None
-    return cfg.replace(front_fuse="off"), staged
-
-
-def _drop_forced_front_fuse(cfg):
-    """Rungs that break a front-fuse prerequisite (fused tail,
-    stagedness) also clear a FORCED front_fuse="on": the resulting
-    config must construct cleanly instead of tripping the knob's
-    loud structural check."""
-    if str(getattr(cfg, "front_fuse", "auto")).lower() == "on":
-        return cfg.replace(front_fuse="off")
-    return cfg
-
-
 def _apply_ring(cfg, staged):
     if str(getattr(cfg, "ingest_ring", "auto")).lower() == "off":
         return None
@@ -316,7 +291,6 @@ def _apply_fused_tail(cfg, staged):
     if not (fused_tail_resolves(cfg, _resolved_staged(cfg, staged))
             or getattr(cfg, "use_pallas", False)):
         return None
-    cfg = _drop_forced_front_fuse(cfg)
     return cfg.replace(fused_tail="off", use_pallas=False), staged
 
 
@@ -337,8 +311,7 @@ def _apply_monolithic(cfg, staged):
                and segment_strategy(cfg, False) == "monolithic")
     if already:
         return None
-    return _drop_forced_front_fuse(cfg).replace(
-        fft_strategy="monolithic"), False
+    return cfg.replace(fft_strategy="monolithic"), False
 
 
 register_step(LadderStep(
@@ -352,10 +325,6 @@ register_step(LadderStep(
 register_step(LadderStep(
     "micro_batch", "drop micro-batching (B x program footprint)",
     _apply_micro_batch))
-register_step(LadderStep(
-    "front_fuse", "drop the front-fused pallas2 megakernel back to "
-    "the classic staged front (the audited Mosaic-balks fallback)",
-    _apply_front_fuse))
 register_step(LadderStep(
     "ring", "drop the ingest ring's carry programs",
     _apply_ring))
@@ -429,19 +398,6 @@ for _fam in (
                {"fft_strategy": "four_step", "fused_tail": "on"},
                donate=True, staged=True,
                env={"SRTB_STAGED_ROWS_IMPL": "pallas"}),
-    PlanFamily("staged_pallas2", "staged with fused two-pass pallas2 "
-               "legs (downgrades to pallas legs below the 2^24 leg "
-               "window)",
-               {"fft_strategy": "four_step", "fused_tail": "on"},
-               donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
-    PlanFamily("staged_pallas2_unfused", "the whole-plane staged plan "
-               "with the legacy 7-pass tail (what staged_unfused was "
-               "before its stages walked the boundary in blocks): where "
-               "the front-fused chain's fused_tail rung lands",
-               {"fft_strategy": "four_step", "fused_tail": "off"},
-               donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     # ---- ingest-ring (ring-v1) families: overlap-save reserves a
     # tail (baseband_reserve_sample + a small dm keeps 0 < reserved
     # < n at the audit shape), so the two-input carry ++ new assemble
@@ -488,27 +444,6 @@ for _fam in (
                {"fft_strategy": "four_step", "fused_tail": "off",
                 **_RING_CFG},
                donate=True, staged=True),
-    # ---- front-fused staged megakernel (staged_ffuse): unpack +
-    # window + even/odd pack + FFT pass 1 fold into the pallas2 pass-1
-    # kernel (raw bytes in, blocked intermediate out) and the whole
-    # spectrum tail into pass 2's epilogue (two megakernel
-    # sweeps).  front_fuse="on" forces the kernels so the audit
-    # covers them on any backend; the demotion rung (front_fuse, the
-    # step right after micro_batch) lands on today's staged plan.
-    PlanFamily("staged_ffuse", "front-fused staged pallas2 megakernel: "
-               "raw bytes -> blocked intermediate -> dedispersed "
-               "spectrum in two kernel passes",
-               {"fft_strategy": "four_step", "fused_tail": "on",
-                "front_fuse": "on"},
-               donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
-    PlanFamily("staged_ffuse_ring", "front-fused staged plan + ingest "
-               "ring: the carry alias must survive the front fusion "
-               "(the PR-7 aval lesson, re-proven per card)",
-               {"fft_strategy": "four_step", "fused_tail": "on",
-                "front_fuse": "on", **_RING_CFG},
-               donate=True, staged=True,
-               env={"SRTB_STAGED_ROWS_IMPL": "pallas2"}),
     # ---- data-quality epilogue (srtb_tpu/quality/): cheap jnp
     # reductions over the spectrum + waterfall ride the detect tail
     # as a side output (coarse-bin-sized extra traffic);

@@ -52,8 +52,7 @@ Self-healing compute (resilience/demote.py, PR 9): failures the
 accelerator side raises — device OOM, Pallas/Mosaic compile faults,
 device halts — are classified from the real jax exception strings and
 recovered instead of escalating: OOM/compile faults demote the plan
-down an audited ladder (micro_batch -> front_fuse -> ring -> skzap ->
-fused_tail ->
+down an audited ladder (micro_batch -> ring -> skzap -> fused_tail ->
 staged -> monolithic) and re-dispatch the faulted segment cold from
 its retained host buffer; halts reinitialize the backend (clear
 caches, rebuild the processor, re-dispatch the in-flight window)

@@ -227,66 +227,6 @@ def fused_tail_resolves(cfg, staged: bool) -> bool:
     return not (bankless and n // 2 > FUSED_TAIL_DF64_MAX_SPECTRUM)
 
 
-def _front_fuse_structural(cfg, staged: bool) -> bool:
-    """Whether the front-fused staged megakernel (``staged_ffuse``,
-    ops/pallas_fft2 pass1_front/pass2_spectrum) is structurally
-    possible for this config: the staged plan with pallas2 rows, a
-    fusable tail, an unpack variant the kernel spells in-register, and
-    a factorizable transform length.  Platform/probe gating lives in
-    :func:`front_fuse_resolves`."""
-    if not staged:
-        return False
-    impl = os.environ.get("SRTB_STAGED_ROWS_IMPL", "xla")
-    if impl not in ("pallas2", "pallas2_interpret"):
-        return False
-    if int(os.environ.get("SRTB_STAGED_BLOCKED", "0")):
-        # the blocked-plane staged pack is a different front entirely
-        return False
-    from srtb_tpu.io import formats as _formats
-    from srtb_tpu.ops import pallas_fft2 as pf2
-    fmt = _formats.resolve(cfg.baseband_format_type)
-    bits = int(cfg.baseband_input_bits)
-    if bits not in pf2.FFUSE_VARIANT_BITS.get(fmt.unpack_variant, ()):
-        return False
-    if not fused_tail_resolves(cfg, staged):
-        # the pass-2 epilogue IS the fused tail; without it there is
-        # nothing to emit the dedispersed spectrum from
-        return False
-    return pf2.ffuse_factor(int(cfg.baseband_input_count) // 2) \
-        is not None
-
-
-def front_fuse_resolves(cfg, staged: bool) -> bool:
-    """Resolution of ``Config.front_fuse`` ("auto"/"on"/"off") for a
-    plan with the given resolved ``staged`` flag — the single home
-    shared by the SegmentProcessor resolver and the demotion ladder's
-    front_fuse rung (pipeline/registry.py).  "auto" additionally gates
-    on the kernels being trusted (the FFUSE_MOSAIC_OK probe flag or
-    SRTB_PALLAS_FFUSE=1 — never implicitly, so existing pallas2
-    configs keep their plan); "on" forces past that gate (the
-    ffuse family / hardware-probe spelling) but raises when the
-    fusion is structurally impossible."""
-    mode = str(getattr(cfg, "front_fuse", "auto")).lower()
-    if mode not in ("auto", "on", "off"):
-        raise ValueError(f"front_fuse must be auto/on/off, got {mode!r}")
-    if mode == "off":
-        return False
-    ok = _front_fuse_structural(cfg, staged)
-    if mode == "on":
-        if not ok:
-            raise ValueError(
-                "front_fuse=on requires the staged plan with "
-                "SRTB_STAGED_ROWS_IMPL=pallas2, a fusable tail "
-                "(fused_tail != off, non-monolithic), a simple "
-                "1/2/4/8-bit or 2-pol byte-interleaved format, and a "
-                "pallas2-factorizable length")
-        return True
-    if not ok:
-        return False
-    from srtb_tpu.ops import pallas_fft2 as pf2
-    return pf2.ffuse_enabled()
-
-
 class SegmentProcessor:
     """Builds and owns the jitted per-segment device function plus its
     precomputed constants (chirp, window, RFI mask, normalization).
@@ -350,8 +290,7 @@ class SegmentProcessor:
         # unpack + pack + FFT with no sample-order interleave anywhere —
         # the sample-order composition materializes a [bytes, count]
         # layout that pads 32x on TPU.  Independent of use_pallas: the
-        # Pallas unpack kernel (sample order) only serves the monolithic
-        # route, which fuses it away.
+        # unpack is XLA's on every plan.
         self._blocked_subbyte = (
             self.fmt.unpack_variant == "simple"
             and cfg.baseband_input_bits in (1, 2, 4))
@@ -390,12 +329,6 @@ class SegmentProcessor:
                 win, 2 * F.own_tail_shape(self.n, _r2c_sample_bits(cfg))[0]))
         from srtb_tpu.utils.metrics import metrics
         metrics.set("segment_r2c_own", int(self.own_tail))
-        # front-fused staged megakernel (Config.front_fuse, the
-        # staged_ffuse family): unpack + window + even/odd pack +
-        # FFT pass 1 fold into the pallas2 pass-1 kernel (raw bytes
-        # in, blocked intermediate out) and the Hermitian + RFI-s1 +
-        # chirp tail into pass 2's epilogue
-        self.front_fuse = front_fuse_resolves(cfg, self.staged)
         # the staged plan's default spelling walks its boundary in this
         # many blocks of rows (0: the whole-plane spellings)
         self.staged_rows = self._resolve_staged_rows()
@@ -515,7 +448,7 @@ class SegmentProcessor:
         in_donate = (0,) if self._donate_input else ()
         self._jit_process = jax.jit(self._process, donate_argnums=in_donate)
         self._jit_process_batch = None  # built lazily (micro-batch mode)
-        if self.staged and not self.front_fuse:
+        if self.staged:
             # natural (pre-canonicalization) shape of the stage (a)
             # intermediate, recovered inside stage (b) by a fused
             # metadata reshape (abstract trace only — no compile, no run)
@@ -523,8 +456,6 @@ class SegmentProcessor:
             self._a_nat_shape = jax.eval_shape(
                 self._stage_a_nat,
                 jax.ShapeDtypeStruct((expected,), jnp.uint8)).shape
-        if self.front_fuse:
-            self._init_front_fuse()
         self._jit_stage_a = jax.jit(self._stage_a, donate_argnums=in_donate)
         # the staged intermediates are consumed exactly once, so stages
         # donate their inputs — and because every boundary shares the
@@ -639,11 +570,10 @@ class SegmentProcessor:
         programs.  The variants that bring their own kernels or fold the
         tail into stage (b) keep their spellings."""
         cfg = self.cfg
-        plain = (self.staged and not self.front_fuse
-                 and not self.fused_tail
+        plain = (self.staged and not self.fused_tail
                  and not cfg.use_pallas and not cfg.use_pallas_sk
                  and not getattr(cfg, "quality_stats", False)
-                 and os.environ.get("SRTB_STAGED_ROWS_IMPL", "xla") == "xla"
+                 and self._staged_rows_impl == "xla"
                  and not self._staged_blocked
                  and self.channel_count * self.watfft_len
                  == self.n_spectrum)
@@ -803,8 +733,6 @@ class SegmentProcessor:
             name += "+rows"
         if self.fused_tail:
             name += "+ftail"
-        if self.front_fuse:
-            name += "+ffuse"
         if self._skzap:
             name += "+skzap"
         if self.ring:
@@ -869,23 +797,13 @@ class SegmentProcessor:
 
     def _unpack(self, raw: jnp.ndarray) -> jnp.ndarray:
         """raw bytes -> windowed float32 samples [S, n]."""
-        cfg = self.cfg
-        interp = getattr(self, "_pallas_interpret", False)
-        from srtb_tpu.ops import pallas_kernels as pk
-        if (cfg.use_pallas and cfg.baseband_input_bits in (1, 2, 4)
-                and self.fmt.unpack_variant == "simple"
-                and (interp or pk.UNPACK_MOSAIC_OK)):
-            return pk.unpack_subbyte_window(raw, cfg.baseband_input_bits,
-                                            self.window,
-                                            interpret=interp)[None, :]
         return unpack_streams(raw, self.fmt.unpack_variant,
-                              cfg.baseband_input_bits, self.window)
+                              self.cfg.baseband_input_bits, self.window)
 
     def _resolve_rows_impl(self, impl: str) -> str:
         """Single home of the off-TPU downgrade rule: 'pallas' runs the
-        kernels in interpret mode on CPU backends.  Unknown names raise —
-        a typo in SRTB_STAGED_ROWS_IMPL must not silently fall back to
-        XLA while the probe log claims a Pallas result."""
+        kernels in interpret mode on CPU backends.  Unknown names raise:
+        a typo in ``fft_strategy`` must not silently fall back to XLA."""
         if impl not in ("xla", "four_step", "mxu", "monolithic", "auto",
                         "pallas", "pallas_interpret",
                         "pallas2", "pallas2_interpret"):
@@ -913,19 +831,8 @@ class SegmentProcessor:
                                                   "pallas_interpret",
                                                   "pallas2",
                                                   "pallas2_interpret"):
-            from srtb_tpu.ops import pallas_kernels as pk
-            interp = getattr(self, "_pallas_interpret", False)
-            planes = None
-            if self.cfg.use_pallas and pk.planes_unpack_enabled(interp) \
-                    and pk.planes_tiling_ok(raw.shape[-1]):
-                # fused unpack + blocked-window multiply in one HBM pass
-                # (the Mosaic-lowerable blocked-plane spelling)
-                planes = pk.unpack_subbyte_planes_window(
-                    raw, self.cfg.baseband_input_bits,
-                    self.window_planes, interpret=interp)
             spec = F.rfft_subbyte(raw, self.cfg.baseband_input_bits,
                                   strategy, self.window_planes,
-                                  planes=planes,
                                   len_cap=self._len_cap,
                                   epilogue=epilogue,
                                   premul=premul)[None, :]
@@ -1060,31 +967,16 @@ class SegmentProcessor:
 
     @property
     def _staged_rows_impl(self) -> str:
-        """Who runs the staged plan's batched leg FFTs.  Default XLA;
-        SRTB_STAGED_ROWS_IMPL=pallas moves the legs to the VMEM row-FFT
-        kernel — both a perf experiment and a workaround candidate for
-        the XLA TPU compiler SIGSEGV on the 2^30 blocked stage_a shape
-        (the crash is in XLA's handling of that batched FFT; Pallas legs
-        never hand XLA an FFT op at all)."""
-        return self._resolve_rows_impl(
-            os.environ.get("SRTB_STAGED_ROWS_IMPL", "xla"))
-
-    def _staged_impl(self) -> str:
-        """The staged plan's leg implementation after the pallas2 window
-        check: the fused two-pass form only covers leg lengths in
-        [2^24, 2^29], so tiny forced-staged test configs downgrade to
-        the pallas-legs four-step (same numeric contract)."""
-        impl = self._staged_rows_impl
-        if impl in ("pallas2", "pallas2_interpret"):
-            from srtb_tpu.ops import pallas_fft2 as pf2
-            count = (8 // self.cfg.baseband_input_bits
-                     if self._staged_blocked else 2)
-            if not pf2.supported(self.n // count):
-                # loud if an explicit SRTB_PALLAS2_N1 pin caused this
-                pf2.require_pin_fit(self.n // count)
-                return ("pallas_interpret" if impl.endswith("interpret")
-                        else "pallas")
-        return impl
+        """Who runs the whole-plane staged stages' batched leg FFTs.
+        Default XLA; SRTB_STAGED_ROWS_IMPL=pallas moves the legs to the
+        VMEM row-FFT kernel (ops/pallas_fft; in interpret mode off the
+        chip).  Any other value is an error that names the two."""
+        impl = os.environ.get("SRTB_STAGED_ROWS_IMPL", "xla")
+        if impl not in ("xla", "pallas"):
+            raise ValueError(
+                f"SRTB_STAGED_ROWS_IMPL={impl!r}: the staged plan's legs "
+                f"are 'xla' or 'pallas'")
+        return self._resolve_rows_impl(impl)
 
     @S.scoped(S.FFT_R2C)
     def _staged_pack(self, raw: jnp.ndarray) -> jnp.ndarray:
@@ -1120,15 +1012,11 @@ class SegmentProcessor:
         return x.reshape(2, -1, self.n_spectrum)
 
     def _stage_a(self, raw: jnp.ndarray):
-        if self.front_fuse:
-            return self._stage_a_front(raw)
         if self.staged_rows:
             return self._stage_a_rows((raw.reshape(self.watfft_len, -1),))
         return self._boundary_canon(self._stage_a_nat(raw))
 
-    def _stage_b(self, a_ri, aux=None):
-        if self.front_fuse:
-            return self._stage_b_front(a_ri, aux)
+    def _stage_b(self, a_ri):
         if self.staged_rows:
             return self._stage_b_rows(a_ri)
         return self._boundary_canon(
@@ -1155,120 +1043,20 @@ class SegmentProcessor:
         return spans
 
     def _run_stage_b(self, a):
-        """Dispatch the stage-(a) boundary into the jitted stage (b).
-        The front-fused boundary is (canonical, accumulators) passed
-        as TWO program arguments so only the canonical leaf is donated
-        — donating the [S, 3, 128] aux (which has no output aval to
-        alias) would be a dropped-donation warning on every compile."""
-        if self.front_fuse:
-            return self._enqueue_stage("b", self._jit_stage_b, *a)
+        """Dispatch the stage-(a) boundary into the jitted stage (b)."""
         return self._enqueue_stage("b", self._jit_stage_b, a)
 
     def _stage_c(self, spec_ri: jnp.ndarray):
         if self.staged_rows:
             return self._stage_c_rows(spec_ri)
-        x = spec_ri.reshape(2, spec_ri.shape[1], -1)
-        if self.front_fuse:
-            # the front-fused stage (b) emits the dedispersed spectrum
-            # in pass-2's k1-major blocked order; unblock here so the
-            # XLA transpose fuses into this program's first read (the
-            # waterfall row view / complex assembly)
-            n1, n2 = self._ffuse_fac
-            x = jnp.swapaxes(x.reshape(2, x.shape[1], n1, n2),
-                             -1, -2).reshape(2, x.shape[1], -1)
-        return self._stage_c_nat(x)
-
-    # ---- front-fused staged stages (the staged_ffuse plan family) ----
-
-    def _init_front_fuse(self) -> None:
-        """Precompute the front-fuse plan constants: the factorization,
-        the even/odd-split blocked window view, the blocked RFI keep
-        mask, and the chirp parameters of pass 2's epilogue."""
-        from srtb_tpu.ops import pallas_fft2 as pf2
-        self._ffuse_fac = pf2.ffuse_factor(self.n_spectrum)
-        n1, n2 = self._ffuse_fac
-        self._ffuse_window = None
-        if self.window is not None:
-            w = np.asarray(self.window)
-            self._ffuse_window = (
-                jnp.asarray(np.ascontiguousarray(
-                    w[0::2].reshape(n1, n2))),
-                jnp.asarray(np.ascontiguousarray(
-                    w[1::2].reshape(n1, n2))))
-        self._ffuse_mask = None
-        if self.rfi_mask is not None:
-            # natural [m] zap mask -> blocked [n1, n2] KEEP multiplier
-            # (bin k = k2*n1 + k1 lives at [k1, k2])
-            keep = 1.0 - np.asarray(self.rfi_mask, np.float32)
-            self._ffuse_mask = jnp.asarray(np.ascontiguousarray(
-                keep.reshape(n2, n1).T))
-        self._ffuse_chirp = dict(
-            f_min=float(self.f_min), df=float(self.df),
-            f_c=float(self.f_c), dm=float(self.cfg.dm))
-
-    @S.scoped(S.FFT_R2C)
-    def _stage_a_front(self, raw: jnp.ndarray):
-        """Front-fused stage (a): the raw uint8 segment goes straight
-        into the pass-1 megakernel (in-kernel unpack + window +
-        even/odd pack + column FFT + four-step twiddle) — HBM pass 1
-        is one raw-byte read + one blocked-intermediate write.  The
-        boundary is (canonical intermediate, [S, 3, 128] RFI-s1
-        mean-power accumulators)."""
-        from srtb_tpu.ops import pallas_fft2 as pf2
-        br, bi, aux = pf2.pass1_front(
-            raw, m=self.n_spectrum, streams=self.fmt.data_stream_count,
-            variant=self.fmt.unpack_variant,
-            nbits=int(self.cfg.baseband_input_bits),
-            window_eo=self._ffuse_window,
-            interpret=self._pallas_interpret)
-        return self._boundary_canon(jnp.stack([br, bi])), aux
-
-    @S.scoped(S.FFT_R2C)
-    def _stage_b_front(self, a_ri, aux):
-        """Front-fused stage (b): pass 2 emits the dedispersed
-        spectrum directly — row FFT + in-kernel Hermitian post +
-        RFI-s1 zap/normalize/mask (threshold from the pass-1
-        accumulators, no spectrum-sized re-read) + the in-register
-        df64 chirp, all in pass 2's epilogue.  The chirp is always the
-        bankless spelling here because staged plans never materialize
-        a chirp bank (see __init__: at 2^30 it would hold 4 GB of HBM
-        for the segment's lifetime) and front fusion requires the
-        staged plan — pass2_spectrum's premul operands exist for the
-        kernel's own generality (tests, future non-staged callers).
-        Output is the canonical boundary holding the blocked spectrum
-        (stage (c) unblocks with a fused metadata transpose)."""
-        from srtb_tpu.ops import pallas_fft2 as pf2
-        n1, n2 = self._ffuse_fac
-        b = a_ri.reshape(2, -1, n1, n2)
-        thr = jnp.float32(
-            self.cfg.mitigate_rfi_average_method_threshold) \
-            * pf2.front_mean_power(aux, n2, self.n_spectrum)
-        outs = []
-        for s in range(b.shape[1]):
-            sr, si = pf2.pass2_spectrum(
-                b[0, s], b[1, s], thr=thr[s], norm=self.norm_coeff,
-                mask_blocked=self._ffuse_mask,
-                chirp=self._ffuse_chirp,
-                interpret=self._pallas_interpret)
-            outs.append((sr, si))
-        spec_ri = jnp.stack([
-            jnp.stack([o[0] for o in outs]),
-            jnp.stack([o[1] for o in outs])])  # [2, S, n1, n2] blocked
-        return self._boundary_canon(spec_ri)
+        return self._stage_c_nat(
+            spec_ri.reshape(2, spec_ri.shape[1], -1))
 
     @S.scoped(S.FFT_R2C)
     def _stage_a_nat(self, raw: jnp.ndarray):
         """unpack + even/odd pack + segment-FFT first half."""
-        impl = self._staged_impl()
-        z = self._staged_pack(raw)
-        if impl in ("pallas2", "pallas2_interpret"):
-            # fused pass 1: transpose + leg FFT + four-step twiddle in
-            # ONE kernel; boundary is the [.., n1, n2] intermediate
-            from srtb_tpu.ops import pallas_fft2 as pf2
-            br, bi = pf2.pass1_ri(jnp.real(z), jnp.imag(z),
-                                  interpret=impl.endswith("interpret"))
-            return jnp.stack([br, bi])
-        a = F.four_step_stage1(z, rows_impl=impl,
+        a = F.four_step_stage1(self._staged_pack(raw),
+                               rows_impl=self._staged_rows_impl,
                                len_cap=self._len_cap)  # [..., n2, n1]
         return jnp.stack([jnp.real(a), jnp.imag(a)])
 
@@ -1278,16 +1066,9 @@ class SegmentProcessor:
         With the fused tail the RFI-s1 + df64-chirp epilogue folds into
         the Hermitian post's single write here, so stage (c) starts from
         an already-dedispersed spectrum."""
-        impl = self._staged_impl()
-        if impl in ("pallas2", "pallas2_interpret"):
-            from srtb_tpu.ops import pallas_fft2 as pf2
-            yr, yi = pf2.pass2_ri(a_ri[0], a_ri[1],
-                                  interpret=impl.endswith("interpret"))
-            zf = jax.lax.complex(yr, yi)
-        else:
-            zf = F.four_step_stage2(jax.lax.complex(a_ri[0], a_ri[1]),
-                                    rows_impl=impl,
-                                    len_cap=self._len_cap)
+        zf = F.four_step_stage2(jax.lax.complex(a_ri[0], a_ri[1]),
+                                rows_impl=self._staged_rows_impl,
+                                len_cap=self._len_cap)
         epilogue = self._tail_epilogue(None) if self.fused_tail else None
         if self._staged_blocked:
             spec = F.finish_rfft_subbyte(zf[0], epilogue=epilogue)[None, :]
@@ -1698,7 +1479,7 @@ class SegmentProcessor:
         "mitigate_rfi_spectral_kurtosis_threshold",
         "mitigate_rfi_freq_list", "baseband_reserve_sample",
         "fft_strategy", "fft_len_cap", "use_pallas", "use_pallas_sk",
-        "use_emulated_fp64", "fused_tail", "front_fuse", "chirp_exact",
+        "use_emulated_fp64", "fused_tail", "chirp_exact",
         # overlap-engine trace shapers: micro_batch_segments changes the
         # traced program (vmapped batch plan) outright;
         # inflight_segments shapes the runtime's donation/aliasing
@@ -1799,11 +1580,6 @@ class SegmentProcessor:
              # other programs and another bank than the fused tail's
              # XLA spelling; a plan without it keeps its signature
              **({"r2c": "own-v1"} if self.own_tail else {}),
-             # resolved front fusion: the staged_ffuse programs have
-             # different boundary pytrees (canonical + accumulators)
-             # and a blocked stage-(b) spectrum — an AOT cache written
-             # by either spelling must miss cleanly for the other
-             "front_fuse": self.front_fuse,
              "skzap": self._skzap,
              # resolved ingest plan: the ring's two-input assemble
              # programs (and their carry avals) exist only when it is
@@ -1842,11 +1618,7 @@ class SegmentProcessor:
         # wrapper would defeat the AOT independence above.
         if self.staged:
             a_out = jax.eval_shape(self._stage_a, raw_s)
-            # the front-fused stage-(a) boundary is (canonical, aux)
-            # passed as two program args so only the canonical leaf is
-            # donated (see _run_stage_b)
-            b_args = tuple(a_out) if self.front_fuse else (a_out,)
-            b_out = jax.eval_shape(self._stage_b, *b_args)
+            b_out = jax.eval_shape(self._stage_b, a_out)
             progs = [
                 ("stage_a",
                  # srtb-lint: disable=recompile-hazard
@@ -1854,7 +1626,7 @@ class SegmentProcessor:
                  (raw_s,), in_donate),
                 # srtb-lint: disable=recompile-hazard
                 ("stage_b", jax.jit(self._stage_b, donate_argnums=(0,)),
-                 b_args, (0,)),
+                 (a_out,), (0,)),
                 # srtb-lint: disable=recompile-hazard
                 ("stage_c", jax.jit(self._stage_c, donate_argnums=(0,)),
                  (b_out,), (0,)),
@@ -1952,12 +1724,11 @@ class SegmentProcessor:
             # chain the boundary avals by abstract evaluation (free:
             # trace only, no compile)
             a_out = jax.eval_shape(self._stage_a, raw_s)
-            b_args = tuple(a_out) if self.front_fuse else (a_out,)
-            b_out = jax.eval_shape(self._stage_b, *b_args)
+            b_out = jax.eval_shape(self._stage_b, a_out)
             self._jit_stage_a = cache.get_or_compile(
                 "stage_a", sig, self._jit_stage_a, raw_s)
             self._jit_stage_b = cache.get_or_compile(
-                "stage_b", sig, self._jit_stage_b, *b_args)
+                "stage_b", sig, self._jit_stage_b, a_out)
             self._jit_stage_c = cache.get_or_compile(
                 "stage_c", sig, self._jit_stage_c, b_out)
             if self.ring:
@@ -2221,11 +1992,7 @@ class SegmentProcessor:
         donated regardless of the raw-input policy, so its expiry must
         not be gated on ``self._donate_input``."""
         from srtb_tpu.analysis import sanitizer as S
-        # the front-fused boundary is (canonical, accumulators); the
-        # contract applies to the canonical leaf, the NaN tripwire to
-        # the whole pytree
-        canon = a[0] if isinstance(a, tuple) else a
-        S.check_contract("stage_a boundary", canon, lead=2,
+        S.check_contract("stage_a boundary", a, lead=2,
                          dtype=jnp.float32)
         S.check_finite("stage_a boundary", a)
         if self._donate_input if donated is None else donated:

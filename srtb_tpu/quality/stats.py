@@ -108,7 +108,7 @@ def quality_stats_device(spec, wf, coarse_bins: int,
 
     Plain jnp on purpose: the inputs are already HBM-resident and tiny
     next to the segment FFT traffic, and a jnp epilogue rides inside
-    every plan family (monolithic / fused / staged / ffuse / skzap)
+    every plan family (monolithic / fused / staged / skzap)
     without new kernels.
     """
     import jax.numpy as jnp

@@ -25,8 +25,7 @@ same property, in six composable pieces:
   baseband dumps, then whole segments (the existing
   ``DropOldestSegmentBuffer``), every step counted;
 - :mod:`demote` — self-healing compute: the plan-demotion ladder
-  (micro_batch -> front_fuse -> ring -> skzap -> fused_tail -> staged
-  -> monolithic)
+  (micro_batch -> ring -> skzap -> fused_tail -> staged -> monolithic)
   that survives device OOM and compile faults on a cheaper plan, and
   bounded device-reinit recovery for halt faults — the compute-side
   twin of the supervisor;

@@ -14,8 +14,7 @@ list of progressively cheaper execution plans derived from the active
 config by switching off features in a fixed order (owned by the plan
 registry, ``pipeline/registry.py``)::
 
-    search_mode -> micro_batch -> front_fuse -> ring -> skzap ->
-    fused_tail
+    search_mode -> micro_batch -> ring -> skzap -> fused_tail
                 -> staged -> monolithic
 
 Each rung is CUMULATIVE (rung k applies every earlier step too) and

@@ -81,9 +81,8 @@ def main(argv=None) -> int:
             print(f"plan-audit selftest: {f}", file=sys.stderr)
         print("plan-audit selftest: "
               + ("FAILED" if failures else
-                 "OK — dropped donation, injected extra spectrum "
-                 "pass, and un-fused ffuse unpack all move the "
-                 "audited cards"))
+                 "OK — dropped donation and injected extra spectrum "
+                 "pass both move the audited cards"))
         return 1 if failures else 0
 
     try:

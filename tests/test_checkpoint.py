@@ -70,10 +70,14 @@ class _Bytes:
 
 
 def test_checkpoint_offset_is_the_segments_own_with_the_reader_ahead(
-        tmp_path):
+        tmp_path, monkeypatch):
     """The offset recorded after segment k is where the source stood
     after segment k, not where the reader, one segment on, stands when
     the sinks return: a resume re-reads nothing and skips nothing."""
+    from srtb_tpu.pipeline import runtime
+
+    # ahead by construction, not by this machine's load
+    monkeypatch.setattr(runtime, "_PULL_AHEAD_SHARE", 0.0)
     cfg = _cfg(tmp_path)
     n = cfg.baseband_input_count
     rng = np.random.default_rng(1)
@@ -87,7 +91,7 @@ def test_checkpoint_offset_is_the_segments_own_with_the_reader_ahead(
     pipe1.checkpoint.update = lambda done, offset: (
         seen.append((done, offset)), update(done, offset))
     assert pipe1.run(max_segments=7).segments == 7
-    assert "reader" in source.threads[3:]      # it did run ahead
+    assert source.threads[3:] == ["reader"] * 4     # it did run ahead
     assert seen == [(k + 1, (k + 1) * n) for k in range(7)]
     ck = StreamCheckpoint(cfg.checkpoint_path)
     assert (ck.segments_done, ck.file_offset_bytes) == (7, 7 * n)

@@ -4,6 +4,8 @@ expression handling; example config userspace/srtb_config_1644-4559.cfg)."""
 import os
 import tempfile
 
+import pytest
+
 from srtb_tpu.config import Config
 from srtb_tpu.utils.expression import parse_expression, parse_number
 
@@ -82,3 +84,43 @@ def test_reference_config_key_parity():
     ours = {f.name for f in fields(Config)}
     missing = reference_keys - ours
     assert not missing, f"reference options without parity: {missing}"
+
+
+@pytest.mark.parametrize("route", ["file", "command_line"])
+@pytest.mark.parametrize("key", ["front_fuse", "warp_drive"])
+def test_an_option_that_does_not_exist_warns_and_sets_nothing(
+        tmp_path, key, route):
+    """A cfg line or a command-line option the program does not have
+    (a misspelling; `front_fuse`, retired in PR 50 with the kernels it
+    chose) is the unknown-option warning, with the file and line where
+    there is one, and the rest of the options are read."""
+    import io
+
+    from srtb_tpu.utils.logging import log
+
+    stream, log.stream = log.stream, io.StringIO()
+    try:
+        if route == "file":
+            path = tmp_path / "retired.cfg"
+            path.write_text(f"dm = 12.5\n{key} = on\nlog_level = 3\n")
+            cfg = Config()
+            cfg.load_file(str(path))
+            want = f"{path}:2: unknown option {key!r}"
+        else:
+            cfg = Config.from_args([
+                "--dm=12.5", f"--{key}=on", "--log_level=3",
+                f"--config_file_name={tmp_path / 'none.cfg'}"])
+            want = f"unknown command-line option --{key}"
+        said = log.stream.getvalue()
+    finally:
+        log.stream = stream
+    assert want in said and said.count("unknown") == 1
+    assert cfg.dm == 12.5 and cfg.log_level == 3
+    assert not hasattr(cfg, key)
+
+
+def test_front_fuse_is_no_field_of_config():
+    """`Config(front_fuse=...)` is the `TypeError` of a field that does
+    not exist: the option went with the kernels no chip compiled."""
+    with pytest.raises(TypeError, match="front_fuse"):
+        Config(front_fuse="on")

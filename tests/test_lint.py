@@ -653,3 +653,41 @@ def test_repo_baseline_entries_have_notes():
     missing = [k for k, e in data["entries"].items()
                if not e.get("note")]
     assert not missing, missing
+
+
+def test_no_module_parks_a_kernel_behind_a_constant_false():
+    """The rule of PR 50 as a test: a kernel or plan that no supported
+    chip compiles is deleted with everything that selects it, not kept
+    behind a module-level ``*_MOSAIC_OK = False`` for interpret-mode
+    tests to keep alive (three such gates stood from PR 24 to PR 49:
+    the sub-byte unpack kernels and the front-fused staged family).  A
+    gate that is ``True`` is dead weight too, but harmless."""
+    import ast
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "srtb_tpu")
+    parked, seen = [], 0
+    for folder, _dirs, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            with open(path) as f:
+                tree = ast.parse(f.read(), path)
+            seen += 1
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Assign):
+                    targets, value = node.targets, node.value
+                elif isinstance(node, ast.AnnAssign) and node.value:
+                    targets, value = [node.target], node.value
+                else:
+                    continue
+                for t in targets:
+                    if isinstance(t, ast.Name) \
+                            and t.id.endswith("_MOSAIC_OK") \
+                            and isinstance(value, ast.Constant) \
+                            and value.value is False:
+                        parked.append(f"{os.path.relpath(path, root)}:"
+                                      f"{node.lineno} {t.id}")
+    assert seen > 100       # the walk saw the package
+    assert parked == []

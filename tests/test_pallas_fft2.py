@@ -1,10 +1,9 @@
-"""Fused two-pass Pallas four-step C2C (ops/pallas_fft2) vs numpy.
+"""The repo's own R2C (ops/pallas_fft2: the column-native passes and the
+post pass) vs numpy, and the rules that choose it.
 
-CPU CI runs interpret mode at the smallest supported size (m = 2^24 —
-the module deliberately only covers the segment sizes where monolithic
-XLA falters); on a real TPU the same cases lower through Mosaic.
-The tolerance is looser than the single-level row kernel's: the value
-passes through four bf16x3 DFT-matmul levels plus two twiddle stages.
+CPU CI runs the kernels in interpret mode, the production legs (4096,
+8192) only where a test asks for 2^24 points by name; everything else
+patches the leg table down (``small_legs``) or hands the legs in.
 """
 
 import numpy as np
@@ -19,55 +18,13 @@ from srtb_tpu.ops import pallas_fft2 as PF2
 ON_TPU = jax.default_backend() == "tpu"
 INTERPRET = not ON_TPU
 
-M = 1 << 24  # smallest pallas2 size (n1=4096, n2=4096)
+M = 1 << 24  # smallest pallas2 size (legs 4096 x 4096)
 
 
 def _rand_c64(shape, seed):
     rng = np.random.default_rng(seed)
     return (rng.standard_normal(shape)
             + 1j * rng.standard_normal(shape)).astype(np.complex64)
-
-
-def test_factorization_window():
-    assert PF2._factor(M) == (4096, 4096)
-    assert PF2._factor(1 << 26) == (4096, 1 << 14)
-    assert PF2._factor(1 << 29) == (8192, 1 << 16)
-    assert not PF2.supported(1 << 23)   # below the window
-    assert not PF2.supported(1 << 30)   # above the window
-    assert not PF2.supported(3 * (1 << 22))  # not a power of two
-
-
-@pytest.mark.parametrize("inverse", [False, True])
-def test_fft2_matches_numpy(inverse):
-    x = _rand_c64(M, 7 + inverse)
-    want = (np.fft.ifft(x.astype(np.complex128), norm="forward") if inverse
-            else np.fft.fft(x.astype(np.complex128)))
-    got = np.asarray(PF2.fft2_c2c(jnp.asarray(x), inverse=inverse,
-                                  interpret=INTERPRET))
-    scale = np.abs(want).max()
-    assert np.abs(got - want).max() / scale < 2e-5
-
-
-# (the pass-1 row spelling and the rows-helper A/B knobs were retired in
-# round 5: real Mosaic rejects their in-kernel minor-lb reshapes, so the
-# column-native pass 1 + the single vmem_fft_rows spelling are the one
-# lowering — covered by every other oracle test in this file)
-
-
-def test_fft2_blocked_output_unblocks():
-    x = _rand_c64(M, 3)
-    want = np.fft.fft(x.astype(np.complex128))
-    raw = PF2.fft2_c2c(jnp.asarray(x), natural=False, interpret=INTERPRET)
-    got = np.asarray(PF2.unblock(raw, M))
-    assert np.abs(got - want).max() / np.abs(want).max() < 2e-5
-
-
-def test_fft2_leading_dims():
-    x = _rand_c64((2, M), 5)
-    want = np.fft.fft(x.astype(np.complex128))
-    got = np.asarray(PF2.fft2_c2c(jnp.asarray(x), interpret=INTERPRET))
-    assert got.shape == x.shape
-    assert np.abs(got - want).max() / np.abs(want).max() < 2e-5
 
 
 def test_segment_rfft_pallas2_strategy():
@@ -111,64 +68,24 @@ def test_segment_rfft_pallas2_small_falls_back():
     assert np.abs(got - want).max() / np.abs(want).max() < 5e-6
 
 
-def test_fourstep_twiddle_precision_at_window_edge():
-    """The in-kernel hi/lo phase split must stay accurate at the top of
-    the window (m = 2^29, residues up to 2^29 — far beyond f32's 24-bit
-    mantissa), where an end-to-end CPU-interpret test is impractical.
-    Checked against float64 on the worst blocks: the highest j2 rows
-    (largest residues) and a mid-spectrum block."""
-    m = 1 << 29
-    n1, n2 = PF2._factor(m)
-    for j2_0 in (n2 - 8, n2 // 2):
-        wr, wi = jax.jit(
-            lambda j0: PF2._fourstep_twiddle_t(n1, 8, m, -1.0, j0),
-            static_argnums=0)(j2_0)
-        k1 = np.arange(n1)[:, None]
-        d = np.arange(8)[None, :] + j2_0
-        want = np.exp(-2j * np.pi * (d * k1).astype(np.float64) / m)
-        err = np.abs((np.asarray(wr) + 1j * np.asarray(wi)) - want).max()
-        assert err < 2e-6, (j2_0, err)
-
-
-def test_fft2_asymmetric_factorization():
-    """m = 2^25 factors 4096 x 8192 (n2 != n1, lb2=64) — the asymmetric
-    shape every production size [2^25, 2^29] uses; the symmetric
-    m = 2^24 tests alone would never exercise distinct leg lengths or
-    the rectangular four-step twiddle."""
-    m = 1 << 25
-    assert PF2._factor(m) == (4096, 8192)
-    x = _rand_c64(m, 41)
-    want = np.fft.fft(x.astype(np.complex128))
-    got = np.asarray(PF2.fft2_c2c(jnp.asarray(x), interpret=INTERPRET))
-    assert np.abs(got - want).max() / np.abs(want).max() < 2e-5
-
-
-def test_block_sizing_budgets_padded_footprint(monkeypatch):
-    """Round-3 advisor catch: blocks must be sized from the PADDED VMEM
-    footprint (bb < 128 lane-pads to 128 across 2x-pipelined in/out
-    refs), not logical f32 words.  Pins: lane-dense pass-1 blocks at
-    every supported factorization, the modeled footprint staying inside
-    the budget, and the absolute env overrides surviving."""
-    monkeypatch.delenv("SRTB_PALLAS2_BB", raising=False)
-    monkeypatch.delenv("SRTB_PALLAS2_RB", raising=False)
-    monkeypatch.delenv("SRTB_PALLAS2_VMEM_MB", raising=False)
-    budget = PF2._vmem_budget()
-    for log2m in range(24, 30):
-        n1, n2 = PF2._factor(1 << log2m)
-        bb = PF2._block_cols(n1, n2)
-        rb = PF2._block_rows(n2, n1)
-        assert bb >= 128 and n2 % bb == 0, (log2m, bb)
-        assert rb >= 8 and n1 % rb == 0, (log2m, rb)
-        assert PF2._pass1_bytes(n1, bb) <= budget, log2m
-        assert PF2._pass2_bytes(n2, rb) <= budget, log2m
-    # refs alone at the padded minimum exceed a 16 MiB-era budget: the
-    # floor is returned (a vmem_limit question, not a sizing one)
-    monkeypatch.setenv("SRTB_PALLAS2_VMEM_MB", "14")
-    assert PF2._block_cols(8192, 1 << 16) == 128
-    monkeypatch.setenv("SRTB_PALLAS2_BB", "64")
-    monkeypatch.setenv("SRTB_PALLAS2_RB", "16")
-    assert PF2._block_cols(4096, 4096) == 64
-    assert PF2._block_rows(4096, 4096) == 16
+@pytest.mark.parametrize("m,top", [
+    (1 << 26, (1 << 26) - 1),       # pass 1: kc * (R * j2_0) and kr * j2_0
+    (1 << 14, (1 << 14) - 1),       # the post pass's row factor, p * n2
+    (1 << 29, (1 << 29) - 1),       # the split's own bound
+])
+def test_phase_split_is_float64_at_the_largest_residues(m, top):
+    """The in-kernel hi/lo phase split (`_phase_cos_sin`: pass 1's two
+    twiddle factors, the post pass's row factor) at residues far beyond
+    float32's 24-bit mantissa, against float64: the top of the range,
+    the middle, and both sides of a multiple of the split's 2^15."""
+    r = np.array([top, top - 1, m // 2 + 1, (1 << 15) - 1, 1 << 15,
+                  min(top, (1 << 15) + 1), 1, 0], np.int64) % m
+    for sign in (-1.0, 1.0):
+        c, s_ = jax.jit(lambda q: PF2._phase_cos_sin(q, m, sign))(
+            jnp.asarray(r, jnp.int32))
+        want = np.exp(sign * 2j * np.pi * r.astype(np.float64) / m)
+        err = np.abs((np.asarray(c) + 1j * np.asarray(s_)) - want).max()
+        assert err < 2e-6, (m, sign, err)
 
 
 # ------------------------------------------------------------------
@@ -200,7 +117,9 @@ def test_cols_production_shapes():
 
 
 @pytest.mark.parametrize("n1,n2,batch,inverse", [
-    (256, 128, 2, False), (128, 256, 1, True), (1024, 512, 1, False)])
+    (256, 128, 2, False), (128, 256, 1, True), (1024, 512, 1, False),
+    # the other direction of each asymmetric pair of legs
+    (256, 128, 1, True), (128, 256, 2, False)])
 def test_cols_transform_matches_numpy(n1, n2, batch, inverse):
     m = n1 * n2
     x = _rand_c64((batch, m), 11)
@@ -208,6 +127,27 @@ def test_cols_transform_matches_numpy(n1, n2, batch, inverse):
             else np.fft.fft(x.astype(np.complex128)))
     got = np.asarray(PF2.fft2_cols(jnp.asarray(x), inverse=inverse,
                                    interpret=True, factor=(n1, n2)))
+    assert np.abs(got - want).max() / np.abs(want).max() < 2e-6
+
+
+@pytest.mark.parametrize("log2m,lead,inverse", [
+    (14, (), False),            # legs 128 x 128, as 2^24 is 4096 x 4096
+    (14, (), True),
+    (15, (2,), False),          # 128 x 256, as 2^25 is 4096 x 8192
+    (15, (), True),
+    (16, (2, 2), False),        # 256 x 256; two leading dimensions
+])
+def test_cols_transform_finds_its_legs(small_legs, log2m, lead, inverse):
+    """`fft2_cols` with no legs handed in (as `_pallas2_or_fallback`
+    calls it): the legs of `cols_factor`, any leading dimensions kept,
+    both directions unnormalized."""
+    m = 1 << log2m
+    x = _rand_c64((*lead, m), 19 + log2m)
+    want = (np.fft.ifft(x.astype(np.complex128), norm="forward") if inverse
+            else np.fft.fft(x.astype(np.complex128)))
+    got = np.asarray(PF2.fft2_cols(jnp.asarray(x), inverse=inverse,
+                                   interpret=True))
+    assert got.shape == x.shape
     assert np.abs(got - want).max() / np.abs(want).max() < 2e-6
 
 
@@ -362,14 +302,16 @@ def test_segment_strategy_decides_once_on_a_tpu(monkeypatch, kw, staged,
     (27, True, "pallas2", True),        # 1 GSa/s by name, on a chip
     (29, True, "pallas2", True),
     (26, True, "pallas2", False),       # the column-native passes
-    (27, False, "pallas2", False),      # lowered off the chip: as it was
-    (27, True, "pallas2_interpret", False),
+    (27, False, "pallas2", True),       # off the chip: the same error
+    (27, True, "pallas2_interpret", True),
 ])
-def test_first_spelling_is_an_error_on_a_chip(monkeypatch, log2m, on_tpu,
-                                              strategy, raises):
-    """`fft_strategy pallas2` at the lengths only the first spelling of
-    the passes factors (2^27 to 2^29 points): Mosaic refuses it for a
-    v5e, so on a chip the dispatch says so and routes nowhere."""
+def test_no_transform_above_the_column_native_lengths(monkeypatch, log2m,
+                                                      on_tpu, strategy,
+                                                      raises):
+    """`fft_strategy pallas2` above 2^26 points: the one spelling of the
+    passes that factored 2^27 to 2^29 went in PR 50 (Mosaic refused it
+    for a v5e), so the dispatch says so on every backend, interpret
+    mode included, and routes nowhere."""
     from srtb_tpu.utils import platform
     monkeypatch.setattr(platform, "on_accelerator", lambda: on_tpu)
     z = jax.ShapeDtypeStruct((1 << log2m,), jnp.complex64)
@@ -378,8 +320,7 @@ def test_first_spelling_is_an_error_on_a_chip(monkeypatch, log2m, on_tpu,
         return jax.eval_shape(
             lambda a: F._pallas2_or_fallback(a, strategy), z)
     if raises:
-        with pytest.raises(ValueError, match="no transform of .* points "
-                                             "on a chip"):
+        with pytest.raises(ValueError, match="no transform of .* points"):
             trace()
     else:
         assert trace().shape == z.shape

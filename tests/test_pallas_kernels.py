@@ -15,7 +15,6 @@ import pytest
 
 from srtb_tpu.ops import dedisperse as dd
 from srtb_tpu.ops import pallas_kernels as pk
-from srtb_tpu.ops import unpack as U
 
 
 @pytest.fixture(params=["interpret", "mosaic"])
@@ -63,31 +62,6 @@ def test_dedisperse_df64_kernel_high_dm(interpret):
     phase_err = np.abs(np.angle(got * np.conj(expected)))
     assert np.percentile(phase_err, 99) < 2e-2
     del spec
-
-
-def _xfail_unpack_mosaic(interpret):
-    if not interpret and not pk.UNPACK_MOSAIC_OK:
-        pytest.xfail("sub-byte lane interleave not lowerable by Mosaic "
-                     "(infer-vector-layout: unsupported shape cast); "
-                     "real-TPU segments use the XLA unpack instead")
-
-
-@pytest.mark.parametrize("with_window", [False, True])
-def test_unpack_2bit_kernel(with_window, interpret):
-    _xfail_unpack_mosaic(interpret)
-    rng = np.random.default_rng(1)
-    m = 1 << 12
-    data = rng.integers(0, 256, size=m, dtype=np.uint8)
-    window = (rng.random(4 * m).astype(np.float32) + 0.5
-              if with_window else None)
-    got = np.asarray(pk.unpack_2bit_window(
-        jnp.asarray(data),
-        None if window is None else jnp.asarray(window),
-        interpret=interpret))
-    expected = U.unpack_oracle(data, 2)
-    if window is not None:
-        expected = expected * window
-    np.testing.assert_allclose(got, expected, rtol=1e-6)
 
 
 def test_sk_zap_timeseries_matches_jnp(interpret):
@@ -156,21 +130,6 @@ def test_sk_zap_timeseries_matches_jnp(interpret):
                           np.asarray(ref_det.signal_counts))
 
 
-@pytest.mark.parametrize("nbits", [1, 2, 4])
-def test_unpack_subbyte_kernel_all_widths(nbits, interpret):
-    _xfail_unpack_mosaic(interpret)
-    m = 1 << 10
-    rng = np.random.default_rng(nbits)
-    raw = rng.integers(0, 256, size=m, dtype=np.uint8)
-    n_out = (8 // nbits) * m
-    win = np.hamming(n_out).astype(np.float32)
-    got = np.asarray(pk.unpack_subbyte_window(
-        jnp.asarray(raw), nbits, jnp.asarray(win), interpret=interpret))
-    expected = np.asarray(U.unpack(jnp.asarray(raw), nbits,
-                                   jnp.asarray(win)))
-    np.testing.assert_allclose(got, expected, rtol=1e-6, atol=1e-6)
-
-
 def test_dedisperse_df64_kernel_high_channel_offset(interpret):
     """The in-kernel chirp must stay phase-accurate when the global
     channel index exceeds float32's exact-integer range (2^24)."""
@@ -232,67 +191,6 @@ def test_rfi_s1_dedisperse_fused_matches_jnp_sequence(interpret, with_mask):
     assert np.max(np.abs(got - want)) < 5e-3 * np.max(np.abs(want))
 
 
-@pytest.mark.parametrize("nbits", [1, 2, 4])
-def test_unpack_planes_kernel_matches_jnp(nbits):
-    """Blocked-plane Pallas unpack (the Mosaic-lowerable spelling) vs the
-    XLA unpack_subbyte_planes, with and without the blocked window."""
-    from srtb_tpu.ops import fft as F
-    from srtb_tpu.ops import unpack as U
-
-    rng = np.random.default_rng(3)
-    m = 1 << 11
-    data = jnp.asarray(rng.integers(0, 256, m, dtype=np.uint8))
-    want = np.asarray(U.unpack_subbyte_planes(data, nbits))
-    got = np.asarray(pk.unpack_subbyte_planes_window(data, nbits,
-                                                     interpret=True))
-    np.testing.assert_array_equal(got, want)
-    win = F.subbyte_window_planes(
-        (np.hanning((8 // nbits) * m) + 0.1).astype(np.float32), nbits)
-    got_w = np.asarray(pk.unpack_subbyte_planes_window(
-        data, nbits, jnp.asarray(win), interpret=True))
-    np.testing.assert_allclose(got_w, want * win, rtol=1e-6)
-
-
-def test_blocked_pipeline_uses_planes_unpack(monkeypatch):
-    """use_pallas on the blocked sub-byte path must route through the
-    fused planes-unpack kernel (interpret mode) and produce the same
-    waterfall as the XLA unpack."""
-    from srtb_tpu.config import Config
-    from srtb_tpu.pipeline.segment import SegmentProcessor, \
-        waterfall_to_numpy
-
-    cfg = Config(
-        baseband_input_count=1 << 14,
-        baseband_input_bits=2,
-        baseband_format_type="simple",
-        baseband_freq_low=1405.0,
-        baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6,
-        dm=30.0,
-        spectrum_channel_count=1 << 5,
-        mitigate_rfi_average_method_threshold=1e9,
-        mitigate_rfi_spectral_kurtosis_threshold=1e9,
-        baseband_reserve_sample=False,
-        fft_strategy="four_step",
-    )
-    rng = np.random.default_rng(4)
-    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
-    base = waterfall_to_numpy(SegmentProcessor(cfg).process(raw)[0])
-
-    called = []
-    orig = pk.unpack_subbyte_planes_window
-
-    def spy(*a, **kw):
-        called.append(True)
-        return orig(*a, **kw)
-
-    monkeypatch.setattr(pk, "unpack_subbyte_planes_window", spy)
-    wf = waterfall_to_numpy(
-        SegmentProcessor(cfg.replace(use_pallas=True)).process(raw)[0])
-    assert called, "planes unpack kernel was not used"
-    np.testing.assert_allclose(wf, base, rtol=2e-3, atol=1e-4)
-
-
 def test_pallas_chirp_exact_fallback_path(monkeypatch):
     """The exact per-element in-kernel chirp (the anchored rewrite's
     fallback, forced via SRTB_PALLAS_CHIRP_EXACT=1) must still match the
@@ -318,9 +216,40 @@ def test_pallas_chirp_exact_fallback_path(monkeypatch):
     assert err.max() < 5e-3 * np.abs(spec).max(), err.max()
 
 
-def test_planes_tiling_ok_gates_fallback():
-    assert pk.planes_tiling_ok(128 * 256)
-    assert not pk.planes_tiling_ok(64)        # not a multiple of 128
-    assert not pk.planes_tiling_ok(128 * 384)  # rows not divisible
-    # small segments: rows_total < _ROWS uses rows_total itself
-    assert pk.planes_tiling_ok(128 * 8)
+@pytest.mark.parametrize("nbits", [1, 2, 4])
+def test_use_pallas_on_subbyte_samples_unpacks_in_xla(nbits):
+    """`use_pallas` on the blocked sub-byte path: the kernels are the
+    chirp's and the waterfall's, the unpack is XLA's blocked planes
+    (`ops/unpack.unpack_subbyte_planes`; the Pallas unpack kernels went
+    in PR 50, no chip compiled them), and the windowed waterfall is the
+    plan's without the kernels."""
+    from srtb_tpu.config import Config
+    from srtb_tpu.pipeline.segment import SegmentProcessor, \
+        waterfall_to_numpy
+
+    cfg = Config(
+        baseband_input_count=1 << 14,
+        baseband_input_bits=nbits,
+        baseband_format_type="simple",
+        baseband_freq_low=1405.0,
+        baseband_bandwidth=64.0,
+        baseband_sample_rate=128e6,
+        dm=30.0,
+        spectrum_channel_count=1 << 5,
+        mitigate_rfi_average_method_threshold=1e9,
+        mitigate_rfi_spectral_kurtosis_threshold=1e9,
+        baseband_reserve_sample=False,
+        fft_strategy="four_step",
+    )
+    rng = np.random.default_rng(4 + nbits)
+    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
+    base = SegmentProcessor(cfg, window_name="hamming")
+    with_kernels = SegmentProcessor(cfg.replace(use_pallas=True),
+                                    window_name="hamming")
+    assert base._blocked_subbyte and with_kernels._blocked_subbyte
+    assert with_kernels.chirp is None and base.chirp is not None
+    assert not [name for name in dir(pk) if "unpack" in name]
+    want = waterfall_to_numpy(base.process(raw)[0])
+    got = waterfall_to_numpy(with_kernels.process(raw)[0])
+    np.testing.assert_allclose(got, want, rtol=2e-3,
+                               atol=2e-4 * np.abs(want).max())

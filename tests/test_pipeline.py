@@ -404,159 +404,29 @@ def test_staged_pallas_rows_impl_matches_default(monkeypatch):
     # a typo'd knob value must raise, not silently fall back to XLA
     monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "palas")
     import pytest
-    with pytest.raises(ValueError, match="rows impl"):
+    with pytest.raises(ValueError, match="'xla' or 'pallas'"):
         SegmentProcessor(cfg, staged=True).process(raw)
 
 
-def test_staged_pallas2_downgrades_below_window(monkeypatch):
-    """SRTB_STAGED_ROWS_IMPL=pallas2 at a leg length below the fused
-    two-pass window must downgrade to the pallas-legs four-step (and
-    stay numerically on-plan), not crash a tiny forced-staged config."""
-    import numpy as np
-
+@pytest.mark.parametrize("impl", ["pallas2", "mxu"])
+def test_staged_rows_impl_is_xla_or_pallas(monkeypatch, impl):
+    """`SRTB_STAGED_ROWS_IMPL` chooses between XLA's legs and the VMEM
+    row-FFT kernel's; `pallas2` (the first spelling of the two passes,
+    gone in PR 50: no v5e compiled it) and a name that used to run the
+    Pallas legs unsaid are refused when the plan is built, naming the
+    two that are left.  A plan that is not staged never reads it."""
     from srtb_tpu.config import Config
-    from srtb_tpu.pipeline.segment import SegmentProcessor, \
-        waterfall_to_numpy
+    from srtb_tpu.pipeline.segment import SegmentProcessor
 
-    cfg = Config(
-        baseband_input_count=1 << 14,
-        baseband_input_bits=2,
-        baseband_format_type="simple",
-        baseband_freq_low=1405.0,
-        baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6,
-        dm=30.0,
-        spectrum_channel_count=1 << 5,
-        mitigate_rfi_average_method_threshold=1e9,
-        mitigate_rfi_spectral_kurtosis_threshold=1e9,
-        baseband_reserve_sample=False,
-    )
-    rng = np.random.default_rng(21)
-    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
-    monkeypatch.delenv("SRTB_STAGED_ROWS_IMPL", raising=False)
-    base = waterfall_to_numpy(
-        SegmentProcessor(cfg, staged=True).process(raw)[0])
-    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "pallas2")
-    proc = SegmentProcessor(cfg, staged=True)
-    assert proc._staged_impl() == "pallas_interpret"
-    got = waterfall_to_numpy(proc.process(raw)[0])
-    np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-4)
-
-
-def test_staged_pallas2_blocked_production_shape(monkeypatch):
-    """The 2^30 production plan in miniature: blocked-plane sub-byte
-    unpack + fused two-pass Pallas FFT legs across the staged (a)/(b)
-    boundary, at the smallest in-window leg (n = 2^25, 4-bit, leg
-    M = 2^24).  No XLA FFT op exists in stages a/b — the SIGSEGV
-    workaround shape — and the waterfall must match the default staged
-    plan."""
-    import numpy as np
-
-    from srtb_tpu.config import Config
-    from srtb_tpu.pipeline.segment import SegmentProcessor, \
-        waterfall_to_numpy
-
-    cfg = Config(
-        baseband_input_count=1 << 25,
-        baseband_input_bits=4,
-        baseband_format_type="simple",
-        baseband_freq_low=1405.0,
-        baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6,
-        dm=30.0,
-        spectrum_channel_count=1 << 9,
-        mitigate_rfi_average_method_threshold=1e9,
-        mitigate_rfi_spectral_kurtosis_threshold=1e9,
-        baseband_reserve_sample=False,
-    )
-    rng = np.random.default_rng(23)
-    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
-    monkeypatch.setenv("SRTB_STAGED_BLOCKED", "1")
-    monkeypatch.delenv("SRTB_STAGED_ROWS_IMPL", raising=False)
-    base = waterfall_to_numpy(
-        SegmentProcessor(cfg, staged=True).process(raw)[0])
-    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "pallas2")
-    proc = SegmentProcessor(cfg, staged=True)
-    assert proc._staged_impl() == "pallas2_interpret"
-    got = waterfall_to_numpy(proc.process(raw)[0])
-    np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-4)
-
-
-def test_staged_pallas2_all_fusions_flagship(monkeypatch):
-    """The queue's n2_30_pallas2_full combination in miniature: classic
-    staged plan with fused two-pass legs PLUS the fused RFI/chirp front
-    half and the fused waterfall/SK-stats epilogue in stage (c).  Every
-    fusion on at once must stay on-plan against the plain staged run."""
-    import numpy as np
-
-    from srtb_tpu.config import Config
-    from srtb_tpu.pipeline.segment import SegmentProcessor, \
-        waterfall_to_numpy
-
-    cfg = Config(
-        baseband_input_count=1 << 25,
-        baseband_input_bits=4,
-        baseband_format_type="simple",
-        baseband_freq_low=1405.0,
-        baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6,
-        dm=30.0,
-        spectrum_channel_count=1 << 9,
-        mitigate_rfi_average_method_threshold=1e9,
-        mitigate_rfi_spectral_kurtosis_threshold=1e9,
-        baseband_reserve_sample=False,
-    )
-    rng = np.random.default_rng(29)
-    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
-    monkeypatch.delenv("SRTB_STAGED_ROWS_IMPL", raising=False)
-    monkeypatch.delenv("SRTB_STAGED_BLOCKED", raising=False)
-    base = waterfall_to_numpy(
-        SegmentProcessor(cfg, staged=True).process(raw)[0])
-    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "pallas2")
-    proc = SegmentProcessor(
-        cfg.replace(use_pallas=True, use_pallas_sk=True), staged=True)
-    assert proc._staged_impl() == "pallas2_interpret"
-    got = waterfall_to_numpy(proc.process(raw)[0])
-    np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-4)
-
-
-@pytest.mark.slow  # pallas2-interpret compile of the 2^26 leg: ~3-4 min
-def test_staged_pallas2_blocked_2bit_production_format(monkeypatch):
-    """The staged_blocked_pallas2 queue probe's exact composition in
-    miniature: 2-bit blocked planes (p = 2 packed plane pairs, the
-    J1644 production format) with fused two-pass legs across the staged
-    (a)/(b) boundary, at the smallest in-window leg (n = 2^26,
-    M = n/4 = 2^24 per plane)."""
-    import numpy as np
-
-    from srtb_tpu.config import Config
-    from srtb_tpu.pipeline.segment import SegmentProcessor, \
-        waterfall_to_numpy
-
-    cfg = Config(
-        baseband_input_count=1 << 26,
-        baseband_input_bits=2,
-        baseband_format_type="simple",
-        baseband_freq_low=1405.0,
-        baseband_bandwidth=64.0,
-        baseband_sample_rate=128e6,
-        dm=30.0,
-        spectrum_channel_count=1 << 10,
-        mitigate_rfi_average_method_threshold=1e9,
-        mitigate_rfi_spectral_kurtosis_threshold=1e9,
-        baseband_reserve_sample=False,
-    )
-    rng = np.random.default_rng(37)
-    raw = rng.integers(0, 256, cfg.segment_bytes(1), dtype=np.uint8)
-    monkeypatch.setenv("SRTB_STAGED_BLOCKED", "1")
-    monkeypatch.delenv("SRTB_STAGED_ROWS_IMPL", raising=False)
-    base = waterfall_to_numpy(
-        SegmentProcessor(cfg, staged=True).process(raw)[0])
-    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "pallas2")
-    proc = SegmentProcessor(cfg, staged=True)
-    assert proc._staged_impl() == "pallas2_interpret"
-    got = waterfall_to_numpy(proc.process(raw)[0])
-    np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-4)
+    cfg = Config(baseband_input_count=1 << 14, baseband_input_bits=2,
+                 baseband_format_type="simple", baseband_freq_low=1405.0,
+                 baseband_bandwidth=64.0, baseband_sample_rate=128e6,
+                 dm=30.0, spectrum_channel_count=1 << 5,
+                 baseband_reserve_sample=False)
+    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", impl)
+    with pytest.raises(ValueError, match="'xla' or 'pallas'"):
+        SegmentProcessor(cfg, staged=True)
+    assert not SegmentProcessor(cfg, staged=False).staged
 
 
 # ------------------------------------------- the served loop's reader
@@ -653,22 +523,31 @@ def test_reader_ahead_matches_the_loop_that_pulls_by_itself(
         np.testing.assert_array_equal(series_a, series_b)
     for rec in recs_a + recs_b:
         ms = rec["stages_ms"]
-        assert ms["ingest_wait"] >= 0 and ms["ingest"] >= 45
+        # the pull's span holds the stub's sleep, which never returns
+        # early, wherever the pull ran (no limit on this machine's
+        # clock: what a loaded host adds has no bound, and `< ingest +
+        # 5` on the wait failed beside five busy workers, PR 50)
+        assert ms["ingest_wait"] >= 0
+        assert ms["ingest"] >= 1e3 * src_a.pull_s - 1e-3
         # the wait is the tail of the segment's own pull: the pull is
         # counted once
         assert telemetry.segment_wall(ms) == pytest.approx(
             ms["ingest"] + ms["dispatch"] + ms["fetch"] + ms["sink"])
     assert all(r["stages_ms"]["ingest_wait"] == 0 for r in recs_a)
-    # pulled ahead, under the loop's dispatch and fetch: the loop waited
-    # for the rest of the pull at most (and the hand-over)
-    assert all(r["stages_ms"]["ingest_wait"] < r["stages_ms"]["ingest"] + 5
-               for r in recs_b[3:])
+    # a wait is journalled where the reader made the pull and nowhere
+    # else: the three the loop pulled by itself waited for nobody
+    assert all(r["stages_ms"]["ingest_wait"] == 0 for r in recs_b[:3])
 
 
-def test_max_segments_bounds_the_pulls_of_a_reader_ahead(tmp_path):
+def test_max_segments_bounds_the_pulls_of_a_reader_ahead(tmp_path,
+                                                         monkeypatch):
+    from srtb_tpu.pipeline import runtime
     from srtb_tpu.utils.bufferpool import BufferPool
     from srtb_tpu.utils.metrics import metrics
 
+    # engaged at the first period the loop reads, by construction (the
+    # count below is of pulls 3, 4, 5), not by this machine's load
+    monkeypatch.setattr(runtime, "_PULL_AHEAD_SHARE", 0.0)
     metrics.reset()
     cfg = _ahead_cfg(tmp_path, "bound")
     pool = BufferPool("test_ahead")
@@ -723,7 +602,11 @@ def test_two_runs_on_one_pipeline_leave_no_reader_thread(tmp_path,
     metrics.reset()
 
 
-def test_a_pull_that_raises_on_the_reader_raises_out_of_run(tmp_path):
+def test_a_pull_that_raises_on_the_reader_raises_out_of_run(tmp_path,
+                                                           monkeypatch):
+    from srtb_tpu.pipeline import runtime
+
+    monkeypatch.setattr(runtime, "_PULL_AHEAD_SHARE", 0.0)
     cfg = _ahead_cfg(tmp_path, "raises")
     source, sink = SlowFile(cfg, raise_at=5), _BytesSink()
     with Pipeline(cfg, source=source, sinks=[sink]) as pipe:

@@ -121,19 +121,6 @@ def test_epilogue_oracle_parity(plan):
     _assert_quality_parity(proc, cfg, raw, plan)
 
 
-def test_epilogue_oracle_parity_ffuse(monkeypatch):
-    """The front-fused staged megakernel computes the same quality
-    vector (the epilogue rides its folded spectrum tail)."""
-    monkeypatch.setenv("SRTB_STAGED_ROWS_IMPL", "pallas2")
-    cfg = _proc_cfg(baseband_input_count=1 << 16,
-                    spectrum_channel_count=8, front_fuse="on")
-    raw = np.random.default_rng(11).integers(
-        0, 256, size=cfg.segment_bytes(1), dtype=np.uint8)
-    proc = SegmentProcessor(cfg, staged=True)
-    assert proc.front_fuse
-    _assert_quality_parity(proc, cfg, raw, "ffuse")
-
-
 def test_quality_off_is_none():
     """quality_stats off: the epilogue is an exact no-op and existing
     consumers see the None pytree subtree."""

@@ -247,3 +247,31 @@ def test_config_knobs_registered_in_field_sets():
     assert cfg.deterministic_timestamps is True
     assert cfg.set_option("periodicity_fold_bins", "32")
     assert cfg.periodicity_fold_bins == 32
+
+
+def test_the_ladder_walks_these_steps_in_this_order():
+    """The rungs by name (the `front_fuse` rung went in PR 50 with the
+    family it dropped): what `plan_ladder auto` walks, and what an
+    operator's explicit list is checked against."""
+    assert registry.ladder_order() == (
+        "quality", "search_mode", "micro_batch", "ring", "skzap",
+        "fused_tail", "staged", "monolithic")
+    with pytest.raises(ValueError, match="front_fuse"):
+        registry.ladder_step("front_fuse")
+    with pytest.raises(ValueError):
+        parse_ladder("micro_batch,front_fuse")
+
+
+def test_every_family_has_a_card_and_every_card_a_family():
+    """The registry and the checked-in plan cards name the same plans
+    (24 since PR 50): a family without a card is unaudited, a card
+    without a family pins a plan nothing can build."""
+    baseline = HA.CardBaseline.load(HA.DEFAULT_BASELINE)
+    assert sorted(baseline.cards) == sorted(registry.plan_keys())
+    assert len(baseline.cards) == 24
+    retired = {"staged_ffuse", "staged_ffuse_ring", "staged_pallas2",
+               "staged_pallas2_unfused"}
+    assert not retired & set(registry.plan_keys())
+    # a family's environment chooses among what is left
+    assert {spec.env.get("SRTB_STAGED_ROWS_IMPL", "xla")
+            for spec in registry.plan_families()} == {"xla", "pallas"}

@@ -169,3 +169,35 @@ def test_two_ring_segments_against_the_float64_chain(shape, staged_here):
         assert ts.size == ts_o.size
         np.testing.assert_allclose(ts, ts_o, atol=2e-4 * np.abs(ts_o).max(),
                                    rtol=2e-3)
+
+
+@pytest.mark.parametrize("bits", [1, 2, 4, 8])
+def test_a_warm_dispatch_is_the_cold_one_bit_for_bit(bits, staged_here):
+    """The second of two overlapped segments through the ring (the
+    carry of the first and its own new bytes, by strips) and as a whole
+    upload: the same waterfall, series, counts and next carry, bit for
+    bit, at every width the blocks take (a row of the bytes' view is
+    ``2 * channels * bits / 8`` bytes; the sub-byte widths had no ring
+    case)."""
+    cfg = _config("2p17_c7").replace(baseband_input_bits=bits)
+    proc = SegmentProcessor(cfg)
+    assert proc.plan_name == "staged:monolithic+rows+ring"
+    row = proc.ring_row_bytes
+    assert row == 2 * proc.channel_count * bits // 8
+    assert proc.reserved_bytes % row == 0 < proc.reserved_bytes
+    stream = np.random.default_rng(60 + bits).integers(
+        0, 256, proc.stride_bytes * 2 + proc.reserved_bytes, dtype=np.uint8)
+    seg_bytes, stride = proc._segment_bytes, proc.stride_bytes
+    segs = [stream[k * stride:k * stride + seg_bytes] for k in (0, 1)]
+    _first, carry = proc.run_device_cold(proc.stage_input(segs[0]))
+    (wf_w, det_w), carry_w = proc.run_device_ring(
+        carry, proc.stage_input(segs[1], stride_only=True))
+    (wf_c, det_c), carry_c = proc.run_device_cold(proc.stage_input(segs[1]))
+    np.testing.assert_array_equal(np.asarray(wf_w), np.asarray(wf_c))
+    assert np.abs(np.asarray(wf_c)).max() > 0
+    np.testing.assert_array_equal(np.asarray(carry_w), np.asarray(carry_c))
+    np.testing.assert_array_equal(np.asarray(carry_w).reshape(-1),
+                                  segs[1][stride:])
+    for got, want in zip(det_w, det_c):
+        if isinstance(got, jax.Array):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

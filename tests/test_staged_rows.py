@@ -242,3 +242,77 @@ def test_the_blocked_plan_is_the_whole_plane_plan(staged_at_this_size,
                         lambda self: 0)
     a_whole = np.asarray(jax.jit(blocked._stage_a)(jnp.asarray(raw)))
     assert np.array_equal(a_rows, a_whole)
+
+
+# ------------------------------- every width the blocks take, windowed
+# and zapped (the parity matrix of the front-fused staged family, gone in
+# PR 50, pointed at the plan two cells run)
+
+SMALL_LOG2N = 16
+
+
+@pytest.fixture
+def staged_at_2p16(monkeypatch):
+    monkeypatch.setattr(segment, "STAGED_MIN_N", 1 << SMALL_LOG2N)
+    monkeypatch.setattr(segment, "FUSED_TAIL_DF64_MAX_SPECTRUM",
+                        1 << (SMALL_LOG2N - 4))
+
+
+@pytest.mark.parametrize("what", ["plain", "hamming", "zapped"])
+@pytest.mark.parametrize("bits", [1, 2, 4, 8])
+def test_blocks_against_whole_planes_at_every_width(bits, what,
+                                                    staged_at_2p16,
+                                                    monkeypatch):
+    """`staged:monolithic+rows` against the whole-plane stages
+    (`_stage_a_nat` / `_stage_b_nat` / `_stage_c_nat`) on one pulsed
+    segment of 1-, 2-, 4- and 8-bit simple samples: as it comes, under
+    a Hamming window (which stage (a) multiplies in block by block and
+    stage (c) divides out of each channel), and with two manual zap
+    ranges, one at the band's edge (a block compares its own bin
+    indices where the whole planes take a mask)."""
+    n = 1 << SMALL_LOG2N
+    cfg = _config(baseband_input_count=n, baseband_input_bits=bits,
+                  spectrum_channel_count="2 ** 3",
+                  dm=-478.8 / (1 << (30 - SMALL_LOG2N)) * 64,
+                  signal_detect_max_boxcar_length=64).replace(
+        mitigate_rfi_freq_list="1436.5-1437, 1418-1422"
+        if what == "zapped" else "")
+    window = "hamming" if what == "hamming" else "rectangle"
+    if what == "hamming":
+        # a channel's 2^12 time samples keep the segment-long window's
+        # shape, which s2 at the source's 1.05 takes for RFI in every
+        # channel: out of the way, so that every channel is compared
+        cfg = cfg.replace(mitigate_rfi_spectral_kurtosis_threshold=1e9)
+    # (a pulse the source's own thresholds leave channels around: 12
+    # sigma in 2^12 time samples a channel is zapped as RFI by s2)
+    raw = synth.make_dispersed_baseband(
+        n, cfg.baseband_freq_low, cfg.baseband_bandwidth, cfg.dm,
+        [n // 2], nbits=bits, pulse_amp=3.0, seed=50 + bits)
+    blocked = SegmentProcessor(cfg, window_name=window)
+    assert blocked.plan_name == "staged:monolithic+rows"
+    assert blocked.staged_rows == 8
+    assert blocked._stage_a_block_rows() == {1: 4, 2: 2, 4: 1, 8: 1}[bits]
+    assert (blocked.rfi_mask is None) and \
+        bool(blocked.rfi_bins) == (what == "zapped")
+    wf_b, det_b = blocked.process(raw)
+    monkeypatch.setattr(F, "block_count", lambda *a, **kw: 0)
+    whole = SegmentProcessor(cfg, window_name=window)
+    assert whole.plan_name == "staged:monolithic"
+    assert (whole.rfi_mask is not None) == (what == "zapped")
+    wf_w, det_w = whole.process(raw)
+    wf_b, wf_w = np.asarray(wf_b), np.asarray(wf_w)
+    assert wf_b.shape == wf_w.shape == (2, 1, 8, n // 16)
+    assert ((wf_b == 0) == (wf_w == 0)).all()
+    assert (wf_w == 0).mean() < 1
+    # the waterfall divides the window out again: 1 / 0.087 at a
+    # Hamming window's edges, on both sides' rounding
+    tol = 2e-5 if what == "hamming" else 2e-6
+    assert np.max(np.abs(wf_b - wf_w)) < tol * np.max(np.abs(wf_w))
+    assert check.series_gap(np.asarray(det_b.time_series),
+                            np.asarray(det_w.time_series)) < 1e-4
+    assert np.array_equal(np.asarray(det_b.zero_count),
+                          np.asarray(det_w.zero_count))
+    assert np.array_equal(np.asarray(det_b.signal_counts),
+                          np.asarray(det_w.signal_counts))
+    if what != "hamming":       # (the window's shape hides 3 sigma)
+        assert int(np.asarray(det_w.signal_counts).sum()) > 0

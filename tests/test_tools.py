@@ -349,25 +349,6 @@ def test_plot_dm_curve(tmp_path):
     assert data[:8] == b"\x89PNG\r\n\x1a\n"
 
 
-def test_pallas2_pin_loud_at_dispatch(monkeypatch):
-    """An SRTB_PALLAS2_N1 pin that cannot fit the actual segment size
-    must fail loudly at the dispatch fallback (ops/fft and the staged
-    plan) instead of silently benchmarking the non-pallas2 path — while
-    the unpinned tiny-config fallback stays quiet."""
-    import jax.numpy as jnp
-    import numpy as np
-    import pytest
-
-    from srtb_tpu.ops import fft as F
-
-    z = jnp.asarray(np.zeros(1 << 13, np.complex64))
-    # unpinned: quiet fallback (the documented tiny-config path)
-    F._pallas2_or_fallback(z, "pallas2_interpret")
-    monkeypatch.setenv("SRTB_PALLAS2_N1", "8192")
-    with pytest.raises(ValueError, match="SRTB_PALLAS2_N1"):
-        F._pallas2_or_fallback(z, "pallas2_interpret")
-
-
 def test_waterfall_service_per_receiver_stream_id(tmp_path):
     """data_stream_id names the PANE for per-receiver (S=1) segments —
     it must not be used as an S index (found live: MultiUdpSource

@@ -118,3 +118,75 @@ def test_unpack_float64_bit_decode_without_x64():
         if g == 0.0 and abs(float(vals[i])) < 2.0 ** -126:
             continue  # f32-subnormal doubles flush to 0 (documented)
         raise AssertionError((i, vals[i], w, g))
+
+
+# ------------------------------------------------------------------
+# the plane forms the plans really call: sub-byte samples as blocked
+# field planes (`unpack_subbyte_planes`, with `subbyte_window_planes`),
+# whole bytes dealt out every count-th sample (`ops/fft.deal_planes`,
+# with `window_planes`).  The width table of the Pallas unpack kernels
+# (gone in PR 50: no chip compiled them), on what a chip runs.
+
+@pytest.mark.parametrize("windowed", [False, True])
+@pytest.mark.parametrize("nbits", [1, 2, 4])
+def test_subbyte_planes_hold_field_k_of_every_byte(nbits, windowed):
+    from srtb_tpu.ops import fft as F
+
+    count = 8 // nbits
+    m = 1 << 10
+    data = np.random.default_rng(30 + nbits).integers(
+        0, 256, size=m, dtype=np.uint8)
+    planes = U.unpack_subbyte_planes(jnp.asarray(data), nbits)
+    assert planes.shape == (count, m) and planes.dtype == jnp.float32
+    samples = U.unpack_oracle(data, nbits)             # sample order
+    want = samples.reshape(m, count).T                 # [k, b]
+    if windowed:
+        window = np.hamming(count * m).astype(np.float32)
+        w_planes = F.subbyte_window_planes(window, nbits)
+        assert w_planes.shape == (count, m) and w_planes.flags.c_contiguous
+        planes = planes * jnp.asarray(w_planes)
+        want = (samples * window).reshape(m, count).T
+        np.testing.assert_allclose(np.asarray(planes), want, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(planes), want)
+
+
+def test_subbyte_planes_keep_a_leading_axis():
+    data = np.random.default_rng(34).integers(
+        0, 256, size=(3, 256), dtype=np.uint8)
+    planes = np.asarray(U.unpack_subbyte_planes(jnp.asarray(data), 2))
+    assert planes.shape == (3, 4, 256)
+    for s in range(3):
+        np.testing.assert_array_equal(
+            planes[s], U.unpack_oracle(data[s], 2).reshape(256, 4).T)
+
+
+@pytest.mark.parametrize("count,lead,dtype", [
+    (2, (), np.uint8),          # the even/odd pack's two parts, as bytes
+    (4, (), np.uint8),          # two plane pairs (2^28 one-byte samples)
+    (2, (3,), np.float32),      # floats, a leading axis
+    (4, (2,), np.float32),
+])
+def test_deal_planes_hands_out_every_count_th_sample(count, lead, dtype):
+    from srtb_tpu.ops import fft as F
+
+    n = 8 * count * 128
+    x = np.random.default_rng(40 + count).integers(
+        0, 256, size=(*lead, n)).astype(dtype)
+    planes = F.deal_planes(jnp.asarray(x), count)
+    assert len(planes) == count
+    for j, plane in enumerate(planes):
+        assert plane.shape == (*lead, n // count) and plane.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(plane), x[..., j::count])
+    # the window dealt out the same way multiplies the same samples
+    window = np.hamming(n).astype(np.float32)
+    w_planes = F.window_planes(window, count)
+    for j in range(count):
+        np.testing.assert_array_equal(w_planes[j], window[j::count])
+
+
+def test_deal_planes_refuses_what_fills_no_whole_rows():
+    from srtb_tpu.ops import fft as F
+
+    with pytest.raises(ValueError, match="rows of 512"):
+        F.deal_planes(jnp.zeros(4 * 128 + 4, jnp.uint8), 4)
